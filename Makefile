@@ -1,7 +1,7 @@
 # Convenience targets; the source of truth for the tier-1 line is
 # ROADMAP.md ("Tier-1 verify"), mirrored in scripts/verify.sh.
 
-.PHONY: verify analyze lint test bench perfcheck perfreport
+.PHONY: verify analyze lint test bench chipsmoke perfcheck perfreport
 
 # The pre-merge gate: static analysis + the full tier-1 suite with the
 # DOTS_PASSED count the driver compares against the seed.
@@ -23,9 +23,15 @@ lint: analyze
 test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly
 
-# The benchmark harness (never crashes; one FINAL JSON line).
+# The benchmark harness: one final JSON line; needs the chip and exits
+# non-zero without one (python bench.py --force-cpu runs it on the CPU).
 bench:
 	python bench.py
+
+# The served path end to end on one TPU chip (fails at once without one;
+# from a sandbox: chiprun -- python chip_smoke.py).
+chipsmoke:
+	python chip_smoke.py
 
 # The perf regression gate: the latest bench_history.jsonl record vs the
 # rolling same-backend median. Nonzero exit on throughput regression or

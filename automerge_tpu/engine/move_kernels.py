@@ -30,8 +30,9 @@ Three implementations, the repo's standard parity-pinned triple:
                            `resolve_moves_pallas` drives it round by
                            round — loop control stays outside, like the
                            span kernels keep their sort in XLA).
-                           Interpret-mode parity on CPU; hardware runs
-                           ride the staged TPU probe.
+                           Interpret-mode parity on CPU, compiled for the
+                           chip at D > 1 (tests/test_chip_compile.py);
+                           unrouted.
 
 Every implementation returns the same schema: ``ptr`` (winner index per
 node; == cand_cnt when the base edge wins), ``parent`` (the resolved
@@ -51,12 +52,9 @@ import jax.numpy as jnp
 
 from .pack import MOVE_PRIO_PAD, pack_moves  # noqa: F401  (re-export)
 
-try:  # pallas is TPU/GPU-oriented; keep imports soft for CPU test runs
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+
+from .pallas_kernels import doc_block_spec
 
 F_MASK, F_BASE, F_OFF, F_CNT = range(4)
 F_PARENT, F_HI, F_LO = range(3)
@@ -256,8 +254,8 @@ def _one_hot_gather(values, idx, n):
 
 def _move_round_kernel(n_pad: int, k_pad: int, steps: int):
     def kernel(nodes_ref, cands_ref, ptr_ref, out_ref):
-        nodes = nodes_ref[:][0]          # [4, N]
-        cands = cands_ref[:][0]          # [3, K]
+        nodes = nodes_ref[:]             # [4, N]
+        cands = cands_ref[:]             # [3, K]
         ptr = ptr_ref[:]                 # [1, N]
         mask = nodes[F_MASK:F_MASK + 1, :] > 0
         base = nodes[F_BASE:F_BASE + 1, :]
@@ -299,16 +297,16 @@ def _move_round_kernel(n_pad: int, k_pad: int, steps: int):
         out = jnp.concatenate([drop.astype(jnp.int32),
                                unresolved.astype(jnp.int32),
                                parent], axis=0)
-        out_ref[:] = out.reshape(1, 3, n_pad)
+        out_ref[:] = out
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def move_round_pallas(nodes, cands, ptr, interpret: bool = False):
+def move_round_pallas(nodes, cands, ptr, interpret: bool | None = None):
     """One fixpoint round for every document: returns [D, 3, N_pad] int32
     lanes (drop mask, unresolved mask, tentative parent)."""
-    if not HAVE_PALLAS:  # pragma: no cover — CPU images always have it
-        raise RuntimeError("pallas unavailable in this jax build")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     d, _f, n_pad = nodes.shape
     k_pad = cands.shape[2]
     if n_pad > PALLAS_MAX_NODES:
@@ -316,25 +314,20 @@ def move_round_pallas(nodes, cands, ptr, interpret: bool = False):
                          f"node lanes (got {n_pad}); route larger realms "
                          "through resolve_moves (XLA)")
     steps = _ceil_log2(n_pad) + 1
-    out = pl.pallas_call(
+    spec = doc_block_spec
+    return pl.pallas_call(
         _move_round_kernel(n_pad, k_pad, steps),
         grid=(d,),
-        in_specs=[pl.BlockSpec((1, 4, n_pad), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((1, 3, k_pad), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((1, n_pad), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((1, 3, n_pad), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
+        in_specs=[spec((4, n_pad)), spec((3, k_pad)), spec((1, n_pad))],
+        out_specs=spec((3, n_pad)),
         out_shape=jax.ShapeDtypeStruct((d, 3, n_pad), jnp.int32),
         interpret=interpret,
     )(jnp.asarray(nodes, jnp.int32), jnp.asarray(cands, jnp.int32),
-      jnp.asarray(ptr, jnp.int32))
-    return out
+      jnp.asarray(ptr, jnp.int32)[:, None, :])
 
 
-def resolve_moves_pallas(packed: dict, interpret: bool = False) -> dict:
+def resolve_moves_pallas(packed: dict,
+                         interpret: bool | None = None) -> dict:
     """Full resolution driven through the pallas round kernel (loop
     control on the host, like the span plane keeps its sort in XLA).
     Same schema as resolve_moves_host."""

@@ -31,7 +31,8 @@ Three implementations, parity-pinned against each other
                        VMEM-resident bitonic sort is not worth its code
                        size at these span counts). Optional acceleration
                        path in the dominated_pallas mold: interpret-mode
-                       parity on CPU, standalone entry for hardware runs.
+                       parity on CPU, compiled for the chip at D > 1
+                       (tests/test_chip_compile.py); unrouted.
 """
 
 from __future__ import annotations
@@ -46,12 +47,7 @@ import jax.numpy as jnp
 from .kernels import _mix4
 from .pack import SPAN_FIELDS, pack_spans  # noqa: F401  (re-export)
 
-try:  # pallas is TPU/GPU-oriented; keep imports soft for CPU test runs
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -140,13 +136,13 @@ def sort_spans(spans):
 # Pallas variant: rank + hash over pre-sorted span lanes
 
 # int32 wraparound murmur finalizer — the ONE definition lives in
-# pallas_kernels (imports cleanly on CPU; its pallas imports are soft)
-from .pallas_kernels import _mix4_i32  # noqa: E402
+# pallas_kernels
+from .pallas_kernels import _mix4_i32, doc_block_spec  # noqa: E402
 
 
 def _rank_hash_kernel(s_pad: int):
     def kernel(x_ref, starts_ref, agg_ref):
-        rows = x_ref[:][0]                    # [F, S_pad]
+        rows = x_ref[:]                       # [F, S_pad]
         mask = rows[F_MASK:F_MASK + 1, :] > 0         # [1, S]
         vis = jnp.where(mask, rows[F_VIS:F_VIS + 1, :], 0)
         # exclusive prefix sum along the lane axis by doubling: log2(S)
@@ -172,28 +168,25 @@ def _rank_hash_kernel(s_pad: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def span_rank_hash_pallas(sorted_spans, interpret: bool = False):
+def span_rank_hash_pallas(sorted_spans, interpret: bool | None = None):
     """Rank + hash over PRE-SORTED span lanes (sort_spans), one grid step
     per document, the whole table VMEM-resident. Returns (starts
     [D, S_pad] int32 in MERGED order, hash [D] uint32, total [D] int32).
     Matches merge_spans bit for bit on the hash (tests pin it in
-    interpret mode; hardware validation rides the staged TPU probe)."""
-    if not HAVE_PALLAS:  # pragma: no cover — CPU images always have it
-        raise RuntimeError("pallas unavailable in this jax build")
+    interpret mode; chip_smoke.py's parity stage runs it on the chip)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     d, f, s_pad = sorted_spans.shape
+    spec = doc_block_spec
     starts, agg = pl.pallas_call(
         _rank_hash_kernel(s_pad),
         grid=(d,),
-        in_specs=[pl.BlockSpec((1, f, s_pad), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=[pl.BlockSpec((1, s_pad), lambda i: (i, 0),
-                                memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, 128), lambda i: (i, 0),
-                                memory_space=pltpu.VMEM)],
-        out_shape=[jax.ShapeDtypeStruct((d, s_pad), jnp.int32),
-                   jax.ShapeDtypeStruct((d, 128), jnp.int32)],
+        in_specs=[spec((f, s_pad))],
+        out_specs=[spec((1, s_pad)), spec((1, 128))],
+        out_shape=[jax.ShapeDtypeStruct((d, 1, s_pad), jnp.int32),
+                   jax.ShapeDtypeStruct((d, 1, 128), jnp.int32)],
         interpret=interpret,
     )(sorted_spans)
-    return (starts,
-            jax.lax.bitcast_convert_type(agg[:, 0], jnp.uint32),
-            agg[:, 1])
+    return (starts[:, 0],
+            jax.lax.bitcast_convert_type(agg[:, 0, 0], jnp.uint32),
+            agg[:, 0, 1])

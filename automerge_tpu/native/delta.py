@@ -239,3 +239,9 @@ class NativeDeltaEncoder:
 
 def native_delta_available() -> bool:
     return _lib() is not None
+
+
+def native_delta_error() -> str | None:
+    """Why the encoder did not load (None when it did)."""
+    _lib()
+    return _state.get("error")

@@ -20,6 +20,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.encode import encode_doc, stack_docs
@@ -163,20 +164,15 @@ _SHARDED_ROWS_CACHE: dict = {}
 
 def _sharded_bytes_fn(mesh: Mesh, meta: tuple, dims: tuple,
                       interpret: bool):
-    # the Mesh itself is the cache key (ADVICE r4, mesh.py:156): its
-    # __eq__/__hash__ compare axis names/shape and the actual Device
-    # objects, so a new Mesh over a restarted backend can never alias a
-    # cached fn bound to dead devices the way id(mesh) could
+    # the Mesh itself is the cache key: its __eq__/__hash__ compare axis
+    # names/shape and the actual Device objects, so a new Mesh over a
+    # restarted backend can never alias a cached fn bound to dead devices
+    # the way id(mesh) could
     key = ("bytes", mesh, meta, dims, interpret)
     fn = _SHARDED_ROWS_CACHE.get(key)
     if fn is not None:
         return fn
     import jax.numpy as jnp
-
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     from ..engine.pack import apply_rows_hash_compact
 
@@ -193,13 +189,8 @@ def _sharded_bytes_fn(mesh: Mesh, meta: tuple, dims: tuple,
                                                    dims, interpret)
 
     spec = P(None, DOCS_AXIS, None)
-    try:
-        sm = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=P(DOCS_AXIS), check_vma=False)
-    except TypeError:
-        sm = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=P(DOCS_AXIS), check_rep=False)
-    fn = jax.jit(sm)
+    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=P(DOCS_AXIS), check_vma=False))
     _SHARDED_ROWS_CACHE[key] = fn
     return fn
 
@@ -213,24 +204,13 @@ def _sharded_rows_fn(mesh: Mesh, dims: tuple, interpret: bool):
         return fn
     from functools import partial
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     from ..engine.pallas_kernels import reconcile_rows_hash
 
     body = partial(reconcile_rows_hash.__wrapped__, dims=dims,
                    interpret=interpret)
-    # replication/vma checks off: pallas_call's out_shape carries no
-    # varying-mesh-axes annotation; the out_spec states the sharding
-    # explicitly. (kwarg renamed check_rep -> check_vma across jax versions)
-    try:
-        sm = shard_map(body, mesh=mesh, in_specs=P(None, DOCS_AXIS),
-                       out_specs=P(DOCS_AXIS), check_vma=False)
-    except TypeError:
-        sm = shard_map(body, mesh=mesh, in_specs=P(None, DOCS_AXIS),
-                       out_specs=P(DOCS_AXIS), check_rep=False)
-    fn = jax.jit(sm)
+    # vma check off: pallas_call's out_shape carries no varying-mesh-axes
+    # annotation; the out_spec states the sharding explicitly
+    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P(None, DOCS_AXIS),
+                           out_specs=P(DOCS_AXIS), check_vma=False))
     _SHARDED_ROWS_CACHE[key] = fn
     return fn

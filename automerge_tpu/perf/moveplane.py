@@ -119,7 +119,7 @@ def smoke_main(argv=None) -> int:
     xla = {k: np.asarray(v)
            for k, v in resolve_moves(packed["nodes"],
                                      packed["cands"]).items()}
-    pls = resolve_moves_pallas(packed, interpret=True)
+    pls = resolve_moves_pallas(packed)
     wptr, _wd = _resolve_walk(p)
     parity = ((host["ptr"] == xla["ptr"]).all()
               and (host["hash"] == xla["hash"]).all()

@@ -1,0 +1,191 @@
+"""Every kernel family compiled for a described TPU v5e, without the chip.
+
+The suite runs on the CPU, where the Pallas kernels run in the interpreter;
+what the chip's own compiler refuses (a block shape off the (8, 128) tiling,
+a working set past the scoped VMEM) no interpret-mode test can see. The TPU
+compiler is installed here and compiles for a chip that is described and not
+attached, so each program of the served path is lowered and compiled at the
+shapes the 10,000-document chip_smoke.py produces (its CPU rehearsal printed
+them: resident dims (512, 4, 64) over 10,112 lanes, storm buckets (8, 4, 0)
+over 1,024 and 2,048 lanes, span and move tables [8, ., 128]).
+
+Nothing runs and no time is implied: a compile that passes is not a chip
+run. The topology is described inside a fixture, never at import (only one
+process at a time may load the TPU's library, and every xdist worker imports
+this file), and all cases live in this one file so one worker owns the
+library.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from automerge_tpu.engine.pack import rows_count
+
+HBM_BYTES = 16 * 1024 ** 3     # one v5e chip
+
+FLEET_LANES = 10_112           # pad_to_lanes(10,044 documents)
+CAPS = (512, 4, 64)            # the smoke's resident (I, A, LE)
+
+
+def _dims(i, a, le):
+    from automerge_tpu.engine.encode import A_DEL, A_SET
+    return (i, a, le, int(A_SET), int(A_DEL))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+class Chip:
+    """Shapes placed on the described chip: `one(shape)` on a single
+    device, `meshed(shape, spec)` over the four-device mesh."""
+
+    def __init__(self, topo):
+        self._one = SingleDeviceSharding(topo.devices[0])
+        self.mesh = Mesh(topo.devices, ("docs",))
+
+    def one(self, shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self._one)
+
+    def meshed(self, shape, spec):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.int32, sharding=NamedSharding(self.mesh, spec))
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    return Chip(topo)
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _megakernel(i, a, le, lanes, force_xl=False):
+    def build(chip):
+        from automerge_tpu.engine.pallas_kernels import reconcile_rows_hash
+        return reconcile_rows_hash.lower(
+            chip.one((rows_count(i, a, le), lanes)), _dims(i, a, le), False,
+            force_xl=force_xl)
+    return build
+
+
+def _apply_final_fleet(chip):
+    from automerge_tpu.engine.resident_rows import _apply_final
+    return _apply_final.lower(
+        chip.one((rows_count(*CAPS), FLEET_LANES)), chip.one((1024, 3)),
+        _dims(*CAPS), False)
+
+
+def _scan_rounds_fleet(chip):
+    from automerge_tpu.engine.resident_rows import _scan_rounds
+    return _scan_rounds.lower(
+        chip.one((rows_count(*CAPS), FLEET_LANES)), chip.one((2, 1024, 3)),
+        _dims(*CAPS), False)
+
+
+def _merge_spans(chip):
+    from automerge_tpu.engine.span_kernels import merge_spans
+    return merge_spans.lower(chip.one((8, 8, 128)))
+
+
+def _span_rank_hash_pallas(chip):
+    from automerge_tpu.engine.span_kernels import span_rank_hash_pallas
+    return span_rank_hash_pallas.lower(chip.one((8, 8, 256)),
+                                       interpret=False)
+
+
+def _resolve_moves(chip):
+    from automerge_tpu.engine.move_kernels import resolve_moves
+    return resolve_moves.lower(chip.one((8, 4, 128)), chip.one((8, 3, 128)))
+
+
+def _move_round_pallas(chip):
+    from automerge_tpu.engine.move_kernels import move_round_pallas
+    return move_round_pallas.lower(
+        chip.one((8, 4, 128)), chip.one((8, 3, 128)), chip.one((8, 128)),
+        interpret=False)
+
+
+def _dominated_pallas(chip):
+    from automerge_tpu.engine.pallas_kernels import dominated_pallas
+    row = chip.one((32, 512))
+    return dominated_pallas.lower(
+        chip.one((32, 512, 8)), row, row, row, row,
+        chip.one((32, 512), jnp.bool_), interpret=False)
+
+
+def _apply_doc_reference(chip):
+    """The XLA reference of chip_smoke's parity stage, one chunk of small
+    documents (a 10,000-document batch compiles too, in 20 s)."""
+    import __graft_entry__ as graft
+    from automerge_tpu.engine.kernels import apply_doc
+    batch, max_fids = graft._example_batch(2)
+    shapes = {k: chip.one((1024,) + v.shape[1:], v.dtype)
+              for k, v in batch.items()}
+    return jax.jit(lambda b: apply_doc(b, max_fids, host_order=True)
+                   ).lower(shapes)
+
+
+def _sharded_megakernel(chip):
+    from automerge_tpu.parallel.mesh import DOCS_AXIS, _sharded_rows_fn
+    fn = _sharded_rows_fn(chip.mesh, _dims(*CAPS), False)
+    return fn.lower(chip.meshed((rows_count(*CAPS), 4 * 256),
+                                P(None, DOCS_AXIS)))
+
+
+# name -> (builder, whether the program holds a Pallas kernel)
+CASES = {
+    "megakernel-base-storm-bucket": (_megakernel(8, 4, 0, 2048), True),
+    "megakernel-base-caps-one-block": (_megakernel(*CAPS, 128), True),
+    # refused before this file existed: two grid steps double-buffer the
+    # input block past the default scoped VMEM
+    "megakernel-base-caps-fleet": (_megakernel(*CAPS, FLEET_LANES), True),
+    "megakernel-xl": (_megakernel(512, 8, 128, 256), True),
+    "megakernel-xl-forced": (
+        _megakernel(1024, 8, 512, 128, force_xl=True), True),
+    "apply_final-fleet": (_apply_final_fleet, True),
+    "scan_rounds-fleet": (_scan_rounds_fleet, True),
+    "merge_spans": (_merge_spans, False),
+    "resolve_moves": (_resolve_moves, False),
+    # refused at D > 1 before their blocks squeezed the docs axis
+    "span_rank_hash_pallas-8-docs": (_span_rank_hash_pallas, True),
+    "move_round_pallas-8-docs": (_move_round_pallas, True),
+    "dominated_pallas": (_dominated_pallas, True),
+    "apply_doc-reference": (_apply_doc_reference, False),
+    "sharded-megakernel-4-devices": (_sharded_megakernel, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_v5e(case, chip, no_compile_cache):
+    build, has_kernel = CASES[case]
+    compiled = build(chip).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{case}: {used} bytes on one device"
+    assert ("tpu_custom_call" in compiled.as_text()) == has_kernel, (
+        f"{case}: Pallas kernel in the compiled program: {not has_kernel}")

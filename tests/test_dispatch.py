@@ -65,29 +65,6 @@ def test_adaptive_small_batch_returns_host_docs():
         assert am.equals(got, want)
 
 
-def test_calibrate_from_profile_partial_and_full():
-    from automerge_tpu.engine import dispatch as dp
-
-    before = dict(dp._LINK)
-    try:
-        applied = dp.calibrate_from_profile({
-            "h2d_ms_by_mb": {"0.001": 12.0, "1": 14.0, "20": 52.0},
-            "d2h_512B_ms": 70.0,
-            "tiny_dispatch_plus_readback_ms": 95.0,
-        })
-        assert applied["h2d_call_s"] == 0.012
-        assert abs(applied["h2d_bytes_per_s"] - 19e6 / 0.038) < 1e3
-        assert applied["d2h_call_s"] == 0.07
-        assert abs(applied["dispatch_fixed_s"] - 0.025) < 1e-9
-        for k, v in applied.items():
-            assert dp._LINK[k] == v
-        # partial profile only touches what it has
-        applied2 = dp.calibrate_from_profile({"d2h_512B_ms": 10.0})
-        assert set(applied2) == {"d2h_call_s"}
-    finally:
-        dp.calibrate(**before)
-
-
 def _trace_concurrent(n_per_actor=100):
     """Merged multi-actor doc: get_missing_changes emits per-actor runs
     whose deps cross runs — NOT causal application order."""

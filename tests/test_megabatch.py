@@ -10,7 +10,7 @@ hashes by design.
 
 Routing is cost-model driven and the baked-in link constants price
 dispatches at TPU PCIe cost, so service-level tests recalibrate to
-CPU-scale constants (fixture) and grow the resident caps with one large
+CPU-scale constants (the `cpu_link` fixture of conftest.py) and grow the resident caps with one large
 doc so a small-doc storm's fused subset gather beats the classic
 full-layout gather — the regime ROADMAP #2 targets, reproduced small.
 """
@@ -24,18 +24,6 @@ from automerge_tpu.core.ids import ROOT_ID
 from automerge_tpu.engine import dispatch, dispatchledger, pack
 from automerge_tpu.sync.service import EngineDocSet
 from automerge_tpu.utils import metrics
-
-
-@pytest.fixture
-def cpu_link():
-    """CPU-scale link constants so the planner's wire comparison (not
-    the TPU round-trip tax) decides routing; restored after."""
-    keys = ("dispatch_fixed_s", "h2d_call_s", "d2h_call_s")
-    saved = {k: dispatch._LINK[k] for k in keys}
-    dispatch.calibrate(dispatch_fixed_s=1e-5, h2d_call_s=1e-6,
-                       d2h_call_s=1e-5)
-    yield
-    dispatch.calibrate(**saved)
 
 
 def eager(svc):

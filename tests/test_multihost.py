@@ -12,33 +12,42 @@ import sys
 
 import pytest
 
-import jax.distributed
-
-# The whole module drives jax.distributed workers; some images ship a jax
-# whose distributed module lacks is_initialized (parallel/multihost.py's
-# idempotence guard — the workers die with AttributeError before ever
-# syncing). Inherited breakage, not a code defect: skip with the reason
-# on those images instead of failing tier-1 (ROADMAP "carried small
-# debts"; the tests run wherever the API exists).
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.distributed, "is_initialized"),
-    reason="jax.distributed.is_initialized missing in this jax build "
-           "(multihost init guard cannot run; see ROADMAP.md #5)")
+_next_port = 0
 
 
 def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+    """A port the workers can bind seconds from now. Asking the kernel
+    for port 0 and closing the socket hands the number back to the
+    ephemeral pool, where any other test's connection can take it before
+    a worker has imported jax; so probe BELOW the ephemeral range, which
+    the kernel never gives out on its own, starting from an offset of
+    this process's own."""
+    global _next_port
+    try:
+        with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+            top = int(f.read().split()[0])
+    except (OSError, ValueError):
+        top = 32768
+    lo = max(top - 12000, 1024)
+    for _ in range(top - lo):
+        port = lo + (os.getpid() * 64 + _next_port) % (top - lo)
+        _next_port += 1
+        with socket.socket() as s:
+            try:
+                s.bind(("127.0.0.1", port))
+            except OSError:
+                continue
+            return port
+    raise RuntimeError("no free port below the ephemeral range")
 
 
 def _run_workers(worker_file: str, ok_marker: str, extra_env=None):
     worker = os.path.join(os.path.dirname(__file__), worker_file)
     coord, sync = _free_port(), _free_port()
     env = dict(os.environ)
-    # the workers pin their own platform/device-count; scrub inherited
-    # settings that would fight them
-    env.pop("JAX_PLATFORMS", None)
+    # a child never takes an accelerator its parent may hold; the workers
+    # set their own device count
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     env.update(extra_env or {})
 
@@ -101,7 +110,7 @@ def test_four_process_hub_sync_and_global_mesh():
                           "multihost_ring_worker.py")
     coord, sync = _free_port(), _free_port()
     env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
 
     nprocs = 4

@@ -9,12 +9,7 @@ import pytest
 
 import jax
 
-pl_mod = pytest.importorskip("jax.experimental.pallas")
-
-from automerge_tpu.engine.pallas_kernels import HAVE_PALLAS, dominated_pallas  # noqa: E402
-
-if not HAVE_PALLAS:
-    pytest.skip("pallas unavailable", allow_module_level=True)
+from automerge_tpu.engine.pallas_kernels import dominated_pallas
 
 
 def reference_dominated(clock_op, actor, fid, seq, change_idx, amask):

@@ -52,25 +52,42 @@ def test_append_load_roundtrip_tolerates_torn_tail(tmp_path):
     assert [r["value"] for r in recs] == [100, 110]
 
 
-def test_backfill_from_committed_bench_captures(tmp_path):
-    """The committed BENCH_r0*.json trajectory seeds the ledger: captures
-    with a parsed final record become history records (backend-labeled),
-    crashed rounds are skipped."""
-    recs = history.backfill_records(str(ROOT))
-    assert len(recs) >= 3
-    assert all(r["source"].startswith("backfill:BENCH_r0") for r in recs)
-    assert all(r["value"] > 0 for r in recs)
-    assert {"cpu", "tpu"} >= {r["backend"] for r in recs}
+def test_backfill_from_bench_captures(tmp_path):
+    """`BENCH_r0*.json` driver captures beside the ledger seed it: captures
+    with a parsed final record become history records (backend-labeled,
+    in filename order), crashed rounds are skipped. The captures are
+    written here — the committed ones are history, not test fixtures."""
+    import json
+
+    def capture(name, parsed):
+        (tmp_path / name).write_text(json.dumps(
+            {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": "",
+             "parsed": parsed}))
+
+    capture("BENCH_r01.json", {
+        "metric": "ops", "value": 5000, "unit": "ops/sec",
+        "vs_baseline": 1.5, "backend": "cpu", "configs": {"1": 1.2}})
+    capture("BENCH_r02.json", {
+        "metric": "ops", "value": 9000, "unit": "ops/sec",
+        "vs_baseline": 2.5, "backend": "tpu",
+        "configs": {"1": {"speedup": 1.4}, "5": {"speedup": 9.0}}})
+    capture("BENCH_r03.json", None)                  # a crashed round
+    (tmp_path / "BENCH_r04.json").write_text("{torn")   # a torn file
+
+    recs = history.backfill_records(str(tmp_path))
+    assert [r["value"] for r in recs] == [5000, 9000]
+    assert [r["source"] for r in recs] == ["backfill:BENCH_r01.json",
+                                           "backfill:BENCH_r02.json"]
+    assert [r["backend"] for r in recs] == ["cpu", "tpu"]
     # per-config speedups normalize to dicts for both record shapes
-    some = [r for r in recs if r["configs"]]
-    assert some and all(
-        isinstance(v, dict) for r in some for v in r["configs"].values())
+    assert all(isinstance(v, dict)
+               for r in recs for v in r["configs"].values())
 
     p = str(tmp_path / "h.jsonl")
-    n = history.ensure_backfilled(str(ROOT), p)
+    n = history.ensure_backfilled(str(tmp_path), p)
     assert n == len(recs) == len(history.load(p))
     # a second call never rewrites existing history
-    assert history.ensure_backfilled(str(ROOT), p) == 0
+    assert history.ensure_backfilled(str(tmp_path), p) == 0
 
 
 def test_record_from_bench_aggregates_compile_counts():

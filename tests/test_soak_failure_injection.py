@@ -64,7 +64,7 @@ def test_soak_random_dispatch_failures_converge(seed, monkeypatch):
     rset = e._resident
     if rset._native is None:
         pytest.skip("python-encoder fallback has no dispatch stage")
-    # this soak targets the DISPATCH failure taxonomy (the TPU posture:
+    # this soak targets the DISPATCH failure classes (the TPU posture:
     # eager per-flush dispatch + cached hash handles); pin lazy off so the
     # CPU service default doesn't bypass the machinery under test, and pin
     # megabatch off so the fused round route (r20 — host-mirror

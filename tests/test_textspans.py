@@ -291,8 +291,11 @@ def _run_divergent(instrs):
 
 if HAVE_HYPOTHESIS:
 
+    # span_plane only patches a module constant, the same for every
+    # example, so hypothesis's function-scoped-fixture check does not apply
     @settings(max_examples=40, deadline=None,
-              suppress_health_check=[HealthCheck.too_slow])
+              suppress_health_check=[HealthCheck.too_slow,
+                                     HealthCheck.function_scoped_fixture])
     @given(st.lists(_instr, min_size=1, max_size=25))
     def test_property_span_merge_equals_perop_replay(span_plane, instrs):
         a, b = _run_divergent(instrs)

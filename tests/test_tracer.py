@@ -359,12 +359,17 @@ def test_tcp_stitch_one_trace_covers_both_processes():
     client = TcpSyncClient(b, server.host, server.port).start()
     try:
         # warm the converged-hash path so the JIT compile does not land
-        # inside the measured trace
-        a.apply_columns("warm", _cols(cold, 1, "w", 0))
+        # inside the measured trace — on doc1 itself, with an unsampled
+        # write: a document the peer already holds is framed by the
+        # writer's own gossip, so the hot write's trace is handed to the
+        # wire before apply returns. (A document new to the peer needs an
+        # advert round trip first, and this thread's hash poll below could
+        # complete the trace locally before the frame left.)
+        a.apply_columns("doc1", _cols(cold, 1, "w", 0))
         deadline = time.perf_counter() + 30.0
         while time.perf_counter() < deadline:
             ha, hb = a.hashes(), b.hashes()
-            if "warm" in ha and "warm" in hb:
+            if "doc1" in ha and "doc1" in hb:
                 break
             time.sleep(0.02)
 
