@@ -1,0 +1,75 @@
+"""Many seeds of one cell in one process: the proof runs of `correct`.
+
+    python3 benchmarks/prove.py --workload <cell> --seeds 1,2,3 --seconds 5
+    python3 benchmarks/prove.py --workload <cell> --seeds 1,2,3 --seconds 5 --control 1
+
+Without `--control` each seed is a whole run of the program (`run_cell`, as
+`run.py` makes it) and has to come out correct. With it, each seed is run
+once for every guarantee `reference.RefService` can break, the reference in
+the program's place at the cell's own size and load, and each has to come
+out not correct. One line a run: the seed, `correct` and the numbers
+compared. Exit code 0 only if every run came out as it has to. The
+benchmark's own runs never call this; it is how the limits of PERF.md
+section 2 were read, and how a later PR reads them again.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import io
+import json
+import sys
+
+import run
+import reference
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True)
+    ap.add_argument("--seconds", type=float, default=5.0)
+    ap.add_argument("--control", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    cell = run.fleetlib.load_json("workloads", args.workload)
+    try:
+        devices = run.claim_devices(cell["chips"])
+    except run.RunFailed as e:
+        print(f"benchmarks/prove.py: {e}", file=sys.stderr)
+        return 2
+    kinds = [k for k in reference.BROKEN if k != "none"] \
+        if args.control else [None]
+    ok = True
+    for seed in (int(s) for s in args.seeds.split(",")):
+        for kind in kinds:
+            def steer(svc, kind=kind):
+                if kind is None:
+                    return None
+                svc.close()
+                return reference.RefService(kind)
+            stages = io.StringIO()
+            with contextlib.redirect_stdout(stages):
+                try:
+                    res = run.run_cell(args.workload, seed, args.seconds, 0,
+                                       devices, steer=steer)
+                except run.RunFailed as e:
+                    res = {"correct": None, "error": str(e), "compared": {},
+                           "attempted": 0, "failed": 0, "metrics": {}}
+            want = kind is None
+            ok = ok and res["correct"] is want
+            print(json.dumps({
+                "seed": seed, "control": kind, "correct": res["correct"],
+                "as_it_has_to": res["correct"] is want,
+                "attempted": res["attempted"], "failed": res["failed"],
+                "compared": {k: v["value"]
+                             for k, v in res["compared"].items()},
+                "error": res.get("error"),
+                "metrics": {k: round(v["value"], 3)
+                            for k, v in res["metrics"].items()}}),
+                flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
