@@ -29,6 +29,8 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass, field
 
+from ..utils import perfscope
+
 # Link cost model (seconds). Not measured on the chip (ROADMAP S3).
 _LINK = {
     "dispatch_fixed_s": 0.025,   # per jitted dispatch (amortizable)
@@ -217,6 +219,7 @@ class RoundPlan:
     est_alt_s: float = 0.0
 
 
+@perfscope.phased("route")
 def plan_round(rset, idxs) -> RoundPlan:
     """Round-level routing for the dirty docs `idxs` of a resident set:
     bucket their exact used sizes (band scans — correct across
@@ -262,7 +265,7 @@ def apply_round_adaptive(rset, plan: RoundPlan, interpret: bool = False):
         return None
     import numpy as np
 
-    from ..utils import metrics, perfscope
+    from ..utils import metrics
     from . import dispatchledger
     from .pack import mega_row_map, pad_to_lanes
     from .pallas_kernels import reconcile_rows_hash
@@ -291,15 +294,15 @@ def apply_round_adaptive(rset, plan: RoundPlan, interpret: bool = False):
         with perfscope.phase("pack"):
             sub = rset.rows_host[np.ix_(rmap, sel)]
         rows_b = len(rmap)
+        sub_dev = rset._to_dev(sub)
         with dispatchledger.call_scope(
                 "rows_mega", backend="device", docs=k,
                 axes={"docs": (k, k_pad), "rows": (rows_b, rows_b)}):
             h = metrics.dispatch_jit(
                 "reconcile_rows_hash", reconcile_rows_hash,
-                rset._to_dev(sub), (i_b, a, le_b, a_set, a_del),
-                interpret)
+                sub_dev, (i_b, a, le_b, a_set, a_del), interpret)
         with perfscope.phase("readback"):
-            vals = np.asarray(h)
+            vals = rset._to_host(h)
         mirror[np.asarray(docs, np.int64)] = vals[:k]
         rset._doc_dirty.difference_update(docs)
         logical += rows_b * k

@@ -773,7 +773,8 @@ class ResidentDocSet:
                  d_ins, d_ins_n, d_nl, d_nl_n]
         meta = tuple((p.shape, int(np.prod(p.shape))) for p in parts)
         flat = np.concatenate([p.astype(np.int32).ravel() for p in parts])
-        return jnp.asarray(flat), meta
+        with perfscope.phase("upload"):
+            return jnp.asarray(flat), meta
 
     # ------------------------------------------------------------------
     def apply_and_reconcile(self, changes_by_doc: dict[str, list[Change]],

@@ -1044,9 +1044,19 @@ class ResidentRowsDocSet(ResidentDocSet):
 
     def _to_dev(self, arr):
         """Upload pinned to this instance's device (None = default)."""
-        if self.device is not None:
-            return jax.device_put(arr, self.device)
-        return jnp.asarray(arr)
+        with perfscope.phase("upload"):
+            if self.device is not None:
+                return jax.device_put(arr, self.device)
+            return jnp.asarray(arr)
+
+    @staticmethod
+    def _to_host(handle) -> np.ndarray:
+        """Read a device array back, inside the caller's `readback`
+        phase: the wait for the device (`device_wait`) and then the
+        copy. One synchronisation point, as np.asarray alone was."""
+        with perfscope.phase("device_wait"):
+            handle.block_until_ready()
+        return np.asarray(handle)
 
     def _mark_trips_dirty(self, trip_list) -> set:
         """Hash invalidation for the lanes a batch of scatter triplets
@@ -1069,6 +1079,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         if pre_rows is not None:
             self.rows_dev = self._to_dev(pre_rows)
             self._dirty = False
+        stacked_dev = self._to_dev(stacked)
         with dispatchledger.call_scope(
                 "rows_scan", backend="device", docs=len(touched),
                 axes={"docs": (len(self.doc_ids), self.n_pad),
@@ -1077,11 +1088,10 @@ class ResidentRowsDocSet(ResidentDocSet):
                                     default=1), p)}):
             self.rows_dev, hashes = metrics.dispatch_jit(
                 "scan_rounds", _scan_rounds,
-                self.rows_dev, self._to_dev(stacked), self.dims(),
-                interpret)
+                self.rows_dev, stacked_dev, self.dims(), interpret)
         self._hash_handle = None
         with perfscope.phase("readback"):
-            vals = np.asarray(hashes)
+            vals = self._to_host(hashes)
         # the FINAL round's row is the canonical post-batch hash table:
         # adopt it so the next hashes() read is free (flush-time capture)
         self._adopt_full_hashes(vals[-1])
@@ -1327,38 +1337,44 @@ class ResidentRowsDocSet(ResidentDocSet):
         # on a 2K-doc node (same pathology core/bulkload.py documents).
         from ..utils.gcpause import gc_paused
         with gc_paused():
-            for rc in rounds:
-                self._register_round_actors(rc)
-            self._precheck_round_frames(rounds)
+            with perfscope.phase("encode"):
+                for rc in rounds:
+                    self._register_round_actors(rc)
+                self._precheck_round_frames(rounds)
             # steady-state fast path: ONE vectorized admission + native
             # encode for the whole micro-batch; falls back to per-round
             # encode (full protocol handling) when any change breaks the
             # per-doc in-order chain shape
             with self._admission_guard():
-                enc_all = self._encode_rounds_batched(rounds)
-                if enc_all is not None:
-                    metrics.bump("rows_rounds_batched", len(rounds))
-                    encoded = [enc_all]
-                else:
-                    if any(rc.cols.n_changes for rc in rounds):
-                        metrics.bump("rows_rounds_fallback", len(rounds))
-                    encoded = [self._encode_round_frame(rc) for rc in rounds]
-                self._grow_for_rounds(encoded)
-                # r20 megabatch intent: an eager round dirtying enough
-                # docs skips the full-buffer device apply (and its
-                # pre-round host copy) — the dirty lanes reconcile
-                # through the fused bucketed dispatches instead, planned
-                # AFTER the trips commit so bucket shapes see this
-                # round's ops (engine/dispatch.py plan_round)
-                mega = (not self.lazy_dispatch
-                        and round_dispatch.megabatch_enabled()
-                        and len({d for rc in rounds for d in rc.doc_ids})
-                        >= round_dispatch.megabatch_min_docs())
-                need_pre = (not self.lazy_dispatch and not mega
-                            and (self._dirty or self.rows_dev is None))
-                pre_rows = self.rows_host.copy() if need_pre else None
-                trip_list = [self._cols_triplets(e) for e in encoded]
-                self._mega_intent = mega
+                with perfscope.phase("encode"):
+                    enc_all = self._encode_rounds_batched(rounds)
+                    if enc_all is not None:
+                        metrics.bump("rows_rounds_batched", len(rounds))
+                        encoded = [enc_all]
+                    else:
+                        if any(rc.cols.n_changes for rc in rounds):
+                            metrics.bump("rows_rounds_fallback",
+                                         len(rounds))
+                        encoded = [self._encode_round_frame(rc)
+                                   for rc in rounds]
+                with perfscope.phase("commit"):
+                    self._grow_for_rounds(encoded)
+                    # r20 megabatch intent: an eager round dirtying enough
+                    # docs skips the full-buffer device apply (and its
+                    # pre-round host copy) — the dirty lanes reconcile
+                    # through the fused bucketed dispatches instead,
+                    # planned AFTER the trips commit so bucket shapes see
+                    # this round's ops (engine/dispatch.py plan_round)
+                    mega = (not self.lazy_dispatch
+                            and round_dispatch.megabatch_enabled()
+                            and len({d for rc in rounds
+                                     for d in rc.doc_ids})
+                            >= round_dispatch.megabatch_min_docs())
+                    need_pre = (not self.lazy_dispatch and not mega
+                                and (self._dirty or self.rows_dev is None))
+                    pre_rows = self.rows_host.copy() if need_pre else None
+                    trip_list = [self._cols_triplets(e) for e in encoded]
+                    self._mega_intent = mega
                 with self._dispatch_guard():
                     return self._dispatch_final(trip_list, pre_rows,
                                                 interpret)
@@ -1844,7 +1860,8 @@ class ResidentRowsDocSet(ResidentDocSet):
         dispatches and the hashes return from the mirror."""
         mega = getattr(self, "_mega_intent", False)
         self._mega_intent = False
-        touched = self._mark_trips_dirty(trip_list)
+        with perfscope.phase("commit"):
+            touched = self._mark_trips_dirty(trip_list)
         if self.lazy_dispatch:
             # _cols_triplets already committed the round to the host
             # mirror; defer upload + reconcile to the next hash read —
@@ -1876,32 +1893,34 @@ class ResidentRowsDocSet(ResidentDocSet):
             out = np.zeros(self.n_pad, np.uint32)
             out[:n] = self._ensure_hash_mirror()[:n]
             return self._to_dev(out)
-        parts = [t for t in trip_list if len(t)]
-        if parts:
-            trips = np.concatenate(parts)
-            key = trips[:, 0].astype(np.int64) * self.n_pad + trips[:, 1]
-            # np.unique keeps the FIRST occurrence per key of the reversed
-            # array == the LAST write in round order
-            _, first = np.unique(key[::-1], return_index=True)
-            trips = trips[len(trips) - 1 - first]
-        else:
-            trips = np.zeros((0, 3), np.int32)
-        p = _pad_to(max(len(trips), 1), 8)
-        oob = self._bases()["rows"]
-        padded = np.zeros((p, 3), dtype=np.int32)
-        padded[:len(trips)] = trips
-        padded[len(trips):, 0] = oob
+        with perfscope.phase("commit"):
+            parts = [t for t in trip_list if len(t)]
+            if parts:
+                trips = np.concatenate(parts)
+                key = trips[:, 0].astype(np.int64) * self.n_pad \
+                    + trips[:, 1]
+                # np.unique keeps the FIRST occurrence per key of the
+                # reversed array == the LAST write in round order
+                _, first = np.unique(key[::-1], return_index=True)
+                trips = trips[len(trips) - 1 - first]
+            else:
+                trips = np.zeros((0, 3), np.int32)
+            p = _pad_to(max(len(trips), 1), 8)
+            oob = self._bases()["rows"]
+            padded = np.zeros((p, 3), dtype=np.int32)
+            padded[:len(trips)] = trips
+            padded[len(trips):, 0] = oob
         if pre_rows is not None:
             self.rows_dev = self._to_dev(pre_rows)
             self._dirty = False
+        padded_dev = self._to_dev(padded)
         with dispatchledger.call_scope(
                 "rows_apply", backend="device", docs=len(touched),
                 axes={"docs": (len(self.doc_ids), self.n_pad),
                       "trips": (max(len(trips), 1), p)}):
             self.rows_dev, h = metrics.dispatch_jit(
                 "apply_final", _apply_final,
-                self.rows_dev, self._to_dev(padded), self.dims(),
-                interpret)
+                self.rows_dev, padded_dev, self.dims(), interpret)
         self._hash_handle = h  # polling hashes() between deltas is free
         return h
 
@@ -1944,7 +1963,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             # already show this thread entered the readback
             flightrec.record("rows_hash_readback", docs=n, cached=True)
             with perfscope.phase("readback"):
-                vals = np.asarray(self._hash_handle)
+                vals = self._to_host(self._hash_handle)
             mirror[:n] = vals[:n]
             self._hash_handle = None   # consumed into the mirror
             self._doc_dirty.clear()
@@ -1986,7 +2005,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                     self.rows_dev, self.dims(), interpret)
             flightrec.record("rows_hash_readback", docs=n, cached=False)
             with perfscope.phase("readback"):
-                vals = np.asarray(h)
+                vals = self._to_host(h)
             mirror[:n] = vals[:n]
             self._hash_handle = None
             self._doc_dirty.clear()
@@ -2034,15 +2053,16 @@ class ResidentRowsDocSet(ResidentDocSet):
         sel = np.asarray(idxs + [idxs[-1]] * (k_pad - k), np.int64)
         with perfscope.phase("pack"):
             sub = np.ascontiguousarray(self.rows_host[:, sel])
+        sub_dev = self._to_dev(sub)
         with dispatchledger.call_scope(
                 "rows_hash", backend="device", docs=k,
                 axes={"docs": (k, k_pad)}):
             h = metrics.dispatch_jit(
                 "reconcile_rows_hash", reconcile_rows_hash,
-                self._to_dev(sub), self.dims(), interpret)
+                sub_dev, self.dims(), interpret)
         flightrec.record("rows_hash_readback", docs=k, cached=False)
         with perfscope.phase("readback"):
-            vals = np.asarray(h)
+            vals = self._to_host(h)
         self._hash_mirror[np.asarray(idxs, np.int64)] = vals[:k]
         self._doc_dirty.difference_update(idxs)
 

@@ -1,7 +1,6 @@
 """automerge_tpu.perf — the performance plane's tooling package.
 
-`python -m automerge_tpu.perf {report,check,contention,doctor,top,
-roofline,resident}`:
+`python -m automerge_tpu.perf {report,check,contention,doctor,top}`:
 
 - `report`   — print the bench-history trajectory (`bench_history.jsonl`)
                plus the latest run's perf telemetry when available.
@@ -14,11 +13,10 @@ roofline,resident}`:
 - `top`      — live terminal dashboard over the fleet collector
                (fleet.py: scrape over `{"metrics": "pull"}`, straggler
                detection; slo.py: the SLO verdict strip).
-- `roofline` — HBM-roofline probe for the rows megakernel (the former
-               repo-root `profile_roofline.py`, now packaged; the script
-               remains as a thin shim).
-- `resident` — stage breakdown of the round-frame resident ingress (the
-               former `profile_resident.py`, likewise packaged).
+
+A kernel's share of its roofline and the stage breakdown of a served
+request are measured on the chip by the benchmark (`benchmarks/`: the
+`trace_roofline` reader, and perfscope's phases on the profiler's clock).
 
 The runtime half of the performance plane (compile telemetry, phase
 attribution, memory gauges) lives in `automerge_tpu/utils/perfscope.py`;

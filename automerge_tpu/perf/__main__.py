@@ -1,6 +1,6 @@
 """CLI for the performance plane: `python -m automerge_tpu.perf
 {report,check,contention,doctor,explain,top,dispatch,tenant,trace,
-remediate,roofline,resident}` (docs/OBSERVABILITY.md "Performance
+remediate}` (docs/OBSERVABILITY.md "Performance
 plane" / "Contention & convergence lag" / "Fleet health" / "Per-doc
 ledger & perf explain" / "Remediation plane" / "Dispatch-efficiency
 ledger" / "Tenant attribution plane" / "Trace plane").
@@ -234,18 +234,9 @@ def main(argv=None) -> int:
         # against the AMTPU_MEGABATCH=0 path, occupancy asserted
         from . import megabatchplane
         return megabatchplane.smoke_main(rest)
-    if cmd == "roofline":
-        from . import roofline
-        roofline.main(rest)
-        return 0
-    if cmd == "resident":
-        from . import resident
-        resident.main(rest)
-        return 0
     print(f"unknown command {cmd!r}; expected one of "
           "report, check, contention, doctor, explain, top, dispatch, "
-          "tenant, trace, remediate, move, bootstrap, race, megabatch, "
-          "roofline, resident",
+          "tenant, trace, remediate, move, bootstrap, race, megabatch",
           file=sys.stderr)
     return 2
 

@@ -52,7 +52,7 @@ import threading
 import time
 import zlib
 
-from ..utils import flightrec, metrics
+from ..utils import flightrec, metrics, perfscope
 from . import tenantledger
 
 #: stripes per buffer (power of two; bounds stripe-lock contention for
@@ -85,12 +85,17 @@ class Ticket:
     both sides — both measured as wake-latency tax on a 2-core host).
     Single-waiter by construction: one writer per ingress."""
 
-    __slots__ = ("doc_id", "exc", "t0", "claimed", "_done", "_lk")
+    __slots__ = ("doc_id", "exc", "t0", "claimed", "ctx", "_done", "_lk")
 
-    def __init__(self, doc_id: str, claimed: bool = False):
+    def __init__(self, doc_id: str, claimed: bool = False,
+                 ctx: dict | None = None):
         self.doc_id = doc_id
         self.exc: BaseException | None = None
         self.t0 = time.perf_counter()
+        # the caller's trace context (metrics.current_context()): the
+        # flush that carries this entry adopts the first rider's, so its
+        # spans share the request's trace id across the flusher thread
+        self.ctx = ctx
         # claimed=True: a writer thread is committed to waiting on this
         # ticket and will run the admission gossip itself after it wakes
         # (synchronous apply_*; set before the entry is published so no
@@ -136,9 +141,10 @@ class Ticket:
         a flusher that died mid-window cannot strand waiters — each poll
         re-kicks the flusher, which re-spawns it if needed."""
         if not self._done:
-            while not self._lk.acquire(timeout=poll_s):
-                if alive_fn is not None:
-                    alive_fn()
+            with perfscope.phase("commit_wait"):
+                while not self._lk.acquire(timeout=poll_s):
+                    if alive_fn is not None:
+                        alive_fn()
         if self.exc is not None:
             raise self.exc
 
@@ -170,10 +176,11 @@ class EpochIngestBuffer:
     def _stripe_of(self, doc_id: str) -> _Stripe:
         return self._stripes[zlib.crc32(doc_id.encode()) % self._n]
 
-    def append(self, doc_id: str, cols, tok, claimed: bool = False) -> Ticket:
+    def append(self, doc_id: str, cols, tok, claimed: bool = False,
+               ctx: dict | None = None) -> Ticket:
         """Buffer one ingress; returns the Ticket the writer waits on.
         Takes only the stripe lock — never the service lock."""
-        ticket = Ticket(doc_id, claimed=claimed)
+        ticket = Ticket(doc_id, claimed=claimed, ctx=ctx)
         entry = Entry(doc_id, cols, tok, ticket)
         s = self._stripe_of(doc_id)
         with s.lock:

@@ -60,7 +60,7 @@ FRAME_MAGIC = b"AMW1"
 #
 # Cross-replica trace propagation (docs/OBSERVABILITY.md): every protocol
 # message MAY carry a `"trace"` key holding the sender's span context in
-# the compact form `<trace_id>-<span_id>` (hex, 16+8 chars). The receiver
+# the compact form `<trace_id>-<span_id>` (hex, 24+16 chars). The receiver
 # adopts it (metrics.adopt_context) so its serving spans join the sender's
 # trace. It rides in the JSON part of the message — the plain-JSON wire and
 # the AMWM binary envelope's JSON head both carry it unchanged — and peers

@@ -23,6 +23,7 @@ whose `._doc.opset` exposes `clock` / `get_missing_changes`),
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time as _time
 from typing import Callable
@@ -36,6 +37,30 @@ from ..engine.resident_rows import CompactionAnchorError, DeviceDispatchError
 from ..utils import (chaos, flightrec, lockprof, metrics, oplag, perfscope,
                      tracer)
 from . import docledger, epochs, tenantledger
+
+
+_request_local = threading.local()
+
+
+@contextlib.contextmanager
+def request_span(tags: dict | None, **labels):
+    """The root span of one served request, `sync_request`: the outermost
+    batch(), or one ingress outside any batch. Every span the request
+    opens below it, the flusher thread's included (the epoch ticket
+    carries this context), shares its trace id. `tags` are its `docs`
+    and `ops`; a batch knows them only at its exit and sets them on the
+    span this yields. Where the thread is already inside a request (a
+    shard's batch under the sharded service's) it yields None and opens
+    nothing."""
+    if getattr(_request_local, "open", False):
+        yield None
+        return
+    _request_local.open = True
+    try:
+        with metrics.trace("sync_request", tags=tags, **labels) as span:
+            yield span
+    finally:
+        _request_local.open = False
 
 
 class _HandleOpSet:
@@ -817,7 +842,8 @@ class EngineDocSet:
             tracer.origin_ingress((c.actor, c.seq) for c in changes)
         if self.backend == "rows":
             from ..native.wire import changes_to_columns
-            return self._rows_ingest(doc_id, changes_to_columns(changes))
+            return self._rows_ingest(
+                doc_id, lambda: changes_to_columns(changes))
 
         def apply_fn():
             if self.live_views:
@@ -826,7 +852,9 @@ class EngineDocSet:
                 return diffs
             self._resident.apply_changes({doc_id: changes})
             return None
-        handle, _ = self._ingest(doc_id, apply_fn)
+        with request_span({"docs": 1,
+                           "ops": sum(len(c.ops) for c in changes)}):
+            handle, _ = self._ingest(doc_id, apply_fn)
         return handle
 
     def apply_columns(self, doc_id: str, cols) -> DocHandle:
@@ -840,7 +868,7 @@ class EngineDocSet:
                 (cols.actors[int(a)], int(s))
                 for a, s in zip(cols.change_actor, cols.change_seq))
         if self.backend == "rows":
-            return self._rows_ingest(doc_id, cols)
+            return self._rows_ingest(doc_id, lambda: cols)
 
         def apply_fn():
             if self.live_views:
@@ -852,7 +880,8 @@ class EngineDocSet:
             else:
                 self._resident.apply_changes({doc_id: cols.to_changes()})
             return None
-        handle, _ = self._ingest(doc_id, apply_fn)
+        with request_span({"docs": 1, "ops": len(cols.op_action)}):
+            handle, _ = self._ingest(doc_id, apply_fn)
         return handle
 
     # -- rows backend: coalesced round-frame ingress ------------------------
@@ -892,33 +921,60 @@ class EngineDocSet:
                 and self._batch_owner != threading.get_ident()
                 and not getattr(self._drain_local, "gossiping", False))
 
-    def _rows_ingest(self, doc_id: str, cols) -> DocHandle:
-        if self._epoch_admission_open():
-            return self._rows_ingest_epoch(doc_id, cols)
-        try:
-            with self._lock:
-                self.add_doc(doc_id)
-                rset = self._resident
-                i = rset.doc_index[doc_id]
-                if rset.ghost_eids[i]:
-                    # reject a ghost-anchored ingress HERE, before it
-                    # coalesces: only the offending sender's call errors,
-                    # never a round shared with innocent peers
-                    rset._check_ghost_anchors_cols(
-                        i, cols, 0, len(cols.op_action))
-                self._pending.setdefault(doc_id, []).append(cols)
-                tok = oplag.admit(doc_id)
-                tracer.admit(doc_id)
-                if tok is not None:
-                    self._lag_pending.append(tok)
-                if not self._batch_depth:
-                    self._flush_locked()
-                handle = self.get_doc(doc_id)
-        except BaseException:
-            self._drain_admitted_shielded()
-            raise
-        self._drain_admitted()
-        return handle
+    def _pending_size(self) -> tuple[int, int]:
+        """(documents, ops) of the coalesced round not yet flushed."""
+        return len(self._pending), sum(
+            len(c.op_action) for parts in self._pending.values()
+            for c in parts)
+
+    def _rows_ingest(self, doc_id: str, wire) -> DocHandle:
+        """`wire()` gives the ingress as wire columns. It is called inside
+        the `admit` phase, so that converting an ingress that arrived as
+        Change objects is admission time, and inside a batch, where a
+        storm admits a thousand changes, under the admission's one
+        entry."""
+        if self._batch_owner == threading.get_ident():
+            # inside this thread's batch(): the batch is the request, and
+            # its exit the flush and the drain (which defers while the
+            # batch is open), so the whole call is admission
+            with perfscope.phase("admit"), self._lock:
+                self._pend_locked(doc_id, wire())
+                return self.get_doc(doc_id)
+        with perfscope.phase("admit"):
+            cols = wire()
+        with request_span({"docs": 1, "ops": len(cols.op_action)},
+                          **self._metric_labels()):
+            if self._epoch_admission_open():
+                return self._rows_ingest_epoch(doc_id, cols)
+            try:
+                with self._lock:
+                    with perfscope.phase("admit"):
+                        self._pend_locked(doc_id, cols)
+                    if not self._batch_depth:
+                        self._flush_locked()
+                    handle = self.get_doc(doc_id)
+            except BaseException:
+                self._drain_admitted_shielded()
+                raise
+            self._drain_admitted()
+            return handle
+
+    def _pend_locked(self, doc_id: str, cols) -> None:
+        """Append one ingress to the coalesced round (under the service
+        lock)."""
+        self.add_doc(doc_id)
+        rset = self._resident
+        i = rset.doc_index[doc_id]
+        if rset.ghost_eids[i]:
+            # reject a ghost-anchored ingress HERE, before it coalesces:
+            # only the offending sender's call errors, never a round
+            # shared with innocent peers
+            rset._check_ghost_anchors_cols(i, cols, 0, len(cols.op_action))
+        self._pending.setdefault(doc_id, []).append(cols)
+        tok = oplag.admit(doc_id)
+        tracer.admit(doc_id)
+        if tok is not None:
+            self._lag_pending.append(tok)
 
     def _rows_ingest_epoch(self, doc_id: str, cols) -> DocHandle:
         """Lock-free-admission ingress: append into the striped epoch
@@ -954,32 +1010,37 @@ class EngineDocSet:
         see attach_governor), oplag-admit, one stripe-lock append, kick
         the flusher. Both the synchronous and the pipelined ingress
         park on the returned ticket via PendingIngress.wait, so the
-        wait/drain/re-raise contract lives in exactly one place."""
-        gov = self.ingress_governor
-        gov_delay = 0.0
-        if gov is not None:
-            # delay happens HERE — on the writer thread, before any
-            # buffer or lock is touched, so backpressure lands on the
-            # low-priority sender alone (shed mode raises instead; the
-            # change is re-offered by the sender's next advert cycle)
-            d = gov.admit(doc_id)
-            if d:
-                _time.sleep(d)
-                gov_delay = d
-        # chaos tenant-storm (utils/chaos.py): multiply ONE tenant's
-        # ingress rate by re-appending this batch's columns as extra
-        # un-waited epoch entries — duplicate changes dedup at admission
-        # (actor, seq), so the storm costs real flush/dispatch work
-        # without corrupting state. Inert (one cached check) unless
-        # AMTPU_CHAOS_TENANT_STORM is set.
-        extra = chaos.tenant_storm(self._chaos_node, doc_id)
-        tok = oplag.admit(doc_id)
-        # trace plane: bind this thread's finalized traces to the doc —
-        # governor park recorded, queue_wait opens here (utils/tracer.py)
-        tracer.admit(doc_id, delay_s=gov_delay)
-        ticket = self._epoch.append(doc_id, cols, tok, claimed=claimed)
-        for _ in range(extra):
-            self._epoch.append(doc_id, cols, None)
+        wait/drain/re-raise contract lives in exactly one place. The
+        ticket carries the caller's trace context, so the flusher's
+        spans join the request's trace."""
+        with perfscope.phase("admit"):
+            gov = self.ingress_governor
+            gov_delay = 0.0
+            if gov is not None:
+                # delay happens HERE — on the writer thread, before any
+                # buffer or lock is touched, so backpressure lands on the
+                # low-priority sender alone (shed mode raises instead; the
+                # change is re-offered by the sender's next advert cycle)
+                d = gov.admit(doc_id)
+                if d:
+                    _time.sleep(d)
+                    gov_delay = d
+            # chaos tenant-storm (utils/chaos.py): multiply ONE tenant's
+            # ingress rate by re-appending this batch's columns as extra
+            # un-waited epoch entries — duplicate changes dedup at
+            # admission (actor, seq), so the storm costs real
+            # flush/dispatch work without corrupting state. Inert (one
+            # cached check) unless AMTPU_CHAOS_TENANT_STORM is set.
+            extra = chaos.tenant_storm(self._chaos_node, doc_id)
+            tok = oplag.admit(doc_id)
+            # trace plane: bind this thread's finalized traces to the doc
+            # — governor park recorded, queue_wait opens here
+            # (utils/tracer.py)
+            tracer.admit(doc_id, delay_s=gov_delay)
+            ticket = self._epoch.append(doc_id, cols, tok, claimed=claimed,
+                                        ctx=metrics.current_context())
+            for _ in range(extra):
+                self._epoch.append(doc_id, cols, None)
         self._kick_or_flush()
         return ticket
 
@@ -1133,8 +1194,13 @@ class EngineDocSet:
             # loop against a persistent failure.
             if tickets and self._pending:
                 self._inflight_tickets = tickets
+                # the flush joins the trace of the first rider that has
+                # one: its spans, opened on this thread, then carry the
+                # request's id
+                ctx = next((t.ctx for t in tickets if t.ctx), None)
                 try:
-                    self._flush_locked()
+                    with metrics.adopt_context(ctx):
+                        self._flush_locked(riders=len(tickets))
                 except BaseException as e:
                     exc = e
                 finally:
@@ -1149,15 +1215,17 @@ class EngineDocSet:
     def _metric_labels(self) -> dict:
         return {"shard": self._shard} if self._shard is not None else {}
 
-    def _flush_locked(self) -> None:
+    def _flush_locked(self, riders: int | None = None,
+                      size: tuple[int, int] | None = None) -> None:
         """Apply every pending per-doc column batch as ONE round frame:
         the traced sync-round span plus per-round throughput accounting
-        around _flush_pending_locked (which does the work)."""
+        around _flush_pending_locked (which does the work). `riders` is
+        the number of epoch tickets the round carries (a span tag);
+        `size` is _pending_size() where the caller has it already."""
         if not self._pending:
             return
         labels = self._metric_labels()
-        n_ops = sum(len(c.op_action) for parts in self._pending.values()
-                    for c in parts)
+        _n_docs, n_ops = size or self._pending_size()
         self._round_seq += 1
         round_no = self._round_seq
         flightrec.record("round_flush", shard=self._shard, round=round_no,
@@ -1169,14 +1237,16 @@ class EngineDocSet:
                       if oplag.enabled() or tracer.enabled() else None)
         phases0 = perfscope.phase_totals() if toks else None
         t0 = _time.perf_counter()
-        with metrics.trace("sync_round_flush", tags={"round": round_no},
-                           **labels), \
+        tags = {"round": round_no}
+        if riders is not None:
+            tags["riders"] = riders
+        with metrics.trace("sync_round_flush", tags=tags, **labels), \
                 dispatchledger.round_scope(
                     len(self._pending),
                     label=(f"shard{self._shard}"
                            if self._shard is not None else None),
                     tenants=tenantledger.round_tenants(self._pending)):
-            self._flush_pending_locked()
+            self._flush_pending_locked(n_ops)
         if round_docs is not None:
             deltas = None
             if toks:
@@ -1190,23 +1260,16 @@ class EngineDocSet:
             self._lag_flushed.append(
                 (toks, round_docs, t0, _time.perf_counter() - t0, deltas,
                  round_no))
-        # failure paths raise out of the span (its timing still records).
-        # The swallowed mid-admission rebuild path restores the round to
-        # self._pending for retry — subtract those ops so throughput
-        # counters only see rounds whose changes reached truth (the retry
-        # flush counts them when they actually admit).
+        # failure paths raise out of the span (its timing still records);
+        # the round's throughput counters are bumped where its riders are
+        # released (_flush_pending_inner_locked)
         metrics.observe("sync_round_seconds", _time.perf_counter() - t0)
-        restored = sum(len(c.op_action) for parts in self._pending.values()
-                       for c in parts)
-        if restored < n_ops:
-            metrics.bump("sync_rounds_flushed", **labels)
-            metrics.bump("sync_ops_ingested", int(n_ops - restored),
-                         **labels)
 
-    def _flush_pending_locked(self) -> None:
-        """Apply every pending per-doc column batch as ONE round frame
-        through the streaming engine's batched admission; queue handler
-        notifications for the docs that admitted changes."""
+    def _flush_pending_locked(self, n_ops: int) -> None:
+        """Apply every pending per-doc column batch (`n_ops` ops in all)
+        as ONE round frame through the streaming engine's batched
+        admission; queue handler notifications for the docs that admitted
+        changes."""
         if not self._pending:
             return
         # chaos slow-apply (utils/chaos.py): an env-gated injected stall
@@ -1237,7 +1300,8 @@ class EngineDocSet:
                 return True
             return len(rset.change_log[rset.doc_index[d]]) > pre[d]
         try:
-            self._flush_pending_inner_locked(rset, pending, _changed)
+            self._flush_pending_inner_locked(rset, pending, _changed,
+                                             n_ops)
         finally:
             # a mid-flush rebuild swapped the engine internals: every
             # doc's log list was replaced, so the whole snapshot read
@@ -1279,7 +1343,8 @@ class EngineDocSet:
             self._clock_cache.pop(d, None)
             self._log_cache.pop(d, None)
 
-    def _flush_pending_inner_locked(self, rset, pending, _changed) -> None:
+    def _flush_pending_inner_locked(self, rset, pending, _changed,
+                                    n_ops: int) -> None:
         try:
             self._apply_with_compaction(rset, pending)
         except DeviceDispatchError as e:
@@ -1322,47 +1387,64 @@ class EngineDocSet:
             self._bump_read_vers_locked(
                 d for d in pending if _changed(d))
             raise
-        admitted = [d for d in pending if _changed(d)]
-        if self.doc_ledger is not None:
-            # per-doc admission stamps (counts only — the ledger's flush
-            # contract forbids clock reads here; lag restamps ride the
-            # read cache). Submitted-change counts, not post-dedup: the
-            # ledger's usefulness split happens at DELIVERY, this stamp
-            # marks frontier movement + recency.
+        with perfscope.phase("publish"):
+            admitted = [d for d in pending if _changed(d)]
+            if self.doc_ledger is not None:
+                # per-doc admission stamps (counts only — the ledger's flush
+                # contract forbids clock reads here; lag restamps ride the
+                # read cache). Submitted-change counts, not post-dedup: the
+                # ledger's usefulness split happens at DELIVERY, this stamp
+                # marks frontier movement + recency.
+                for d in admitted:
+                    self.doc_ledger.note_admit(
+                        d, sum(int(p.n_changes) for p in pending[d]))
             for d in admitted:
-                self.doc_ledger.note_admit(
+                tenantledger.note_ingress(
                     d, sum(int(p.n_changes) for p in pending[d]))
-        for d in admitted:
-            tenantledger.note_ingress(
-                d, sum(int(p.n_changes) for p in pending[d]))
-        if self.handlers:
-            # no registered handlers -> no notifications to queue: the
-            # post-flush drain then needs no service-lock reacquisition
-            # per admitted doc (measured as the residual service-lock
-            # traffic of the epoch admission path)
-            self._admit_notify.extend(admitted)
-        self._bump_read_vers_locked(admitted)
-        # Log-horizon auto-trigger: MUST run after `admitted` above —
-        # archiving shrinks change_log, and the length-based _changed is
-        # only sound before any archival of this flush's docs.
-        if self.log_horizon_changes is not None \
-                and getattr(rset, "log_archive", None) is not None:
-            for d in admitted:
-                i = rset.doc_index[d]
-                if len(rset.change_log[i]) > self.log_horizon_changes:
-                    floor = self._compaction_floor_locked(d)
-                    if floor:
-                        rset.archive_log_prefix(d, floor)
-        # Host admission (and any archival) is durable and the snapshot
-        # read plane re-keyed: the round's riding tickets can resolve
-        # NOW, overlapping the remaining flush tail (span/metric
-        # accounting, lock release) with the writers' wake-and-next-
-        # append window — on a 2-core host that serial wake chain was a
-        # measurable slice of every group-commit cycle. Notifications
-        # were queued above, so a woken writer's drain sees them; the
-        # archival runs BEFORE this, so apply's post-conditions (horizon
-        # set, RAM log bounded) hold the moment the writer returns.
-        self._early_resolve_locked()
+            if self.handlers:
+                # no registered handlers -> no notifications to queue: the
+                # post-flush drain then needs no service-lock reacquisition
+                # per admitted doc (measured as the residual service-lock
+                # traffic of the epoch admission path)
+                self._admit_notify.extend(admitted)
+            self._bump_read_vers_locked(admitted)
+            # Log-horizon auto-trigger: MUST run after `admitted` above —
+            # archiving shrinks change_log, and the length-based _changed is
+            # only sound before any archival of this flush's docs.
+            if self.log_horizon_changes is not None \
+                    and getattr(rset, "log_archive", None) is not None:
+                for d in admitted:
+                    i = rset.doc_index[d]
+                    if len(rset.change_log[i]) > self.log_horizon_changes:
+                        floor = self._compaction_floor_locked(d)
+                        if floor:
+                            rset.archive_log_prefix(d, floor)
+            # Host admission (and any archival) is durable and the snapshot
+            # read plane re-keyed: the round's riding tickets can resolve
+            # NOW, overlapping the remaining flush tail (span/metric
+            # accounting, lock release) with the writers' wake-and-next-
+            # append window — on a 2-core host that serial wake chain was a
+            # measurable slice of every group-commit cycle. Notifications
+            # were queued above, so a woken writer's drain sees them; the
+            # archival runs BEFORE this, so apply's post-conditions (horizon
+            # set, RAM log bounded) hold the moment the writer returns —
+            # and the round is counted BEFORE it, so a caller that returns
+            # already sees its ops in sync_ops_ingested. The swallowed
+            # mid-admission rebuild path above restored the round to
+            # self._pending for retry: those ops are subtracted, so the
+            # throughput counters only see changes that reached truth (the
+            # retry flush counts them when they actually admit).
+            restored = self._pending_size()[1]
+            if restored < n_ops:
+                labels = self._metric_labels()
+                metrics.bump("sync_rounds_flushed", **labels)
+                metrics.bump("sync_ops_ingested", int(n_ops - restored),
+                             **labels)
+            self._early_resolve_locked()
+            # the round's per-document column parts die here, inside the
+            # tail's phase and after the riders are released, not at the
+            # caller's frame exit where no span would see the time
+            pending.clear()
 
     def _apply_with_compaction(self, rset, pending: dict) -> None:
         """Apply one coalesced round; on VMEM-budget pressure, compact
@@ -1391,7 +1473,8 @@ class EngineDocSet:
         # AMTPU_MEGABATCH_MIN_DOCS — or on a cost-model loss — the engine
         # falls back to the per-doc-era dispatch paths; converged hashes
         # are byte-equal either way (tests/test_megabatch.py pins it).
-        round_ = round_from_parts(pending)
+        with perfscope.phase("encode"):
+            round_ = round_from_parts(pending)
         try:
             rset.apply_round_frames([round_])
         except RowsBudgetError:
@@ -1472,28 +1555,31 @@ class EngineDocSet:
         of small ingress allocations would otherwise trigger gen-2 scans
         over the whole service heap — measured at ~4x the round cost on a
         100K-doc fleet node."""
-        import contextlib
-
         from ..utils.gcpause import gc_paused
 
         @contextlib.contextmanager
         def _cm():
-            try:
-                with self._lock, gc_paused():
-                    prev_owner = self._batch_owner
-                    self._batch_owner = threading.get_ident()
-                    self._batch_depth += 1
-                    try:
-                        yield self
-                    finally:
-                        self._batch_depth -= 1
-                        self._batch_owner = prev_owner
-                        if not self._batch_depth:
-                            self._flush_locked()
-            except BaseException:
-                self._drain_admitted_shielded()
-                raise
-            self._drain_admitted()
+            with request_span(None, **self._metric_labels()) as span:
+                try:
+                    with self._lock, gc_paused():
+                        prev_owner = self._batch_owner
+                        self._batch_owner = threading.get_ident()
+                        self._batch_depth += 1
+                        try:
+                            yield self
+                        finally:
+                            self._batch_depth -= 1
+                            self._batch_owner = prev_owner
+                            if not self._batch_depth:
+                                size = self._pending_size()
+                                if span is not None:
+                                    span.tags = dict(zip(("docs", "ops"),
+                                                         size))
+                                self._flush_locked(size=size)
+                except BaseException:
+                    self._drain_admitted_shielded()
+                    raise
+                self._drain_admitted()
             # other threads' ingresses buffered while this batch held the
             # lock: hand them to the flusher now
             if self._epoch is not None and not self._epoch.empty() \
@@ -1517,27 +1603,27 @@ class EngineDocSet:
         hold time or round latency the contention plane exists to
         measure. Runs before handler gossip so every token is parked in
         the awaiting-wire table before its doc's message leaves."""
-        if self._commit_waits:
+        if not self._commit_waits and not self._lag_flushed:
+            # unlocked peek: nothing was flushed since the last drain
+            return
+        with perfscope.phase("publish"):
             with self._lock:
                 waits, self._commit_waits = self._commit_waits, []
+                batch, self._lag_flushed = self._lag_flushed, []
             for w in waits:
                 metrics.observe("sync_commit_wait_s", w)
-        if not self._lag_flushed:
-            return
-        with self._lock:
-            batch, self._lag_flushed = self._lag_flushed, []
-        for toks, round_docs, t0, flush_s, deltas, round_no in batch:
-            # retire stale awaiting tokens for docs this round re-flushed
-            # BEFORE parking the round's own tokens
-            oplag.flush_boundary(round_docs)
-            for tok in toks:
-                oplag.flushed(tok, flush_start=t0, flush_s=flush_s,
-                              phases=deltas)
-            # trace plane: the round's sampled lifecycle traces record
-            # queue_wait / coalesce_wait / dispatch and park in the
-            # awaiting-wire table — like the tokens above, BEFORE the
-            # handler gossip ships their docs' messages
-            tracer.flush_round(round_docs, round_no, t0, flush_s)
+            for toks, round_docs, t0, flush_s, deltas, round_no in batch:
+                # retire stale awaiting tokens for docs this round
+                # re-flushed BEFORE parking the round's own tokens
+                oplag.flush_boundary(round_docs)
+                for tok in toks:
+                    oplag.flushed(tok, flush_start=t0, flush_s=flush_s,
+                                  phases=deltas)
+                # trace plane: the round's sampled lifecycle traces record
+                # queue_wait / coalesce_wait / dispatch and park in the
+                # awaiting-wire table — like the tokens above, BEFORE the
+                # handler gossip ships their docs' messages
+                tracer.flush_round(round_docs, round_no, t0, flush_s)
 
     def _drain_admitted(self) -> None:
         """Notify handlers for admitted docs, outside self._lock (a handler
@@ -1567,14 +1653,15 @@ class EngineDocSet:
             return
         self._drain_local.draining = True
         try:
-            while True:
-                with self._lock:
-                    if self._batch_depth or not self._admit_notify:
-                        return
-                    doc_id = self._admit_notify.pop(0)
-                    handle = self.get_doc(doc_id)
-                for handler in list(self.handlers):
-                    handler(doc_id, handle)
+            with perfscope.phase("publish"):
+                while True:
+                    with self._lock:
+                        if self._batch_depth or not self._admit_notify:
+                            return
+                        doc_id = self._admit_notify.pop(0)
+                        handle = self.get_doc(doc_id)
+                    for handler in list(self.handlers):
+                        handler(doc_id, handle)
         finally:
             self._drain_local.draining = False
 
@@ -1630,7 +1717,7 @@ class EngineDocSet:
             return
         self._inflight_tickets = tickets
         try:
-            self._flush_locked()
+            self._flush_locked(riders=len(tickets) if tickets else None)
         except BaseException as e:
             leftover, self._inflight_tickets = self._inflight_tickets, []
             epochs.EpochIngestBuffer.resolve(leftover, e)

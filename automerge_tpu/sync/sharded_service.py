@@ -26,7 +26,7 @@ import zlib
 from typing import Callable
 
 from ..utils import flightrec, metrics, perfscope
-from .service import EngineDocSet
+from .service import EngineDocSet, request_span
 
 # Stall-watchdog budget for the hash fan-out (the r5 config-8 hang site:
 # `sharded_service.hashes → service.hashes → resident_rows.hashes` sat on a
@@ -168,10 +168,19 @@ class ShardedEngineDocSet:
         """Coalesce a burst into at most ONE dispatch per shard."""
         @contextlib.contextmanager
         def _cm():
-            with contextlib.ExitStack() as stack:
+            # one root for the fleet-wide request: the shards' batches
+            # open none of their own inside it, and their flushes keep
+            # the shard= label under its trace id
+            with request_span(None) as span, contextlib.ExitStack() as stack:
                 for s in self.shards:
                     stack.enter_context(s.batch())
-                yield self
+                try:
+                    yield self
+                finally:
+                    if span is not None:
+                        sizes = [s._pending_size() for s in self.shards]
+                        span.tags = {"docs": sum(d for d, _ in sizes),
+                                     "ops": sum(o for _, o in sizes)}
         return _cm()
 
     # -- protocol / engine reads ---------------------------------------------

@@ -64,6 +64,7 @@ Usage:
 from __future__ import annotations
 
 import binascii
+import itertools
 import logging
 import os
 import re
@@ -600,6 +601,10 @@ SPANS: dict[str, str] = {
     "engine_hashes": "docs-major reconcile / hash read",
     "rows_round_apply": "rows-engine round-frame admission + dispatch",
     "rows_hashes": "rows-engine hash read (the readback barrier)",
+    "sync_request":
+        "one served request, root of its spans: the outermost batch(), or "
+        "an apply_changes / apply_columns outside any batch {shard=...}; "
+        "tags docs, ops",
     "sync_round_flush": "service coalesced-round flush {shard=...}",
     "sync_hashes": "service hash read, incl. read-triggered flush",
     "sync_hashes_fanout": "sharded service hash fan-out over all shards",
@@ -648,8 +653,28 @@ def _flat_key(name: str, lk: tuple) -> str:
     return name + "{" + ",".join(f"{k}={v}" for k, v in lk) + "}"
 
 
-def _new_id(nbytes: int) -> str:
-    return binascii.hexlify(os.urandom(nbytes)).decode()
+# Span and trace ids: a random prefix drawn once a process plus a counter
+# (next() on itertools.count is atomic under the GIL), so a span costs no
+# syscall. The prefix (8 random bytes in a trace id, their first 4 in a
+# span id) keeps ids of different replicas apart in a merged timeline; a
+# forked child draws its own, or it would repeat its parent's ids.
+def _draw_id_prefix() -> None:
+    global _trace_prefix, _span_prefix, _id_counter
+    _trace_prefix = binascii.hexlify(os.urandom(8)).decode()
+    _span_prefix = _trace_prefix[:8]
+    _id_counter = itertools.count(1)
+
+
+_draw_id_prefix()
+os.register_at_fork(after_in_child=_draw_id_prefix)
+
+
+def _new_trace_id() -> str:
+    return f"{_trace_prefix}{next(_id_counter):08x}"
+
+
+def _new_span_id() -> str:
+    return f"{_span_prefix}{next(_id_counter):08x}"
 
 
 # Thread-local adopted trace context: (trace_id, parent_span_id) a remote
@@ -675,9 +700,9 @@ class _Span:
             self.parent_sid = parent.span_id
         else:
             ctx = getattr(_tls, "ctx", None)
-            self.trace_id = ctx[0] if ctx else _new_id(8)
+            self.trace_id = ctx[0] if ctx else _new_trace_id()
             self.parent_sid = ctx[1] if ctx else None
-        self.span_id = _new_id(4)
+        self.span_id = _new_span_id()
         self.tags = None
 
 

@@ -19,11 +19,18 @@ be checkable run over run:
   land as registered gauges (`engine_kernel_flops{kernel=...}`,
   `engine_kernel_hbm_bytes{kernel=...,section=...}`) and in the `perf`
   section of `metrics.snapshot()`.
-- **phase attribution** — `phase(name)` accumulates wall time into one
-  of the registered PHASES (pack → dispatch → device_wait → readback →
-  host_materialize → sync_wire), so a run self-reports where its time
-  went across layers. Phase names are lint-enforced (the graftlint
-  registry pass) the same way metric names are.
+- **phase attribution** — `phase(name)` is one host accumulator AND one
+  `jax.profiler.TraceAnnotation` from one call site: the wall time lands
+  under one of the registered PHASES (`phase.<name>` wherever the perf
+  section is read) and, while a profiler session runs, the same interval
+  stands on the device trace's clock under the same name. On the served
+  path the phases are a partition of the blocking thread's time, layer
+  boundary by layer boundary (admit → commit_wait | encode → commit →
+  route → pack → upload → dispatch → readback[device_wait] → publish);
+  the `metrics.trace` spans `sync_request`, `sync_round_flush` and
+  `rows_round_apply` are their parents and carry the request's id. Phase
+  names are lint-enforced (the graftlint registry pass) the same way
+  metric names are.
 - **memory gauges** — a throttled `jax.live_arrays()` sample maintains
   the live-array footprint and its high-water mark
   (`obs_live_arrays_bytes` / `obs_live_arrays_peak_bytes`); the engines
@@ -48,16 +55,21 @@ stays on in every mode.
 Locking discipline: the store lock guards only dict arithmetic. Metric
 emission, jax calls, and the AOT analysis all run outside it, so this
 module adds no lock-order edge against the metrics store (the
-lock-discipline pass scans utils/).
+lock-discipline pass scans utils/). A phase exit takes no lock at all:
+each thread accumulates into a dict of its own, and the readers
+(`phase_totals()`, `perf_snapshot()`, `reset()`) merge them.
 """
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
 import time
 from contextlib import contextmanager
+
+import jax.profiler
 
 log = logging.getLogger("automerge_tpu.perfscope")
 
@@ -66,11 +78,29 @@ log = logging.getLogger("automerge_tpu.perfscope")
 #: phase() call sites, exactly like metric names (docs/OBSERVABILITY.md
 #: "Performance plane").
 PHASES: dict[str, str] = {
-    "pack": "columnar batch/rows packing on the host (engine/pack.py)",
+    "admit": "one ingress of the service up to its place in the pending "
+             "round or the epoch buffer: wire columns, ghost check, append "
+             "(sync/service.py); not the flush, not the park",
+    "commit_wait": "a caller parked on its epoch ticket until the flush "
+                   "that carried its entry resolves it (sync/epochs.py)",
+    "encode": "round-frame decode, actor registration, budget precheck and "
+              "the native delta encode (resident_rows._apply_round_frames)",
+    "commit": "the encoded round committed to the host row mirror: growth, "
+              "the pre-round copy, scatter triplets, dirty marks, dedup and "
+              "padding (resident_rows)",
+    "route": "the round router: used-size band scans over the dirty lanes "
+             "and bucket planning against the link prices "
+             "(engine/dispatch.py plan_round)",
+    "pack": "columnar batch/rows packing on the host (engine/pack.py) and "
+            "the gather of dirty lanes from the host row mirror",
+    "upload": "host->device transfers of the resident engines (_to_dev)",
     "dispatch": "jitted kernel dispatch calls (metrics.dispatch_jit)",
     "device_wait": "explicit host barriers on in-flight device work "
-                   "(block_until_ready)",
+                   "(block_until_ready), inside `readback` on the rows path",
     "readback": "device->host readbacks (hash reads, the trusted barrier)",
+    "publish": "the service's tail after the engine returns: admission "
+               "scans, ledgers, read versions, notify queue, archive "
+               "trigger, ticket resolve, handler gossip (sync/service.py)",
     "host_materialize": "interpretive apply + snapshot materialization "
                         "(frontend/materialize.py)",
     "sync_wire": "wire encode/decode of sync frames (sync/frames.py)",
@@ -125,7 +155,11 @@ class _Store:
     def __init__(self):
         self.lock = threading.Lock()
         self.kernels: dict[str, _KernelStats] = {}
-        self.phases: dict[str, list] = {}     # name -> [seconds, count]
+        # phase accumulators, name -> [seconds, count]: one dict a live
+        # thread (written by its owner alone, without this lock) and the
+        # folded totals of the threads that have exited
+        self.thread_phases: list[tuple[threading.Thread, dict]] = []
+        self.retired_phases: dict[str, list] = {}
         self.live_bytes = 0
         self.live_peak = 0
         self._last_live = 0.0
@@ -410,41 +444,103 @@ def _analyze(kernel: str, fn, args: tuple, kwargs: dict, marker) -> None:
 # phase attribution
 
 
-@contextmanager
-def phase(name: str):
-    """Accumulate wall time under one of the registered PHASES. Cheap (two
-    perf_counter reads + one locked dict update), safe to nest; phases are
-    attribution, not a partition — overlapping phases both count."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        with _store.lock:
-            e = _store.phases.get(name)
-            if e is None:
-                _store.phases[name] = [dt, 1]
+def _fold(into: dict, acc: dict) -> None:
+    # list(): the owner may insert a name while this runs
+    for name, (s, c) in list(acc.items()):
+        e = into.get(name)
+        if e is None:
+            into[name] = [s, c]
+        else:
+            e[0] += s
+            e[1] += c
+
+
+def _thread_phases() -> dict:
+    """Register and return the calling thread's accumulator (its first
+    phase exit). Accumulators of threads that have exited are folded
+    into the retired totals here, so respawning flusher threads do not
+    grow the registry."""
+    acc: dict = {}
+    with _store.lock:
+        live = []
+        for t, a in _store.thread_phases:
+            if t.is_alive():
+                live.append((t, a))
             else:
-                e[0] += dt
-                e[1] += 1
+                _fold(_store.retired_phases, a)
+        live.append((threading.current_thread(), acc))
+        _store.thread_phases = live
+    _tls.phases = acc
+    return acc
+
+
+def _merged_phases() -> dict[str, list]:
+    """name -> [seconds, count] over every thread. Call with the store
+    lock held."""
+    out = {n: list(e) for n, e in _store.retired_phases.items()}
+    for _t, acc in _store.thread_phases:
+        _fold(out, acc)
+    return out
+
+
+# bound once: a phase entry/exit is a per-admission cost, and each
+# attribute lookup it saves is some 3 % of it
+_Annotation = jax.profiler.TraceAnnotation
+_annotation_init = _Annotation.__init__
+_annotation_enter = _Annotation.__enter__
+_annotation_exit = _Annotation.__exit__
+_now = time.perf_counter
+
+
+class phase(_Annotation):
+    """`with phase(name):` accumulates wall time under one of the
+    registered PHASES and holds a `jax.profiler.TraceAnnotation(name)`
+    for the same interval, so a phase has the same name in the counters
+    and on the profiler's timeline. Cheap enough for a per-admission
+    site: two perf_counter reads, the annotation (a no-op without a
+    profiler session), one thread-local dict update, no lock. On the
+    served path phases are placed as a partition; elsewhere a nested
+    phase counts in both."""
+
+    __slots__ = ("_name", "_t0")
+
+    def __init__(self, name: str):
+        _annotation_init(self, name)
+        self._name = name
+
+    def __enter__(self):
+        _annotation_enter(self)
+        self._t0 = _now()
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = _now() - self._t0
+        _annotation_exit(self, exc_type, exc, tb)
+        try:
+            acc = _tls.phases
+        except AttributeError:
+            acc = _thread_phases()
+        e = acc.get(self._name)
+        if e is None:
+            acc[self._name] = [dt, 1]
+        else:
+            e[0] += dt
+            e[1] += 1
 
 
 def phase_totals() -> dict[str, float]:
-    """Accumulated seconds per phase since the last reset — a cheap
-    point-in-time read (one locked dict copy). The op-lifecycle plane
+    """Accumulated seconds per phase since the last reset, over every
+    thread — a cheap point-in-time read. The op-lifecycle plane
     (utils/oplag.py) snapshots this around a round flush and attributes
     the delta (pack/dispatch/device_wait) to the sampled ops that rode
     the round."""
     with _store.lock:
-        return {n: e[0] for n, e in _store.phases.items()}
+        return {n: e[0] for n, e in _merged_phases().items()}
 
 
 def phased(name: str):
     """Decorator form of phase() for whole-function attribution (the pack
     entry points in engine/pack.py). Same lint discipline: the name
     literal at the decoration site must be a registered PHASE."""
-    import functools
-
     def deco(fn):
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
@@ -508,10 +604,11 @@ def perf_snapshot() -> dict | None:
             # memory) stay out of the per-run snapshot
             if st.dispatches or st.compiles or st.compile_s
             or st.trace_s or st.lower_s}
-        if not kernels and not _store.phases and not _store.live_peak:
+        merged = _merged_phases()
+        if not kernels and not merged and not _store.live_peak:
             return None
         phases = {n: {"s": round(s, 6), "count": c}
-                  for n, (s, c) in _store.phases.items()}
+                  for n, (s, c) in merged.items()}
         memory = None
         if _store.live_peak:
             memory = {"live_array_bytes": _store.live_bytes,
@@ -545,7 +642,9 @@ def reset() -> None:
             st.lower_s = 0.0
         _store.kernels = {k: st for k, st in _store.kernels.items()
                           if st.signatures}
-        _store.phases.clear()
+        _store.retired_phases.clear()
+        for _t, acc in _store.thread_phases:
+            acc.clear()     # in place: the owner keeps writing into it
         _store.live_bytes = 0
         _store.live_peak = 0
         _store._last_live = 0.0

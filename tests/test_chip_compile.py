@@ -9,6 +9,13 @@ shapes the 10,000-document chip_smoke.py produces (its CPU rehearsal printed
 them: resident dims (512, 4, 64) over 10,112 lanes, storm buckets (8, 4, 0)
 over 1,024 and 2,048 lanes, span and move tables [8, ., 128]).
 
+The two cells of the benchmark are here at their own shapes too (resident
+dims (512, 4, 32): `reconcile_rows_hash` over [6308, 1280], `_apply_final`
+over [6308, 10112]), and each compiled program must hold an instruction that
+the cell's roofline metric finds by the patterns of its own file under
+benchmarks/metrics/: a renamed kernel then fails here, and not as
+`output_malformed` on the chip.
+
 Nothing runs and no time is implied: a compile that passes is not a chip
 run. The topology is described inside a fixture, never at import (only one
 process at a time may load the TPU's library, and every xdist worker imports
@@ -16,7 +23,9 @@ this file), and all cases live in this one file so one worker owns the
 library.
 """
 
+import json
 import os
+import re
 
 import pytest
 
@@ -31,6 +40,8 @@ HBM_BYTES = 16 * 1024 ** 3     # one v5e chip
 
 FLEET_LANES = 10_112           # pad_to_lanes(10,044 documents)
 CAPS = (512, 4, 64)            # the smoke's resident (I, A, LE)
+BENCH_CAPS = (512, 4, 32)      # the benchmark fleet's (its load stage line)
+BENCH_STORM_LANES = 1_280      # a storm request's dirty documents, padded
 
 
 def _dims(i, a, le):
@@ -189,3 +200,53 @@ def test_compiles_for_v5e(case, chip, no_compile_cache):
     assert used < HBM_BYTES, f"{case}: {used} bytes on one device"
     assert ("tpu_custom_call" in compiled.as_text()) == has_kernel, (
         f"{case}: Pallas kernel in the compiled program: {not has_kernel}")
+
+
+def _apply_final_bench(chip):
+    from automerge_tpu.engine.resident_rows import _apply_final
+    return _apply_final.lower(
+        chip.one((rows_count(*BENCH_CAPS), FLEET_LANES)), chip.one((16, 3)),
+        _dims(*BENCH_CAPS), False)
+
+
+# roofline metric -> (builder of its cell's kernel call, the shape the
+# metric's `shape` pattern must read from the instruction)
+CELL_KERNELS = {
+    "megakernel_roofline": (
+        _megakernel(*BENCH_CAPS, BENCH_STORM_LANES),
+        (rows_count(*BENCH_CAPS), BENCH_STORM_LANES)),
+    "apply_final_roofline": (
+        _apply_final_bench, (rows_count(*BENCH_CAPS), FLEET_LANES)),
+}
+
+
+def _event_names(compiled) -> list:
+    """The instructions of a compiled program as the profiler names their
+    events on the device's operations line: the HLO text with operand
+    shapes, one instruction a line."""
+    from jax._src.lib import _jax
+    opts = _jax.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = "\n".join(m.to_string(opts)
+                     for m in compiled.runtime_executable().hlo_modules())
+    return [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
+
+
+@pytest.mark.parametrize("metric", sorted(CELL_KERNELS))
+def test_cell_kernel_is_found_by_its_roofline_metric(metric, chip,
+                                                     no_compile_cache):
+    build, want = CELL_KERNELS[metric]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "metrics", metric + ".json"),
+              encoding="utf-8") as f:
+        args = json.load(f)["args"]
+    event, shape = re.compile(args["event"]), re.compile(args["shape"])
+    compiled = build(chip).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    hits = [ln for ln in _event_names(compiled) if event.search(ln)]
+    assert hits, f"{metric}: no instruction matches {args['event']!r}"
+    read = [tuple(map(int, m.groups()))
+            for m in map(shape.search, hits) if m]
+    assert want in read, (
+        f"{metric}: {args['shape']!r} reads {read} from {hits[0][:200]!r}")

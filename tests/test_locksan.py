@@ -201,14 +201,16 @@ def test_violation_discloses_to_metrics_and_flightrec(monkeypatch,
     the bounded list."""
     from automerge_tpu.utils import flightrec, metrics
     _arm(monkeypatch, tmp_path, 1)
-    seen = len(flightrec.events())
+    # the ring is bounded: where earlier tests of this worker have filled
+    # it, "the events after the first `seen`" would be none
+    flightrec.reset()
     with locksan.named_lock("beta"):
         with locksan.named_lock("alpha"):
             pass
     snap = metrics.snapshot()
     assert snap.get(
         "obs_locksan_order_violations_total{lock=alpha}", 0) >= 1
-    ev = [e for e in flightrec.events()[seen:]
+    ev = [e for e in flightrec.events()
           if e.get("kind") == "locksan_violation"]
     assert len(ev) == 1
     assert ev[0]["violation"] == "order" and ev[0]["lock"] == "alpha"

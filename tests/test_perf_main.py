@@ -28,8 +28,6 @@ def _capture(monkeypatch, module, attr, rc=0):
     ("remediate", "remediate", "smoke_main"),
     ("move", "moveplane", "smoke_main"),
     ("bootstrap", "bootstrap", "smoke_main"),
-    ("roofline", "roofline", "main"),
-    ("resident", "resident", "main"),
 ])
 def test_lazy_subcommands_route_with_rest_argv(monkeypatch, cmd, modname,
                                                attr):
@@ -65,7 +63,7 @@ def test_unknown_command_exits_nonzero_with_usage(capsys):
     assert "unknown command 'frobnicate'" in err
     for cmd in ("report", "check", "contention", "doctor", "explain",
                 "top", "dispatch", "tenant", "remediate", "move",
-                "bootstrap", "roofline", "resident"):
+                "bootstrap"):
         assert cmd in err
 
 

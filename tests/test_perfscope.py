@@ -174,12 +174,62 @@ def test_every_dispatched_kernel_has_perf_rows():
 
 
 def test_phases_cover_the_engine_round():
+    from automerge_tpu.core.change import Change, Op
+    from automerge_tpu.core.ids import ROOT_ID
+    from automerge_tpu.sync.frames import encode_round_frame
+
     rset = _tiny_rows_engine()
     rset.hashes()
+    # a round frame through the served path's engine entry: decode, encode
+    # and commit on the host, the triplets' upload, the dispatch; the read
+    # then waits for the device inside its readback
+    rset.apply_round_frames([encode_round_frame({
+        "d0": [Change(actor="B", seq=1, deps={},
+                      ops=[Op("set", ROOT_ID, key="m", value=7)])]})])
+    rset.hashes()
     phases = metrics.snapshot()["perf"]["phases"]
-    for name in ("dispatch", "readback", "host_materialize"):
+    for name in ("dispatch", "readback", "host_materialize", "sync_wire",
+                 "encode", "commit", "upload", "device_wait"):
         assert phases[name]["count"] >= 1, (name, phases)
+    assert phases["device_wait"]["s"] <= phases["readback"]["s"]
     assert set(phases) <= set(perfscope.PHASES)
+
+
+def test_phase_totals_merge_threads_and_survive_their_exit():
+    """A phase exit takes no lock: each thread accumulates into a dict of
+    its own, and the readers merge them, the exited threads' too."""
+    import threading
+
+    def work():
+        for _ in range(100):
+            with perfscope.phase("pack"):
+                pass
+
+    for _ in range(3):      # three generations of short-lived threads
+        ts = [threading.Thread(target=work) for _ in range(4)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    with perfscope.phase("pack"):
+        pass
+    assert metrics.snapshot()["perf"]["phases"]["pack"]["count"] == 1201
+    assert perfscope.phase_totals()["pack"] > 0
+    # exited threads are folded away as later ones register, not kept
+    # one dict each: at most the last generation is still listed
+    assert sum(not t.is_alive()
+               for t, _acc in perfscope._store.thread_phases) <= 4
+    metrics.reset()
+    assert "pack" not in perfscope.phase_totals()
+    with perfscope.phase("pack"):       # this thread's dict was cleared in
+        pass                            # place and still counts
+    assert metrics.snapshot()["perf"]["phases"]["pack"]["count"] == 1
+
+
+def test_a_phase_is_a_profiler_annotation_of_its_own_name():
+    import jax.profiler
+    assert isinstance(perfscope.phase("pack"), jax.profiler.TraceAnnotation)
 
 
 # -- memory gauges + flight-recorder embedding ------------------------------
