@@ -329,6 +329,7 @@ def compact(rset, floors: dict[str, dict[str, int]],
     if touched:
         rset._dirty = True
         rset._hash_handle = None
+        rset._h_prev = None
         rset.rows_dev = None
         rset._elems_hi = max((t.max_elems for t in rset.tables), default=0)
         metrics.bump("rows_docs_compacted")

@@ -32,7 +32,7 @@ from . import dispatchledger
 from .encode import _pad_to, content_hash
 from .resident import ResidentDocSet
 from . import dispatch as round_dispatch
-from .pack import pad_to_lanes
+from .pack import LANE, pad_to_lanes
 from .pallas_kernels import reconcile_rows_hash
 from ..utils import flightrec, metrics, perfscope
 
@@ -177,6 +177,11 @@ class ResidentRowsDocSet(ResidentDocSet):
         # hashes_for() reconcile only dirty lanes (narrow [ROWS, k_pad]
         # gather + the same fused kernel) and a clean read is free.
         self._hash_handle = None
+        # The last all-lane hash vector a kernel produced from rows_dev,
+        # kept on the device (readers consume _hash_handle; this stays).
+        # Valid exactly while rows_dev is: _apply_final patches the dirty
+        # 128-lane blocks' hashes into it instead of rehashing the fleet.
+        self._h_prev = None
         # dense admission cache (vectorized round-frame fast path): per-doc
         # clock rows + single-head frontier summary. Rebuilt lazily from the
         # authoritative DocTables dicts for docs in _cache_dirty.
@@ -283,6 +288,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             self._refill_actor_hash_band()
             self.rows_dev = None
             self._dirty = True
+            self._h_prev = None
         # admission cache: fresh lanes are valid empty docs (zero clock,
         # empty frontier) — grow the cache arrays in place rather than
         # dropping them, or one-doc-at-a-time ingress of N new docs would
@@ -325,6 +331,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         # ah band is likewise re-filled from the actor table
         self._refill_actor_hash_band()
         self._dirty = True
+        self._h_prev = None
         # re-layout preserves hashes but rewrites every lane: conservative
         self._mark_all_hash_dirty()
 
@@ -499,6 +506,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                              for (s, e, a, p) in entries]
         self._refill_actor_hash_band()
         self._dirty = True
+        self._h_prev = None
         # rank remap rewrites every lane's act/co rows; hash values are
         # preserved (content hashes), mirror stays conservative anyway
         self._mark_all_hash_dirty()
@@ -699,6 +707,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             self.rows_dev = None
             self._dirty = True
             self._hash_handle = None
+            self._h_prev = None
             metrics.bump("rows_dispatch_failed")
             raise DeviceDispatchError(str(e), admission_complete=True) from e
 
@@ -1090,6 +1099,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                 "scan_rounds", _scan_rounds,
                 self.rows_dev, stacked_dev, self.dims(), interpret)
         self._hash_handle = None
+        self._h_prev = None
         with perfscope.phase("readback"):
             vals = self._to_host(hashes)
         # the FINAL round's row is the canonical post-batch hash table:
@@ -1312,9 +1322,11 @@ class ResidentRowsDocSet(ResidentDocSet):
         advertises clocks from host state and only needs hashes when a
         convergence check runs — reading them is the caller's explicit
         barrier. Consecutive calls chain device-side (the rows buffer is
-        donated), so ingress pipelines: host encode of batch k+1 overlaps
-        device work of batch k, and the fixed per-transfer latency leaves
-        the critical path entirely.
+        donated; the hash array is not, a caller may keep it), and each
+        reconciles only the 128-lane blocks its documents sit in
+        (_dispatch_final), so ingress pipelines: host encode of batch k+1
+        overlaps device work of batch k, and the fixed per-transfer
+        latency leaves the critical path entirely.
 
         frames: list of round-frame bytes (or decoded RoundColumns).
         Documents must already exist in this set.
@@ -1852,12 +1864,17 @@ class ResidentRowsDocSet(ResidentDocSet):
         """One scatter + one reconcile for the whole micro-batch: round
         triplets are merged in order with last-wins dedup (rounds only
         overwrite each other on re-linearized position rows), so the scan
-        over rounds collapses into a single gather-free scatter. Returns
-        the device hash array without reading it back (None under
-        lazy_dispatch — the next hashes() read reconciles). Under the
-        megabatch route (_mega_intent, set by _apply_round_frames) the
-        host mirror is refreshed in place through the fused bucketed
-        dispatches and the hashes return from the mirror."""
+        over rounds collapses into a single gather-free scatter. The
+        reconcile covers the 128-lane blocks the triplets touched, on the
+        device, from the resident rows, and patches their hashes into the
+        hash vector of the last call (_h_prev); the whole buffer when
+        there is no such vector (after an upload) or the dirty blocks are
+        no minority. Returns the device hash array of every lane without
+        reading it back (None under lazy_dispatch — the next hashes() read
+        reconciles). Under the megabatch route (_mega_intent, set by
+        _apply_round_frames) the host mirror is refreshed in place through
+        the fused bucketed dispatches and the hashes return from the
+        mirror."""
         mega = getattr(self, "_mega_intent", False)
         self._mega_intent = False
         with perfscope.phase("commit"):
@@ -1870,6 +1887,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             self.rows_dev = None
             self._dirty = True
             self._hash_handle = None
+            self._h_prev = None
             return None
         if mega and touched:
             # megabatch route: the round is committed to the host mirror,
@@ -1883,6 +1901,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             self.rows_dev = None
             self._dirty = True
             self._hash_handle = None
+            self._h_prev = None
             plan = round_dispatch.plan_round(self, sorted(touched))
             round_dispatch.apply_round_adaptive(self, plan, interpret)
             # keep the return contract (post-batch per-doc hashes, padded
@@ -1913,14 +1932,35 @@ class ResidentRowsDocSet(ResidentDocSet):
         if pre_rows is not None:
             self.rows_dev = self._to_dev(pre_rows)
             self._dirty = False
+            self._h_prev = None
         padded_dev = self._to_dev(padded)
+        # the 128-lane blocks this round dirtied, padded to a power of two
+        # by repeating the last (its hashes are written twice: harmless).
+        # A minority of the blocks reconciles alone and patches _h_prev;
+        # otherwise the whole buffer does, which (re)creates _h_prev: the
+        # "minority dirty" rule of _refresh_hash_mirror, per block
+        blocks = sorted({lane // LANE for lane in touched})
+        nb = _pad_to(len(blocks), 1)
+        h_prev = self._h_prev \
+            if blocks and 2 * nb <= self.n_pad // LANE else None
+        if h_prev is not None:
+            docs_axis = (len(touched), nb * LANE)
+            blocks_dev = self._to_dev(np.asarray(
+                blocks + blocks[-1:] * (nb - len(blocks)), np.int32))
+            metrics.bump("rows_apply_block_calls")
+            metrics.bump("rows_apply_blocks", nb)
+        else:
+            docs_axis = (len(self.doc_ids), self.n_pad)
+            blocks_dev = None
         with dispatchledger.call_scope(
                 "rows_apply", backend="device", docs=len(touched),
-                axes={"docs": (len(self.doc_ids), self.n_pad),
+                axes={"docs": docs_axis,
                       "trips": (max(len(trips), 1), p)}):
             self.rows_dev, h = metrics.dispatch_jit(
                 "apply_final", _apply_final,
-                self.rows_dev, padded_dev, self.dims(), interpret)
+                self.rows_dev, padded_dev, blocks_dev, h_prev,
+                self.dims(), interpret)
+        self._h_prev = h
         self._hash_handle = h  # polling hashes() between deltas is free
         return h
 
@@ -2003,6 +2043,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                 h = metrics.dispatch_jit(
                     "reconcile_rows_hash", reconcile_rows_hash,
                     self.rows_dev, self.dims(), interpret)
+            self._h_prev = h   # every lane, from the buffer just primed
             flightrec.record("rows_hash_readback", docs=n, cached=False)
             with perfscope.phase("readback"):
                 vals = self._to_host(h)
@@ -2104,8 +2145,9 @@ class ResidentRowsDocSet(ResidentDocSet):
     def resident_bytes(self) -> int:
         """Footprint of this engine's resident state: the host row mirror,
         the device buffer (same layout), and the per-doc admission
-        counters. The memory gauge (`rows_resident_bytes`) and flight-
-        recorder post-mortems carry this number."""
+        counters; not the device hash vector beside the buffer (_h_prev,
+        4 bytes a lane). The memory gauge (`rows_resident_bytes`) and
+        flight-recorder post-mortems carry this number."""
         total = int(self.rows_host.nbytes)
         if self.rows_dev is not None:
             total += int(self.rows_host.nbytes)   # device copy, same layout
@@ -2188,12 +2230,30 @@ class ResidentRowsDocSet(ResidentDocSet):
 
 @partial(jax.jit, static_argnames=("dims", "interpret"),
          donate_argnums=(0,))
-def _apply_final(rows, trips, dims, interpret):
-    """Merged-batch apply: one ordered-dedup scatter, one reconcile+hash.
-    Async by design — the caller decides when (and whether) to read the
-    hashes back."""
+def _apply_final(rows, trips, blocks, h_prev, dims, interpret):
+    """Merged-batch apply: one ordered-dedup scatter, then reconcile+hash
+    of the lanes it dirtied. `blocks` (int32 [nb]) names the 128-lane
+    blocks to reconcile, the unit the kernel reads anyway; their hashes
+    are patched into `h_prev`, the all-lane vector the last call on this
+    buffer returned (not donated: a caller may still hold it). With
+    `blocks` None every block reconciles: the same kernel over the whole
+    buffer. Returns (rows, hashes of every lane). Async by design — the
+    caller decides when (and whether) to read the hashes back."""
     rows = rows.at[trips[:, 0], trips[:, 1]].set(trips[:, 2], mode="drop")
-    h = reconcile_rows_hash.__wrapped__(rows, dims, interpret)
+    # None is no tracer: the branch is on the call's structure
+    if blocks is None:   # graftlint: disable=jit-tracer-branch
+        return rows, reconcile_rows_hash.__wrapped__(rows, dims, interpret)
+    # dynamic_slice, not reshape + take: that form makes XLA copy the
+    # whole buffer into another layout on every call
+    starts = blocks * LANE
+    sub = jnp.concatenate(
+        [jax.lax.dynamic_slice(rows, (0, starts[k]), (rows.shape[0], LANE))
+         for k in range(blocks.shape[0])], axis=1)
+    h_sub = reconcile_rows_hash.__wrapped__(sub, dims, interpret)
+    h = h_prev
+    for k in range(blocks.shape[0]):
+        h = jax.lax.dynamic_update_slice(
+            h, h_sub[k * LANE:(k + 1) * LANE], (starts[k],))
     return rows, h
 
 

@@ -166,6 +166,10 @@ COUNTERS: dict[str, str] = {
     "rows_engine_poisoned": "engines poisoned by an unrecoverable failure",
     "rows_horizon_truncated": "log prefixes truncated below the horizon",
     "rows_docs_compacted": "documents compacted in place",
+    "rows_apply_block_calls":
+        "classic-route applies that reconciled only the dirty 128-lane "
+        "blocks of the resident rows (resident_rows._apply_final)",
+    "rows_apply_blocks": "128-lane blocks those applies reconciled",
     # sync — services, wire protocol, transports, log archive
     "sync_frames_sent": "columnar change frames sent",
     "sync_frames_received": "columnar change frames received",

@@ -10,11 +10,12 @@ them: resident dims (512, 4, 64) over 10,112 lanes, storm buckets (8, 4, 0)
 over 1,024 and 2,048 lanes, span and move tables [8, ., 128]).
 
 The two cells of the benchmark are here at their own shapes too (resident
-dims (512, 4, 32): `reconcile_rows_hash` over [6308, 1280], `_apply_final`
-over [6308, 10112]), and each compiled program must hold an instruction that
-the cell's roofline metric finds by the patterns of its own file under
-benchmarks/metrics/: a renamed kernel then fails here, and not as
-`output_malformed` on the chip.
+dims (512, 4, 32): `reconcile_rows_hash` over [6308, 1280]; `_apply_final`
+on the [6308, 10112] fleet, reconciling the one 128-lane block a request
+dirtied, and every block as the first request after an upload does), and
+each compiled program must hold an instruction that the cell's roofline
+metric finds by the patterns of its own file under benchmarks/metrics/: a
+renamed kernel then fails here, and not as `output_malformed` on the chip.
 
 Nothing runs and no time is implied: a compile that passes is not a chip
 run. The topology is described inside a fixture, never at import (only one
@@ -103,11 +104,18 @@ def _megakernel(i, a, le, lanes, force_xl=False):
     return build
 
 
-def _apply_final_fleet(chip):
-    from automerge_tpu.engine.resident_rows import _apply_final
-    return _apply_final.lower(
-        chip.one((rows_count(*CAPS), FLEET_LANES)), chip.one((1024, 3)),
-        _dims(*CAPS), False)
+def _apply_final(caps, trips, blocks=None):
+    """`_apply_final` on the fleet at resident `caps`: reconciling
+    `blocks` 128-lane blocks and patching their hashes into the last
+    hash vector, or with None every block, as after an upload."""
+    def build(chip):
+        from automerge_tpu.engine.resident_rows import _apply_final
+        by_block = (None, None) if blocks is None else (
+            chip.one((blocks,)), chip.one((FLEET_LANES,), jnp.uint32))
+        return _apply_final.lower(
+            chip.one((rows_count(*caps), FLEET_LANES)),
+            chip.one((trips, 3)), *by_block, _dims(*caps), False)
+    return build
 
 
 def _scan_rounds_fleet(chip):
@@ -167,7 +175,8 @@ def _sharded_megakernel(chip):
                                 P(None, DOCS_AXIS)))
 
 
-# name -> (builder, whether the program holds a Pallas kernel)
+# name -> (builder, whether the program holds a Pallas kernel[, the most
+# bytes of temporaries the compiled program may need])
 CASES = {
     "megakernel-base-storm-bucket": (_megakernel(8, 4, 0, 2048), True),
     "megakernel-base-caps-one-block": (_megakernel(*CAPS, 128), True),
@@ -177,7 +186,13 @@ CASES = {
     "megakernel-xl": (_megakernel(512, 8, 128, 256), True),
     "megakernel-xl-forced": (
         _megakernel(1024, 8, 512, 128, force_xl=True), True),
-    "apply_final-fleet": (_apply_final_fleet, True),
+    "apply_final-fleet": (_apply_final(CAPS, 1024), True),
+    # a block route that copies the 255 MB buffer (reshape + take does)
+    # fails here and not as 3 ms a request on the chip
+    "apply_final-bench-one-block": (
+        _apply_final(BENCH_CAPS, 16, blocks=1), True, 1 << 20),
+    "apply_final-bench-four-blocks": (
+        _apply_final(BENCH_CAPS, 16, blocks=4), True, 1 << 20),
     "scan_rounds-fleet": (_scan_rounds_fleet, True),
     "merge_spans": (_merge_spans, False),
     "resolve_moves": (_resolve_moves, False),
@@ -192,31 +207,33 @@ CASES = {
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_v5e(case, chip, no_compile_cache):
-    build, has_kernel = CASES[case]
+    build, has_kernel, *temp_limit = CASES[case]
     compiled = build(chip).compile()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < HBM_BYTES, f"{case}: {used} bytes on one device"
+    for limit in temp_limit:
+        assert mem.temp_size_in_bytes < limit, (
+            f"{case}: {mem.temp_size_in_bytes} bytes of temporaries")
     assert ("tpu_custom_call" in compiled.as_text()) == has_kernel, (
         f"{case}: Pallas kernel in the compiled program: {not has_kernel}")
 
 
-def _apply_final_bench(chip):
-    from automerge_tpu.engine.resident_rows import _apply_final
-    return _apply_final.lower(
-        chip.one((rows_count(*BENCH_CAPS), FLEET_LANES)), chip.one((16, 3)),
-        _dims(*BENCH_CAPS), False)
-
-
-# roofline metric -> (builder of its cell's kernel call, the shape the
-# metric's `shape` pattern must read from the instruction)
+# case -> (roofline metric, builder of its cell's kernel call, the shape
+# the metric's `shape` pattern must read from the instruction)
 CELL_KERNELS = {
     "megakernel_roofline": (
-        _megakernel(*BENCH_CAPS, BENCH_STORM_LANES),
+        "megakernel_roofline", _megakernel(*BENCH_CAPS, BENCH_STORM_LANES),
         (rows_count(*BENCH_CAPS), BENCH_STORM_LANES)),
+    # a request of the `edits` cell: the one block it dirtied
     "apply_final_roofline": (
-        _apply_final_bench, (rows_count(*BENCH_CAPS), FLEET_LANES)),
+        "apply_final_roofline", _apply_final(BENCH_CAPS, 16, blocks=1),
+        (rows_count(*BENCH_CAPS), 128)),
+    # the first request after an upload: every block
+    "apply_final_roofline-after-upload": (
+        "apply_final_roofline", _apply_final(BENCH_CAPS, 16),
+        (rows_count(*BENCH_CAPS), FLEET_LANES)),
 }
 
 
@@ -232,10 +249,10 @@ def _event_names(compiled) -> list:
     return [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()]
 
 
-@pytest.mark.parametrize("metric", sorted(CELL_KERNELS))
-def test_cell_kernel_is_found_by_its_roofline_metric(metric, chip,
+@pytest.mark.parametrize("case", sorted(CELL_KERNELS))
+def test_cell_kernel_is_found_by_its_roofline_metric(case, chip,
                                                      no_compile_cache):
-    build, want = CELL_KERNELS[metric]
+    metric, build, want = CELL_KERNELS[case]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "metrics", metric + ".json"),
               encoding="utf-8") as f:
