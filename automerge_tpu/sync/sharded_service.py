@@ -6,10 +6,12 @@ VMEM envelope and rejects batches that would blow it with the advice
 prechecks). This module productizes that advice: documents are
 partitioned across K independent `EngineDocSet` shards by a stable hash
 of the doc id, every Connection-facing read/write routes to the owning
-shard, and `batch()` coalesces a burst into at most one device dispatch
-PER SHARD — on a multi-chip host each shard's dispatch can bind to its
-own device, making this the single-process analog of the mesh-sharded
-DocSet (parallel/mesh.py) for the streaming service posture.
+shard, and `batch()` coalesces a burst into one round PER SHARD, flushed
+at its exit one shard after another on the caller's thread (the fan-out:
+phase `shard_fanout`, histogram `sync_shard_fanout_seconds`) — on a
+multi-chip host each shard's dispatch binds to its own device, making this
+the single-process analog of the mesh-sharded DocSet (parallel/mesh.py)
+for the streaming service posture.
 
 Duck-typing contract: same surface Connection consumes from EngineDocSet
 (doc_ids, get_doc, add_doc, apply_changes, apply_columns,
@@ -22,6 +24,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
+import time as _time
 import zlib
 from typing import Callable
 
@@ -154,33 +157,67 @@ class ShardedEngineDocSet:
     def flush(self) -> None:
         """Flush every shard even if one raises (shards are independent;
         batch() has the same semantics via ExitStack): the first error
-        propagates after all shards have drained."""
+        propagates after all shards have drained. Counted as one fan-out,
+        as a batch() exit is; the shards' pending documents are a peek
+        (no shard lock is held here)."""
         first: BaseException | None = None
+        t0 = _time.perf_counter()
+        docs = [len(s._pending) for s in self.shards]
         for s in self.shards:
             try:
                 s.flush()
             except BaseException as e:
                 first = first or e
+        self._fanout_done(t0, docs)
         if first is not None:
             raise first
 
+    def _fanout_done(self, t0: float, docs: list[int]) -> None:
+        """The counters of one fan-out: `docs` are the documents each
+        shard had pending when it began at `t0`."""
+        metrics.observe("sync_shard_fanout_seconds",
+                        _time.perf_counter() - t0)
+        metrics.bump("sync_shard_fanout_rounds")
+        metrics.bump("sync_shard_fanout_shards", sum(1 for d in docs if d))
+        metrics.bump("sync_shard_round_docs", sum(docs))
+        metrics.bump("sync_shard_round_docs_fullest", max(docs))
+
     def batch(self):
-        """Coalesce a burst into at most ONE dispatch per shard."""
+        """Coalesce a burst into one round a shard. At the exit every
+        shard flushes what it has pending, one shard after another (the
+        last shard first) on the caller's thread; every shard's lock is
+        held from the entry until the last flush has returned, and only
+        then is the burst acknowledged. Every shard flushes even if one
+        raises. The exit of the outermost batch is one fan-out: phase
+        `shard_fanout` from the end of the caller's body to the last
+        shard's return, around the shards' own phases."""
         @contextlib.contextmanager
         def _cm():
             # one root for the fleet-wide request: the shards' batches
             # open none of their own inside it, and their flushes keep
-            # the shard= label under its trace id
-            with request_span(None) as span, contextlib.ExitStack() as stack:
+            # the shard= label under its trace id. `fanout` is entered
+            # at the end of the body and unwinds after `stack` has left
+            # (and so flushed) the shards.
+            with request_span(None) as span, \
+                    contextlib.ExitStack() as fanout, \
+                    contextlib.ExitStack() as stack:
                 for s in self.shards:
                     stack.enter_context(s.batch())
+                outermost = self.shards[0]._batch_depth == 1
                 try:
                     yield self
                 finally:
-                    if span is not None:
+                    if span is not None or outermost:
                         sizes = [s._pending_size() for s in self.shards]
-                        span.tags = {"docs": sum(d for d, _ in sizes),
-                                     "ops": sum(o for _, o in sizes)}
+                        docs = [d for d, _ in sizes]
+                    if span is not None:
+                        span.tags = {"docs": sum(docs),
+                                     "ops": sum(o for _, o in sizes),
+                                     "shards": sum(1 for d in docs if d)}
+                    if outermost:
+                        fanout.callback(self._fanout_done,
+                                        _time.perf_counter(), docs)
+                        fanout.enter_context(perfscope.phase("shard_fanout"))
         return _cm()
 
     # -- protocol / engine reads ---------------------------------------------

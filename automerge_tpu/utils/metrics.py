@@ -181,6 +181,15 @@ COUNTERS: dict[str, str] = {
     "sync_wire_bytes_received": "framed bytes read from a TCP transport",
     "sync_ops_ingested": "ops admitted through service round flushes",
     "sync_rounds_flushed": "coalesced service round flushes",
+    # the sharded service's fan-out (sync/sharded_service.py): one round
+    # = the exit of an outermost batch(), or a flush()
+    "sync_shard_fanout_rounds": "fan-outs of the sharded service",
+    "sync_shard_fanout_shards":
+        "shards that had pending work at a fan-out, summed over fan-outs",
+    "sync_shard_round_docs":
+        "documents pending over all shards at a fan-out, summed",
+    "sync_shard_round_docs_fullest":
+        "documents pending on the fullest shard at a fan-out, summed",
     # epoch-batched ingestion (sync/epochs.py): the lock-free admission
     # path and its snapshot read plane (sync/service.py)
     "sync_ops_buffered":
@@ -547,6 +556,9 @@ GAUGES: dict[str, str] = {
 
 HISTOGRAMS: dict[str, str] = {
     "sync_round_seconds": "latency of coalesced service round flushes",
+    "sync_shard_fanout_seconds":
+        "one fan-out of the sharded service: the end of a batch()'s body "
+        "(or the entry of flush()) to the last shard's return",
     # lockprof (utils/lockprof.py): per-lock contention profile. Named
     # with the `_s` unit suffix (the ISSUE-6 contract names) — they
     # export as `sync_lock_wait_s{lock=...}_{count,sum,min,max}`.

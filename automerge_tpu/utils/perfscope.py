@@ -107,6 +107,10 @@ PHASES: dict[str, str] = {
     "fleet_hashes": "fleet-wide convergence reads: the sharded hash "
                     "fan-out incl. per-shard dirty-lane reconciles "
                     "(sync/sharded_service.py)",
+    "shard_fanout": "the sharded service's fan-out at the exit of a "
+                    "batch(): every shard's flush, one after another, "
+                    "around the shards' own phases "
+                    "(sync/sharded_service.py)",
     "span_merge": "span-granularity text-merge placement: run placement "
                   "walks + ElemList splices (core/textspans.py)",
 }

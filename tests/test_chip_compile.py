@@ -16,6 +16,11 @@ dirtied, and every block as the first request after an upload does), and
 each compiled program must hold an instruction that the cell's roofline
 metric finds by the patterns of its own file under benchmarks/metrics/: a
 renamed kernel then fails here, and not as `output_malformed` on the chip.
+So are the shapes of a shard of the four-chip cell, `fleet10k-4shard.storm`
+(a quarter of the fleet a chip, the same caps): `reconcile_rows_hash` over
+the [6308, 384] a quarter of a storm round pads to, and over 256 and 512
+lanes, the neighbours a seed may reach; `_apply_final` on a shard's
+resident [6308, 2560] at one block.
 
 Nothing runs and no time is implied: a compile that passes is not a chip
 run. The topology is described inside a fixture, never at import (only one
@@ -43,6 +48,8 @@ FLEET_LANES = 10_112           # pad_to_lanes(10,044 documents)
 CAPS = (512, 4, 64)            # the smoke's resident (I, A, LE)
 BENCH_CAPS = (512, 4, 32)      # the benchmark fleet's (its load stage line)
 BENCH_STORM_LANES = 1_280      # a storm request's dirty documents, padded
+SHARD_LANES = 2_560            # pad_to_lanes(a shard's 2,510 or 2,512 documents)
+SHARD_STORM_LANES = (256, 384, 512)   # a quarter of a round: 262-354 documents
 
 
 def _dims(i, a, le):
@@ -104,16 +111,17 @@ def _megakernel(i, a, le, lanes, force_xl=False):
     return build
 
 
-def _apply_final(caps, trips, blocks=None):
-    """`_apply_final` on the fleet at resident `caps`: reconciling
-    `blocks` 128-lane blocks and patching their hashes into the last
-    hash vector, or with None every block, as after an upload."""
+def _apply_final(caps, trips, blocks=None, lanes=FLEET_LANES):
+    """`_apply_final` on the fleet (or a shard's `lanes` of it) at
+    resident `caps`: reconciling `blocks` 128-lane blocks and patching
+    their hashes into the last hash vector, or with None every block, as
+    after an upload."""
     def build(chip):
         from automerge_tpu.engine.resident_rows import _apply_final
         by_block = (None, None) if blocks is None else (
-            chip.one((blocks,)), chip.one((FLEET_LANES,), jnp.uint32))
+            chip.one((blocks,)), chip.one((lanes,), jnp.uint32))
         return _apply_final.lower(
-            chip.one((rows_count(*caps), FLEET_LANES)),
+            chip.one((rows_count(*caps), lanes)),
             chip.one((trips, 3)), *by_block, _dims(*caps), False)
     return build
 
@@ -234,7 +242,19 @@ CELL_KERNELS = {
     "apply_final_roofline-after-upload": (
         "apply_final_roofline", _apply_final(BENCH_CAPS, 16),
         (rows_count(*BENCH_CAPS), FLEET_LANES)),
+    # a single edit on a shard of the four-chip cell: one block of its
+    # resident [6308, 2560]
+    "apply_final_roofline-shard-one-block": (
+        "apply_final_roofline",
+        _apply_final(BENCH_CAPS, 16, blocks=1, lanes=SHARD_LANES),
+        (rows_count(*BENCH_CAPS), 128)),
 }
+# a shard's quarter of a storm round
+CELL_KERNELS.update({
+    f"megakernel_roofline-shard-{lanes}-lanes": (
+        "megakernel_roofline", _megakernel(*BENCH_CAPS, lanes),
+        (rows_count(*BENCH_CAPS), lanes))
+    for lanes in SHARD_STORM_LANES})
 
 
 def _event_names(compiled) -> list:
