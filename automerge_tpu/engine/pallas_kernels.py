@@ -525,6 +525,75 @@ def reconcile_rows_hash(rows, dims: tuple, interpret: bool = False,
     return jax.lax.bitcast_convert_type(out[0], jnp.uint32)
 
 
+# ---------------------------------------------------------------------------
+# Lane gather out of a resident row buffer
+#
+# `rows[:, sel]` as XLA lowers it for the chip copies the whole buffer into
+# a lanes-major layout first (255 MB of temporaries for the 10K fleet). Here
+# the buffer is read by 128-lane blocks in place: `sel` ascends, so an
+# output block draws from a run of consecutive input blocks, and all the
+# (input block, output block) pairs of a call are at most one more than the
+# blocks of both sides together. The grid walks the pairs; a step selects,
+# inside one vreg row, the lanes of its input block that the output block
+# wants and leaves the others as earlier steps wrote them.
+
+
+def lane_gather_plan(sel: np.ndarray, n_pad: int) -> np.ndarray:
+    """The host half of `gather_lanes`: `sel` (ascending lane indices, a
+    multiple of 128 of them) followed by the pairs' input blocks and their
+    output blocks, one int32 vector and so one upload. The pair count is
+    padded to the static `n_pad // 128 + len(sel) // 128` by repeating the
+    last pair (a repeated step fetches nothing and writes the same
+    lanes)."""
+    nb_in, nb_out = n_pad // 128, len(sel) // 128
+    sel = np.asarray(sel, np.int64)
+    pairs = np.unique(np.repeat(np.arange(nb_out), 128) * nb_in + sel // 128)
+    steps = nb_in + nb_out
+    pairs = np.concatenate(
+        [pairs, np.full(steps - len(pairs), pairs[-1], np.int64)])
+    return np.concatenate([sel, pairs % nb_in, pairs // nb_in]
+                          ).astype(np.int32)
+
+
+def _gather_lanes_kernel(blk_in_ref, blk_out_ref, rows_ref, sel_ref,
+                         out_ref):
+    del blk_out_ref     # read by the block specs alone
+    local = sel_ref[...] - blk_in_ref[pl.program_id(0)] * 128     # [1, 128]
+    mine = (local >= 0) & (local < 128)
+    x = rows_ref[...]
+    picked = jnp.take_along_axis(
+        x, jnp.broadcast_to(jnp.clip(local, 0, 127), x.shape), axis=1)
+    out_ref[...] = jnp.where(mine, picked, out_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("k_pad", "interpret"))
+def gather_lanes(rows, plan, k_pad: int, interpret: bool = False):
+    """`rows[:, sel]` for the `sel` of `plan = lane_gather_plan(sel,
+    rows.shape[1])`: [ROWS, k_pad] int32, with no temporary beside the
+    output. Every output lane is written by the one pair that holds its
+    input block, so the buffer the first step finds needs no clearing."""
+    rows_n, n_pad = rows.shape
+    steps = n_pad // 128 + k_pad // 128
+    sel = plan[:k_pad].reshape(1, k_pad)
+    blk_in, blk_out = plan[k_pad:k_pad + steps], plan[k_pad + steps:]
+    return pl.pallas_call(
+        _gather_lanes_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((rows_n, 128), lambda p, bi, bo: (0, bi[p])),
+                pl.BlockSpec((1, 128), lambda p, bi, bo: (0, bo[p]))],
+            out_specs=pl.BlockSpec((rows_n, 128),
+                                   lambda p, bi, bo: (0, bo[p]))),
+        out_shape=jax.ShapeDtypeStruct((rows_n, k_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(blk_in, blk_out, rows, sel)
+
+
 def _dom_kernel(clockop_ref, actor_ref, fid_ref, seq_ref, change_ref,
                 amask_ref, out_ref):
     """One document: full-block domination compute in VMEM."""

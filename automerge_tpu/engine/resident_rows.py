@@ -33,7 +33,8 @@ from .encode import _pad_to, content_hash
 from .resident import ResidentDocSet
 from . import dispatch as round_dispatch
 from .pack import LANE, pad_to_lanes
-from .pallas_kernels import reconcile_rows_hash
+from .pallas_kernels import (gather_lanes, lane_gather_plan,
+                             reconcile_rows_hash)
 from ..utils import flightrec, metrics, perfscope
 
 
@@ -203,6 +204,13 @@ class ResidentRowsDocSet(ResidentDocSet):
         from .encode import A_DEL, A_SET
         return (self.cap_ops, self.cap_actors,
                 self.cap_lists * self.cap_elems, int(A_SET), int(A_DEL))
+
+    @property
+    def _dev_current(self) -> bool:
+        """The device copy of the rows exists and equals the host mirror
+        (every re-layout of the mirror sets _dirty; every site that loses
+        the buffer drops it)."""
+        return self.rows_dev is not None and not self._dirty
 
     def _alloc_rows(self):
         b = self._bases()
@@ -1016,7 +1024,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._reserve_for(rounds)
         with self._admission_guard():
             pre_rows = self.rows_host.copy() \
-                if self._dirty or self.rows_dev is None else None
+                if not self._dev_current else None
             trip_list = [self._round_triplets(r) for r in rounds]
             with self._dispatch_guard():
                 return self._dispatch_rounds(trip_list, pre_rows, interpret)
@@ -1046,7 +1054,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             encoded = [self._native_encode_round(r) for r in rounds]
             self._grow_for_rounds(encoded)
             pre_rows = self.rows_host.copy() \
-                if self._dirty or self.rows_dev is None else None
+                if not self._dev_current else None
             trip_list = [self._cols_triplets(e) for e in encoded]
             with self._dispatch_guard():
                 return self._dispatch_rounds(trip_list, pre_rows, interpret)
@@ -1383,7 +1391,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                                      for d in rc.doc_ids})
                             >= round_dispatch.megabatch_min_docs())
                     need_pre = (not self.lazy_dispatch and not mega
-                                and (self._dirty or self.rows_dev is None))
+                                and not self._dev_current)
                     pre_rows = self.rows_host.copy() if need_pre else None
                     trip_list = [self._cols_triplets(e) for e in encoded]
                     self._mega_intent = mega
@@ -1860,6 +1868,30 @@ class ResidentRowsDocSet(ResidentDocSet):
             "adm_cidx": m_cidx,
         }
 
+    def _merged_trips(self, trip_list, least: int = 8):
+        """The rounds' scatter triplets as one scatter: merged in round
+        order with last-wins dedup (rounds only overwrite each other on
+        re-linearized position rows), padded to a power of two (`least`
+        or more) with a row past the buffer, which the scatter drops.
+        Returns (the padded [p, 3] int32 array, the count before
+        padding)."""
+        parts = [t for t in trip_list if len(t)]
+        if parts:
+            trips = np.concatenate(parts)
+            key = trips[:, 0].astype(np.int64) * self.n_pad + trips[:, 1]
+            # np.unique keeps the FIRST occurrence per key of the
+            # reversed array == the LAST write in round order
+            _, first = np.unique(key[::-1], return_index=True)
+            trips = trips[len(trips) - 1 - first]
+        else:
+            trips = np.zeros((0, 3), np.int32)
+        n = len(trips)
+        p = _pad_to(max(n, 1), least)
+        padded = np.zeros((p, 3), dtype=np.int32)
+        padded[:n] = trips
+        padded[n:, 0] = self._bases()["rows"]
+        return padded, n
+
     def _dispatch_final(self, trip_list, pre_rows, interpret):
         """One scatter + one reconcile for the whole micro-batch: round
         triplets are merged in order with last-wins dedup (rounds only
@@ -1872,9 +1904,12 @@ class ResidentRowsDocSet(ResidentDocSet):
         no minority. Returns the device hash array of every lane without
         reading it back (None under lazy_dispatch — the next hashes() read
         reconciles). Under the megabatch route (_mega_intent, set by
-        _apply_round_frames) the host mirror is refreshed in place through
-        the fused bucketed dispatches and the hashes return from the
-        mirror."""
+        _apply_round_frames) a device copy that is current takes the same
+        scatter and stays; the round's lanes reconcile through the fused
+        bucketed dispatches or, where the router declines them, through
+        _reconcile_lanes, which gathers them out of that copy; the hashes
+        return from the host mirror. A copy that is not current is
+        dropped, and _reconcile_lanes uploads the mirror anew."""
         mega = getattr(self, "_mega_intent", False)
         self._mega_intent = False
         with perfscope.phase("commit"):
@@ -1890,16 +1925,32 @@ class ResidentRowsDocSet(ResidentDocSet):
             self._h_prev = None
             return None
         if mega and touched:
-            # megabatch route: the round is committed to the host mirror,
-            # which becomes authoritative — drop the device copy and
+            # megabatch route: the round is committed to the host mirror;
             # reconcile ONLY this round's lanes through the fused
             # bucketed dispatches (flush-time hash freshness at O(round),
             # not the O(fleet) full-buffer apply). A cost-model fallback
-            # leaves the lanes dirty; the next hash read reconciles them
-            # through the classic narrow gather — byte-identical hashes
-            # either way (pack.mega_row_map's subset property).
-            self.rows_dev = None
-            self._dirty = True
+            # leaves the lanes dirty; the hash refresh below reconciles
+            # them through the narrow gather — byte-identical hashes
+            # either way (pack.mega_row_map's subset property). A device
+            # copy that is current takes the round's triplets too, so the
+            # gather finds the rows on the device; one that is not is
+            # dropped (the gather then reads the mirror and uploads it).
+            if self._dev_current:
+                with perfscope.phase("commit"):
+                    # a round's count moves with its documents: small
+                    # rounds share one shape, large ones a power of two
+                    padded, n_trips = self._merged_trips(trip_list, 1024)
+                padded_dev = self._to_dev(padded)
+                with dispatchledger.call_scope(
+                        "rows_scatter", backend="device",
+                        docs=len(touched),
+                        axes={"trips": (max(n_trips, 1), len(padded))}):
+                    self.rows_dev = metrics.dispatch_jit(
+                        "scatter_trips", _scatter_trips,
+                        self.rows_dev, padded_dev)
+            else:
+                self.rows_dev = None
+                self._dirty = True
             self._hash_handle = None
             self._h_prev = None
             plan = round_dispatch.plan_round(self, sorted(touched))
@@ -1913,22 +1964,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             out[:n] = self._ensure_hash_mirror()[:n]
             return self._to_dev(out)
         with perfscope.phase("commit"):
-            parts = [t for t in trip_list if len(t)]
-            if parts:
-                trips = np.concatenate(parts)
-                key = trips[:, 0].astype(np.int64) * self.n_pad \
-                    + trips[:, 1]
-                # np.unique keeps the FIRST occurrence per key of the
-                # reversed array == the LAST write in round order
-                _, first = np.unique(key[::-1], return_index=True)
-                trips = trips[len(trips) - 1 - first]
-            else:
-                trips = np.zeros((0, 3), np.int32)
-            p = _pad_to(max(len(trips), 1), 8)
-            oob = self._bases()["rows"]
-            padded = np.zeros((p, 3), dtype=np.int32)
-            padded[:len(trips)] = trips
-            padded[len(trips):, 0] = oob
+            padded, n_trips = self._merged_trips(trip_list)
         if pre_rows is not None:
             self.rows_dev = self._to_dev(pre_rows)
             self._dirty = False
@@ -1955,7 +1991,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         with dispatchledger.call_scope(
                 "rows_apply", backend="device", docs=len(touched),
                 axes={"docs": docs_axis,
-                      "trips": (max(len(trips), 1), p)}):
+                      "trips": (max(n_trips, 1), len(padded))}):
             self.rows_dev, h = metrics.dispatch_jit(
                 "apply_final", _apply_final,
                 self.rows_dev, padded_dev, blocks_dev, h_prev,
@@ -1989,15 +2025,13 @@ class ResidentRowsDocSet(ResidentDocSet):
         """
         n = len(self.doc_ids)
         mirror = self._ensure_hash_mirror()
-        if self._hash_handle is not None \
-                and (self._dirty or self.rows_dev is None):
+        if self._hash_handle is not None and not self._dev_current:
             # the handle predates a re-layout/invalidation (add_docs pad
             # growth, _grow, remap): it can never be consumed — drop it,
             # or hashes_clean would stay False forever and the sharded
             # cache would re-read this shard on every fleet read
             self._hash_handle = None
-        if self._hash_handle is not None and not self._dirty \
-                and self.rows_dev is not None:
+        if self._hash_handle is not None and self._dev_current:
             # breadcrumb BEFORE the readback barrier: a device hang
             # surfaces at np.asarray below, and the flight recorder must
             # already show this thread entered the readback
@@ -2034,7 +2068,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             # majority dirty: the narrow gather would copy most of the
             # buffer anyway — run the full-buffer reconcile (one kernel
             # shape for the steady fleet, device copy re-primed)
-            if self.rows_dev is None or self._dirty:
+            if not self._dev_current:
                 self.rows_dev = self._to_dev(self.rows_host)
                 self._dirty = False
             with dispatchledger.call_scope(
@@ -2078,34 +2112,57 @@ class ResidentRowsDocSet(ResidentDocSet):
         return i_used.astype(np.int64), l_used.astype(np.int64)
 
     def _reconcile_lanes(self, idxs: list[int], interpret) -> None:
-        """Reconcile ONLY the given doc lanes: gather their columns from
-        the host row mirror into a narrow [ROWS, k_pad] buffer and run the
-        SAME fused kernel on it (dims carry no lane count, so the kernel
-        is reused across fleets; k_pad quantizes to the 128 lane width, so
+        """Reconcile ONLY the given doc lanes (ascending): gather their
+        columns into a narrow [ROWS, k_pad] buffer and run the SAME fused
+        kernel on it (dims carry no lane count, so the kernel is reused
+        across fleets; k_pad quantizes to the 128 lane width, so
         recompiles are bounded by the dirty-set size distribution, not its
         values). Dispatch + readback cost is O(dirty), independent of
         fleet size — the difference between a convergence read that scales
-        and the r5 O(fleet) stall."""
+        and the r5 O(fleet) stall.
+
+        Where the device copy is current the columns are gathered out of
+        it, on the device (gather_lanes), and only the lane indices cross
+        the link. Where it is not (after add_docs pad growth, _grow, a
+        remap, compact, a failed dispatch) they are gathered out of the
+        host mirror and uploaded, and an eager engine then uploads the
+        mirror once, so that the next round or read finds the copy (a lazy
+        engine drops it at every round: nothing to prime)."""
         k = len(idxs)
         k_pad = pad_to_lanes(k)
-        # padding lanes must be VALID doc columns (a zero column is not:
-        # empty lanes carry -1 in the ac/fid/if/io bands); repeat the last
-        # dirty lane — its extra hashes are discarded below
-        sel = np.asarray(idxs + [idxs[-1]] * (k_pad - k), np.int64)
+        on_device = self._dev_current
         with perfscope.phase("pack"):
-            sub = np.ascontiguousarray(self.rows_host[:, sel])
-        sub_dev = self._to_dev(sub)
+            # padding lanes must be VALID doc columns (a zero column is
+            # not: empty lanes carry -1 in the ac/fid/if/io bands); repeat
+            # the last dirty lane — its extra hashes are discarded below
+            sel = np.asarray(idxs + [idxs[-1]] * (k_pad - k), np.int64)
+            # what crosses the link: the gather's plan, or the lanes
+            staged = lane_gather_plan(sel, self.n_pad) if on_device \
+                else np.ascontiguousarray(self.rows_host[:, sel])
+        sub_dev = self._to_dev(staged)
         with dispatchledger.call_scope(
                 "rows_hash", backend="device", docs=k,
                 axes={"docs": (k, k_pad)}):
+            if on_device:
+                sub_dev = metrics.dispatch_jit(
+                    "gather_lanes", gather_lanes,
+                    self.rows_dev, sub_dev, k_pad, interpret)
             h = metrics.dispatch_jit(
                 "reconcile_rows_hash", reconcile_rows_hash,
                 sub_dev, self.dims(), interpret)
+        if on_device:
+            metrics.bump("rows_lane_gathers_device")
+        else:
+            metrics.bump("rows_lane_gathers_host")
         flightrec.record("rows_hash_readback", docs=k, cached=False)
         with perfscope.phase("readback"):
             vals = self._to_host(h)
         self._hash_mirror[np.asarray(idxs, np.int64)] = vals[:k]
         self._doc_dirty.difference_update(idxs)
+        if not on_device and not self.lazy_dispatch:
+            self.rows_dev = self._to_dev(self.rows_host)
+            self._dirty = False
+            self._h_prev = None
 
     def hashes(self, interpret: bool | None = None) -> np.ndarray:
         """Current per-doc state hashes from resident state, O(dirty) not
@@ -2144,9 +2201,12 @@ class ResidentRowsDocSet(ResidentDocSet):
 
     def resident_bytes(self) -> int:
         """Footprint of this engine's resident state: the host row mirror,
-        the device buffer (same layout), and the per-doc admission
-        counters; not the device hash vector beside the buffer (_h_prev,
-        4 bytes a lane). The memory gauge (`rows_resident_bytes`) and
+        the device buffer (same layout; an eager engine holds it through
+        rounds and single ingests alike, from the first hash refresh after
+        whatever dropped it), and the per-doc admission counters; not the
+        device hash vector beside the buffer (_h_prev, 4 bytes a lane),
+        nor a round's gathered [ROWS, k_pad] lanes, which live for one
+        reconcile. The memory gauge (`rows_resident_bytes`) and
         flight-recorder post-mortems carry this number."""
         total = int(self.rows_host.nbytes)
         if self.rows_dev is not None:
@@ -2255,6 +2315,17 @@ def _apply_final(rows, trips, blocks, h_prev, dims, interpret):
         h = jax.lax.dynamic_update_slice(
             h, h_sub[k * LANE:(k + 1) * LANE], (starts[k],))
     return rows, h
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter_trips(rows, trips):
+    """A round's merged triplets (_merged_trips) into the resident rows,
+    donated. Keyed on the triplet pad alone. The reconcile is the
+    caller's: a round's lanes are gathered out of the result
+    (gather_lanes) for reconcile_rows_hash. No `unique_indices` /
+    `indices_are_sorted`, true as both are of merged triplets: with them
+    the chip's scatter lost a cell of 12,300 every few rounds."""
+    return rows.at[trips[:, 0], trips[:, 1]].set(trips[:, 2], mode="drop")
 
 
 @partial(jax.jit, static_argnames=("dims", "interpret"),

@@ -170,6 +170,12 @@ COUNTERS: dict[str, str] = {
         "classic-route applies that reconciled only the dirty 128-lane "
         "blocks of the resident rows (resident_rows._apply_final)",
     "rows_apply_blocks": "128-lane blocks those applies reconciled",
+    "rows_lane_gathers_device":
+        "lane reconciles whose columns were gathered on the device, out "
+        "of the resident rows (resident_rows._reconcile_lanes)",
+    "rows_lane_gathers_host":
+        "lane reconciles whose columns were gathered out of the host "
+        "mirror and uploaded (the device copy was not current)",
     # sync — services, wire protocol, transports, log archive
     "sync_frames_sent": "columnar change frames sent",
     "sync_frames_received": "columnar change frames received",

@@ -92,7 +92,8 @@ PHASES: dict[str, str] = {
              "and bucket planning against the link prices "
              "(engine/dispatch.py plan_round)",
     "pack": "columnar batch/rows packing on the host (engine/pack.py) and "
-            "the gather of dirty lanes from the host row mirror",
+            "the gather of dirty lanes from the host row mirror, or the "
+            "plan of their gather on the device (_reconcile_lanes)",
     "upload": "host->device transfers of the resident engines (_to_dev)",
     "dispatch": "jitted kernel dispatch calls (metrics.dispatch_jit)",
     "device_wait": "explicit host barriers on in-flight device work "

@@ -11,6 +11,14 @@ document the round never touched.
 
 The fleet spans eight blocks, the last partly padding: three dirty blocks
 pad to four, which is still a minority of eight.
+
+The round route keeps the device copy too (`_dispatch_final`'s megabatch
+branch scatters the round's triplets into it, `_reconcile_lanes` gathers
+the dirty lanes out of it), so the same parity holds after rounds, and
+wherever the copy is current it must equal the host mirror cell for cell:
+a triplet the scatter lost would show there, and as a wrong hash one
+round later. On the CPU `jnp.asarray` may alias the mirror's memory, which
+would make that comparison empty: the fleet's uploads copy.
 """
 
 import numpy as np
@@ -63,6 +71,7 @@ class Fleet:
         self.rset = ResidentRowsDocSet(self.ids, actors=["W"])
         if self.rset._native is None:
             pytest.skip("round frames need the native encoder")
+        self.rset._to_dev = jnp.array       # a copy, never an alias
         self.want = loaded_hashes.copy()
         # every document in one round finds no device buffer and dirties
         # every block: the mirror uploads and the whole buffer reconciles
@@ -94,6 +103,31 @@ class Fleet:
             jnp.asarray(self.rset.rows_host), self.rset.dims(), True))
         np.testing.assert_array_equal(got, fresh)
         np.testing.assert_array_equal(got[:n], self.want)
+        if self.current():
+            np.testing.assert_array_equal(np.asarray(self.rset.rows_dev),
+                                          self.rset.rows_host)
+
+    def current(self) -> bool:
+        return self.rset.rows_dev is not None and not self.rset._dirty
+
+    def round(self, docs, gathered):
+        """One checked round of the round route (two documents or more)
+        whose dirty lanes must be gathered on the `gathered` side
+        ("device" or "host"; None: the router fused it, no lane gather);
+        the device copy is current afterwards either way."""
+        before, jits = _gathers(), _dispatched()
+        handle = self.apply(docs)
+        after = _gathers()
+        want = {"device": (1, 0), "host": (0, 1), None: (0, 0)}[gathered]
+        assert (after[0] - before[0], after[1] - before[1]) == want, (
+            f"round over {docs}")
+        assert self.current() and self.rset._h_prev is None
+        if gathered == "device":
+            # the reconcile stays a dispatch of the top-level jit on the
+            # gathered lanes (megakernel_roofline finds its kernel by the
+            # jit's name), behind one scatter and one gather
+            assert [b - a for a, b in zip(jits, _dispatched())] == [1, 1, 1]
+        return handle
 
     def routed(self, docs, calls, blocks=0):
         """One checked round that must make `calls` block-route calls
@@ -110,6 +144,28 @@ def _counters():
     snap = metrics.snapshot()
     return (snap.get("rows_apply_block_calls", 0),
             snap.get("rows_apply_blocks", 0))
+
+
+def _gathers():
+    snap = metrics.snapshot()
+    return (snap.get("rows_lane_gathers_device", 0),
+            snap.get("rows_lane_gathers_host", 0))
+
+
+def _dispatched():
+    kernels = (metrics.snapshot().get("perf") or {}).get("kernels") or {}
+    return [(kernels.get(k) or {}).get("dispatches", 0) for k in
+            ("scatter_trips", "gather_lanes", "reconcile_rows_hash")]
+
+
+def _round_route(monkeypatch, fuses=False):
+    """Rounds of two and more documents take the round route again; the
+    router fuses them, or declines and leaves them to the lane gather
+    (what it does in every cell of the benchmark)."""
+    monkeypatch.setattr(round_dispatch, "_megabatch", True)
+    if not fuses:
+        monkeypatch.setattr(round_dispatch, "apply_round_adaptive",
+                            lambda rset, plan, interpret=False: None)
 
 
 @pytest.fixture
@@ -144,12 +200,94 @@ def _majority_of_blocks_takes_whole_buffer(f, monkeypatch):
 
 
 def _after_fused_round(f, monkeypatch):
-    monkeypatch.setattr(round_dispatch, "_megabatch", True)
+    """A round the router fuses leaves the copy on the device, with the
+    round scattered into it; the hash vector beside it is gone, so the
+    next single edit reconciles the whole buffer once and the one after
+    it a block."""
+    _round_route(monkeypatch, fuses=True)
     f.rset.hashes()            # the load's hashes read: no lane is dirty
-    f.routed([10, 600], 0)     # two documents: the fused route's round
-    assert f.rset.rows_dev is None and f.rset._h_prev is None
+    f.round([10, 600], None)   # two documents: the fused route's round
     f.routed([10], 0)
     f.routed([600], 1, 1)
+
+
+def _round_on_current_copy(f, monkeypatch):
+    _round_route(monkeypatch)
+    f.rset.hashes()
+    f.round([10, 600, 601], "device")
+    f.routed([10], 0)
+    f.routed([601], 1, 1)
+
+
+def _two_rounds_in_a_row(f, monkeypatch):
+    """The second round overwrites cells the first wrote (the same
+    documents' next ops land on new rows, their clocks on the same)."""
+    _round_route(monkeypatch)
+    f.rset.hashes()
+    f.round([3, 130, 999], "device")
+    f.round([3, 4, 130, 500], "device")
+    f.routed([4], 0)
+
+
+def _round_after_grow(f, monkeypatch):
+    _round_route(monkeypatch)
+    f.rset._grow(cap_ops=2 * f.rset.cap_ops)
+    f.rset.hashes()            # every lane dirty: the whole buffer, primed
+    f.rset._grow(cap_ops=2 * f.rset.cap_ops)
+    assert not f.current()
+    # most lanes are dirty again: a hashes_for read reconciles a few on
+    # the host and primes; the round after it gathers on the device
+    before = _gathers()
+    f.rset.hashes_for([700, 701])
+    assert _gathers() == (before[0], before[1] + 1) and f.current()
+    f.rset.hashes()
+    f.round([700, 701], "device")
+
+
+def _round_after_add_docs(f, monkeypatch):
+    _round_route(monkeypatch)
+    f.rset.hashes()
+    _add_docs(f, 30)           # a ninth block: the buffer is dropped
+    assert f.rset.rows_dev is None
+    f.round([5, N_DOCS + 20], "host")      # reads the mirror, then primes
+    f.round([5, N_DOCS + 21], "device")
+    _add_docs(f, 10)           # inside the padding: the buffer stays
+    f.round([6, N_DOCS + 35], "device")    # and the fresh lanes with it
+
+
+def _round_after_compact(f, monkeypatch):
+    f.routed([42], 1, 1)       # a second write of "n": the first is dominated
+    f.rset.hashes()
+    _round_route(monkeypatch)
+    stats = f.rset.compact({f.ids[42]: {"W": len(f.logs[42])}})
+    assert stats[f.ids[42]]["ops_after"] < stats[f.ids[42]]["ops_before"]
+    assert f.rset.rows_dev is None
+    f.round([42, 43], "host")
+    f.round([42, 44], "device")
+
+
+def _failed_gather_then_retry(f, monkeypatch):
+    """tests/test_dispatch_failure.py's contract on the round route: the
+    admission stands in the host mirror, the device copy is dropped, the
+    lanes stay dirty, and the next round reconciles them from the mirror."""
+    _round_route(monkeypatch)
+    f.rset.hashes()
+
+    def lost(*args, **kwargs):
+        raise RuntimeError("device lost mid-gather")
+    failed = metrics.snapshot().get("rows_dispatch_failed", 0)
+    with monkeypatch.context() as m:
+        m.setattr(resident_rows, "gather_lanes", lost)
+        with pytest.raises(DeviceDispatchError) as err:
+            f.apply([300, 301], check=False)
+    assert err.value.admission_complete
+    assert metrics.snapshot()["rows_dispatch_failed"] == failed + 1
+    assert f.rset.rows_dev is None and f.rset._h_prev is None
+    assert {300, 301} <= f.rset._doc_dirty
+    for i in (300, 301):
+        f.want[i] = _oracle([f.logs[i]])[0]
+    f.round([302, 303], "host")
+    f.round([300, 302], "device")
 
 
 def _after_fused_round_and_majority_read(f, monkeypatch):
@@ -178,17 +316,19 @@ def _after_compact(f, monkeypatch):
     f.routed([43], 1, 1)
 
 
+def _add_docs(f, k):
+    new = [f"e{len(f.ids) + j:04d}" for j in range(k)]
+    f.rset.add_docs(new)
+    f.ids += new
+    f.logs += [[] for _ in new]
+    f.want = np.concatenate([f.want, _oracle([[]] * k)])
+
+
 def _after_add_docs(f, monkeypatch):
-    def add(k):
-        new = [f"e{len(f.ids) + j:04d}" for j in range(k)]
-        f.rset.add_docs(new)
-        f.ids += new
-        f.logs += [[] for _ in new]
-        f.want = np.concatenate([f.want, _oracle([[]] * k)])
-    add(10)                    # inside the padding: the buffer stays
+    _add_docs(f, 10)           # inside the padding: the buffer stays
     assert f.rset.n_pad == N_BLOCKS * 128 and f.rset._h_prev is not None
     f.routed([N_DOCS + 2], 1, 1)
-    add(30)                    # a ninth block: the buffer is dropped
+    _add_docs(f, 30)           # a ninth block: the buffer is dropped
     assert f.rset.n_pad == (N_BLOCKS + 1) * 128
     assert f.rset.rows_dev is None and f.rset._h_prev is None
     f.routed([N_DOCS + 20], 0)
@@ -233,6 +373,12 @@ SCENARIOS = {
     "after-add-docs": _after_add_docs,
     "kept-handle-not-donated": _kept_handle_is_not_donated,
     "failed-dispatch-then-retry": _failed_dispatch_then_retry,
+    "round-on-current-copy": _round_on_current_copy,
+    "two-rounds-in-a-row": _two_rounds_in_a_row,
+    "round-after-grow": _round_after_grow,
+    "round-after-add-docs": _round_after_add_docs,
+    "round-after-compact": _round_after_compact,
+    "failed-gather-then-retry": _failed_gather_then_retry,
 }
 
 

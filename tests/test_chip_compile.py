@@ -22,6 +22,18 @@ the [6308, 384] a quarter of a storm round pads to, and over 256 and 512
 lanes, the neighbours a seed may reach; `_apply_final` on a shard's
 resident [6308, 2560] at one block.
 
+Since a round keeps the rows on the chip, its two programs are here as
+well: `_scatter_trips` at the power-of-two triplet pads a round of either
+cell reaches, and `gather_lanes` out of the fleet's [6308, 10112] (a
+shard's [6308, 2560]) into the 1152, 1280 and 1408 (256, 384, 512) lanes
+a round's dirty documents pad to. The gather may need no temporary worth
+the name: `rows[:, sel]` as XLA lowers it copies the whole buffer into
+another layout first, 255 MB a request. The scatter is XLA's and does
+re-lay the donated buffer on the device around its updates (3.1 ms a
+round of 12,300 triplets on the chip); it is held to the device's memory
+alone. The reconcile that follows the gather is `reconcile_rows_hash` on
+those same lane counts, each held to its roofline metric's patterns.
+
 Nothing runs and no time is implied: a compile that passes is not a chip
 run. The topology is described inside a fixture, never at import (only one
 process at a time may load the TPU's library, and every xdist worker imports
@@ -50,6 +62,10 @@ BENCH_CAPS = (512, 4, 32)      # the benchmark fleet's (its load stage line)
 BENCH_STORM_LANES = 1_280      # a storm request's dirty documents, padded
 SHARD_LANES = 2_560            # pad_to_lanes(a shard's 2,510 or 2,512 documents)
 SHARD_STORM_LANES = (256, 384, 512)   # a quarter of a round: 262-354 documents
+STORM_LANES = (1_152, 1_280, 1_408)   # a round: 1,170-1,300 documents
+# a round's merged triplets (9-11 a change), padded to a power of two
+STORM_TRIP_PADS = (8_192, 16_384)
+SHARD_TRIP_PADS = (2_048, 4_096)
 
 
 def _dims(i, a, le):
@@ -123,6 +139,24 @@ def _apply_final(caps, trips, blocks=None, lanes=FLEET_LANES):
         return _apply_final.lower(
             chip.one((rows_count(*caps), lanes)),
             chip.one((trips, 3)), *by_block, _dims(*caps), False)
+    return build
+
+
+def _scatter_trips(lanes, trips):
+    def build(chip):
+        from automerge_tpu.engine.resident_rows import _scatter_trips
+        return _scatter_trips.lower(
+            chip.one((rows_count(*BENCH_CAPS), lanes)), chip.one((trips, 3)))
+    return build
+
+
+def _gather_lanes(lanes, k_pad):
+    def build(chip):
+        from automerge_tpu.engine.pallas_kernels import gather_lanes
+        steps = lanes // 128 + k_pad // 128
+        return gather_lanes.lower(
+            chip.one((rows_count(*BENCH_CAPS), lanes)),
+            chip.one((k_pad + 2 * steps,)), k_pad, False)
     return build
 
 
@@ -211,6 +245,20 @@ CASES = {
     "apply_doc-reference": (_apply_doc_reference, False),
     "sharded-megakernel-4-devices": (_sharded_megakernel, True),
 }
+CASES.update({
+    f"scatter_trips-{name}-{trips}-triplets": (
+        _scatter_trips(lanes, trips), False)
+    for name, lanes, pads in (("fleet", FLEET_LANES, STORM_TRIP_PADS),
+                              ("shard", SHARD_LANES, SHARD_TRIP_PADS))
+    for trips in pads})
+# a gather that copies the buffer it reads (XLA's `rows[:, sel]` does)
+# fails here: its only large buffer is its output
+CASES.update({
+    f"gather_lanes-{name}-{k_pad}-lanes": (
+        _gather_lanes(lanes, k_pad), True, 1 << 20)
+    for name, lanes, pads in (("fleet", FLEET_LANES, STORM_LANES),
+                              ("shard", SHARD_LANES, SHARD_STORM_LANES))
+    for k_pad in pads})
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -255,6 +303,13 @@ CELL_KERNELS.update({
         "megakernel_roofline", _megakernel(*BENCH_CAPS, lanes),
         (rows_count(*BENCH_CAPS), lanes))
     for lanes in SHARD_STORM_LANES})
+# the reconcile behind a round's device gather: the same top-level jit on
+# the gathered lanes, at the neighbours of 1,280 a seed may reach
+CELL_KERNELS.update({
+    f"megakernel_roofline-{lanes}-lanes": (
+        "megakernel_roofline", _megakernel(*BENCH_CAPS, lanes),
+        (rows_count(*BENCH_CAPS), lanes))
+    for lanes in STORM_LANES if lanes != BENCH_STORM_LANES})
 
 
 def _event_names(compiled) -> list:
