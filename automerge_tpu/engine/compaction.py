@@ -327,10 +327,7 @@ def compact(rset, floors: dict[str, dict[str, int]],
             if rset._native is not None:
                 _sync_native_elem_slots(rset, i)
     if touched:
-        rset._dirty = True
-        rset._hash_handle = None
-        rset._h_prev = None
-        rset.rows_dev = None
+        rset._drop_copy()
         rset._elems_hi = max((t.max_elems for t in rset.tables), default=0)
         metrics.bump("rows_docs_compacted")
     return stats

@@ -15,13 +15,15 @@ A small single document therefore *belongs on the host* wherever a link
 roundtrip costs more than the job; the DocSet batch axis is where the
 device path wins (128+ documents per dispatch). This module is the
 product-path router that makes that call, the moral equivalent of XLA's
-own host/device offload decisions.
+own host/device offload decisions. It also holds the one decision of how
+a resident rows set reconciles its dirty lanes (reconcile_route): a round
+asks it once, a hash read once, and the engine executes what it returns.
 
 The cost-model constants below were not measured on a directly attached
 chip: they price a dispatch at 25 ms and a readback at 70 ms, which no
-chip run of this round bears out. Re-pricing them from the chip, or
-deleting the decisions one side always wins, is ROADMAP S3; calibrate()
-overrides them meanwhile.
+chip run bears out. Re-pricing them from the chip, or deleting the
+decisions one side always wins, is ROADMAP S5; calibrate() overrides them
+meanwhile.
 """
 
 from __future__ import annotations
@@ -29,9 +31,16 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass, field
 
-from ..utils import perfscope
+import numpy as np
 
-# Link cost model (seconds). Not measured on the chip (ROADMAP S3).
+from ..utils import metrics, perfscope
+from . import dispatchledger
+from .encode import _pad_to
+from .pack import (LANE, MOVE_CAND_FIELDS, MOVE_NODE_FIELDS, SPAN_FIELDS,
+                   mega_row_map, pack_spans, pad_to_lanes, plan_megabuckets,
+                   rows_count)
+
+# Link cost model (seconds). Not measured on the chip (ROADMAP S5).
 _LINK = {
     "dispatch_fixed_s": 0.025,   # per jitted dispatch (amortizable)
     "h2d_call_s": 0.010,         # per host->device transfer call
@@ -121,14 +130,6 @@ def plan_for(doc_changes: list, passes: int = 1) -> Plan:
     """Plan (no execution) for a concrete from-scratch batch: estimates the
     wire from the same padded dims pack.py will use, and prices the host
     side per document with apply_host's actual bulk/interpretive predicate."""
-    from .pack import pad_to_lanes, rows_count
-
-    def _pad(n, minimum=8):
-        p = minimum
-        while p < n:
-            p *= 2
-        return p
-
     # one fused pass per doc (this runs per ROUTED job — on a millisecond
     # single-doc apply the router's own scan is a measurable tax)
     max_ops = 1
@@ -147,8 +148,8 @@ def plan_for(doc_changes: list, passes: int = 1) -> Plan:
             max_ops = doc_ops
         if doc_ins > max_ins:
             max_ins = doc_ins
-    ops_pad = _pad(max_ops)
-    ins_pad = _pad(max_ins)
+    ops_pad = _pad_to(max_ops)
+    ins_pad = _pad_to(max_ins)
     d_pad = pad_to_lanes(len(doc_changes))  # pack.py's canonical lane pad
     wire_bytes = (rows_count(ops_pad, max(len(actors), 1), ins_pad)
                   * d_pad * 4)
@@ -170,15 +171,18 @@ def plan_for(doc_changes: list, passes: int = 1) -> Plan:
 
 
 # ---------------------------------------------------------------------------
-# Megabatch round planning (r20): one fused multi-doc dispatch per flush
-# round. pack.plan_megabuckets quantizes the round's ragged doc sizes onto
-# a small shape ladder; this planner prices the fused bucketed dispatches
-# against what the engine would otherwise do (full-buffer reconcile when a
-# majority of the fleet is dirty, the narrow full-dims lane gather
-# otherwise) and apply_round_adaptive executes the winning route.
+# How a resident rows set reconciles its dirty lanes. reconcile_route is
+# the decision; plan_round prices the fused route for it:
+# pack.plan_megabuckets quantizes the lanes' ragged doc sizes onto a small
+# shape ladder and the bucketed dispatches are compared with what the
+# lanes would take otherwise (the whole buffer when a majority of the
+# fleet is dirty, the full-dims lane gather when not).
+
+# A round or a read of fewer documents never plans the fused route: no
+# batch of one amortizes bucket planning.
+MEGABATCH_MIN_DOCS = 2
 
 _megabatch: bool | None = None
-_megabatch_min: int | None = None
 
 
 def megabatch_enabled() -> bool:
@@ -190,24 +194,9 @@ def megabatch_enabled() -> bool:
     return _megabatch
 
 
-def megabatch_min_docs() -> int:
-    """Routing threshold (AMTPU_MEGABATCH_MIN_DOCS, default 2): rounds
-    dirtying fewer docs stay on the per-doc path — no batch of one can
-    amortize bucket planning."""
-    global _megabatch_min
-    if _megabatch_min is None:
-        try:
-            _megabatch_min = max(
-                int(os.environ.get("AMTPU_MEGABATCH_MIN_DOCS", "2")), 1)
-        except ValueError:
-            _megabatch_min = 2
-    return _megabatch_min
-
-
 def _reload_for_tests() -> None:
-    global _megabatch, _megabatch_min
+    global _megabatch
     _megabatch = None
-    _megabatch_min = None
 
 
 @dataclass
@@ -219,18 +208,131 @@ class RoundPlan:
     est_alt_s: float = 0.0
 
 
-@perfscope.phased("route")
-def plan_round(rset, idxs) -> RoundPlan:
-    """Round-level routing for the dirty docs `idxs` of a resident set:
-    bucket their exact used sizes (band scans — correct across
-    compaction/rebuild) and compare the fused bucketed dispatches against
-    the per-doc-path alternative. Returns a RoundPlan whose buckets are
-    the offset tables apply_round_adaptive executes."""
-    from ..utils import metrics
-    from .pack import pad_to_lanes, plan_megabuckets, rows_count
+@dataclass(frozen=True)
+class Route:
+    """What reconcile_route decided; ResidentRowsDocSet executes it."""
+    kind: str             # deferred | blocks | whole | lanes | fused | handle
+    lanes: list = field(default_factory=list)   # the lanes it reconciles
+    plan: RoundPlan | None = None               # fused: its dispatches
+    blocks: tuple = ()    # blocks: the dirty 128-lane blocks, padded
+    # False: scatter and reconcile are one program (_apply_final) whose
+    # hash vector stays on the device as the pending handle
+    readback: bool = True
 
+
+def share_route(rset, lanes) -> Route:
+    """The minority rule: a majority of the fleet reconciles as the whole
+    buffer (the lane gather would copy most of it anyway; one kernel
+    shape, and the device copy is primed), a minority as gathered lanes."""
+    whole = 2 * len(lanes) >= len(rset.doc_ids)
+    return Route("whole" if whole else "lanes", lanes)
+
+
+def _fused_route(rset, lanes) -> Route | None:
+    plan = plan_round(rset, lanes)
+    return Route("fused", lanes, plan) if plan.route == "megabatch" else None
+
+
+def _read_route(rset, lanes) -> Route:
+    minority = 2 * len(lanes) < len(rset.doc_ids)
+    return (minority and _fused_route(rset, lanes)) or share_route(rset, lanes)
+
+
+@perfscope.phased("route")
+def reconcile_route(rset, lanes, round_docs: int | None = None) -> Route:
+    """How the dirty `lanes` (doc indices, ascending) of a resident rows
+    set reconcile. The one place this is decided: a round asks once, after
+    it has committed its triplets to the host mirror and marked the lanes
+    they touch dirty (`lanes` are those, `round_docs` the number of
+    documents its frames name); a hash read asks once (`round_docs` None,
+    `lanes` the dirty ones among those asked for, at least one unless a
+    handle is pending). Reads state, changes none. "Plans" below is one
+    plan_round call: off under AMTPU_MEGABATCH=0 and for fewer than
+    MEGABATCH_MIN_DOCS lanes, else the link cost model's verdict on the
+    bucketed dispatches. n = len(rset.doc_ids).
+
+    A round:
+
+    | engine | the round                        | observed                           | route    |
+    |--------|----------------------------------|------------------------------------|----------|
+    | lazy   | any                              |                                    | deferred |
+    | eager  | one document, or no lane touched,| _h_prev valid, the dirty 128-lane  | blocks   |
+    |        | or AMTPU_MEGABATCH=0: never plans| blocks (padded to a power of two)  |          |
+    |        |                                  | at most half of n_pad / 128        |          |
+    | eager  | the same                         | no valid _h_prev (the copy was     | whole,   |
+    |        |                                  | uploaded or re-laid since), or the | readback |
+    |        |                                  | blocks are no minority             | False    |
+    | eager  | two documents or more: plans over| the plan fuses                     | fused    |
+    |        | its lanes whatever their share   |                                    |          |
+    | eager  | the same                         | declined; no other lane is dirty;  | lanes    |
+    |        |                                  | 2 * lanes < n                      |          |
+    | eager  | the same                         | declined; no other lane is dirty;  | whole    |
+    |        |                                  | 2 * lanes >= n                     |          |
+    | eager  | the same                         | declined; lanes from outside the   | a read's,|
+    |        |                                  | round are dirty too (a failed      | over all |
+    |        |                                  | dispatch, a deferred read)         | of them  |
+
+    A read:
+
+    | observed                                                   | route  |
+    |------------------------------------------------------------|--------|
+    | a flush-time hash handle is pending and the copy current   | handle |
+    | 2 * lanes < n: plans; the plan fuses                       | fused  |
+    | 2 * lanes < n, declined                                    | lanes  |
+    | 2 * lanes >= n: never plans                                | whole  |
+
+    deferred: drop the copy; the next read reconciles. blocks, and whole
+    with readback False: the scatter and the reconcile in one program
+    (_apply_final), on the device copy (uploaded first where it is not
+    current), the hash vector left there as _h_prev and the pending
+    handle. fused: the plan's bucketed dispatches out of the host mirror.
+    lanes: gather_lanes out of a current copy, the host gather and an
+    upload where it is not, one reconcile. whole: reconcile_rows_hash
+    over the copy (uploaded where not current), read back, kept as
+    _h_prev. handle: one readback of the pending vector. A round's
+    fused, lanes and read-back whole first scatter its triplets into the
+    copy where it is current (one that is not is dropped) and drop _h_prev
+    and the handle.
+
+    Where the round and the read differ: a round plans over its lanes
+    even when they are a majority of the fleet, a read only for a
+    minority; a round of one document never plans, a read of its lane and
+    another's does (ROADMAP S5: undecided).
+    """
+    if round_docs is None:
+        if rset._hash_handle is not None and rset._dev_current:
+            return Route("handle")
+        return _read_route(rset, lanes)
+    if rset.lazy_dispatch:
+        return Route("deferred")
+    if not (megabatch_enabled() and round_docs >= MEGABATCH_MIN_DOCS
+            and lanes):
+        blocks = sorted({i // LANE for i in lanes})
+        nb = _pad_to(len(blocks), 1)
+        if blocks and rset._h_prev is not None and rset._dev_current \
+                and 2 * nb <= rset.n_pad // LANE:
+            # padded by repeating the last: its hashes are written twice
+            return Route("blocks", lanes, readback=False, blocks=tuple(
+                blocks + blocks[-1:] * (nb - len(blocks))))
+        return Route("whole", lanes, readback=False)
+    fused = _fused_route(rset, lanes)
+    if fused is not None:
+        return fused
+    n = len(rset.doc_ids)
+    dirty = sorted(i for i in rset._doc_dirty if i < n)
+    return share_route(rset, lanes) if dirty == lanes \
+        else _read_route(rset, dirty)
+
+
+def plan_round(rset, idxs) -> RoundPlan:
+    """The fused route priced for the dirty docs `idxs` of a resident
+    set: bucket their exact used sizes (band scans — correct across
+    compaction/rebuild) and compare the fused bucketed dispatches against
+    what the lanes take otherwise. Returns a RoundPlan whose buckets are
+    the offset tables apply_round_adaptive executes. Its time counts in
+    the `route` phase of reconcile_route, its one caller in the package."""
     idxs = sorted(int(i) for i in idxs)
-    if not megabatch_enabled() or len(idxs) < megabatch_min_docs():
+    if not megabatch_enabled() or len(idxs) < MEGABATCH_MIN_DOCS:
         return RoundPlan("per_doc", idxs)
     i_used, l_used = rset._mega_doc_sizes(idxs)
     dims_i, a, dims_le, _a_set, _a_del = rset.dims()
@@ -259,15 +361,10 @@ def apply_round_adaptive(rset, plan: RoundPlan, interpret: bool = False):
     documents makes the hashes bit-identical to the per-doc path. The
     per-doc hash mirror is refreshed in place (the offset tables make
     unpacking exact); returns the round's occupancy summary, or None
-    when the plan routed per-doc (caller falls through to the classic
-    paths)."""
+    when the plan routed per-doc (the lanes stay dirty: the caller
+    reconciles them by their share of the fleet)."""
     if plan is None or plan.route != "megabatch" or not plan.buckets:
         return None
-    import numpy as np
-
-    from ..utils import metrics
-    from . import dispatchledger
-    from .pack import mega_row_map, pad_to_lanes
     from .pallas_kernels import reconcile_rows_hash
 
     dims_i, a, dims_le, a_set, a_del = rset.dims()
@@ -340,8 +437,6 @@ def plan_spans(n_docs: int, s_pad: int, passes: int = 1) -> Plan:
     whose span axis padded to `s_pad` lanes (engine/span_kernels.py). The
     wire is the packed [D, F, S_pad] block; the host alternative is the
     numpy reference path."""
-    from .pack import SPAN_FIELDS
-
     wire_bytes = n_docs * len(SPAN_FIELDS) * s_pad * 4
     dev = _device_cost(wire_bytes, passes)
     host = _LINK["span_fixed_s"] + n_docs * s_pad * _LINK["span_op_s"]
@@ -352,11 +447,7 @@ def merge_spans_adaptive(doc_spans: list, passes: int = 1):
     """Route a batched span-table merge through the cheaper backend.
     Returns (plan, result dict) — result arrays are numpy on the host
     path, device arrays on the device path (same schema)."""
-    from ..utils import metrics
-    from .pack import pack_spans
     from .span_kernels import merge_spans, merge_spans_host
-
-    from . import dispatchledger
 
     spans = pack_spans(doc_spans)
     plan = plan_spans(spans.shape[0], spans.shape[2], passes)
@@ -377,8 +468,6 @@ def plan_moves(n_docs: int, n_pad: int, k_pad: int,
     realms padded to `n_pad` node / `k_pad` candidate lanes
     (engine/move_kernels.py). The wire is the two packed lane blocks;
     the host alternative is the numpy fixpoint."""
-    from .pack import MOVE_CAND_FIELDS, MOVE_NODE_FIELDS
-
     wire_bytes = n_docs * (len(MOVE_NODE_FIELDS) * n_pad
                            + len(MOVE_CAND_FIELDS) * k_pad) * 4
     dev = _device_cost(wire_bytes, passes)
@@ -391,12 +480,7 @@ def resolve_moves_adaptive(packed: dict, passes: int = 1):
     """Route a batched move resolution through the cheaper backend.
     Returns (plan, result dict) — numpy arrays on the host path, device
     arrays on the device path (same schema)."""
-    from ..utils import metrics
     from .move_kernels import resolve_moves, resolve_moves_host
-
-    import numpy as _np
-
-    from . import dispatchledger
 
     nodes = packed["nodes"]
     plan = plan_moves(nodes.shape[0], nodes.shape[2],
@@ -404,8 +488,8 @@ def resolve_moves_adaptive(packed: dict, passes: int = 1):
     metrics.bump("engine_move_resolves", backend=plan.backend)
     # logical lane occupancy from the packed masks (row 0 is the node
     # mask, row 3 the per-node candidate counts)
-    n_log = int(_np.asarray(nodes)[:, 0, :].sum(axis=1).max(initial=0))
-    k_log = int(_np.asarray(nodes)[:, 3, :].sum(axis=1).max(initial=0))
+    n_log = int(np.asarray(nodes)[:, 0, :].sum(axis=1).max(initial=0))
+    k_log = int(np.asarray(nodes)[:, 3, :].sum(axis=1).max(initial=0))
     with dispatchledger.call_scope(
             "moves", plan=plan, docs=nodes.shape[0],
             axes={"docs": (nodes.shape[0], nodes.shape[0]),
@@ -495,7 +579,6 @@ def apply_host(changes, actor_id: str = "engine"):
         if ordered is not None:
             opset = try_bulk_build(changes_to_columns(ordered))
             if opset is not None:
-                from ..utils import metrics
                 metrics.bump("engine_bulk_built")
                 return materialize_root(actor_id, opset)
     doc = init(actor_id)
@@ -513,12 +596,6 @@ def apply_batch_adaptive(doc_changes: list, passes: int = 1):
     the host path, or the per-doc state-hash array on the device path
     (the device's readable-state decode is on-demand, engine/batchdoc.py).
     """
-    import numpy as np
-
-    from ..utils import metrics
-
-    from . import dispatchledger
-
     plan = plan_for(doc_changes, passes)
     with metrics.trace("engine_dispatch", backend=plan.backend), \
             dispatchledger.call_scope("apply", plan=plan,

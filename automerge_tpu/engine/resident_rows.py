@@ -712,10 +712,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         try:
             yield
         except Exception as e:
-            self.rows_dev = None
-            self._dirty = True
-            self._hash_handle = None
-            self._h_prev = None
+            self._drop_copy()
             metrics.bump("rows_dispatch_failed")
             raise DeviceDispatchError(str(e), admission_complete=True) from e
 
@@ -1379,25 +1376,16 @@ class ResidentRowsDocSet(ResidentDocSet):
                                    for rc in rounds]
                 with perfscope.phase("commit"):
                     self._grow_for_rounds(encoded)
-                    # r20 megabatch intent: an eager round dirtying enough
-                    # docs skips the full-buffer device apply (and its
-                    # pre-round host copy) — the dirty lanes reconcile
-                    # through the fused bucketed dispatches instead,
-                    # planned AFTER the trips commit so bucket shapes see
-                    # this round's ops (engine/dispatch.py plan_round)
-                    mega = (not self.lazy_dispatch
-                            and round_dispatch.megabatch_enabled()
-                            and len({d for rc in rounds
-                                     for d in rc.doc_ids})
-                            >= round_dispatch.megabatch_min_docs())
-                    need_pre = (not self.lazy_dispatch and not mega
-                                and not self._dev_current)
-                    pre_rows = self.rows_host.copy() if need_pre else None
                     trip_list = [self._cols_triplets(e) for e in encoded]
-                    self._mega_intent = mega
+                    touched = sorted(self._mark_trips_dirty(trip_list))
+                    round_docs = len({d for rc in rounds
+                                      for d in rc.doc_ids})
                 with self._dispatch_guard():
-                    return self._dispatch_final(trip_list, pre_rows,
-                                                interpret)
+                    # routed AFTER the trips commit: the fused route's
+                    # bucket shapes see this round's ops
+                    route = round_dispatch.reconcile_route(
+                        self, touched, round_docs)
+                    return self._dispatch_final(trip_list, route, interpret)
 
     def _register_round_actors(self, rc) -> None:
         cols = rc.cols
@@ -1892,104 +1880,86 @@ class ResidentRowsDocSet(ResidentDocSet):
         padded[n:, 0] = self._bases()["rows"]
         return padded, n
 
-    def _dispatch_final(self, trip_list, pre_rows, interpret):
-        """One scatter + one reconcile for the whole micro-batch: round
-        triplets are merged in order with last-wins dedup (rounds only
-        overwrite each other on re-linearized position rows), so the scan
-        over rounds collapses into a single gather-free scatter. The
-        reconcile covers the 128-lane blocks the triplets touched, on the
-        device, from the resident rows, and patches their hashes into the
-        hash vector of the last call (_h_prev); the whole buffer when
-        there is no such vector (after an upload) or the dirty blocks are
-        no minority. Returns the device hash array of every lane without
-        reading it back (None under lazy_dispatch — the next hashes() read
-        reconciles). Under the megabatch route (_mega_intent, set by
-        _apply_round_frames) a device copy that is current takes the same
-        scatter and stays; the round's lanes reconcile through the fused
-        bucketed dispatches or, where the router declines them, through
-        _reconcile_lanes, which gathers them out of that copy; the hashes
-        return from the host mirror. A copy that is not current is
-        dropped, and _reconcile_lanes uploads the mirror anew."""
-        mega = getattr(self, "_mega_intent", False)
-        self._mega_intent = False
-        with perfscope.phase("commit"):
-            touched = self._mark_trips_dirty(trip_list)
-        if self.lazy_dispatch:
+    def _dispatch_final(self, trip_list, route, interpret):
+        """Execute the route dispatch.reconcile_route gave this round (its
+        docstring holds the table). The rounds' triplets go to the device
+        as one scatter (_merged_trips). Returns the device hash array of
+        every lane, padded to n_pad, not read back where the route leaves
+        it on the device; None under `deferred`."""
+        if route.kind == "deferred":
             # _cols_triplets already committed the round to the host
-            # mirror; defer upload + reconcile to the next hash read —
-            # which, with the dirty lanes just marked, reconciles ONLY
-            # this round's docs (O(changes)), not the fleet
-            self.rows_dev = None
-            self._dirty = True
-            self._hash_handle = None
-            self._h_prev = None
+            # mirror; the next hash read uploads it and, with the dirty
+            # lanes marked, reconciles ONLY this round's docs
+            # (O(changes)), not the fleet
+            self._drop_copy()
             return None
-        if mega and touched:
-            # megabatch route: the round is committed to the host mirror;
-            # reconcile ONLY this round's lanes through the fused
-            # bucketed dispatches (flush-time hash freshness at O(round),
-            # not the O(fleet) full-buffer apply). A cost-model fallback
-            # leaves the lanes dirty; the hash refresh below reconciles
-            # them through the narrow gather — byte-identical hashes
-            # either way (pack.mega_row_map's subset property). A device
-            # copy that is current takes the round's triplets too, so the
-            # gather finds the rows on the device; one that is not is
-            # dropped (the gather then reads the mirror and uploads it).
-            if self._dev_current:
-                with perfscope.phase("commit"):
-                    # a round's count moves with its documents: small
-                    # rounds share one shape, large ones a power of two
-                    padded, n_trips = self._merged_trips(trip_list, 1024)
-                padded_dev = self._to_dev(padded)
-                with dispatchledger.call_scope(
-                        "rows_scatter", backend="device",
-                        docs=len(touched),
-                        axes={"trips": (max(n_trips, 1), len(padded))}):
-                    self.rows_dev = metrics.dispatch_jit(
-                        "scatter_trips", _scatter_trips,
-                        self.rows_dev, padded_dev)
-            else:
-                self.rows_dev = None
-                self._dirty = True
-            self._hash_handle = None
-            self._h_prev = None
-            plan = round_dispatch.plan_round(self, sorted(touched))
-            round_dispatch.apply_round_adaptive(self, plan, interpret)
-            # keep the return contract (post-batch per-doc hashes, padded
-            # to n_pad): a cost-model fallback — or dirty lanes outside
-            # this round — reconciles through the classic paths first
-            self._refresh_hash_mirror(None, interpret)
-            n = len(self.doc_ids)
-            out = np.zeros(self.n_pad, np.uint32)
-            out[:n] = self._ensure_hash_mirror()[:n]
-            return self._to_dev(out)
+        if not route.readback:
+            return self._apply_final_route(trip_list, route, interpret)
+        if self._dev_current:
+            with perfscope.phase("commit"):
+                # a round's count moves with its documents: small
+                # rounds share one shape, large ones a power of two
+                padded, n_trips = self._merged_trips(trip_list, 1024)
+            padded_dev = self._to_dev(padded)
+            with dispatchledger.call_scope(
+                    "rows_scatter", backend="device", docs=len(route.lanes),
+                    axes={"trips": (max(n_trips, 1), len(padded))}):
+                self.rows_dev = metrics.dispatch_jit(
+                    "scatter_trips", _scatter_trips, self.rows_dev,
+                    padded_dev)
+            self._hash_handle = self._h_prev = None
+        else:
+            self._drop_copy()
+        if route.kind == "fused":
+            round_dispatch.apply_round_adaptive(self, route.plan, interpret)
+        else:
+            self._reconcile(route, interpret)
+        # lanes a fused round did not take, and lanes left dirty from
+        # outside the round (a failed dispatch, a deferred read), are
+        # routed as a read; the steady round leaves none
+        self._refresh_hash_mirror(None, interpret)
+        n = len(self.doc_ids)
+        out = np.zeros(self.n_pad, np.uint32)
+        out[:n] = self._hash_mirror[:n]
+        return self._to_dev(out)
+
+    def _drop_copy(self) -> None:
+        """Forget the device copy and what was computed from it; the next
+        route that needs it uploads the host mirror (_prime)."""
+        self.rows_dev = None
+        self._dirty = True
+        self._hash_handle = None
+        self._h_prev = None
+
+    def _prime(self) -> None:
+        """Upload the host mirror as the device copy."""
+        self.rows_dev = self._to_dev(self.rows_host)
+        self._dirty = False
+        self._h_prev = None
+
+    def _apply_final_route(self, trip_list, route, interpret):
+        """`blocks`, or `whole` for a round that never plans: scatter and
+        reconcile in one program (_apply_final). `blocks` patches the
+        dirty blocks' hashes into _h_prev; `whole` (re)creates it."""
         with perfscope.phase("commit"):
             padded, n_trips = self._merged_trips(trip_list)
-        if pre_rows is not None:
-            self.rows_dev = self._to_dev(pre_rows)
-            self._dirty = False
-            self._h_prev = None
+        if not self._dev_current:
+            # the mirror holds the round already; its triplets land on
+            # equal cells (the scatter is an idempotent set)
+            self._prime()
         padded_dev = self._to_dev(padded)
-        # the 128-lane blocks this round dirtied, padded to a power of two
-        # by repeating the last (its hashes are written twice: harmless).
-        # A minority of the blocks reconciles alone and patches _h_prev;
-        # otherwise the whole buffer does, which (re)creates _h_prev: the
-        # "minority dirty" rule of _refresh_hash_mirror, per block
-        blocks = sorted({lane // LANE for lane in touched})
-        nb = _pad_to(len(blocks), 1)
-        h_prev = self._h_prev \
-            if blocks and 2 * nb <= self.n_pad // LANE else None
-        if h_prev is not None:
-            docs_axis = (len(touched), nb * LANE)
-            blocks_dev = self._to_dev(np.asarray(
-                blocks + blocks[-1:] * (nb - len(blocks)), np.int32))
+        if route.kind == "blocks":
+            nb = len(route.blocks)
+            docs_axis = (len(route.lanes), nb * LANE)
+            blocks_dev = self._to_dev(np.asarray(route.blocks, np.int32))
+            h_prev = self._h_prev
             metrics.bump("rows_apply_block_calls")
             metrics.bump("rows_apply_blocks", nb)
         else:
             docs_axis = (len(self.doc_ids), self.n_pad)
-            blocks_dev = None
+            blocks_dev = h_prev = None
         with dispatchledger.call_scope(
-                "rows_apply", backend="device", docs=len(touched),
+                "rows_apply", backend="device", docs=len(route.lanes),
                 axes={"docs": docs_axis,
                       "trips": (max(n_trips, 1), len(padded))}):
             self.rows_dev, h = metrics.dispatch_jit(
@@ -2014,78 +1984,63 @@ class ResidentRowsDocSet(ResidentDocSet):
 
     def _refresh_hash_mirror(self, want, interpret) -> None:
         """Bring the host hash mirror current for `want` (doc indices;
-        None = every doc), doing the minimum device work:
-
-        - an unconsumed flush-time device handle covers every lane: ONE
-          readback refreshes the whole mirror, no reconcile dispatch;
-        - otherwise only lanes in `want` that are dirty reconcile, via the
-          narrow gathered sub-buffer (_reconcile_lanes), UNLESS a majority
-          of the fleet is dirty — then the classic full-buffer reconcile
-          is cheaper (and re-primes the device copy).
-        """
+        None = every doc) by the route dispatch.reconcile_route gives a
+        read; no device work where no handle is pending and no lane asked
+        for is dirty."""
         n = len(self.doc_ids)
-        mirror = self._ensure_hash_mirror()
+        self._ensure_hash_mirror()
         if self._hash_handle is not None and not self._dev_current:
             # the handle predates a re-layout/invalidation (add_docs pad
             # growth, _grow, remap): it can never be consumed — drop it,
             # or hashes_clean would stay False forever and the sharded
             # cache would re-read this shard on every fleet read
             self._hash_handle = None
-        if self._hash_handle is not None and self._dev_current:
-            # breadcrumb BEFORE the readback barrier: a device hang
-            # surfaces at np.asarray below, and the flight recorder must
-            # already show this thread entered the readback
-            flightrec.record("rows_hash_readback", docs=n, cached=True)
-            with perfscope.phase("readback"):
-                vals = self._to_host(self._hash_handle)
-            mirror[:n] = vals[:n]
-            self._hash_handle = None   # consumed into the mirror
-            self._doc_dirty.clear()
-            return
         dirty = sorted(i for i in self._doc_dirty if i < n
                        and (want is None or i in want))
-        if not dirty:
-            return
-        if round_dispatch.megabatch_enabled() \
-                and 2 * len(dirty) < n \
-                and len(dirty) >= round_dispatch.megabatch_min_docs():
-            # r20 megabatch: bucket the dirty lanes by quantized shape
-            # and reconcile each bucket in ONE fused dispatch at the
-            # bucket's (smaller) dims — strictly less wire and compute
-            # than the full-dims alternatives below whenever doc sizes
-            # sit under the fleet caps. Falls through on a cost-model
-            # per-doc verdict (plan_round), hashes byte-identical.
-            # Gated to a minority-dirty fleet: when most lanes are dirty
-            # the bucketed gathers approach full-buffer size anyway, and
-            # the classic branch below re-primes the resident device
-            # copy (the posture the sharded per-device binding relies
-            # on) for one kernel shape.
-            plan = round_dispatch.plan_round(self, dirty)
+        if dirty or self._hash_handle is not None:
+            self._reconcile(
+                round_dispatch.reconcile_route(self, dirty), interpret)
+
+    def _reconcile(self, route, interpret) -> None:
+        """Execute a read's route (for a round: its `lanes` or `whole`)."""
+        if route.kind == "handle":
+            return self._read_back_all(self._hash_handle, cached=True)
+        if route.kind == "fused":
             if round_dispatch.apply_round_adaptive(
-                    self, plan, interpret) is not None:
+                    self, route.plan, interpret) is not None:
                 return
-        if 2 * len(dirty) >= n:
-            # majority dirty: the narrow gather would copy most of the
-            # buffer anyway — run the full-buffer reconcile (one kernel
-            # shape for the steady fleet, device copy re-primed)
-            if not self._dev_current:
-                self.rows_dev = self._to_dev(self.rows_host)
-                self._dirty = False
-            with dispatchledger.call_scope(
-                    "rows_hash", backend="device", docs=len(dirty),
-                    axes={"docs": (n, self.n_pad)}):
-                h = metrics.dispatch_jit(
-                    "reconcile_rows_hash", reconcile_rows_hash,
-                    self.rows_dev, self.dims(), interpret)
-            self._h_prev = h   # every lane, from the buffer just primed
-            flightrec.record("rows_hash_readback", docs=n, cached=False)
-            with perfscope.phase("readback"):
-                vals = self._to_host(h)
-            mirror[:n] = vals[:n]
-            self._hash_handle = None
-            self._doc_dirty.clear()
-            return
-        self._reconcile_lanes(dirty, interpret)
+            # None: nothing was fused and the lanes are still dirty
+            route = round_dispatch.share_route(self, route.lanes)
+        if route.kind == "lanes":
+            return self._reconcile_lanes(route.lanes, interpret)
+        self._reconcile_whole(len(route.lanes), interpret)
+
+    def _reconcile_whole(self, n_dirty: int, interpret) -> None:
+        """Reconcile the whole buffer (one kernel shape for the steady
+        fleet), uploading the mirror first where the copy is not current,
+        and read every lane's hash back; the vector stays as _h_prev."""
+        if not self._dev_current:
+            self._prime()
+        with dispatchledger.call_scope(
+                "rows_hash", backend="device", docs=n_dirty,
+                axes={"docs": (len(self.doc_ids), self.n_pad)}):
+            h = metrics.dispatch_jit(
+                "reconcile_rows_hash", reconcile_rows_hash,
+                self.rows_dev, self.dims(), interpret)
+        self._h_prev = h   # every lane, from the buffer just primed
+        self._read_back_all(h, cached=False)
+
+    def _read_back_all(self, h, cached: bool) -> None:
+        """One readback of an all-lane hash vector into the mirror."""
+        # breadcrumb BEFORE the readback barrier: a device hang surfaces
+        # at np.asarray below, and the flight recorder must already show
+        # this thread entered the readback
+        flightrec.record("rows_hash_readback", docs=len(self.doc_ids),
+                         cached=cached)
+        with perfscope.phase("readback"):
+            vals = self._to_host(h)
+        self._adopt_full_hashes(vals)
+        self._hash_handle = None   # consumed into the mirror
 
     def _mega_doc_sizes(self, idxs):
         """Exact per-doc used sizes for megabatch bucket planning, from
@@ -2157,12 +2112,10 @@ class ResidentRowsDocSet(ResidentDocSet):
         flightrec.record("rows_hash_readback", docs=k, cached=False)
         with perfscope.phase("readback"):
             vals = self._to_host(h)
-        self._hash_mirror[np.asarray(idxs, np.int64)] = vals[:k]
+        self._ensure_hash_mirror()[np.asarray(idxs, np.int64)] = vals[:k]
         self._doc_dirty.difference_update(idxs)
         if not on_device and not self.lazy_dispatch:
-            self.rows_dev = self._to_dev(self.rows_host)
-            self._dirty = False
-            self._h_prev = None
+            self._prime()
 
     def hashes(self, interpret: bool | None = None) -> np.ndarray:
         """Current per-doc state hashes from resident state, O(dirty) not

@@ -1466,13 +1466,9 @@ class EngineDocSet:
             rset.lazy_dispatch = jax.default_backend() == "cpu"
             self._lazy_resolved = True
 
-        # r20 megabatch handoff: the coalesced round frame (every doc
-        # dirtied this round, one columnar frame) IS the unit the engine's
-        # round planner buckets into fused multi-doc dispatches
-        # (engine/dispatch.py plan_round / apply_round_adaptive). Below
-        # AMTPU_MEGABATCH_MIN_DOCS — or on a cost-model loss — the engine
-        # falls back to the per-doc-era dispatch paths; converged hashes
-        # are byte-equal either way (tests/test_megabatch.py pins it).
+        # one frame for the whole coalesced round: the unit the engine
+        # routes (engine/dispatch.py reconcile_route); converged hashes
+        # are byte-equal on every route (tests/test_megabatch.py)
         with perfscope.phase("encode"):
             round_ = round_from_parts(pending)
         try:

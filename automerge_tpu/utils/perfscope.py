@@ -86,11 +86,12 @@ PHASES: dict[str, str] = {
     "encode": "round-frame decode, actor registration, budget precheck and "
               "the native delta encode (resident_rows._apply_round_frames)",
     "commit": "the encoded round committed to the host row mirror: growth, "
-              "the pre-round copy, scatter triplets, dirty marks, dedup and "
-              "padding (resident_rows)",
-    "route": "the round router: used-size band scans over the dirty lanes "
-             "and bucket planning against the link prices "
-             "(engine/dispatch.py plan_round)",
+              "scatter triplets, dirty marks, dedup and padding "
+              "(resident_rows)",
+    "route": "the reconcile router, once a round and once a hash read "
+             "(engine/dispatch.py reconcile_route); where it plans the "
+             "fused route: used-size band scans over the dirty lanes and "
+             "bucket planning against the link prices (plan_round)",
     "pack": "columnar batch/rows packing on the host (engine/pack.py) and "
             "the gather of dirty lanes from the host row mirror, or the "
             "plan of their gather on the device (_reconcile_lanes)",

@@ -43,7 +43,7 @@ def test_dispatch_failure_keeps_admission_and_recovers():
     real = rset._dispatch_final
     calls = {"n": 0}
 
-    def failing(trip_list, pre_rows, interpret):
+    def failing(trip_list, route, interpret):
         calls["n"] += 1
         raise RuntimeError("device lost mid-dispatch")
 

@@ -81,11 +81,11 @@ def test_soak_random_dispatch_failures_converge(seed, monkeypatch):
     real_dispatch = rset._dispatch_final
     fail_next = {"mode": None}
 
-    def flaky(trip_list, pre_rows, interpret):
+    def flaky(trip_list, route, interpret):
         if fail_next["mode"] == "dispatch":
             fail_next["mode"] = None
             raise RuntimeError("injected dispatch failure")
-        return real_dispatch(trip_list, pre_rows, interpret)
+        return real_dispatch(trip_list, route, interpret)
 
     rset._dispatch_final = flaky
     n_injected = 0
