@@ -7,6 +7,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..storage import _ACTION_IDX
 from . import get_lib
 
 V_NONE, V_NULL, V_FALSE, V_TRUE, V_INT, V_DOUBLE, V_STR, V_BIGINT = range(8)
@@ -39,6 +40,14 @@ class WireColumns:
     @property
     def n_changes(self) -> int:
         return len(self.change_actor)
+
+    @property
+    def n_ops(self) -> int:
+        return len(self.op_action)
+
+    def columns(self) -> "WireColumns":
+        """The part as columns: itself (ChangesPart converts)."""
+        return self
 
     def op_value(self, j: int):
         """Decode op j's scalar value (None for absent/null)."""
@@ -170,7 +179,9 @@ class _Interner:
 
 
 def _encode_value(op, strings: _Interner):
-    """(vtag, vint, vdbl, vstr) for one op, matching WireColumns.op_value."""
+    """(vtag, vint, vdbl, vstr) for one op, matching WireColumns.op_value.
+    A new rejection here needs its mirror in _plain_ops (see
+    changes_to_columns)."""
     if op.action not in ("set", "link", "move"):
         return V_NONE, 0, 0.0, -1
     v = op.value
@@ -193,8 +204,13 @@ def _encode_value(op, strings: _Interner):
 
 def changes_to_columns(changes) -> WireColumns:
     """Encode Change objects as columns (the send-side per-op pass — the
-    analog of the per-op dict building JSON senders pay in to_dict)."""
-    from ..storage import _ACTION_IDX
+    analog of the per-op dict building JSON senders pay in to_dict).
+
+    What raises here must be refused by _plain_ops: a batch keeps what
+    _plain_ops passes unconverted and calls this at its flush, where a
+    raise would fail a round shared with innocent senders. Any new
+    rejection here or in _encode_value is mirrored there
+    (tests/test_round_frame_direct.py draws random fields to hold it)."""
     actors, objects, keys, messages, strings = (
         _Interner(), _Interner(), _Interner(), _Interner(), _Interner())
     n = len(changes)
@@ -251,6 +267,88 @@ def changes_to_columns(changes) -> WireColumns:
         op_vstr=np.asarray(op_vstr, np.int32),
         actors=actors.items, objects=objects.items, keys=keys.items,
         messages=messages.items, strings=strings.items)
+
+
+class ChangesPart:
+    """An ingress kept as the caller's Change objects: what a batch of the
+    rows service pends until its flush turns the whole round into one
+    frame in one changes_to_columns pass (sync/frames.py
+    round_from_parts). It answers what a pending part is asked
+    (`n_changes`, `n_ops`, `columns()`) as WireColumns does, so a reader
+    of the pending round never asks which kind it holds. Made only by
+    changes_part, which has checked that the conversion cannot raise."""
+
+    __slots__ = ("changes", "n_ops")
+
+    def __init__(self, changes: tuple, n_ops: int):
+        self.changes = changes
+        self.n_ops = n_ops
+
+    @property
+    def n_changes(self) -> int:
+        return len(self.changes)
+
+    def columns(self) -> WireColumns:
+        return changes_to_columns(self.changes)
+
+
+_I32_MIN = -(2 ** 31)
+_I32_MAX = 2 ** 31 - 1
+_PLAIN_VALUES = frozenset((type(None), bool, int, float, str))
+
+
+def _plain_ops(changes) -> int | None:
+    """The op count of `changes` if every field is of the plain type and
+    range changes_to_columns takes without a conversion of its own (so a
+    later pass over them cannot raise), else None. Exact types only: a
+    numpy integer, an int subclass, a string `elem` may well encode, but
+    the caller then converts at once and learns it there."""
+    n_ops = 0
+    for c in changes:
+        seq = c.seq
+        msg = c.message
+        if (type(seq) is not int or not _I32_MIN <= seq <= _I32_MAX
+                or type(c.actor) is not str
+                or (msg is not None and type(msg) is not str)):
+            return None
+        for a, s in c.deps.items():
+            if (type(a) is not str or type(s) is not int
+                    or not _I32_MIN <= s <= _I32_MAX):
+                return None
+        ops = c.ops
+        for op in ops:
+            key = op.key
+            elem = op.elem
+            if (op.action not in _ACTION_IDX or type(op.obj) is not str
+                    or (key is not None and type(key) is not str)
+                    or (elem is not None and (
+                        type(elem) is not int
+                        or not _I32_MIN <= elem <= _I32_MAX))
+                    or type(op.value) not in _PLAIN_VALUES):
+                return None
+        n_ops += len(ops)
+    return n_ops
+
+
+def changes_part(changes) -> "ChangesPart | WireColumns":
+    """A batch's pending part for an ingress of Change objects: the changes
+    as they came when all of them are plain (the flush converts the round
+    once), else their columns now, so that an ingress that cannot be
+    encoded raises here, at its sender's call, exactly what
+    changes_to_columns raises."""
+    if type(changes) is not tuple:
+        # one read of the caller's iterable: what is checked is what is
+        # kept (a generator would be spent by the check); a sequence
+        # without len raises its TypeError here, as the conversion's did
+        len(changes)
+        changes = tuple(changes)
+    try:
+        n_ops = _plain_ops(changes)
+    except Exception:
+        n_ops = None   # not Change-shaped: the conversion says how
+    if n_ops is None:
+        return changes_to_columns(changes)
+    return ChangesPart(changes, n_ops)
 
 
 def _table(lib, handle, which: int, n_items: int, blob_len: int) -> list[str]:

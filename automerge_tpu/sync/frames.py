@@ -266,15 +266,19 @@ class RoundColumns:
     """A decoded round frame: one WireColumns holding every change of the
     round, plus the doc table mapping contiguous change ranges to doc ids.
     `cols.frame_bytes` is the embedded AMW1 frame — the native delta
-    encoder's direct input, shared by all documents of the round."""
+    encoder's direct input, shared by all documents of the round.
+    `direct` says the columns came from ONE changes_to_columns pass over
+    the round's Change objects, with no join of column parts
+    (round_from_parts; the service counts such rounds)."""
 
-    __slots__ = ("doc_ids", "change_off", "cols")
+    __slots__ = ("doc_ids", "change_off", "cols", "direct")
 
     def __init__(self, doc_ids: list[str], change_off: np.ndarray,
-                 cols: WireColumns):
+                 cols: WireColumns, direct: bool = False):
         self.doc_ids = doc_ids
         self.change_off = change_off
         self.cols = cols
+        self.direct = direct
 
     def to_dict(self) -> dict[str, list[Change]]:
         chs = self.cols.to_changes()  # bulk materialization, one pass
@@ -314,24 +318,45 @@ def round_from_columns(deltas: dict[str, "WireColumns"]) -> RoundColumns:
 
 
 def round_from_parts(doc_parts: dict[str, list]) -> RoundColumns:
-    """Like round_from_columns but accepting SEVERAL column batches per doc
-    (a coalescing service's pending queue): one concat across everything
-    instead of per-doc merges followed by a cross-doc merge."""
-    from ..native.wire import concat_columns
+    """One decoded round from a coalescing service's pending queue:
+    SEVERAL parts a document, each a WireColumns or a ChangesPart (an
+    ingress a batch kept as Change objects, native/wire.py). Documents in
+    the dict's order, a document's parts in admission order. Every run of
+    ChangesParts, across documents, is converted in ONE
+    changes_to_columns pass; a round made of nothing else (a batch of
+    apply_changes calls) is that one pass and joins nothing (`direct`).
+    Column parts between the runs (apply_columns inside the batch, sealed
+    epoch entries) are joined with the runs' columns by ONE
+    concat_columns, never a merge a document. The frame is the same
+    bytes whichever way the parts came: both intern a string where the
+    ops first meet it."""
+    from ..native.wire import ChangesPart, concat_columns
 
     doc_ids = list(doc_parts)
-    flat = []
+    flat: list[WireColumns] = []
+    run: list[Change] = []
     off = np.zeros(len(doc_ids) + 1, np.int32)
+    n_changes = 0
     for k, d in enumerate(doc_ids):
-        parts = doc_parts[d]
-        flat.extend(parts)
-        off[k + 1] = off[k] + sum(p.n_changes for p in parts)
+        for p in doc_parts[d]:
+            n_changes += p.n_changes
+            if type(p) is ChangesPart:
+                run.extend(p.changes)
+                continue
+            if run:
+                flat.append(changes_to_columns(run))
+                run = []
+            flat.append(p)
+        off[k + 1] = n_changes
+    direct = not flat and bool(doc_ids)
+    if run or not flat:
+        flat.append(changes_to_columns(run))
     merged = concat_columns(flat)
     # single-part passthrough may already carry its received frame bytes;
     # only serialize when absent (and cache for the native encoder)
     if getattr(merged, "frame_bytes", None) is None:
         merged.frame_bytes = columns_to_bytes(merged)
-    return RoundColumns(doc_ids, off, merged)
+    return RoundColumns(doc_ids, off, merged, direct)
 
 
 @perfscope.phased("sync_wire")

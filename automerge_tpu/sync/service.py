@@ -34,6 +34,7 @@ from ..core.change import Change
 from ..engine import dispatchledger
 from ..engine.resident import ResidentDocSet
 from ..engine.resident_rows import CompactionAnchorError, DeviceDispatchError
+from ..native.wire import changes_part, changes_to_columns
 from ..utils import (chaos, flightrec, lockprof, metrics, oplag, perfscope,
                      tracer)
 from . import docledger, epochs, tenantledger
@@ -835,15 +836,23 @@ class EngineDocSet:
     def apply_changes(self, doc_id: str, changes: list[Change]) -> DocHandle:
         """Admit a change batch into resident state (causal buffering and
         duplicate-drop happen in the engine's delta encoder) and notify
-        handlers so attached Connections gossip the update."""
+        handlers so attached Connections gossip the update.
+
+        Inside this thread's batch() (rows backend) the changes are kept
+        as they came and READ AT THE BATCH'S EXIT, where the whole round
+        becomes one frame in one pass: a Change handed to a batch must
+        not be mutated before the batch returns (Change copies its deps
+        and freezes its ops; nothing in the package mutates one). What
+        cannot be encoded still raises here, at its own call."""
         if tracer.enabled():
             # trace plane: hand-built changes have no frontend finalize;
             # the sampled ones' lifecycle starts at this service boundary
             tracer.origin_ingress((c.actor, c.seq) for c in changes)
         if self.backend == "rows":
-            from ..native.wire import changes_to_columns
-            return self._rows_ingest(
-                doc_id, lambda: changes_to_columns(changes))
+            convert = (changes_part
+                       if self._batch_owner == threading.get_ident()
+                       else changes_to_columns)
+            return self._rows_ingest(doc_id, lambda: convert(changes))
 
         def apply_fn():
             if self.live_views:
@@ -924,24 +933,26 @@ class EngineDocSet:
     def _pending_size(self) -> tuple[int, int]:
         """(documents, ops) of the coalesced round not yet flushed."""
         return len(self._pending), sum(
-            len(c.op_action) for parts in self._pending.values()
-            for c in parts)
+            p.n_ops for parts in self._pending.values() for p in parts)
 
-    def _rows_ingest(self, doc_id: str, wire) -> DocHandle:
-        """`wire()` gives the ingress as wire columns. It is called inside
-        the `admit` phase, so that converting an ingress that arrived as
-        Change objects is admission time, and inside a batch, where a
-        storm admits a thousand changes, under the admission's one
-        entry."""
+    def _rows_ingest(self, doc_id: str, part) -> DocHandle:
+        """`part()` gives the ingress as a pending part, called inside the
+        `admit` phase. Inside this thread's batch it may be a ChangesPart
+        (native/wire.py: apply_changes' Change objects, checked and kept
+        unconverted), and admission is the bookkeeping alone, under one
+        phase entry a call: the flush converts the round once, inside
+        `encode`. Outside a batch it gives wire columns: converting one
+        ingress of Change objects there is admission time, and the epoch
+        buffer's contract is stated in columns."""
         if self._batch_owner == threading.get_ident():
             # inside this thread's batch(): the batch is the request, and
             # its exit the flush and the drain (which defers while the
             # batch is open), so the whole call is admission
             with perfscope.phase("admit"), self._lock:
-                self._pend_locked(doc_id, wire())
+                self._pend_locked(doc_id, part())
                 return self.get_doc(doc_id)
         with perfscope.phase("admit"):
-            cols = wire()
+            cols = part()
         with request_span({"docs": 1, "ops": len(cols.op_action)},
                           **self._metric_labels()):
             if self._epoch_admission_open():
@@ -959,18 +970,21 @@ class EngineDocSet:
             self._drain_admitted()
             return handle
 
-    def _pend_locked(self, doc_id: str, cols) -> None:
-        """Append one ingress to the coalesced round (under the service
-        lock)."""
-        self.add_doc(doc_id)
+    def _pend_locked(self, doc_id: str, part) -> None:
+        """Append one ingress, columns or a ChangesPart, to the coalesced
+        round (under the service lock)."""
         rset = self._resident
+        if doc_id not in rset.doc_index:
+            self.add_doc(doc_id)
         i = rset.doc_index[doc_id]
         if rset.ghost_eids[i]:
             # reject a ghost-anchored ingress HERE, before it coalesces:
             # only the offending sender's call errors, never a round
-            # shared with innocent peers
-            rset._check_ghost_anchors_cols(i, cols, 0, len(cols.op_action))
-        self._pending.setdefault(doc_id, []).append(cols)
+            # shared with innocent peers. The check reads columns, so a
+            # compacted document's ingress is converted at its call.
+            part = part.columns()
+            rset._check_ghost_anchors_cols(i, part, 0, part.n_ops)
+        self._pending.setdefault(doc_id, []).append(part)
         tok = oplag.admit(doc_id)
         tracer.admit(doc_id)
         if tok is not None:
@@ -1277,8 +1291,6 @@ class EngineDocSet:
         # attributes as "slow_apply". Inert (one cached check) unless
         # AMTPU_CHAOS_SLOW_APPLY_S is set.
         chaos.slow_apply(self._chaos_node)
-        from .frames import round_from_parts
-
         pending = self._pending
         self._pending = {}
         rset = self._resident
@@ -1345,8 +1357,16 @@ class EngineDocSet:
 
     def _flush_pending_inner_locked(self, rset, pending, _changed,
                                     n_ops: int) -> None:
+        from .frames import round_from_parts
+
         try:
-            self._apply_with_compaction(rset, pending)
+            # one frame for the whole coalesced round: the unit the engine
+            # routes (engine/dispatch.py reconcile_route); converged hashes
+            # are byte-equal on every route (tests/test_megabatch.py). A
+            # batch's Change objects become columns here, in one pass.
+            with perfscope.phase("encode"):
+                round_ = round_from_parts(pending)
+            self._apply_with_compaction(rset, pending, round_)
         except DeviceDispatchError as e:
             # The admitted part of the flush is durable on the host
             # (change_log, clocks, queue and the row mirror are consistent).
@@ -1438,23 +1458,25 @@ class EngineDocSet:
             if restored < n_ops:
                 labels = self._metric_labels()
                 metrics.bump("sync_rounds_flushed", **labels)
+                if round_.direct:
+                    metrics.bump("sync_rounds_direct_frame", **labels)
                 metrics.bump("sync_ops_ingested", int(n_ops - restored),
                              **labels)
             self._early_resolve_locked()
-            # the round's per-document column parts die here, inside the
+            # the round's per-document parts die here, inside the
             # tail's phase and after the riders are released, not at the
             # caller's frame exit where no span would see the time
             pending.clear()
 
-    def _apply_with_compaction(self, rset, pending: dict) -> None:
-        """Apply one coalesced round; on VMEM-budget pressure, compact
+    def _apply_with_compaction(self, rset, pending: dict, round_) -> None:
+        """Apply one coalesced round (`round_`, the frame of the parts in
+        `pending`); on VMEM-budget pressure, compact
         every doc to its known-peer clock floor (engine/compaction.py) and
         retry once. RowsBudgetError is raised BEFORE admission, so the
         retry re-submits the identical round against the reclaimed state —
         this is what lets a single long-lived document outlive the
         pre-compaction budget instead of hitting a hard admission wall."""
         from ..engine.resident_rows import RowsBudgetError
-        from .frames import round_from_parts
 
         if not getattr(self, "_lazy_resolved", False):
             # CPU-backend services defer the reconcile to hash reads
@@ -1466,11 +1488,6 @@ class EngineDocSet:
             rset.lazy_dispatch = jax.default_backend() == "cpu"
             self._lazy_resolved = True
 
-        # one frame for the whole coalesced round: the unit the engine
-        # routes (engine/dispatch.py reconcile_route); converged hashes
-        # are byte-equal on every route (tests/test_megabatch.py)
-        with perfscope.phase("encode"):
-            round_ = round_from_parts(pending)
         try:
             rset.apply_round_frames([round_])
         except RowsBudgetError:
@@ -1497,7 +1514,8 @@ class EngineDocSet:
         pins: dict[str, set] = {}
         for d, parts in pending.items():
             p: set = set()
-            for cols in parts:
+            for part in parts:
+                cols = part.columns()
                 acts = np.asarray(cols.op_action)
                 for j in np.nonzero(acts == _ACTION_IDX["ins"])[0].tolist():
                     k = int(cols.op_key[j])
@@ -1545,12 +1563,16 @@ class EngineDocSet:
         """Context manager: coalesce every ingress inside the block into
         ONE device dispatch at exit (rows backend). The service lock is
         held for the duration, so the block must not wait on other threads
-        that ingest into this node. Generational GC pauses for the whole
-        block INCLUDING the exit flush (utils.gcpause — refcounted, so
-        concurrent nodes cannot re-enable each other mid-burst): a burst
-        of small ingress allocations would otherwise trigger gen-2 scans
-        over the whole service heap — measured at ~4x the round cost on a
-        100K-doc fleet node."""
+        that ingest into this node. An apply_changes inside the block
+        keeps its Change objects as they came (checked at the call, where
+        what cannot be encoded still raises); the exit turns the round's
+        changes into its frame in one pass, so they are read THERE and
+        must not be mutated before the block returns. Generational GC
+        pauses for the whole block INCLUDING the exit flush
+        (utils.gcpause — refcounted, so concurrent nodes cannot re-enable
+        each other mid-burst): a burst of small ingress allocations would
+        otherwise trigger gen-2 scans over the whole service heap —
+        measured at ~4x the round cost on a 100K-doc fleet node."""
         from ..utils.gcpause import gc_paused
 
         @contextlib.contextmanager

@@ -187,6 +187,10 @@ COUNTERS: dict[str, str] = {
     "sync_wire_bytes_received": "framed bytes read from a TCP transport",
     "sync_ops_ingested": "ops admitted through service round flushes",
     "sync_rounds_flushed": "coalesced service round flushes",
+    "sync_rounds_direct_frame":
+        "flushed rounds whose frame came from one changes_to_columns "
+        "pass over a batch's Change objects, with no join of column "
+        "parts (sync/frames.py round_from_parts)",
     # the sharded service's fan-out (sync/sharded_service.py): one round
     # = the exit of an outermost batch(), or a flush()
     "sync_shard_fanout_rounds": "fan-outs of the sharded service",

@@ -365,7 +365,7 @@ def test_trace_readers_on_the_recorded_v5e_slice():
 
 def test_new_files_are_found_for_the_cell():
     found = {m["name"]: m for m in run.cell_metrics(CELL)}
-    assert set(NEW_METRICS) <= set(found) and len(found) == 21
+    assert set(NEW_METRICS) <= set(found) and len(found) == 22
     for name in NEW_METRICS:
         m = found[name]
         assert m["workloads"] == [CELL]
@@ -419,4 +419,6 @@ def test_traced_tiny_run_of_the_cell_reads_the_host_side_metrics(
     # holds the gather itself); the phase around it is still read
     assert got["resident_gather_share"] == 100.0 - got["fused_round_share"]
     assert 0.0 < got["pack_share"] < 50.0
+    # every shard's round was made in one pass from the batch's changes
+    assert got["direct_frame_share"] == 100.0
     assert "pod_idle_share" not in got and "chip_busy_balance" not in got
