@@ -26,6 +26,12 @@ with the limit 0 (exact comparisons):
 - `host_fallbacks`: the engine acknowledged from host truth after a device
   dispatch failed (`rows_dispatch_failed`, `rows_log_rebuilt`,
   `rows_engine_poisoned`), over the whole run.
+
+This module is also the check a configuration gets by naming none: what
+`run.py` takes from it (`read_untouched`, `read_program`, `decide`,
+`FALLBACK_COUNTERS`) and what `prove.py` takes (`CONTROLS`) is what a
+check of a configuration's own gives (`checks/<name>.py`; README, "The
+three seams").
 """
 
 from __future__ import annotations
@@ -39,6 +45,10 @@ LIMITS = {"requests_raised": 0, "acks_before_flush": 0,
           "untouched_moved": 0, "host_fallbacks": 0}
 FALLBACK_COUNTERS = ("rows_dispatch_failed", "rows_log_rebuilt",
                      "rows_engine_poisoned")
+# the controls `prove.py --control 1` runs: for each guarantee that can be
+# broken, what makes the reference that stands in the program's place
+CONTROLS = {kind: (lambda kind=kind: reference.RefService(kind))
+            for kind in reference.BROKEN if kind != "none"}
 
 
 def sample_docs(fleet, seed: int, n: int) -> list:

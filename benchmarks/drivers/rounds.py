@@ -1,11 +1,12 @@
 """Driver `rounds`: one caller, closed loop. A request is what the caller
-submits and waits for: with `batch` true one coalesced round, entry of
-`with svc.batch():` to its exit, which is what a relay upstream waits for;
-with `batch` false the request's `svc.apply_changes` calls one after
-another, each its own flush. The next request is built and sent only after
-the last one returned. The driver calls `svc.batch()` /
+submits and waits for: where the schedule says `batch(r)`, one coalesced
+round, entry of `with svc.batch():` to its exit, which is what a relay
+upstream waits for; where it does not, the request's `svc.apply_changes`
+calls one after another, each its own flush. The next request is built and
+sent only after the last one returned. The driver asks the schedule what
+request `r` draws and the fleet for its changes, calls `svc.batch()` /
 `svc.apply_changes` and nothing below them, and keeps no change it sent:
-`fleet.replay` makes them again for the comparison.
+the fleet's `replay` makes them again for the comparison.
 """
 
 from __future__ import annotations
@@ -37,17 +38,15 @@ def run(svc, fleet, schedule, *, first: int, max_requests: int,
         seconds: float | None = None, between=None) -> dict:
     """Issue requests `first`, `first + 1`, ... until `seconds` have passed
     (checked before each request), `max_requests` were issued, a request
-    raised, or the next one would take a document past the fleet's
-    `history_cap`. `between(now)` runs between requests (the harness starts
-    and stops its trace there). After each return, outside the timed span,
+    raised, or the fleet can take no more (the maps fleet: the next
+    request would take a document past its `history_cap`). `between(now)`
+    runs between requests (the harness starts and stops its trace there).
+    After each return, outside the timed span,
     the driver reads how many ops the service has flushed through its
     engine: the acknowledgement must not come before them. Returns the
     requests with the window's own begin and end."""
-    from fleet import ops_ingested, request_changes
+    from fleet import ops_ingested
 
-    cap = fleet.spec.history_cap
-    batch = bool(schedule.mix["batch"])
-    depth = fleet.depth
     requests: list = []
     stopped = "max_requests"
     begin = time.perf_counter()
@@ -60,11 +59,11 @@ def run(svc, fleet, schedule, *, first: int, max_requests: int,
             break
         if between is not None:
             between(now)
-        drawn = schedule.request(r)
-        if any(depth[fleet.small[i]] >= cap for i in drawn[0].tolist()):
-            stopped = "history_cap"
+        round_ = fleet.request_changes(schedule.request(r))
+        if isinstance(round_, str):      # the fleet can take no more
+            stopped = round_
             break
-        round_ = request_changes(fleet, drawn)
+        batch, n_ops = schedule.batch(r), fleet.request_ops(round_)
         t0 = time.perf_counter()
         building += t0 - now
         err = None
@@ -81,12 +80,12 @@ def run(svc, fleet, schedule, *, first: int, max_requests: int,
         t1 = time.perf_counter()
         after = ops_ingested(svc)
         for _ in range(GRACE_READS):
-            if after - ingested >= len(round_) or err is not None:
+            if after - ingested >= n_ops or err is not None:
                 break
             time.sleep(GRACE_SLEEP_S)
             after = ops_ingested(svc)
-        requests.append(Request(r, t0, t1, len(round_),
-                                after - ingested >= len(round_), err))
+        requests.append(Request(r, t0, t1, n_ops,
+                                after - ingested >= n_ops, err))
         ingested = after
         if err is not None:
             stopped = "error"

@@ -7,6 +7,10 @@ loaded with (`history_changes_max`: a number of earlier edits drawn for
 each document, uniform from none to that many), and `replay`, which makes
 again, from the seed, every change the run sent, so that the run keeps
 none of them while the window is timed.
+
+This module is also the fleet kind a configuration gets by naming none:
+`make(config, seed)` and the `Fleet` it returns are what `run.py` and the
+drivers ask of any kind (`fleets/<name>.py`; README, "The three seams").
 """
 
 from __future__ import annotations
@@ -63,10 +67,49 @@ class Fleet:
     depth: dict = field(default_factory=dict)       # ops sent per small doc
     first: dict = field(default_factory=dict)       # the structured
     # documents' load changes, kept: their object ids are not seeded
+    seed: int = 0                                   # what `make` was given
+
+    # growing a cap re-shapes the whole resident buffer (README): a run
+    # whose resident dims moved inside the window ends with no result
+    dims_fixed = True
+    n_fields = len(SMALL_KEYS)     # fields a schedule may draw from
 
     @property
     def doc_ids(self) -> list:
         return self.structured + self.small
+
+    # what run.py and the drivers ask of a fleet, whatever its kind
+
+    def load_rounds(self):
+        """The load, one coalesced round at a time, in order."""
+        yield self.first
+        yield from small_load_rounds(self, self.seed)
+
+    def load_line(self) -> dict:
+        """What the `load` stage prints about the fleet as loaded."""
+        depths = sorted(self.depth.values())
+        return {"small_depth_min_median_max": [
+            depths[0], depths[len(depths) // 2], depths[-1]]}
+
+    def request_changes(self, drawn: tuple):
+        """{doc id: [Change]} of one request as the schedule drew it, or,
+        where the fleet can take no more, the name of what stops it."""
+        cap, depth, small = self.spec.history_cap, self.depth, self.small
+        if any(depth[small[i]] >= cap for i in drawn[0].tolist()):
+            return "history_cap"
+        return request_changes(self, drawn)
+
+    request_ops = staticmethod(len)    # ops of a request: one a document
+
+    def replay(self, schedule, numbers) -> tuple:
+        return replay(self, self.seed, schedule, numbers)
+
+
+def make(config: dict, seed: int) -> Fleet:
+    """The fleet of a configuration that names no kind of its own."""
+    fleet = make_fleet(FleetSpec.from_config(config), seed)
+    fleet.seed = int(seed)
+    return fleet
 
 
 def storm_change(fleet: Fleet, doc_id: str, ops: tuple) -> list:

@@ -195,7 +195,8 @@ class RefService:
     - `lose_acknowledged`: one acknowledged change in `every` is dropped
       from the log, so `missing_changes` does not serve it back;
     - `stale_hash`: one flush in seven stops refreshing the hash of one of
-      its dirty documents, for good;
+      its dirty documents, for good (a flush that holds no document with
+      a hash yet, as a load round of new documents does, is passed over);
     - `first_writer_wins`: a `set` never removes an earlier one, so the
       materialized state and the hash are no longer Automerge's.
     """
@@ -249,7 +250,9 @@ class RefService:
         self._pending_ops = 0
         self._n_flushes += 1
         if self.broken == "stale_hash" and self._n_flushes % 7 == 0:
-            self._stale.add(min(d for d in self._dirty if d in self._hashes))
+            hashed = [d for d in self._dirty if d in self._hashes]
+            if hashed:
+                self._stale.add(min(hashed))
         for d in self._dirty - self._stale:
             self._hashes[d] = self._hash_of(d)
         self._dirty.clear()

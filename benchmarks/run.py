@@ -4,12 +4,15 @@
 
 A cell is `workloads/<cell>.json`: a configuration (`configs/`), a traffic
 mix (`traffic/`, issued by its driver under `drivers/`), and the chips it
-needs. The run touches JAX first and ends with exit code 2, printing no
-result, unless JAX reports a TPU with the cell's chips: there is no CPU path
-here (the tests call `run_cell` with devices passed in). It then builds the
-fleet from the seed, loads it, warms up through the same driver, measures
-for `--seconds`, decides `correct` (check.py) and prints one JSON line a
-stage and, last, the result line.
+needs. A configuration may name its fleet kind (`fleets/`) and its check
+(`checks/`), a mix its schedule (`schedules/`); one that names none gets
+`fleet.py`, `check.py` with `reference.py`, `traffic.py`. The run touches
+JAX first and ends with exit code 2, printing no result, unless JAX reports
+a TPU with the cell's chips: there is no CPU path here (the tests call
+`run_cell` with devices passed in). It then builds the fleet from the seed,
+loads it, warms up through the same driver, measures for `--seconds`,
+decides `correct` (the check) and prints one JSON line a stage and, last,
+the result line.
 
 With `--trace 0` the result's metrics are the end-to-end ones, taken here
 from the host's clock; with `--trace 1` a slice of the window is traced by
@@ -59,8 +62,8 @@ def emit(rec: dict) -> None:
 
 
 def load_by_path(kind: str, name: str, root: str = HERE):
-    """`<root>/<kind>/<name>.py` as a module: drivers and readers are files
-    found by name."""
+    """`<root>/<kind>/<name>.py` as a module: drivers, readers, fleet
+    kinds, schedules and checks are files found by name."""
     path = os.path.join(root, kind, name + ".py")
     spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -82,15 +85,40 @@ def claim_devices(chips: int) -> list:
     return devs
 
 
+def seam(data: dict, key: str, kind: str, default, root: str = HERE):
+    """The module a data file names under `key` (`<kind>/<name>.py`), or
+    `default`, today's code, where it names none."""
+    return load_by_path(kind, data[key], root) if key in data else default
+
+
+def metric_holds(m: dict, cell_name: str, mixes: set, service: str) -> bool:
+    """Whether a metric's file holds for a cell. The file says so by what
+    the cell is: `mixes`, the traffic mixes under which the metric finds
+    something to read, and `services`, the service kinds; either may be
+    left out and then does not narrow. A file may list cells by name
+    instead (`workloads`), and one that gives none of the three holds for
+    every cell."""
+    if "mixes" not in m and "services" not in m:
+        return "workloads" not in m or cell_name in m["workloads"]
+    return bool(mixes & set(m.get("mixes", mixes))) \
+        and service in m.get("services", (service,))
+
+
 def cell_metrics(cell_name: str, root: str = HERE) -> list:
     """The per-layer metrics this cell reports: every `metrics/*.json`
-    that lists the cell, or lists no cells at all."""
+    that holds for it (`metric_holds`). The cell's mix counts under its
+    own name and under every name in its file's `reports_as`: a mix built
+    from accepted ones takes their metrics as data."""
+    cell = fleetlib.load_json("workloads", cell_name, root)
+    mix = fleetlib.load_json("traffic", cell["traffic"], root)
+    mixes = {cell["traffic"], *mix.get("reports_as", ())}
+    service = fleetlib.load_json("configs", cell["config"], root)["service"]
     out = []
     for fn in sorted(os.listdir(os.path.join(root, "metrics"))):
         if not fn.endswith(".json"):
             continue
         m = fleetlib.load_json("metrics", fn[:-5], root)
-        if "workloads" not in m or cell_name in m["workloads"]:
+        if metric_holds(m, cell_name, mixes, service):
             out.append(m)
     return out
 
@@ -185,6 +213,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: int, devices,
     config = fleetlib.load_json("configs", cell["config"], root)
     mix = fleetlib.load_json("traffic", cell["traffic"], root)
     driver = load_by_path("drivers", mix["driver"], root)
+    fleet_kind = seam(config, "fleet_kind", "fleets", fleetlib, root)
+    scheduler = seam(mix, "schedule", "schedules", traffic, root)
+    checker = seam(config, "check", "checks", check, root)
     metric_files = cell_metrics(cell_name, root) if trace else []
     readers = {m["reader"]: load_by_path("readers", m["reader"], root)
                for m in metric_files}
@@ -200,10 +231,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: int, devices,
 
     counters_at_start = fleetlib.counters()
     t = time.perf_counter()
-    spec = fleetlib.FleetSpec.from_config(config)
-    fleet = fleetlib.make_fleet(spec, seed)
-    schedule = traffic.Schedule(mix, len(fleet.small),
-                                len(fleetlib.SMALL_KEYS), seed)
+    fleet = fleet_kind.make(config, seed)
+    schedule = scheduler.make(mix, fleet, seed, root)
     emit({"stage": "fleet", "seconds": round(time.perf_counter() - t, 2),
           "docs": len(fleet.doc_ids), "cache_dir": cache_dir,
           "cache_entries": compile_cache.entries(cache_dir)})
@@ -213,18 +242,14 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: int, devices,
         svc = steer(svc) or svc
     try:
         t = time.perf_counter()
-        fleetlib.apply_round(svc, fleet.first)
-        n_rounds = 1
-        for round_ in fleetlib.small_load_rounds(fleet, seed):
+        n_rounds = 0
+        for round_ in fleet.load_rounds():
             fleetlib.apply_round(svc, round_)
             n_rounds += 1
         del round_
-        untouched_before = check.read_untouched(svc, fleet)
-        depths = sorted(fleet.depth.values())
+        untouched_before = checker.read_untouched(svc, fleet)
         emit({"stage": "load", "seconds": round(time.perf_counter() - t, 2),
-              "load_rounds": n_rounds,
-              "small_depth_min_median_max": [
-                  depths[0], depths[len(depths) // 2], depths[-1]],
+              "load_rounds": n_rounds, **fleet.load_line(),
               "dims": fleetlib.resident_dims(svc),
               "resident_bytes": sum(e.resident_bytes()
                                     for e in fleetlib.engines(svc))})
@@ -283,23 +308,23 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: int, devices,
               "rounds_flushed": delta.get("sync_rounds_flushed", 0),
               "ops_ingested": delta.get("sync_ops_ingested", 0),
               "megabatch_rounds": delta.get("engine_megabatch_rounds", 0)})
-        if dims_after != dims_before:
+        if dims_after != dims_before and fleet.dims_fixed:
             raise RunFailed(f"the resident dims changed inside the window: "
                             f"{dims_before} -> {dims_after}")
         if not reqs:
             raise RunFailed("the window issued no request")
 
         t = time.perf_counter()
-        read = check.read_program(svc, fleet, seed, STATE_SAMPLE)
+        read = checker.read_program(svc, fleet, seed, STATE_SAMPLE)
         fallbacks = sum(counters_after.get(k, 0) - counters_at_start.get(k, 0)
-                        for k in check.FALLBACK_COUNTERS)
+                        for k in checker.FALLBACK_COUNTERS)
     finally:
         svc.close()
     del svc
     acked = list(range(first)) + [q.number for q in reqs if q.error is None]
-    sent, origin = fleetlib.replay(fleet, seed, schedule, acked)
-    verdict = check.decide(read, fleet, sent, origin, untouched_before, reqs,
-                           fallbacks)
+    sent, origin = fleet.replay(schedule, acked)
+    verdict = checker.decide(read, fleet, sent, origin, untouched_before,
+                             reqs, fallbacks)
     del sent, origin
     emit({"stage": "check", "seconds": round(time.perf_counter() - t, 2),
           "compared": verdict["sizes"]})

@@ -8,6 +8,8 @@ The CPU reaches the road the chip takes only where a test steers there
 (`eager`, `cpu_link`), as tests/test_chip_smoke_stages.py does.
 """
 
+import functools
+import hashlib
 import json
 import os
 import re
@@ -35,7 +37,11 @@ TINY_FLEET = dict(n_small=200, n_heavy=2, heavy_ops=40, n_list=2, n_text=2,
                   n_move=1, load_batch=100, history_changes_max=2,
                   history_cap=64)
 TINY_MIX = {"storm": dict(draws_per_request=80, warmup_requests=3),
-            "edits": dict(warmup_requests=3)}
+            "edits": dict(warmup_requests=3),
+            # two cycles: the second round comes after single edits, as
+            # every round of a window does
+            "mixed": dict(warmup_requests=18)}
+ACCEPTED = ("fleet10k.storm", "fleet10k.edits", "fleet10k-4shard.storm")
 DEVICE_METRICS = ("megakernel_roofline", "apply_final_roofline",
                   "device_idle_share")
 
@@ -97,7 +103,8 @@ def run_tiny(root, cell="fleet10k.storm", trace=0, steer=eager, seed=2**31 + 7):
 # the harness
 
 
-@pytest.mark.parametrize("cell", ["fleet10k.storm", "fleet10k.edits"])
+@pytest.mark.parametrize("cell", ["fleet10k.storm", "fleet10k.edits",
+                                  "fleet10k.mixed"])
 def test_result_line_has_the_contracts_keys(tiny, cpu_link, capsys, cell):
     res = run_tiny(tiny, cell=cell)
     assert list(res) == RESULT_KEYS + ["compared"]
@@ -152,6 +159,33 @@ def test_edits_cell_takes_the_resident_route(tiny, cpu_link, capsys):
         == window["ops"] == res["attempted"]
 
 
+def test_mixed_cell_takes_both_routes_in_one_window(tiny, cpu_link, capsys):
+    """Cycles of nine: a round under `batch()`, then eight single edits,
+    each its own flush. The cell reads the round route's metrics and the
+    resident route's from the accepted files, with no twin."""
+    res = run_tiny(tiny, cell="fleet10k.mixed", trace=1)
+    assert res["correct"] is True, res["compared"]
+    window = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+              if '"window"' in ln][0]
+    first = TINY_MIX["mixed"]["warmup_requests"] + 1
+    rounds = sum(1 for r in range(first, first + res["attempted"])
+                 if r % 9 == 0)
+    assert res["attempted"] >= 18 and rounds >= 2
+    assert window["rounds_flushed"] == res["attempted"]
+    assert window["ops"] == window["ops_ingested"] \
+        > res["attempted"] - rounds + 30 * rounds
+    assert window["dims_before"] == window["dims_after"]
+    assert res["metrics"]["compiles_in_window"]["value"] == 0
+    # the first edit after a round reconciles the whole buffer, the seven
+    # behind it one block each
+    assert res["metrics"]["block_apply_share"]["value"] == pytest.approx(
+        100 * 7 / 9, abs=6)
+    assert res["metrics"]["direct_frame_share"]["value"] == pytest.approx(
+        100 * rounds / res["attempted"])
+    assert {"commit_wait_mean_ms", "pack_share", "readback_share",
+            "route_share", "fused_round_share"} <= set(res["metrics"])
+
+
 def test_a_declared_metric_that_reads_nothing_ends_the_run(tiny, cpu_link):
     # a CPU trace has no device plane: the device readers read nothing
     with pytest.raises(run.RunFailed, match="megakernel_roofline"):
@@ -200,9 +234,9 @@ def test_warmup_that_leaves_a_program_uncompiled_fails_loudly(tiny, cpu_link):
 # correct can fail: the control, and the timed path broken underneath
 
 
-@pytest.mark.parametrize("cell", ["fleet10k.storm", "fleet10k.edits"])
-@pytest.mark.parametrize("broken", [k for k in reference.BROKEN
-                                    if k != "none"])
+@pytest.mark.parametrize("cell", ["fleet10k.storm", "fleet10k.edits",
+                                  "fleet10k.mixed"])
+@pytest.mark.parametrize("broken", list(check.CONTROLS))
 def test_control_comes_out_not_correct(tiny, broken, cell):
     def steer(svc):
         svc.close()
@@ -275,6 +309,10 @@ class Faulty:
     ("value_altered", "fleet10k.storm", "hashes_wrong"),
     ("hash_altered", "fleet10k.storm", "hashes_wrong"),
     ("shard_left_out", "fleet10k-4shard.storm", "hashes_wrong"),
+    ("state_unchanged", "fleet10k.mixed", "changes_unserved"),
+    ("half_left_out", "fleet10k.mixed", "hashes_wrong"),
+    ("value_altered", "fleet10k.mixed", "hashes_wrong"),
+    ("hash_altered", "fleet10k.mixed", "hashes_wrong"),
 ])
 def test_a_fault_under_the_timed_path_comes_out_not_correct(
         tiny, cpu_link, fault, cell, number):
@@ -462,15 +500,254 @@ def test_benchmark_json_agrees_with_the_files():
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert "setup_s" in e2e and all(0.01 <= m["bound"] <= 0.25
                                     for m in e2e.values())
+    # a metric's file says which cells report it by what a cell is; the
+    # index lists them by name: the rule's expansion over the listed cells
+    reports = {name: {m["name"] for m in run.cell_metrics(name)}
+               for name in listed}
+    assert sorted(m["name"] for m in bench["per_layer"]) == sorted(
+        set().union(*reports.values()))
     for m in bench["per_layer"]:
         f = metrics[m["name"]]
         assert m == {k: f[k] for k in ("name", "unit", "better", "source",
-                                       "layer", "moves", "workloads")
-                     if k in f} | {"workloads": [
-                         w for w in f["workloads"] if w in listed]}
-        assert m["moves"] in e2e and set(m["workloads"]) <= set(listed)
-    for name in listed:
-        assert any(name in m["workloads"] for m in bench["per_layer"])
+                                       "layer", "moves")} | {"workloads": [
+                         w for w in listed if m["name"] in reports[w]]}
+        assert m["moves"] in e2e and m["workloads"]
+    assert all(reports.values())
+
+
+# what each accepted cell reported at PR 32, the parent of the PR that made
+# the metric files say their cells by mix and service kind
+ROUND_METRICS = ["device_wait_share", "direct_frame_share",
+                 "fused_round_share", "megakernel_roofline", "pack_share",
+                 "readback_share", "resident_gather_share", "route_share"]
+EDIT_METRICS = ["apply_final_roofline", "block_apply_share",
+                "commit_wait_mean_ms"]
+SHARD_METRICS = ["chip_busy_balance", "pod_idle_share", "shard_docs_skew",
+                 "shard_fanout_share", "shard_flush_concurrency"]
+EVERY_METRICS = ["admit_share", "commit_share", "compiles_in_window",
+                 "device_idle_share", "dispatch_share", "encode_share",
+                 "flush_mean_ms", "publish_share", "upload_share"]
+
+
+@pytest.mark.parametrize("cell,names", [
+    ("fleet10k.storm", EVERY_METRICS + ROUND_METRICS),
+    ("fleet10k.edits", EVERY_METRICS + EDIT_METRICS),
+    ("fleet10k-4shard.storm", EVERY_METRICS + ROUND_METRICS + SHARD_METRICS),
+    # the one-chip metrics of both accepted mixes, with no twin file
+    ("fleet10k.mixed", EVERY_METRICS + ROUND_METRICS + EDIT_METRICS),
+])
+def test_a_cell_reports_the_metrics_its_mix_and_service_give(cell, names):
+    assert [m["name"] for m in run.cell_metrics(cell)] == sorted(names)
+
+
+def test_metric_rule_by_mix_service_and_name():
+    every = dict(name="m")
+    assert run.metric_holds(every, "c", {"x"}, "single")
+    named = dict(every, workloads=["c"])
+    assert run.metric_holds(named, "c", {"x"}, "single")
+    assert not run.metric_holds(named, "d", {"x"}, "single")
+    ruled = dict(every, mixes=["storm"], services=["sharded"])
+    assert run.metric_holds(ruled, "c", {"mixed", "storm"}, "sharded")
+    assert not run.metric_holds(ruled, "c", {"mixed", "storm"}, "single")
+    assert not run.metric_holds(ruled, "c", {"edits"}, "sharded")
+    assert run.metric_holds(dict(every, mixes=["edits"]), "c", {"edits"},
+                            "anything")
+
+
+# ---------------------------------------------------------------------------
+# the accepted cells do not move
+
+
+UUID = re.compile(r"[0-9a-f]{8}-[0-9a-f]{4}-[0-9a-f]{4}-[0-9a-f]{4}-"
+                  r"[0-9a-f]{12}")
+
+
+class Digest:
+    """sha256 over changes in the order they are made: `(actor, seq, deps,
+    ops)` of each. Object ids that are not seeded (uuid4, in the list and
+    text documents' load) are renamed by order of first appearance."""
+
+    def __init__(self):
+        self.h = hashlib.sha256()
+        self.names = {reference.ROOT_ID: reference.ROOT_ID}
+
+    def _s(self, v):
+        if isinstance(v, str):
+            return UUID.sub(lambda m: self.names.setdefault(
+                m.group(0), f"obj{len(self.names)}"), v)
+        return v
+
+    def change(self, c):
+        return (c.actor, c.seq, sorted(c.deps.items()),
+                [(o.action, self._s(o.obj), self._s(o.key), self._s(o.value),
+                  o.elem) for o in c.ops])
+
+    def round(self, tag, round_):
+        self.h.update(repr((tag, [(d, [self.change(c) for c in chs])
+                                  for d, chs in round_.items()])).encode())
+
+
+# computed on the tree of PR 32 (bd44103) with the calls run.py and
+# drivers/rounds.py made there: FleetSpec.from_config + make_fleet,
+# traffic.Schedule, fleet.first + small_load_rounds, request_changes,
+# fleet.replay; the full 10,044-document fleet
+PINNED = {
+    ("fleet10k", "storm", 7):
+        "7d10ad28276f47ec9f9702b9d19096bbf9c96b022cea57191119a5d151898e03",
+    ("fleet10k", "storm", 2**31 + 11):
+        "aaec14dc86e6943d0d9b6f10e8100b71a0cc43335becf4fd998f74c6be99872d",
+    ("fleet10k", "storm", 123456789):
+        "1ab56e28c529273b18809789c001ff8765daf637cd05f532c18aadb5eb572f39",
+    ("fleet10k", "edits", 7):
+        "aceb3cdfe05adacbabdc644e15841e8abcbc346001147b85c5a4c1080a8dc8e2",
+    ("fleet10k", "edits", 2**31 + 11):
+        "fc06009b7b1987a9862133f041a95be7e0a5b565163f4275a7e9f4275835b920",
+    ("fleet10k", "edits", 123456789):
+        "cb4b16c7ce791cfacd4425399f395c4f0d4bd453fca56d0305702fa5fb13eece",
+    ("fleet10k-4shard", "storm", 7):
+        "7d10ad28276f47ec9f9702b9d19096bbf9c96b022cea57191119a5d151898e03",
+    ("fleet10k-4shard", "storm", 2**31 + 11):
+        "aaec14dc86e6943d0d9b6f10e8100b71a0cc43335becf4fd998f74c6be99872d",
+    ("fleet10k-4shard", "storm", 123456789):
+        "1ab56e28c529273b18809789c001ff8765daf637cd05f532c18aadb5eb572f39",
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _digest_of(config_json: str, mix_json: str, seed: int) -> str:
+    """Keyed by what the files say, so that the sharded configuration,
+    whose fleet is fleet10k's number for number, is made once a seed."""
+    config, mix = json.loads(config_json), json.loads(mix_json)
+    fleet = run.seam(config, "fleet_kind", "fleets", fleetlib).make(
+        config, seed)
+    schedule = run.seam(mix, "schedule", "schedules", traffic).make(
+        mix, fleet, seed, BENCH)
+    dg = Digest()
+    for round_ in fleet.load_rounds():
+        dg.round("load", round_)
+    for r in range(41):
+        assert schedule.batch(r) is mix["batch"]
+        dg.round(r, fleet.request_changes(schedule.request(r)))
+    sent, origin = fleet.replay(schedule, range(41))
+    dg.round("sent", sent)
+    dg.h.update(repr(sorted(origin.items())).encode())
+    return dg.h.hexdigest()
+
+
+@pytest.mark.parametrize("config_name,mix_name,seed", list(PINNED))
+def test_accepted_cells_send_what_they_sent_at_pr_32(config_name, mix_name,
+                                                     seed):
+    """The load rounds, requests 0..40 and the replay of those requests,
+    through the calls run.py and the driver make now, give the digest the
+    parent's calls gave: the same fleet, the same requests, the same
+    replay from the same seed."""
+    config = fleetlib.load_json("configs", config_name)
+    mix = fleetlib.load_json("traffic", mix_name)
+    assert not {"fleet_kind", "check"} & set(config) and "schedule" not in mix
+    # of a configuration, only its fleet's numbers reach the fleet
+    fleetlib.FleetSpec.from_config(config)
+    assert _digest_of(json.dumps({"fleet": config["fleet"]}, sort_keys=True),
+                      json.dumps(mix, sort_keys=True),
+                      seed) == PINNED[config_name, mix_name, seed]
+
+
+# ---------------------------------------------------------------------------
+# the cycle schedule and the mix built on it
+
+
+def test_cycle_schedule_is_built_from_the_accepted_mixes():
+    mix = fleetlib.load_json("traffic", "mixed")
+    fleet = fleetlib.Fleet(None, small=[f"d{i}" for i in range(10_000)])
+    cycle = run.load_by_path("schedules", mix["schedule"]).make(
+        mix, fleet, 2**31 + 11, BENCH)
+    again = run.load_by_path("schedules", mix["schedule"]).make(
+        mix, fleet, 2**31 + 11, BENCH)
+    storm = traffic.Schedule(fleetlib.load_json("traffic", "storm"),
+                             10_000, 7, 2**31 + 11)
+    edits = traffic.Schedule(fleetlib.load_json("traffic", "edits"),
+                             10_000, 7, 2**31 + 11)
+    n_warm = mix["warmup_requests"]
+    assert n_warm == 72 and n_warm % 9 == 0 and mix["hot_set_stride"] == 37
+    for r in (0, 9, 71, 72, 73, 80, 81, 900, 907):
+        drawn = cycle.request(r)
+        assert [x.tolist() for x in drawn] == \
+            [x.tolist() for x in again.request(r)]
+        assert cycle.batch(r) is (r % 9 == 0)
+        if r >= n_warm:
+            # past the warm-up a request is its part's own request r
+            part = storm if r % 9 == 0 else edits
+            assert [x.tolist() for x in drawn] == \
+                [x.tolist() for x in part.request(r)]
+        assert len(drawn[0]) == 1 if r % 9 else len(drawn[0]) > 1000
+    # the eight warm-up rounds span the sizes a window's rounds come to,
+    # as the storm mix's eight warm-up requests do
+    warm = [len(cycle.request(r)[0]) for r in range(0, n_warm, 9)]
+    assert len(warm) == 8 and warm[0] == min(warm) and warm[-1] == max(warm)
+    later = [len(cycle.request(r)[0]) for r in range(90, 1890, 9)]
+    assert min(warm) < min(later) - 20 and max(later) + 20 < max(warm)
+    with pytest.raises(ValueError, match="whole number of cycles"):
+        run.load_by_path("schedules", "cycle").make(
+            dict(mix, warmup_requests=70), fleet, 1, BENCH)
+
+
+def test_mixed_history_stays_under_the_cap():
+    """1,700 requests, more than a window holds (about 1,500), leave every
+    document under half the resident history cap."""
+    import numpy as np
+    mix = fleetlib.load_json("traffic", "mixed")
+    config = fleetlib.load_json("configs", "fleet10k")
+    fleet = fleetlib.make(config, 4)
+    for _ in fleet.load_rounds():
+        pass
+    loaded = np.array([fleet.depth[d] for d in fleet.small])
+    cycle = run.load_by_path("schedules", "cycle").make(mix, fleet, 4, BENCH)
+    for r in range(1700):
+        loaded[cycle.request(r)[0]] += 1
+    assert loaded.max() < fleet.spec.history_cap / 2
+
+
+# ---------------------------------------------------------------------------
+# the control at any load, and the prover
+
+
+def test_stale_hash_control_passes_over_a_flush_of_new_documents(tiny):
+    """A fleet loaded in ten flushes: the seventh holds new documents only,
+    none with a hash to leave stale. The control goes on and is read not
+    correct by the window's flushes."""
+    _rewrite(os.path.join(tiny, "configs", "fleet10k.json"),
+             fleet={"load_batch": 23})
+    flushes = []
+
+    def steer(svc):
+        svc.close()
+        ref = reference.RefService("stale_hash")
+        real = ref._flush
+
+        def flush():
+            if ref._dirty:
+                flushes.append(len(ref._stale))
+            real()
+        ref._flush = flush
+        return ref
+    res = run_tiny(tiny, steer=steer)
+    # the load is ten flushes, 1 + 9 rounds; the seventh left nothing stale
+    assert flushes[:10] == [0] * 10 and flushes[-1] >= 1
+    assert res["correct"] is False
+    assert res["compared"]["hashes_wrong"]["value"] > 0
+
+
+def test_prove_runs_the_controls_the_check_declares(tiny, cpu_link, capsys):
+    import prove
+    argv = ["--workload", "fleet10k.mixed", "--seeds", "5", "--seconds", "30"]
+    kw = dict(root=tiny, devices=jax.devices(), max_requests=12)
+    assert prove.main(argv + ["--control", "1"], **kw) == 0
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert [ln["control"] for ln in lines] == list(check.CONTROLS)
+    assert all(ln["correct"] is False and ln["as_it_has_to"] for ln in lines)
+    assert prove.main(argv, steer=eager, **kw) == 0
+    line, = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert line["correct"] is True and line["control"] is None
+    assert set(line["compared"].values()) == {0}
 
 
 # ---------------------------------------------------------------------------
@@ -511,6 +788,215 @@ def test_new_files_are_found_and_run(tiny, cpu_link, capsys):
     # the old cell does not report the new cell's metric
     assert "ops_twice" not in [m["name"] for m in run.cell_metrics(
         "fleet10k.storm", tiny)]
+
+
+TWO_ACTOR_FLEET = '''"""Fleet kind `two_actor`: the maps fleet, written by two actors
+concurrently. A request gives each drawn document two changes to the same
+field, one by `storm` and one by `zed`, neither of which has seen the
+other: the field holds zed's value and storm's as its conflict."""
+import fleet as fleetlib
+
+
+class Fleet(fleetlib.Fleet):
+    dims_fixed = False       # reported, and the run goes on
+
+    def request_changes(self, drawn):
+        from automerge_tpu.core.change import Change, Op
+        from automerge_tpu.core.ids import ROOT_ID
+        out = {}
+        for i, f, v in zip(*(x.tolist() for x in drawn)):
+            d, key = self.small[i], fleetlib.SMALL_KEYS[f]
+            self.zed[d] = self.zed.get(d, 0) + 1
+            out[d] = fleetlib.storm_change(
+                self, d, (Op("set", ROOT_ID, key=key, value=v),)) + [
+                Change("zed", self.zed[d], {},
+                       [Op("set", ROOT_ID, key=key, value=-v)])]
+        return out
+
+    @staticmethod
+    def request_ops(round_):
+        return 2 * len(round_)
+
+    def load_line(self):
+        return {"writers": ["storm", "zed"]}
+
+    def replay(self, schedule, numbers):
+        again = Fleet(self.spec, small=self.small,
+                      structured=self.structured, seed=self.seed)
+        again.zed = {}
+        sent = {d: list(chs) for d, chs in self.first.items()}
+        for round_ in fleetlib.small_load_rounds(again, self.seed):
+            sent.update(round_)
+        origin = {}
+        for r in numbers:
+            for d, chs in again.request_changes(schedule.request(r)).items():
+                sent[d].extend(chs)
+                origin[(d, chs[0].seq)] = r
+        return sent, origin
+
+
+def make(config, seed):
+    made = fleetlib.make(dict(config, fleet={
+        k: v for k, v in config["fleet"].items() if k != "writers"}), seed)
+    fleet = Fleet(made.spec, small=made.small, structured=made.structured,
+                  seqs=made.seqs, first=made.first, seed=made.seed)
+    fleet.zed = {}
+    return fleet
+'''
+
+ALTERNATE_SCHEDULE = '''"""Schedule `alternate`: even requests are rounds of the mix's draws under
+one batch(), odd ones a single draw, a bare apply_changes."""
+import traffic
+
+
+class Alternate(traffic.Schedule):
+    def batch(self, r):
+        return r % 2 == 0
+
+    def request(self, r):
+        return self.drawn(r, self.draws if r % 2 == 0 else 1)
+
+
+def make(mix, fleet, seed, root):
+    return Alternate(mix, len(fleet.small), fleet.n_fields, seed)
+'''
+
+CONFLICTS_CHECK = '''"""Check `conflicts`: the accepted comparison and one number more,
+`conflicts_lost`, by a plain reference of its own for what this fleet
+writes: of two concurrent sets the higher actor's is the value, the other
+its conflict."""
+import check
+import reference
+
+read_untouched, read_program = check.read_untouched, check.read_program
+FALLBACK_COUNTERS = check.FALLBACK_COUNTERS
+CONTROLS = {"first_writer_wins":
+            lambda: reference.RefService("first_writer_wins")}
+
+
+def expected(log):
+    """{key: (value, {loser: value})} of the fields the last concurrent
+    pair of each key wrote; the changes of a pair follow one another."""
+    out = {}
+    for a, b in zip(log, log[1:]):
+        if (a.actor, b.actor) == ("storm", "zed"):
+            out[a.ops[0].key] = (b.ops[0].value, {"storm": a.ops[0].value})
+    return out
+
+
+def decide(read, fleet, sent, origin, untouched_before, requests, fallbacks):
+    verdict = check.decide(read, fleet, sent, origin, untouched_before,
+                           requests, fallbacks)
+    lost = 0
+    for d, got in read["states"].items():
+        for key, (value, losers) in expected(sent.get(d, ())).items():
+            if not isinstance(got, dict) or got["data"].get(key) != value \
+                    or got["conflicts"].get(key) != losers:
+                lost += 1
+    verdict["compared"]["conflicts_lost"] = {"value": lost, "limit": 0}
+    verdict["correct"] = verdict["correct"] and lost == 0
+    verdict["sizes"]["pairs"] = sum(
+        len(expected(sent.get(d, ()))) for d in read["states"])
+    return verdict
+'''
+
+
+def _cell_of_its_own_seams(tiny):
+    """A configuration that names its fleet kind and its check, a mix that
+    names its schedule, a cell of the two: new files in a copy, no edit."""
+    for kind, name, text in (("fleets", "two_actor", TWO_ACTOR_FLEET),
+                             ("schedules", "alternate", ALTERNATE_SCHEDULE),
+                             ("checks", "conflicts", CONFLICTS_CHECK)):
+        os.makedirs(os.path.join(tiny, kind), exist_ok=True)
+        with open(os.path.join(tiny, kind, name + ".py"), "w",
+                  encoding="utf-8") as f:
+            f.write(text)
+    shutil.copy(os.path.join(tiny, "configs", "fleet10k.json"),
+                os.path.join(tiny, "configs", "fleet-2w.json"))
+    _rewrite(os.path.join(tiny, "configs", "fleet-2w.json"), name="fleet-2w",
+             fleet_kind="two_actor", check="conflicts", fleet={"writers": 2})
+    shutil.copy(os.path.join(tiny, "traffic", "storm.json"),
+                os.path.join(tiny, "traffic", "pairs.json"))
+    _rewrite(os.path.join(tiny, "traffic", "pairs.json"),
+             schedule="alternate", reports_as=["storm"], warmup_requests=4)
+    with open(os.path.join(tiny, "workloads", "fleet-2w.pairs.json"), "w",
+              encoding="utf-8") as f:
+        json.dump({"name": "fleet-2w.pairs", "config": "fleet-2w",
+                   "traffic": "pairs", "chips": 1, "why": "a new cell"}, f)
+    return "fleet-2w.pairs"
+
+
+def test_a_cell_of_its_own_fleet_schedule_and_check_runs(tiny, cpu_link,
+                                                         capsys):
+    cell = _cell_of_its_own_seams(tiny)
+    res = run_tiny(tiny, cell=cell)
+    assert res["correct"] is True, res["compared"]
+    assert list(res["compared"])[-1] == "conflicts_lost"
+    assert all(row["value"] == 0 == row["limit"]
+               for row in res["compared"].values())
+    stages = {json.loads(ln)["stage"]: json.loads(ln)
+              for ln in capsys.readouterr().out.splitlines()}
+    assert stages["load"]["writers"] == ["storm", "zed"]
+    assert "small_depth_min_median_max" not in stages["load"]
+    # six rounds and six single edits, two ops a document
+    assert res["attempted"] == 12
+    assert stages["window"]["ops"] == stages["window"]["ops_ingested"] > 12
+    assert stages["window"]["rounds_flushed"] == 12
+    assert stages["check"]["compared"]["pairs"] > 0
+    # the new mix takes the accepted round metrics as data
+    assert "megakernel_roofline" in [m["name"] for m in run.cell_metrics(
+        cell, tiny)]
+
+
+def test_a_cell_of_its_own_seams_with_a_guarantee_broken(tiny, cpu_link):
+    cell = _cell_of_its_own_seams(tiny)
+    controls = run.load_by_path("checks", "conflicts", tiny).CONTROLS
+    assert list(controls) == ["first_writer_wins"]
+
+    def steer(svc):
+        svc.close()
+        return controls["first_writer_wins"]()
+    res = run_tiny(tiny, cell=cell, steer=steer)
+    assert res["correct"] is False
+    assert res["compared"]["conflicts_lost"]["value"] > 0
+    # the fleet's own fault: zed's half of every pair never reaches the engine
+
+    class OneWriter(Faulty):
+        def apply_changes(self, doc_id, changes):
+            return self._svc.apply_changes(
+                doc_id, [c for c in changes if c.actor != "zed"])
+
+    def steer(svc):
+        eager(svc)
+        return OneWriter(svc, None)
+    res = run_tiny(tiny, cell=cell, steer=steer)
+    assert res["correct"] is False
+    assert res["compared"]["conflicts_lost"]["value"] > 0
+    assert res["compared"]["changes_unserved"]["value"] > 0
+
+
+def test_a_fleet_whose_dims_may_move_has_both_reported(tiny, cpu_link, capsys):
+    """The default ends a run whose resident dims moved in the window; a
+    fleet kind that says its documents arrive there has both reported and
+    the run goes on."""
+    cell = _cell_of_its_own_seams(tiny)
+    real = fleetlib.resident_dims
+    calls = []
+
+    def moving(svc):
+        calls.append(1)
+        return real(svc) + [[len(calls)]]
+    fleetlib.resident_dims = moving
+    try:
+        res = run_tiny(tiny, cell=cell)
+        window = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+                  if '"window"' in ln][0]
+        assert res["correct"] is True
+        assert window["dims_before"] != window["dims_after"]
+        with pytest.raises(run.RunFailed, match="dims changed"):
+            run_tiny(tiny, cell="fleet10k.storm")
+    finally:
+        fleetlib.resident_dims = real
 
 
 # ---------------------------------------------------------------------------

@@ -27,6 +27,11 @@ A mix's parameters:
   through the same driver. They make from (1 - spread) to (1 + spread)
   times the draws, evenly, so that the sizes the window's requests come
   to lie inside what was warmed up, whatever the program compiles by size.
+
+This module is also the schedule a mix gets by naming none: `make(mix,
+fleet, seed, root)` and the `Schedule` it returns are what `run.py` and the
+drivers ask of any schedule (`schedules/<name>.py`; README, "The three
+seams").
 """
 
 from __future__ import annotations
@@ -50,17 +55,33 @@ class Schedule:
         self._order = np.random.default_rng(
             [self.seed, 0x0D0C5]).permutation(self.n)
 
+    def batch(self, r: int) -> bool:
+        """Whether request `r` goes under one `svc.batch()`."""
+        return bool(self.mix["batch"])
+
+    def warmup_draws(self, k: int, n_warm: int) -> int:
+        """Draws of the `k`-th of `n_warm` warm-up requests: from (1 -
+        spread) to (1 + spread) times the mix's draws, evenly."""
+        along = 2 * k / max(n_warm - 1, 1) - 1     # -1 .. 1
+        return max(1, round(self.draws * (1 + self.warmup_spread * along)))
+
     def request(self, r: int) -> tuple:
         """What request `r` updates: the indices of its distinct small
         documents (sorted), and for each the index of the field written
         and the value."""
+        return self.drawn(r, self.warmup_draws(r, self.warmup)
+                          if r < self.warmup else self.draws)
+
+    def drawn(self, r: int, draws: int) -> tuple:
+        """Request `r` at `draws` key draws."""
         rng = np.random.default_rng([self.seed, 1, int(r)])
-        draws = self.draws
-        if r < self.warmup:
-            along = 2 * r / max(self.warmup - 1, 1) - 1     # -1 .. 1
-            draws = max(1, round(draws * (1 + self.warmup_spread * along)))
         ranks = np.searchsorted(self._cdf, rng.random(draws))
         ranks = np.unique(np.minimum(ranks, self.n - 1))
         docs = np.sort(self._order[(ranks + self.stride * int(r)) % self.n])
         return (docs, rng.integers(0, self.n_fields, size=len(docs)),
                 rng.integers(0, 1 << 16, size=len(docs)))
+
+
+def make(mix: dict, fleet, seed: int, root: str | None = None) -> Schedule:
+    """The schedule of a mix that names none of its own."""
+    return Schedule(mix, len(fleet.small), fleet.n_fields, seed)
