@@ -19,8 +19,11 @@ One `DocLedger` per sync node (DocSet/EngineDocSet), attached lazily by
   change-bearing sends (`record_send`), deliveries split into useful vs
   duplicate against the pre-apply local clock (`record_receive`), chaos/
   transport drops (`record_drop`);
-- `sync/service.py`: per-doc admissions at flush time (`note_admit` —
-  counts and stamps only; the flush hot path never pays a clock read);
+- `sync/service.py`: per-doc admissions at flush time, once a round
+  (`note_admit_round`, the rows flush's call site in
+  `_flush_pending_inner_locked`: one stamp, one lock for all the round's
+  documents; `note_admit` is a round of one, the classic backend's) —
+  counts and stamps only; the flush hot path never pays a clock read;
 - `sync/epochs.py`: buffered-entry visibility (`EpochIngestBuffer
   .doc_count`), read at export time.
 
@@ -302,6 +305,12 @@ class DocLedger:
             # LRU touch: move to the MRU end (dicts keep insertion order)
             self._docs[doc_id] = self._docs.pop(doc_id)
         e.touches += 1
+        self._mutated_locked(1)
+        return e
+
+    def _mutated_locked(self, n: int) -> None:
+        """Count `n` mutations; the gauges refresh when the count crosses
+        a multiple of GAUGE_REFRESH."""
         if not self._active:
             # first mutation since construction or a metrics.reset():
             # (re-)register so the snapshot section sees this node again.
@@ -310,10 +319,10 @@ class DocLedger:
             self._active = True
             with _registry_lock:
                 _registry.add(self)
-        self._mutations += 1
-        if self._mutations % GAUGE_REFRESH == 0:
+        before = self._mutations
+        self._mutations = before + n
+        if before // GAUGE_REFRESH != self._mutations // GAUGE_REFRESH:
             self._refresh_gauges_locked()
-        return e
 
     def _refresh_gauges_locked(self) -> None:
         """Periodic registered-series refresh, on the MUTATION path (every
@@ -342,23 +351,24 @@ class DocLedger:
         if delta > 0:
             metrics.observe("obs_doc_ledger_s", delta)
 
-    def _evict_locked(self) -> None:
-        """Fold one entry into the aggregate bucket: the least-recently-
-        touched NON-lagging doc within the scan window; only when every
-        scanned candidate is behind does a lagging one go (the table
-        exists to hold the lagging tail)."""
+    def _victim_locked(self) -> str:
+        """The doc to fold next: the least-recently-touched NON-lagging
+        doc within the scan window; only when every scanned candidate is
+        behind does a lagging one go (the table exists to hold the
+        lagging tail). A value that is a plain count is a document
+        note_admit_round has not made an entry for yet: never behind."""
         victim = None
         for i, (d, e) in enumerate(self._docs.items()):
             if i >= EVICT_SCAN:
                 break
-            if e.behind_since is None:
-                victim = d
-                break
+            if e.__class__ is int or e.behind_since is None:
+                return d
             if victim is None:
                 victim = d
-        if victim is None:                      # empty table (can't be)
-            return
-        e = self._docs.pop(victim)
+        return victim
+
+    def _fold_locked(self, e: _DocEntry) -> None:
+        """One evicted entry's counts into the aggregate bucket."""
         a = self._agg
         a["docs"] += 1
         a["admitted"] += e.admitted
@@ -369,6 +379,10 @@ class DocLedger:
             a["bytes_sent"] += pv.bytes_sent
             a["bytes_received"] += pv.bytes_received
             a["drops"] += pv.drops
+
+    def _evict_locked(self) -> None:
+        """Fold one entry (`_victim_locked`'s) into the aggregate."""
+        self._fold_locked(self._docs.pop(self._victim_locked()))
         self._evictions += 1
         metrics.bump("obs_doc_evictions")
 
@@ -494,24 +508,91 @@ class DocLedger:
             self._self_s += time.perf_counter() - t0
 
     def note_admit(self, doc_id: str, n_changes: int) -> None:
-        """A flush admitted changes for a doc. Called under the service
-        lock — counts and stamps ONLY (dict math, no clock reads: the
-        ~18%-of-a-fleet-round StaleView cost stays off the flush). The
-        lag restamp happens opportunistically from the read cache."""
+        """A flush admitted changes for one doc: a round of one."""
+        self.note_admit_round({doc_id: n_changes})
+
+    def note_admit_round(self, counts: dict) -> None:
+        """A flush admitted `counts[doc]` changes for each of a round's
+        docs. Called under the service lock — counts and stamps ONLY
+        (dict math, no clock reads: the ~18%-of-a-fleet-round StaleView
+        cost stays off the flush; the lag restamp happens
+        opportunistically from the read cache), with one stamp, one
+        interval of self-time and one acquisition of the ledger's lock
+        for the whole round.
+
+        The table is left as the same docs admitted one by one, in
+        `counts`' order, would leave it: the same entries in the same LRU
+        order, the same aggregate, evictions and `_mutations`. A doc the
+        table does not hold enters it as its plain count and gets its
+        `_DocEntry` only if it is still there when the round ends: a
+        round larger than `top_k` folds the docs that cannot survive it
+        straight into the aggregate. The gauges are refreshed once, after
+        the round, when its mutations cross a multiple of GAUGE_REFRESH
+        (a round of one: every 32nd call, as ever)."""
+        if not counts:
+            return
         t0 = time.perf_counter()
         now = time.time()
         with self._lock:
-            e = self._entry_locked(doc_id)
-            e.admitted += int(n_changes)
-            e.last_admit_at = now
-            # cheap catch-up check: the post-flush clock is not in the
-            # read cache yet (the flush just invalidated it), so only a
-            # later advert/refresh can clear the lag exactly — but an
-            # admission at least refreshes the stamp time for a doc
-            # already known behind, keeping lag_s honest while traffic
-            # flows.
-            if e.behind_since is not None:
-                e.lag_s = max(0.0, now - e.behind_since)
+            docs, top_k = self._docs, self.top_k
+            made: list[str] = []
+            evicted = unmade = unmade_admitted = 0
+            try:
+                for d, n in counts.items():
+                    e = docs.pop(d, None)
+                    if e is not None:
+                        # LRU touch: back in at the MRU end (dicts keep
+                        # insertion order)
+                        docs[d] = e
+                        e.touches += 1
+                        e.admitted += int(n)
+                        e.last_admit_at = now
+                        # cheap catch-up check: the post-flush clock is
+                        # not in the read cache yet (the flush just
+                        # invalidated it), so only a later advert/refresh
+                        # can clear the lag exactly — but an admission at
+                        # least refreshes the stamp time for a doc already
+                        # known behind, keeping lag_s honest while traffic
+                        # flows.
+                        if e.behind_since is not None:
+                            e.lag_s = max(0.0, now - e.behind_since)
+                        continue
+                    docs[d] = int(n)
+                    made.append(d)
+                    if len(docs) <= top_k:
+                        continue
+                    # _evict_locked's rule, call for call; the LRU head
+                    # when it is not behind, as wherever no peer is
+                    # attached
+                    victim = next(iter(docs))
+                    e = docs[victim]
+                    if e.__class__ is not int and e.behind_since is not None:
+                        victim = self._victim_locked()
+                    e = docs.pop(victim)
+                    evicted += 1
+                    if e.__class__ is int:
+                        unmade += 1
+                        unmade_admitted += e
+                    else:
+                        self._fold_locked(e)
+            finally:
+                # however the loop ended, the table holds entries only
+                # before anything else reads it: what is left of the
+                # round's new docs (the newest: the rule never passes over
+                # an unmade doc for a younger one) gets its entry here
+                for d in made[-top_k:]:
+                    n = docs.get(d)
+                    if n.__class__ is int:
+                        e = docs[d] = _DocEntry()
+                        e.touches = 1
+                        e.admitted = n
+                        e.last_admit_at = now
+                self._agg["docs"] += unmade
+                self._agg["admitted"] += unmade_admitted
+                self._evictions += evicted
+            if evicted:
+                metrics.bump("obs_doc_evictions", evicted)
+            self._mutated_locked(len(counts))
             self._self_s += time.perf_counter() - t0
 
     # -- export --------------------------------------------------------------

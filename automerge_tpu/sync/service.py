@@ -1408,19 +1408,23 @@ class EngineDocSet:
                 d for d in pending if _changed(d))
             raise
         with perfscope.phase("publish"):
-            admitted = [d for d in pending if _changed(d)]
+            # per-doc admission stamps, written once a round: one call a
+            # ledger for all the round's docs (counts only — the ledger's
+            # flush contract forbids clock reads here; lag restamps ride
+            # the read cache). Submitted-change counts, not post-dedup: the
+            # ledger's usefulness split happens at DELIVERY, this stamp
+            # marks frontier movement + recency.
+            counts: dict[str, int] = {}
+            for d, parts in pending.items():
+                if _changed(d):
+                    n = 0
+                    for p in parts:
+                        n += int(p.n_changes)
+                    counts[d] = n
+            admitted = list(counts)
             if self.doc_ledger is not None:
-                # per-doc admission stamps (counts only — the ledger's flush
-                # contract forbids clock reads here; lag restamps ride the
-                # read cache). Submitted-change counts, not post-dedup: the
-                # ledger's usefulness split happens at DELIVERY, this stamp
-                # marks frontier movement + recency.
-                for d in admitted:
-                    self.doc_ledger.note_admit(
-                        d, sum(int(p.n_changes) for p in pending[d]))
-            for d in admitted:
-                tenantledger.note_ingress(
-                    d, sum(int(p.n_changes) for p in pending[d]))
+                self.doc_ledger.note_admit_round(counts)
+            tenantledger.note_ingress_round(counts)
             if self.handlers:
                 # no registered handlers -> no notifications to queue: the
                 # post-flush drain then needs no service-lock reacquisition

@@ -20,8 +20,12 @@ One process-global ledger (tenancy is a fleet property, like dispatch
 routing). Hooks feed it:
 
 - `sync/service.py` stamps per-tenant **ingress** at both admission
-  sites (`note_ingress` — alongside the doc ledger's `note_admit`) and
-  hands each coalesced flush round's per-tenant dirty-doc counts to the
+  sites — the rows flush once a round (`note_ingress_round`, beside the
+  doc ledger's `note_admit_round` in `_flush_pending_inner_locked`: the
+  round's documents folded by tenant, one write a tenant under one
+  lock), the classic backend a document (`note_ingress`, a round of
+  one) — and hands each coalesced flush round's per-tenant dirty-doc
+  counts to the
   dispatch ledger (`round_tenants`), whose round fold forwards the
   round's **dispatch/padding shares** here (`note_round`, attributed
   proportionally by dirty-doc count);
@@ -177,19 +181,31 @@ class TenantLedger:
     # -- table ---------------------------------------------------------------
 
     def _tenant_locked(self, tid: str) -> _Tenant:
+        t = self._slot_locked(tid, 1)
+        self._mutated_locked(1)
+        return t
+
+    def _slot_locked(self, tid: str, events: int) -> _Tenant:
+        """The tenant's account, made if there is room, else the
+        ``_overflow`` bucket's; `events` is how many mutations ask."""
         t = self._tenants.get(tid)
         if t is None:
             if (len(self._tenants) >= MAX_TENANTS
                     and tid != OVERFLOW_TENANT):
-                self._overflowed += 1
-                metrics.bump("sync_tenant_overflow")
-                return self._tenant_locked(OVERFLOW_TENANT)
+                self._overflowed += events
+                metrics.bump("sync_tenant_overflow", events)
+                return self._slot_locked(OVERFLOW_TENANT, events)
             t = self._tenants[tid] = _Tenant()
         self._active = True
-        self._mutations += 1
-        if self._mutations % GAUGE_REFRESH == 0:
-            self._refresh_gauges_locked()
         return t
+
+    def _mutated_locked(self, n: int) -> None:
+        """Count `n` mutations; the gauges refresh when the count crosses
+        a multiple of GAUGE_REFRESH."""
+        before = self._mutations
+        self._mutations = before + n
+        if before // GAUGE_REFRESH != self._mutations // GAUGE_REFRESH:
+            self._refresh_gauges_locked()
 
     def _refresh_gauges_locked(self) -> None:
         """Periodic registered-series refresh on the MUTATION path —
@@ -215,17 +231,42 @@ class TenantLedger:
     # -- mutation hooks ------------------------------------------------------
 
     def note_ingress(self, doc_id: str, n_changes: int) -> None:
-        if not enabled() or n_changes <= 0:
+        self.note_ingress_round({doc_id: n_changes})
+
+    def note_ingress_round(self, counts: dict) -> None:
+        """A flush admitted `counts[doc]` changes for each of a round's
+        docs: folded by tenant, then one write a tenant under one lock
+        and one stamp, leaving what one call a doc would (`admit_events`
+        counts the docs; a doc with no change is passed over). The gauges
+        are refreshed once, after the writes, when the round's mutations
+        cross a multiple of GAUGE_REFRESH."""
+        if not enabled():
             return
         t0 = time.perf_counter()
-        tid = tenant_of(doc_id)
+        fold: dict[str, list] = {}
+        for d, n in counts.items():
+            if n <= 0:
+                continue
+            tid = tenant_of(d)
+            acc = fold.get(tid)
+            if acc is None:
+                fold[tid] = [int(n), 1]
+            else:
+                acc[0] += int(n)
+                acc[1] += 1
+        if not fold:
+            return
         now = time.time()
         with self._lock:
-            t = self._tenant_locked(tid)
-            t.admitted += int(n_changes)
-            t.admit_events += 1
-            t.last_admit_at = now
-            self._admitted_total += int(n_changes)
+            events = 0
+            for tid, (n, docs) in fold.items():
+                t = self._slot_locked(tid, docs)
+                t.admitted += n
+                t.admit_events += docs
+                t.last_admit_at = now
+                self._admitted_total += n
+                events += docs
+            self._mutated_locked(events)
             self._self_s += time.perf_counter() - t0
 
     def note_wire(self, doc_id: str, sent: int = 0, bytes_sent: int = 0,
@@ -438,6 +479,10 @@ def ledger() -> TenantLedger:
 
 def note_ingress(doc_id: str, n_changes: int) -> None:
     _ledger.note_ingress(doc_id, n_changes)
+
+
+def note_ingress_round(counts: dict) -> None:
+    _ledger.note_ingress_round(counts)
 
 
 def note_wire(doc_id: str, **kw) -> None:

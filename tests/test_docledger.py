@@ -338,3 +338,167 @@ def test_chaos_stall_doc_inert_when_unset():
     chaos.reload()
     assert chaos.stall_doc(None, "any") is False
     assert not chaos.enabled()
+
+
+# -- the flush's round call -------------------------------------------------
+
+ROUND_NOW = 1_000.0
+
+
+class _Peer:
+    peer_label = "W"
+
+
+def _admit_as_the_seed_did(led, doc_id, n_changes):
+    """`note_admit` as it stood before the round call: one `_entry_locked`
+    (LRU touch or insert, one eviction by `_evict_locked`'s rule, the gauge
+    cadence) and the stamps, a document."""
+    with led._lock:
+        e = led._entry_locked(doc_id)
+        e.admitted += int(n_changes)
+        e.last_admit_at = ROUND_NOW
+        if e.behind_since is not None:
+            e.lag_s = max(0.0, ROUND_NOW - e.behind_since)
+
+
+def _one_by_one(led, counts):
+    for d, n in counts.items():
+        led.note_admit(d, n)
+
+
+def _seed_one_by_one(led, counts):
+    for d, n in counts.items():
+        _admit_as_the_seed_did(led, d, n)
+
+
+def _held(n, behind=()):
+    """`n` tracked docs with a peer lane each (counts a fold must carry),
+    those at the LRU positions `behind` lagging."""
+    def prepare(led):
+        for i in range(n):
+            led.record_receive(f"held{i}", _Peer(), i + 1, 1, nbytes=10)
+        with led._lock:
+            for i in behind:
+                e = led._docs[f"held{i}"]
+                e.behind_since, e.lag_changes = ROUND_NOW - 5.0 - i, 2
+                e.lag_s = 5.0 + i
+    return prepare
+
+
+def _round(new, hits=(), start=0):
+    """`new` docs the table has never held, in order, with tracked doc
+    `held<h>` placed at position `at` for each (at, h) of `hits`."""
+    docs = [f"new{start + i}" for i in range(new)]
+    for at, h in sorted(hits):
+        docs.insert(at, f"held{h}")
+    return {d: 1 + i % 3 for i, d in enumerate(docs)}
+
+
+# top_k is 32; every case ends on a multiple of GAUGE_REFRESH mutations, so
+# the last refresh of the one-by-one feed reads the state the round leaves
+ROUND_CASES = {
+    # 8 tracked, a round of 24 (4 of them tracked): nothing is evicted
+    "smaller-than-top-k": (_held(8), [_round(20, [(0, 3), (5, 0), (9, 7),
+                                                  (23, 4)])]),
+    # 32 tracked, a round of 96: held30 and held2 are touched while they
+    # are still in the table, held20 after it was folded (made anew, folded
+    # again), held9 late enough to survive the round
+    "larger-none-behind": (_held(32), [_round(92, [(1, 30), (2, 2),
+                                                   (40, 20), (90, 9)])]),
+    # the same with 18 lagging docs at the LRU end (a scan of EVICT_SCAN
+    # finds no other victim) and one in the middle; held1 is touched behind
+    "larger-entries-behind": (_held(32, behind=(*range(18), 25)),
+                              [_round(92, [(1, 30), (2, 1), (40, 20),
+                                           (90, 9)])]),
+    # two rounds of 64 that share docs: the second finds the first's last
+    # 32 tracked, touches some and makes the folded ones anew
+    "repeated-across-two-rounds": (_held(0), [
+        _round(64), {f"new{i}": 2 for i in (*range(60, 30, -3),
+                                            *range(100, 154))}]),
+}
+
+
+def _ledger_state(led):
+    with led._lock:
+        entries = [(d, e.admitted, e.touches, e.last_admit_at,
+                    e.behind_since, e.lag_s, e.lag_changes,
+                    {lbl: (pv.recv_useful, pv.recv_duplicate,
+                           pv.bytes_received) for lbl, pv in e.peers.items()})
+                   for d, e in led._docs.items()]
+        state = {"entries": entries, "aggregate": dict(led._agg),
+                 "mutations": led._mutations, "evictions": led._evictions}
+    snap = metrics.snapshot()
+    state["gauges"] = {k: v for k, v in snap.items()
+                       if k.startswith("obs_doc_")
+                       and not k.startswith("obs_doc_ledger_s")}
+    sec = led.section()
+    sec.pop("self_s")
+    state["section"] = sec
+    return state
+
+
+@pytest.mark.parametrize("case", sorted(ROUND_CASES))
+def test_a_round_call_leaves_what_one_call_a_document_leaves(
+        case, monkeypatch):
+    prepare, rounds = ROUND_CASES[case]
+    monkeypatch.setattr(time, "time", lambda: ROUND_NOW)
+    left = {}
+    for feed in ("seed", "one-by-one", "round"):
+        metrics.reset()
+        led = DocLedger(label="n", top_k=32)
+        prepare(led)
+        refreshes = []
+        real = led._refresh_gauges_locked
+        led._refresh_gauges_locked = lambda: (refreshes.append(1), real())
+        for counts in rounds:
+            before = len(refreshes)
+            {"seed": _seed_one_by_one, "one-by-one": _one_by_one,
+             "round": DocLedger.note_admit_round}[feed](led, counts)
+            if feed == "round":
+                assert len(refreshes) - before <= 1
+        assert led._mutations % docledger.GAUGE_REFRESH == 0
+        assert refreshes
+        left[feed] = _ledger_state(led)
+    assert left["round"] == left["seed"]
+    assert left["one-by-one"] == left["seed"]
+    if case != "smaller-than-top-k":
+        assert left["round"]["evictions"] >= 64
+        assert left["round"]["gauges"]["obs_doc_evictions"] \
+            == left["round"]["evictions"]
+        assert len(left["round"]["entries"]) == 32
+
+
+def test_a_round_of_one_keeps_the_gauge_cadence_of_one_refresh_in_32():
+    led = DocLedger(label="n")
+    refreshes = []
+    real = led._refresh_gauges_locked
+    led._refresh_gauges_locked = lambda: (refreshes.append(1), real())
+    for i in range(3 * docledger.GAUGE_REFRESH):
+        led.note_admit_round({f"d{i % 5}": 1})
+    assert len(refreshes) == 3
+    led.note_admit_round({})                 # no document, no mutation
+    assert led._mutations == 3 * docledger.GAUGE_REFRESH
+
+
+def test_a_round_that_raises_midway_leaves_entries_only():
+    """A bad count in the middle of a round larger than `top_k`: the call
+    raises, and the table it leaves holds `_DocEntry`s alone (no plain
+    count of a doc that was waiting for its entry), within `top_k`, with
+    every doc that came before the bad one accounted for; the ledger's
+    reads and the next round work."""
+    led = DocLedger(label="n", top_k=8)
+    _held(8)(led)
+    counts = {f"new{i}": 1 for i in range(40)}
+    counts["new20"] = "not a count"
+    with pytest.raises(ValueError):
+        led.note_admit_round(counts)
+    with led._lock:
+        assert all(e.__class__ is docledger._DocEntry
+                   for e in led._docs.values())
+        assert list(led._docs) == [f"new{i}" for i in range(12, 20)]
+        assert led._agg["docs"] == led._evictions == 20
+        assert led._agg["admitted"] == 12       # the 8 held admitted none
+        led._refresh_gauges_locked()
+    assert led.section(k=8)["tracked"] == 8
+    led.note_admit_round({f"new{i}": 1 for i in range(20, 40)})
+    assert len(led.section(k=8)["docs"]) == 8

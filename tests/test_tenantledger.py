@@ -5,6 +5,8 @@ cached check), proportional round attribution, the tenantplane
 attribution check, and the `tenant_storm` chaos fault.
 """
 
+import time
+
 import pytest
 
 from automerge_tpu.perf import tenantplane
@@ -108,6 +110,109 @@ def test_section_accounts_and_shares():
     assert sec["tenants"]["_default"]["admitted"] == 2
     # hottest-ingress ranks first
     assert list(sec["tenants"])[0] == "a"
+
+
+# the flush's round call: one write a tenant, what one call a doc leaves
+
+ROUND_NOW = 1_000.0
+
+
+def _ingress_as_the_seed_did(doc_id, n_changes):
+    """`note_ingress` as it stood before the round call: one
+    `_tenant_locked` (slot or overflow, the gauge cadence) and the stamps,
+    a document."""
+    led = tenantledger.ledger()
+    if not tenantledger.enabled() or n_changes <= 0:
+        return
+    tid = tenantledger.tenant_of(doc_id)
+    with led._lock:
+        t = led._tenant_locked(tid)
+        t.admitted += int(n_changes)
+        t.admit_events += 1
+        t.last_admit_at = ROUND_NOW
+        led._admitted_total += int(n_changes)
+
+
+def _seed_one_by_one(counts):
+    for d, n in counts.items():
+        _ingress_as_the_seed_did(d, n)
+
+
+def _one_by_one(counts):
+    for d, n in counts.items():
+        tenantledger.note_ingress(d, n)
+
+
+# every case counts a multiple of GAUGE_REFRESH mutations, so the last
+# refresh of a one-by-one feed sees every tenant the round makes
+INGRESS_ROUNDS = {
+    "default-tenant-only": ({}, [{f"doc-{i}": 1 + i % 2 for i in range(64)}]),
+    "prefixed-and-default-tenants": ({}, [
+        {(f"tenant/t{i % 5}/d{i}" if i % 3 else f"plain{i}"): 1 + i % 4
+         for i in range(64)},
+        {(f"tenant/t{i % 7}/d{i}" if i % 2 else f"plain{i}"): 2
+         for i in range(32, 64)}]),
+    "a-zero-count-is-passed-over": ({}, [
+        {f"tenant/t{i % 3}/d{i}": (0 if i % 4 == 0 else -1 if i == 5 else 2)
+         for i in range(87)}]),          # 22 zeros, one negative: 64 count
+    "more-tenants-than-the-table-holds": ({}, [
+        {f"tenant/t{i % 80}/d{i}": 1 for i in range(160)}]),
+    "the-plane-disabled": ({"AMTPU_TENANTLEDGER": "0"}, [
+        {f"tenant/t{i % 5}/d{i}": 1 for i in range(64)}]),
+}
+
+
+def _tenant_state():
+    led = tenantledger.ledger()
+    sec = led.section()
+    if sec is not None:
+        sec.pop("self_s")
+    snap = metrics.snapshot()
+    return {"section": sec, "mutations": led._mutations,
+            "overflowed": led._overflowed,
+            "order": list(led._tenants),
+            "overflow_counter": snap.get("sync_tenant_overflow"),
+            "tracked_gauge": snap.get("obs_tenant_tracked")}
+
+
+@pytest.mark.parametrize("case", sorted(INGRESS_ROUNDS))
+def test_an_ingress_round_leaves_what_one_call_a_document_leaves(
+        case, monkeypatch):
+    env, rounds = INGRESS_ROUNDS[case]
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(time, "time", lambda: ROUND_NOW)
+    led = tenantledger.ledger()
+    real = led._refresh_gauges_locked
+    refreshes = []
+    monkeypatch.setattr(led, "_refresh_gauges_locked",
+                        lambda: (refreshes.append(1), real()))
+    left = {}
+    for feed in ("seed", "one-by-one", "round"):
+        tenantledger._reload_for_tests()
+        metrics.reset()
+        for counts in rounds:
+            before = len(refreshes)
+            {"seed": _seed_one_by_one, "one-by-one": _one_by_one,
+             "round": tenantledger.note_ingress_round}[feed](counts)
+            if feed == "round":
+                assert len(refreshes) - before <= 1
+        left[feed] = _tenant_state()
+    assert left["round"] == left["seed"]
+    assert left["one-by-one"] == left["seed"]
+    got = left["round"]
+    if env:
+        assert got["section"] is None and got["mutations"] == 0
+        return
+    assert got["mutations"] % tenantledger.GAUGE_REFRESH == 0
+    counted = [n for counts in rounds for n in counts.values() if n > 0]
+    assert got["section"]["admitted_total"] == sum(counted)
+    assert sum(t["admit_events"]
+               for t in got["section"]["tenants"].values()) <= len(counted)
+    assert got["mutations"] == len(counted)
+    if case == "more-tenants-than-the-table-holds":
+        # 16 tenants of 80 find the table full, twice each but for t48..t63
+        assert got["overflowed"] == got["overflow_counter"] == 16 + 16
 
 
 def test_idle_snapshots_byte_equal():
