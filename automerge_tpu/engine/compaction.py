@@ -86,11 +86,7 @@ def causal_floor(rset, i: int) -> dict[str, int]:
         T = t.state_clocks.get((a, s))
         if T is None:
             return {}   # no frontier memo: stay conservative
-        if not isinstance(T, dict):
-            arr, ridx = T   # lazy dense-row memo from the fast path
-            T = {rset.actors[r]: int(v)
-                 for r, v in enumerate(arr[ridx]) if v}
-            t.state_clocks[(a, s)] = T
+        T = rset._memo_dict(t, (a, s))   # a lazy dense row becomes a dict
         F = dict(T)
         F[a] = max(F.get(a, 0), s)
         floor = F if floor is None else {
@@ -98,11 +94,13 @@ def causal_floor(rset, i: int) -> dict[str, int]:
     return {b: v for b, v in floor.items() if v > 0}
 
 
-def _floor_ranks(rset, floor: dict[str, int]) -> np.ndarray:
-    """Per-actor-rank floor seqs (0 for actors the floor doesn't cover)."""
+def _floor_ranks(rset, i: int, floor: dict[str, int]) -> np.ndarray:
+    """Floor seqs by actor rank in doc i's own basis (0 for actors the
+    floor doesn't cover)."""
     out = np.zeros(rset.cap_actors, np.int64)
+    rank = rset.tables[i].actor_rank
     for a, s in (floor or {}).items():
-        r = rset.actor_rank.get(a)
+        r = rank.get(a)
         if r is not None:
             out[r] = int(s)
     return out
@@ -164,7 +162,7 @@ def compact_doc(rset, i: int, floor: dict[str, int],
     fh = col[b["fh"]:b["fh"] + I].copy()
     vh = col[b["vh"]:b["vh"] + I].copy()
     co = col[b["co"]:b["co"] + A * I].reshape(A, I).copy()
-    floor_r = _floor_ranks(rset, floor)
+    floor_r = _floor_ranks(rset, i, floor)
 
     keep = _op_keep_mask(om, ac, fid, act, seq, chg, co, floor_r)
     n_ops0 = int(rset.op_count[i])
@@ -234,7 +232,7 @@ def compact_doc(rset, i: int, floor: dict[str, int],
                     keep_slot[k] = (efid in cand_fids
                                     or efid in fids_above
                                     or (bool(pins) and make_elem_id(
-                                        rset.actors[arank_c], elem_c)
+                                        t.actors[arank_c], elem_c)
                                         in pins))
                 if keep_slot[k] or k in has_kept_child:
                     keep_tree[k] = True
@@ -267,7 +265,7 @@ def compact_doc(rset, i: int, floor: dict[str, int],
                 slot, elem, arank, _parent = entries[k]
                 if slot >= 0:
                     rset.ghost_eids[i].add(
-                        make_elem_id(rset.actors[arank], elem))
+                        make_elem_id(rset.tables[i].actors[arank], elem))
             rset.ins_log[i][lrow] = new_entries
             rset.ins_idx[i][lrow] = {
                 s: k for k, (s, _, _, _) in enumerate(new_entries)
@@ -350,6 +348,6 @@ def _sync_native_elem_slots(rset, i: int) -> None:
                 continue
             objs.append(oi)
             slots.append(slot)
-            eids.append(make_elem_id(rset.actors[arank], elem))
+            eids.append(make_elem_id(rset.tables[i].actors[arank], elem))
     rset._native.reset_elem_slots(i, objs, slots, eids,
                                   rset.tables[i].max_elems)

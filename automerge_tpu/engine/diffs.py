@@ -173,7 +173,7 @@ def decode_round_diffs(rset, chg_fid: np.ndarray, chg_elem: np.ndarray,
                 if a == w:
                     continue
                 v, is_link = _decode_value(t, int(st_value[i, j]))
-                rec = {"actor": rset.actors[a], "value": v}
+                rec = {"actor": t.actors[a], "value": v}
                 if is_link:
                     rec["link"] = True
                 recs.append(rec)

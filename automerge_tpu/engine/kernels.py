@@ -185,10 +185,9 @@ def state_hash(candidate, fid, actor_hash, fid_hash, value_hash, fid_is_list,
     interning-table order, so incrementally-grown resident tables and
     from-scratch canonical tables agree — and `actor_hash` is the op
     actor's CONTENT hash, never its rank: a rank is a position in the
-    engine instance's global sorted actor table, which shifts whenever an
-    unrelated doc introduces a new actor, so a rank-mixed hash would
-    differ between replicas holding different doc subsets (a shard vs the
-    whole fleet). The sum is order-independent, hence
+    document's own sorted actor list, which shifts whenever a device
+    joins the document, so a rank-mixed hash would differ between
+    replicas that have met different writers. The sum is order-independent, hence
     delivery-order-independent.
     """
     safe_fid = jnp.maximum(fid, 0)

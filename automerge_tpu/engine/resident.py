@@ -11,10 +11,13 @@ Key mechanics:
 - Interning tables grow in arrival order (canonical ordering cannot be kept
   incrementally); state hashes stay canonical anyway because they mix content
   hashes, not table ids (encode.content_hash).
-- Actor ranks MUST remain sorted by actor string (the LWW tie-break). When a
-  new actor appears, the host computes the new ranking and the device remaps
-  the resident actor columns and clock matrix with one gather
-  (`_remap_actors`). New actors are rare; the gather is cheap.
+- The actor axis is a DOCUMENT'S OWN: a rank is a position in the sorted list
+  of the actors that have written that document (`DocTables.actors`), so rank
+  order is actor-string order inside a document, which is all the LWW
+  tie-break asks. `cap_actors` is the widest document's count. When a device
+  joins a document, the host computes that document's new ranking and the
+  device remaps that document's actor columns and clock matrix
+  (`_remap_actors`, one row of the permutation a document).
 - Capacities (ops, changes, elements, fids, actors) are padded to powers of
   two and doubled on overflow, bounding recompilation.
 - Causality: each document keeps a host-side queue of changes whose
@@ -60,6 +63,10 @@ class DocTables:
         self.list_rows: dict[int, int] = {}      # obj_idx -> list row
         self.elem_slots: dict[int, dict[str, int]] = {}  # obj_idx -> eid -> slot
         self.state_clocks: dict[tuple[str, int], dict[str, int]] = {}
+        # the actors that have written this document, sorted: a rank is a
+        # position here (ResidentDocSet._register_doc_actors)
+        self.actors: list[str] = []
+        self.actor_rank: dict[str, int] = {}
         self.clock: dict[str, int] = {}
         # dependency frontier: the maximal (actor, seq) heads — the same
         # pruned set the reference keeps as opSet.deps (op_set.js:243-249).
@@ -164,8 +171,6 @@ class ResidentDocSet:
         self.doc_index = {d: i for i, d in enumerate(self.doc_ids)}
         n = len(self.doc_ids)
         self.tables = [DocTables() for _ in range(n)]
-        self.actors: list[str] = []
-        self.actor_rank: dict[str, int] = {}
         # running fleet-wide maxima of per-doc list/elem stats (values only
         # grow, so the cached max is exact): replaces O(n_docs) generator
         # scans on every streaming round's precheck/grow
@@ -187,6 +192,11 @@ class ResidentDocSet:
 
         self.op_count = np.zeros(self.cap_docs, dtype=np.int64)
         self.change_count = np.zeros(self.cap_docs, dtype=np.int64)
+        # [cap_docs, cap_actors]: row i holds the CONTENT hashes of document
+        # i's actors in rank order (0 past its count). The state hash mixes
+        # these, never a rank (kernels.state_hash)
+        self._ahash = np.zeros((self.cap_docs, self.cap_actors), np.int32)
+        self._ahash_ver = 0     # bumped by every registration
         # doc indices whose causal queue is non-empty (so budget prechecks
         # scan O(queued) tables, not O(all))
         self._queued_docs: set[int] = set()
@@ -257,6 +267,7 @@ class ResidentDocSet:
                    cap_actors=self.cap_actors)
         for k, v in caps.items():
             setattr(self, k, v)
+        self._fit_ahash()
 
         def pad(arr, pads, fill):
             return jnp.pad(arr, pads, constant_values=fill)
@@ -282,6 +293,13 @@ class ResidentDocSet:
             if d_l:
                 s["list_obj"] = pad(s["list_obj"], ((0, 0), (0, d_l)), -1)
                 s["list_obj_hash"] = pad(s["list_obj_hash"], ((0, 0), (0, d_l)), -1)
+
+    def _fit_ahash(self) -> None:
+        """Pad the actor-hash table to the current (cap_docs, cap_actors)."""
+        d = self.cap_docs - self._ahash.shape[0]
+        a = self.cap_actors - self._ahash.shape[1]
+        if d > 0 or a > 0:
+            self._ahash = np.pad(self._ahash, ((0, max(d, 0)), (0, max(a, 0))))
 
     # ------------------------------------------------------------------
     def add_docs(self, new_ids: list[str]) -> None:
@@ -309,6 +327,7 @@ class ResidentDocSet:
         self.op_count = np.concatenate([self.op_count, np.zeros(k, np.int64)])
         self.change_count = np.concatenate([self.change_count,
                                             np.zeros(k, np.int64)])
+        self._fit_ahash()
         fills = {"op_mask": False, "action": -1, "fid": -1, "value": -1,
                  "ins_mask": False, "ins_parent": -1, "ins_fid": -1,
                  "list_obj": -1, "list_obj_hash": -1}
@@ -350,38 +369,72 @@ class ResidentDocSet:
 
     # ------------------------------------------------------------------
     def _register_actors(self, changes_by_doc) -> None:
-        self._register_actor_names(
-            {c.actor for changes in changes_by_doc.values() for c in changes})
+        self._register_doc_actors(
+            {self.doc_index[d]: {c.actor for c in changes}
+             for d, changes in changes_by_doc.items()})
 
-    def _register_actor_names(self, names: set) -> None:
-        new = set(names) - set(self.actors)
-        if not new:
+    def _register_doc_actors(self, names_by_doc: dict) -> None:
+        """Register actors with the documents they write: {doc index:
+        actor names}. A document's actor list stays sorted, so a rank is
+        actor-string order inside the document (the LWW tie-break), and a
+        new name rewrites that document's ranks alone
+        (_adopt_doc_actors). `cap_actors` follows the widest document; it
+        grows here, before any rank moves, and nowhere else."""
+        plans: dict[int, list[str]] = {}
+        widest = 0
+        for i, names in names_by_doc.items():
+            rank = self.tables[i].actor_rank
+            new = [a for a in names if a not in rank]
+            if new:
+                plans[i] = sorted(self.tables[i].actors + new)
+                widest = max(widest, len(plans[i]))
+        if not plans:
             return
-        old_actors = list(self.actors)
-        self.actors = sorted(set(self.actors) | new)
-        self.actor_rank = {a: i for i, a in enumerate(self.actors)}
-        if len(self.actors) > self.cap_actors:
-            self._grow(cap_actors=_pad_to(len(self.actors), 2))
-        if not old_actors:
+        if widest > self.cap_actors:
+            self._grow(cap_actors=_pad_to(widest, 2))
+        self._adopt_doc_actors(plans)
+
+    def _set_doc_actors(self, i: int, actors: list[str]) -> np.ndarray:
+        """Give document i its new sorted actor list. Returns the
+        permutation old rank -> new rank (empty for a first registration)."""
+        t = self.tables[i]
+        old = t.actors
+        t.actors = actors
+        t.actor_rank = {a: r for r, a in enumerate(actors)}
+        self._ahash[i, :len(actors)] = [content_hash(a) for a in actors]
+        self._ahash_ver += 1
+        return np.fromiter((t.actor_rank[a] for a in old), np.int32, len(old))
+
+    def _adopt_doc_actors(self, plans: dict) -> None:
+        """Device sink of a registration: the documents whose ranks moved
+        remap their actor columns and clock matrix in one gather."""
+        n, A = self.cap_docs, self.cap_actors
+        perm = np.tile(np.arange(A, dtype=np.int32), (n, 1))
+        inv = perm.copy()
+        moved = []
+        for i, actors in plans.items():
+            p = self._set_doc_actors(i, actors)
+            if len(p) and (p != np.arange(len(p))).any():
+                # ranks past the old count hold no op yet: any bijection
+                rest = np.setdiff1d(np.arange(A, dtype=np.int32), p)
+                perm[i] = np.concatenate([p, rest])
+                inv[i] = -1
+                inv[i, p] = np.arange(len(p), dtype=np.int32)
+                moved.append(i)
+        if not moved:
             return
         # hash VALUES survive the remap (content hashes, never ranks), but
-        # the mirror stays conservative across a whole-state rewrite —
-        # remaps are rare after warmup, so the one full re-read is cheap
-        # insurance against a remap bug silently serving stale hashes
-        self._mark_all_hash_dirty()
-        # remap resident actor columns + clock matrix columns
-        perm = np.array([self.actor_rank[a] for a in old_actors], dtype=np.int32)
-        inv = np.full(self.cap_actors, -1, dtype=np.int32)
-        for old_rank, new_rank in enumerate(perm):
-            inv[new_rank] = old_rank
-        self.state = _remap_actors(self.state, jnp.asarray(perm), jnp.asarray(inv))
+        # the mirror stays conservative for the documents rewritten
+        self._mark_hash_dirty(moved)
+        perm_j = jnp.asarray(perm)
+        self.state = _remap_actors(self.state, perm_j, jnp.asarray(inv))
         if self._diff_prev is not None:
             # the diff baseline's winner ranks must follow the remap, or
-            # every field of every doc would look changed next diff round
+            # every field of a remapped doc would look changed next round
             p, wv, wa, sh, ev, vr = self._diff_prev
-            perm_j = jnp.asarray(perm)
-            wa = jnp.where(wa >= 0,
-                           perm_j[jnp.clip(wa, 0, len(perm) - 1)], wa)
+            k = wa.shape[0]
+            wa = jnp.where(wa >= 0, jnp.take_along_axis(
+                perm_j[:k], jnp.clip(wa, 0, A - 1), axis=1), wa)
             self._diff_prev = (p, wv, wa, sh, ev, vr)
 
     # ------------------------------------------------------------------
@@ -425,6 +478,19 @@ class ResidentDocSet:
         t.queue = pending
         return ready
 
+    @staticmethod
+    def _memo_dict(t: DocTables, key) -> dict | None:
+        """The state-clock memo of change `key` as {actor: seq}; a lazy
+        dense row (matrix, row index) in the document's rank basis is
+        converted where it sits."""
+        trans = t.state_clocks.get(key)
+        if trans is not None and not isinstance(trans, dict):
+            arr, ridx = trans
+            trans = t.state_clocks[key] = {
+                t.actors[r]: int(v) for r, v in enumerate(
+                    arr[ridx][:len(t.actors)].tolist()) if v}
+        return trans
+
     def _clock_row(self, t: DocTables, actor: str, seq: int,
                    deps: dict) -> np.ndarray:
         """Transitive clock row for one admitted change; also advances the
@@ -438,12 +504,10 @@ class ResidentDocSet:
             trans = t.state_clocks.get((a, s))
             if trans is not None and not isinstance(trans, dict):
                 # lazy dense-row memo from the vectorized fast path:
-                # (matrix, row_idx) in the CURRENT rank basis (converted to
-                # dicts on actor remap, see _register_actor_names overrides)
-                arr, ridx = trans
-                trans = {self.actors[r]: int(v)
-                         for r, v in enumerate(arr[ridx]) if v}
-                t.state_clocks[(a, s)] = trans
+                # (matrix, row_idx) in the document's CURRENT rank basis
+                # (converted to dicts when its ranks move, see the rows
+                # engine's _adopt_doc_actors)
+                trans = self._memo_dict(t, (a, s))
             if trans:
                 for a2, s2 in trans.items():
                     if s2 > full.get(a2, 0):
@@ -460,7 +524,7 @@ class ResidentDocSet:
         t.state_clocks[(actor, seq)] = full
         row = np.zeros(self.cap_actors, dtype=np.int32)
         for a, s in full.items():
-            row[self.actor_rank[a]] = s
+            row[t.actor_rank[a]] = s
         return row
 
     def _encode_delta(self, doc_idx: int, changes: list[Change]) -> Delta:
@@ -483,7 +547,7 @@ class ResidentDocSet:
             if t.n_changes > self._changes_hi:
                 self._changes_hi = t.n_changes
 
-            arank = self.actor_rank[c.actor]
+            arank = t.actor_rank[c.actor]
             for op in c.ops:
                 code = _ACTION_CODE[op.action]
                 if code in (A_MAKE_MAP, A_MAKE_LIST, A_MAKE_TEXT):
@@ -589,11 +653,11 @@ class ResidentDocSet:
         return self._apply_flat(flat, meta, diffs)
 
     def _register_actors_cols(self, cols_by_doc: dict) -> None:
-        new = set()
-        for cols in cols_by_doc.values():
-            for i in set(np.asarray(cols.change_actor).tolist()):
-                new.add(cols.actors[i])
-        self._register_actor_names(new)
+        self._register_doc_actors(
+            {self.doc_index[d]: {
+                cols.actors[k]
+                for k in set(np.asarray(cols.change_actor).tolist())}
+             for d, cols in cols_by_doc.items()})
 
     def _build_delta_arrays(self, changes_by_doc: dict[str, list[Change]]):
         n = self.cap_docs
@@ -642,7 +706,7 @@ class ResidentDocSet:
                 adm_frame.append(frame_of[id(c)])
                 adm_idx.append(j)
                 adm_doc.append(i)
-                aranks.append(self.actor_rank[p.actor])
+                aranks.append(t.actor_rank[p.actor])
                 seqs.append(p.seq)
                 cidxs.append(t.n_changes)
                 t.n_changes += 1
@@ -801,23 +865,19 @@ class ResidentDocSet:
         return self._apply_flat(flat, meta, diffs)
 
     def _ensure_actor_hash_state(self):
-        """Keep state["actor_hash"] current: [cap_docs, cap_actors] actor
-        CONTENT hashes in the current rank basis (kernels.state_hash mixes
-        these, never ranks, so hashes are independent of the instance's
-        global actor set). Rebuilt only when the actor table or the
-        capacities it is shaped by change; between rebuilds the array
-        rides the state pytree through the donating apply jits (the
-        returned copy is the live one — a side cache would hand back a
-        donated/deleted buffer)."""
-        key = (len(self.actors), self.cap_actors, self.cap_docs)
+        """Keep state["actor_hash"] current: [cap_docs, cap_actors], a row
+        a document, its actors' CONTENT hashes in its own rank basis
+        (kernels.state_hash mixes these, never ranks, so a hash does not
+        depend on who else the instance holds). Uploaded from the host
+        table (_ahash) only when a registration or a capacity changed it;
+        between uploads the array rides the state pytree through the
+        donating apply jits (the returned copy is the live one — a side
+        cache would hand back a donated/deleted buffer)."""
+        key = (self._ahash_ver, self._ahash.shape)
         if self.state.get("actor_hash") is not None \
                 and getattr(self, "_actor_hash_key", None) == key:
             return
-        vals = np.zeros(self.cap_actors, np.int32)
-        for r, a in enumerate(self.actors):
-            vals[r] = content_hash(a)
-        self.state["actor_hash"] = jnp.asarray(np.broadcast_to(
-            vals, (self.cap_docs, self.cap_actors)))
+        self.state["actor_hash"] = jnp.asarray(self._ahash)
         self._actor_hash_key = key
 
     def _apply_flat(self, flat, meta, diffs: bool):
@@ -833,13 +893,10 @@ class ResidentDocSet:
             return vals
         prev = self._prev_for_diffs()
         prev_vis_host, prev_rank_host = self._prev_host_for_diffs()
-        actor_hashes = jnp.asarray(
-            [content_hash(a) for a in self.actors]
-            + [0] * (self.cap_actors - len(self.actors)), dtype=jnp.int32)
         with metrics.trace("engine_resident_apply"):
             self.state, out, survh, chg_fid, chg_elem = metrics.dispatch_jit(
                 "scatter_apply_diff", _scatter_apply_diff,
-                self.state, flat, meta, actor_hashes, *prev,
+                self.state, flat, meta, *prev,
                 max_fids=self.cap_fids)
         self._out = out
         # the baseline for the NEXT diff round: device refs (no transfer);
@@ -1060,7 +1117,7 @@ class ResidentDocSet:
         enc.fid = host["fid"]
         enc.actor = host["actor"]
         enc.value = host["value"]
-        enc.actors = self.actors
+        enc.actors = t.actors
         enc.objects = t.objects
         enc.fields = t.fields
         enc.ins_fid = host["ins_fid"]
@@ -1078,23 +1135,26 @@ class ResidentDocSet:
 
 @jax.jit
 def _remap_actors(state, perm, inv):
-    """Renumber actor ranks after a new actor joins: op/ins actor columns map
-    through `perm` (old->new); clock columns gather through `inv` (new->old,
-    -1 where no old column existed)."""
+    """Renumber actor ranks after actors joined documents, a row of
+    `perm` / `inv` ([docs, actors]) a document (the identity where none
+    did): op/ins actor columns map through `perm` (old->new); clock
+    columns gather through `inv` (new->old, -1 where no old column
+    existed)."""
     out = dict(state)
-    amask = state["op_mask"]
-    out["actor"] = jnp.where(amask, perm[jnp.clip(state["actor"], 0, perm.shape[0] - 1)],
-                             state["actor"])
-    imask = state["ins_mask"]
-    out["ins_actor"] = jnp.where(
-        imask, perm[jnp.clip(state["ins_actor"], 0, perm.shape[0] - 1)],
-        state["ins_actor"])
+    hi = perm.shape[1] - 1
+    out["actor"] = jnp.where(
+        state["op_mask"],
+        jnp.take_along_axis(perm, jnp.clip(state["actor"], 0, hi), axis=1),
+        state["actor"])
+    ins = state["ins_actor"]
+    mapped = jnp.take_along_axis(
+        perm, jnp.clip(ins, 0, hi).reshape(ins.shape[0], -1), axis=1)
+    out["ins_actor"] = jnp.where(state["ins_mask"],
+                                 mapped.reshape(ins.shape), ins)
     clock = state["clock"]
-    n_new = inv.shape[0]
-    safe = jnp.clip(inv, 0, clock.shape[-1] - 1)
-    gathered = clock[..., safe]
-    out["clock"] = jnp.where(inv[None, None, :n_new] >= 0,
-                             gathered[..., :n_new], 0)
+    gathered = jnp.take_along_axis(
+        clock, jnp.clip(inv, 0, hi)[:, None, :], axis=2)
+    out["clock"] = jnp.where(inv[:, None, :] >= 0, gathered, 0)
     return out
 
 
@@ -1164,15 +1224,17 @@ def _scatter_and_apply(state, flat, meta, *, max_fids):
     return new_state, out
 
 
-def _fid_survivor_hash(state, out, max_fids: int, actor_hashes):
+def _fid_survivor_hash(state, out, max_fids: int):
     """Order-independent per-field hash of the surviving (actor, value)
     pairs — changes whenever a field's conflict set changes even if the LWW
     winner didn't (op_set.js:95-103 is the reference surface this feeds).
-    Actors are mixed by CONTENT hash (actor_hashes[rank]), not rank, so the
-    hash survives the global rank remap a newly-registered actor causes."""
+    Actors are mixed by CONTENT hash (state["actor_hash"], the document's
+    own row), not rank, so the hash survives the rank remap a device that
+    joins the document causes."""
     from .kernels import _mix4
-    safe_actor = jnp.clip(state["actor"], 0, actor_hashes.shape[0] - 1)
-    ah = actor_hashes[safe_actor]
+    actor_hashes = state["actor_hash"]
+    safe_actor = jnp.clip(state["actor"], 0, actor_hashes.shape[1] - 1)
+    ah = jnp.take_along_axis(actor_hashes, safe_actor, axis=1)
     contrib = _mix4(ah, state["value_hash"], ah ^ 0x5BF0,
                     state["value_hash"])
     n, _ = state["op_mask"].shape
@@ -1183,7 +1245,7 @@ def _fid_survivor_hash(state, out, max_fids: int, actor_hashes):
 
 
 @partial(jax.jit, static_argnames=("meta", "max_fids"), donate_argnums=(0,))
-def _scatter_apply_diff(state, flat, meta, actor_hashes, prev_present,
+def _scatter_apply_diff(state, flat, meta, prev_present,
                         prev_win_value, prev_win_actor, prev_survh,
                         prev_vis, prev_rank, *, max_fids):
     """_scatter_and_apply plus device-side change detection: per-field and
@@ -1194,7 +1256,7 @@ def _scatter_apply_diff(state, flat, meta, actor_hashes, prev_present,
     back to the host."""
     new_state = _scatter_delta.__wrapped__(state, flat, meta)
     out = apply_doc.__wrapped__(new_state, max_fids)
-    survh = _fid_survivor_hash(new_state, out, max_fids, actor_hashes)
+    survh = _fid_survivor_hash(new_state, out, max_fids)
     chg_fid = ((out["present"] != prev_present)
                | (out["win_value"] != prev_win_value)
                | (out["win_actor"] != prev_win_actor)

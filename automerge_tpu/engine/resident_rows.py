@@ -9,7 +9,10 @@ deltas applied as point scatters, and MANY rounds processed in ONE dispatch
 (`lax.scan` over stacked per-round scatter triplets, reconciling after each
 round). Per round the device work is one scatter + one fused kernel; the
 host keeps an authoritative numpy mirror, so structural events (capacity
-growth, new actors) rebuild host-side and re-upload once.
+growth) rebuild host-side and re-upload once. A device that joins a document
+rewrites that document's lane alone (_adopt_doc_actors): the actor axis is a
+document's own (resident.py), and the cells that moved ride the next
+scatter.
 
 Causal admission, interning, and LWW actor ranking reuse the host machinery
 of `resident.ResidentDocSet` (the reference semantics live in
@@ -21,6 +24,7 @@ from-scratch batch path.
 from __future__ import annotations
 
 import contextlib
+import time
 from functools import partial
 
 import numpy as np
@@ -29,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatchledger
-from .encode import _pad_to, content_hash
+from .encode import _pad_to
 from .resident import ResidentDocSet
 from . import dispatch as round_dispatch
 from .pack import LANE, pad_to_lanes
@@ -160,13 +164,22 @@ class ResidentRowsDocSet(ResidentDocSet):
         # detection use cheap log-length compares except across a rebuild
         # (which restores the archived prefix into the RAM log)
         self._rebuild_gen = 0
-        if actors:
-            # Pre-registering the expected actor set avoids a mirror remap +
-            # re-upload when they first appear in deltas.
-            self.actors = sorted(actors)
-            self.actor_rank = {a: i for i, a in enumerate(self.actors)}
-            if len(self.actors) > self.cap_actors:
-                self.cap_actors = _pad_to(len(self.actors), 2)
+        if len(actors) > self.cap_actors:
+            # the writers a document is expected to have: sizing the actor
+            # axis now avoids a re-layout when the widest document gets them
+            self.cap_actors = _pad_to(len(actors), 2)
+            self._fit_ahash()
+        # instance-wide interning of actor names, in arrival order: an id
+        # means identity alone. _doc_gids [cap_docs, cap_actors] holds each
+        # document's actors' ids in RANK order (-1 past its count), so a
+        # round's (document, actor) -> rank lookups are one compare
+        self._gid: dict[str, int] = {}
+        self._doc_gids = np.full((self.cap_docs, self.cap_actors), -1,
+                                 np.int64)
+        # cells a lane rewrite changed while the device copy was current:
+        # [k, 3] (row, lane, value) parts the next scatter carries
+        self._lane_trips: list[np.ndarray] = []
+        self._gid_memo: dict = {}     # id(frame columns) -> (them, ids)
         self._rows_ready = True
         self._alloc_rows()
         self.rows_dev = None
@@ -228,16 +241,15 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._refill_actor_hash_band()
 
     def _refill_actor_hash_band(self) -> None:
-        """Rewrite the ah band (rank -> actor CONTENT hash, broadcast per
-        doc column) from the current actor table. Called after alloc, any
-        re-layout, and every registration/remap — the state hash mixes
-        these values, never ranks, so per-doc hashes stay independent of
-        the instance's global actor set (kernels.state_hash)."""
+        """Rewrite the ah band (rank -> actor CONTENT hash, a column a
+        lane in the document's own rank basis) from the host table
+        (_ahash). Called after alloc and any re-layout; a registration
+        writes its own lane (_adopt_doc_actors). The state hash mixes
+        these values, never ranks (kernels.state_hash)."""
         b = self._bases()
-        vals = np.zeros(self.cap_actors, np.int32)
-        for r, a in enumerate(self.actors):
-            vals[r] = content_hash(a)
-        self.rows_host[b["ah"]:b["ah"] + self.cap_actors] = vals[:, None]
+        n = min(self._ahash.shape[0], self.n_pad)
+        self.rows_host[b["ah"]:b["ah"] + self.cap_actors, :n] = \
+            self._ahash[:n].T
 
     # the docs-major device state of the base class is never built
     def _alloc(self):
@@ -276,6 +288,9 @@ class ResidentRowsDocSet(ResidentDocSet):
                 [self.op_count, np.zeros(k, np.int64)])
             self.change_count = np.concatenate(
                 [self.change_count, np.zeros(k, np.int64)])
+            self._fit_ahash()
+            self._doc_gids = np.pad(self._doc_gids, ((0, k), (0, 0)),
+                                    constant_values=-1)
         new_pad = pad_to_lanes(n)
         if new_pad > self.n_pad:
             b = self._bases()
@@ -293,10 +308,10 @@ class ResidentRowsDocSet(ResidentDocSet):
                 self.cap_elems)[:, None]
             self.rows_host = grown
             self.n_pad = new_pad
-            self._refill_actor_hash_band()
             self.rows_dev = None
             self._dirty = True
             self._h_prev = None
+            self._lane_trips.clear()
         # admission cache: fresh lanes are valid empty docs (zero clock,
         # empty frontier) — grow the cache arrays in place rather than
         # dropping them, or one-doc-at-a-time ingress of N new docs would
@@ -316,10 +331,16 @@ class ResidentRowsDocSet(ResidentDocSet):
             return
         old_b = self._bases()
         old = self.rows_host
+        A_old = self.cap_actors
         old_caps = dict(I=self.cap_ops, C=self.cap_changes, A=self.cap_actors,
                         L=self.cap_lists, E=self.cap_elems)
         for k, v in caps.items():
             setattr(self, k, v)
+        self._fit_ahash()
+        if self.cap_actors > A_old:
+            self._doc_gids = np.pad(
+                self._doc_gids, ((0, 0), (0, self.cap_actors - A_old)),
+                constant_values=-1)
         b = self._bases()
         self._alloc_rows()
         new = self.rows_host
@@ -335,16 +356,17 @@ class ResidentRowsDocSet(ResidentDocSet):
             src = old[old_b[g]:old_b[g] + L0 * E0].reshape(L0, E0, -1)
             new[b[g]:b[g] + self.cap_lists * self.cap_elems] \
                 .reshape(self.cap_lists, self.cap_elems, -1)[:L0, :E0] = src
-        # il is static (re-filled by _alloc_rows for the new strides); the
-        # ah band is likewise re-filled from the actor table
-        self._refill_actor_hash_band()
+        # il is static, and the ah band comes from the host table: both
+        # are re-filled by _alloc_rows for the new strides
         self._dirty = True
         self._h_prev = None
+        self._lane_trips.clear()
         # re-layout preserves hashes but rewrites every lane: conservative
         self._mark_all_hash_dirty()
 
-    # _register_actors/_register_actors_cols are inherited from the base
-    # class; only the remap sink differs (host rows mirror vs device state).
+    # _register_actors/_register_actors_cols/_register_doc_actors are
+    # inherited from the base class; only the sink differs
+    # (_adopt_doc_actors: a lane of the host mirror vs device state).
     class _StaleView:
         """Read-through guard left in place of a fast-path-stale table's
         clock/frontier dict: ANY read materializes the real dicts first
@@ -445,9 +467,9 @@ class ResidentRowsDocSet(ResidentDocSet):
         cc = self._clock_cache
         if cc is None:
             # the only cache-invalidation sites materialize stale tables
-            # first (_register_actor_names, _refresh_admission_cache)
+            # first (_adopt_doc_actors, _refresh_admission_cache)
             raise RuntimeError("stale table with no clock cache")
-        actors = self.actors
+        actors = t.actors
         t.clock = {actors[r]: int(v)
                    for r, v in enumerate(cc[i].tolist())
                    if v and r < len(actors)}
@@ -462,62 +484,80 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._sync_stale_table(t)
         return super()._admit(t, incoming)
 
-    def _register_actor_names(self, new: set) -> None:
-        """Host-mirror version of the base remap (act rows through perm,
-        clock_op bands re-gathered)."""
-        new = set(new) - set(self.actors)
-        if not new:
-            return
-        # stale tables read the cache in the OLD rank basis: materialize
-        # them before the cache is invalidated below
-        self.sync_tables()
-        # dense clock memos/caches are in the OLD rank basis: materialize
-        # memos to actor-name dicts now, rebuild caches lazily
-        old_actor_list = list(self.actors)
-        for t in self.tables:
-            for key, trans in t.state_clocks.items():
-                if trans is not None and not isinstance(trans, dict):
-                    arr, ridx = trans
-                    t.state_clocks[key] = {
-                        old_actor_list[r]: int(v)
-                        for r, v in enumerate(arr[ridx])
-                        if v and r < len(old_actor_list)}
-        self._clock_cache = None
-        self._cache_dirty = set(range(len(self.doc_ids)))
-        old_actors = list(self.actors)
-        self.actors = sorted(set(self.actors) | new)
-        self.actor_rank = {a: i for i, a in enumerate(self.actors)}
-        if len(self.actors) > self.cap_actors:
-            self._grow(cap_actors=_pad_to(len(self.actors), 2))
-        if not old_actors or not getattr(self, "_rows_ready", False):
-            if getattr(self, "_rows_ready", False):
-                self._refill_actor_hash_band()   # first registration
-            return
+    def _adopt_doc_actors(self, plans: dict) -> None:
+        """Host-mirror sink of a registration ({doc index: its new sorted
+        actor list}): each document rewrites ITS lane and nothing else —
+        its act row through the document's permutation, its co bands, its
+        ah column, its ins_log ranks, its row of the admission cache and
+        its lazy memos. The lane's hash goes dirty; the other lanes, the
+        device copy and _h_prev stay as they are: where the copy is
+        current the cells that changed are pended as triplets
+        (_lane_trips) and the round's scatter carries them."""
         b = self._bases()
         I, A = self.cap_ops, self.cap_actors
-        perm = np.array([self.actor_rank[a] for a in old_actors],
-                        dtype=np.int32)
-        act = self.rows_host[b["act"]:b["act"] + I]
-        om = self.rows_host[b["om"]:b["om"] + I]
-        safe = np.clip(act, 0, len(perm) - 1)
-        self.rows_host[b["act"]:b["act"] + I] = np.where(
-            om > 0, perm[safe], act)
-        co = self.rows_host[b["co"]:b["co"] + A * I].reshape(A, I, -1)
-        remapped = np.zeros_like(co)
-        for old_rank, new_rank in enumerate(perm):
-            remapped[new_rank] = co[old_rank]
-        self.rows_host[b["co"]:b["co"] + A * I] = remapped.reshape(A * I, -1)
-        # actor ranks inside ins_log entries must follow the remap too
-        for log in self.ins_log:
-            for lrow, entries in log.items():
-                log[lrow] = [(s, e, int(perm[a]) if a < len(perm) else a, p)
-                             for (s, e, a, p) in entries]
-        self._refill_actor_hash_band()
-        self._dirty = True
-        self._h_prev = None
-        # rank remap rewrites every lane's act/co rows; hash values are
-        # preserved (content hashes), mirror stays conservative anyway
-        self._mark_all_hash_dirty()
+        joins = lanes = 0
+        for i, actors in plans.items():
+            t = self.tables[i]
+            n_old = len(t.actors)
+            if n_old:
+                # the stale view and the lazy memos read the cache in the
+                # document's OLD rank basis: materialize them first
+                self._sync_stale_table(t)
+                for key in t.state_clocks:
+                    self._memo_dict(t, key)
+                joins += len(actors) - n_old
+                lanes += 1
+            perm = self._set_doc_actors(i, actors)
+            self._doc_gids[i, :len(actors)] = [
+                self._gid.setdefault(a, len(self._gid)) for a in actors]
+            # the lane's rank-bearing cells: its ah column and, where a
+            # rank moved, the act row and co bands of the ops it holds (a
+            # lane is a strided column of the mirror: touch no more)
+            at = [b["ah"] + np.arange(A)]
+            moved = n_old and (perm != np.arange(n_old)).any()
+            if moved:
+                ops = np.arange(int(self.op_count[i]))
+                at += [b["act"] + ops] + [b["co"] + r * I + ops
+                                          for r in range(len(actors))]
+            at = np.concatenate(at)
+            was = self.rows_host[at, i]
+            now = was.copy()
+            now[:A] = self._ahash[i]
+            if moved:
+                k = len(ops)
+                now[A:A + k] = perm[np.clip(was[A:A + k], 0, n_old - 1)]
+                co = was[A + k:].reshape(len(actors), k)
+                now[A + k:] = 0
+                now[A + k:].reshape(len(actors), k)[perm] = co[:n_old]
+                for lrow, entries in self.ins_log[i].items():
+                    self.ins_log[i][lrow] = [
+                        (s, e, int(perm[a]) if a < n_old else a, p)
+                        for (s, e, a, p) in entries]
+                if self._clock_cache is not None \
+                        and self._clock_cache.shape[1] == A:
+                    # (another width: _refresh_admission_cache rebuilds)
+                    row = self._clock_cache[i]
+                    seen = row[:n_old].copy()
+                    row[:] = 0
+                    row[perm] = seen
+                    if self._hrank[i] >= 0:
+                        self._hrank[i] = perm[self._hrank[i]]
+            diff = now != was
+            self.rows_host[at[diff], i] = now[diff]
+            if self._dev_current and diff.any():
+                self._lane_trips.append(np.stack(
+                    [at[diff], np.full(int(diff.sum()), i), now[diff]],
+                    axis=1).astype(np.int32))
+        self._mark_hash_dirty(plans)
+        if joins:
+            metrics.bump("rows_actor_joins", joins)
+            metrics.bump("rows_actor_remap_lanes", lanes)
+
+    def _take_lane_trips(self) -> list:
+        """The pended lane rewrites, handed to the scatter that carries
+        them (before the round's own triplets: a later write wins)."""
+        trips, self._lane_trips = self._lane_trips, []
+        return trips
 
     # ------------------------------------------------------------------
     # delta encoding to scatter triplets
@@ -829,7 +869,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         i = self.doc_index[doc_id]
         t = self.tables[i]
         self._sync_stale_table(t)
-        self._register_actor_names(set(clock))
+        self._register_doc_actors({i: set(clock)})
         heads = head_closures or {}
         for a, s in clock.items():
             if s > t.clock.get(a, 0):
@@ -906,8 +946,8 @@ class ResidentRowsDocSet(ResidentDocSet):
                            if isinstance(pay, tuple) else pay)
             if chs:
                 round_[d] = chs
-        fresh = ResidentRowsDocSet(docs, actors=list(self.actors),
-                                   native=self._native is not None)
+        fresh = ResidentRowsDocSet(docs, native=self._native is not None)
+        fresh.reserve(actors=self.cap_actors)   # one layout for the replay
         fresh.log_archive = self.log_archive
         fresh.snapshot_store = self.snapshot_store
         fresh.compaction_floors = dict(self.compaction_floors)
@@ -1083,6 +1123,12 @@ class ResidentRowsDocSet(ResidentDocSet):
         return touched
 
     def _dispatch_rounds(self, trip_list, pre_rows, interpret):
+        if pre_rows is None and self._lane_trips and trip_list:
+            # the copy is current: the first round's scatter carries the
+            # lanes a registration rewrote (last write wins on a cell)
+            merged, n = self._merged_trips(
+                self._take_lane_trips() + trip_list[:1])
+            trip_list = [merged[:n]] + trip_list[1:]
         p = _pad_to(max((len(t) for t in trip_list), default=1), 8)
         oob = self._bases()["rows"]  # out-of-range row => dropped by scatter
         stacked = np.full((len(trip_list), p, 3), 0, dtype=np.int32)
@@ -1353,10 +1399,14 @@ class ResidentRowsDocSet(ResidentDocSet):
         # over the whole service heap — measured at ~2/3 of the ingress cost
         # on a 2K-doc node (same pathology core/bulkload.py documents).
         from ..utils.gcpause import gc_paused
+        self._gid_memo.clear()
         with gc_paused():
             with perfscope.phase("encode"):
+                t0 = time.perf_counter()
                 for rc in rounds:
                     self._register_round_actors(rc)
+                metrics.observe("rows_actor_register_seconds",
+                                time.perf_counter() - t0)
                 self._precheck_round_frames(rounds)
             # steady-state fast path: ONE vectorized admission + native
             # encode for the whole micro-batch; falls back to per-round
@@ -1374,9 +1424,15 @@ class ResidentRowsDocSet(ResidentDocSet):
                                          len(rounds))
                         encoded = [self._encode_round_frame(rc)
                                    for rc in rounds]
+                    admitted = [e for e in encoded if e is not None]
+                    metrics.bump("rows_changes_admitted", sum(
+                        len(e["adm_doc"]) for e in admitted))
+                    metrics.bump("rows_changes_admitted_general", sum(
+                        e.get("n_general", 0) for e in admitted))
                 with perfscope.phase("commit"):
                     self._grow_for_rounds(encoded)
-                    trip_list = [self._cols_triplets(e) for e in encoded]
+                    trip_list = self._take_lane_trips() + [
+                        self._cols_triplets(e) for e in encoded]
                     touched = sorted(self._mark_trips_dirty(trip_list))
                     round_docs = len({d for rc in rounds
                                       for d in rc.doc_ids})
@@ -1387,10 +1443,43 @@ class ResidentRowsDocSet(ResidentDocSet):
                         self, touched, round_docs)
                     return self._dispatch_final(trip_list, route, interpret)
 
+    def _frame_gids(self, cols) -> np.ndarray:
+        """The instance's id of each name in a frame's actor table; a
+        name never met gets the next id. Kept for the frame while its
+        round is applied (an id never changes)."""
+        memo = self._gid_memo.get(id(cols))
+        if memo is None:
+            gid = self._gid
+            memo = self._gid_memo[id(cols)] = (cols, np.fromiter(
+                (gid.setdefault(a, len(gid)) for a in cols.actors),
+                np.int64, len(cols.actors)))
+        return memo[1]
+
+    def _ranks_in(self, docs: np.ndarray, gids: np.ndarray) -> np.ndarray:
+        """Rank of actor gids[k] in document docs[k], a position in the
+        document's own sorted list; -1 where it has not written there."""
+        hit = self._doc_gids[docs] == gids[:, None]
+        return np.where(hit.any(axis=1), hit.argmax(axis=1), -1)
+
     def _register_round_actors(self, rc) -> None:
+        """Register the frame's writers with the documents they write:
+        one compare finds the (document, actor) pairs not met yet, and
+        only those are walked."""
         cols = rc.cols
-        idx = set(np.asarray(cols.change_actor).tolist())
-        self._register_actor_names({cols.actors[i] for i in idx})
+        if not cols.n_changes:
+            return
+        doc_of_k = np.fromiter((self.doc_index[d] for d in rc.doc_ids),
+                               np.int64, len(rc.doc_ids))
+        chg_doc = np.repeat(doc_of_k, np.diff(
+            np.asarray(rc.change_off, np.int64)))
+        chg_actor = np.asarray(cols.change_actor, np.int64)
+        new = np.nonzero(self._ranks_in(
+            chg_doc, self._frame_gids(cols)[chg_actor]) < 0)[0]
+        if len(new):
+            names: dict[int, set] = {}
+            for i, k in zip(chg_doc[new].tolist(), chg_actor[new].tolist()):
+                names.setdefault(i, set()).add(cols.actors[k])
+            self._register_doc_actors(names)
 
     def _precheck_round_frames(self, rounds) -> None:
         """Vectorized VMEM-budget precheck for round frames (the analog of
@@ -1467,7 +1556,6 @@ class ResidentRowsDocSet(ResidentDocSet):
             dirty = self._cache_dirty
         else:
             return
-        rank_of = self.actor_rank
         cc, fs, hr, hs = (self._clock_cache, self._fsize,
                           self._hrank, self._hseq)
         for i in dirty:
@@ -1476,6 +1564,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                 # fast-path-stale AND dirtied: the dicts must be current
                 # before this rebuild reads them
                 self._sync_stale_table(t)
+            rank_of = t.actor_rank
             row = cc[i]
             row[:] = 0
             for a, s in t.clock.items():
@@ -1491,7 +1580,13 @@ class ResidentRowsDocSet(ResidentDocSet):
     def _encode_rounds_batched(self, rounds):
         """Whole-micro-batch vectorized admission (the streaming steady
         state): every change in every round rides a per-doc SAME-ACTOR
-        in-order chain — one peer's consecutive edits per document. One
+        in-order chain — one peer's consecutive edits per document —
+        whose frontier is one head. ONE change concurrent with another
+        device's last write, or one document with two heads, returns
+        None for the whole round: the benchmark's `fleet10k-devices.storm`
+        takes the per-round fallback every round (`rows_rounds_fallback`;
+        `rows_changes_admitted_general` counts what its general path
+        took), the other cells never. One
         classification over the concatenated frame columns, one batched
         clock-row construction, ONE native encode call for all rounds;
         per-change Python shrinks to the state-clock memo + change-log
@@ -1504,7 +1599,6 @@ class ResidentRowsDocSet(ResidentDocSet):
         if not rcs:
             return None
         self._refresh_admission_cache()
-        rank_of = self.actor_rank
 
         doc_l, j_l, rnd_l, arank_l, seq_l = [], [], [], [], []
         dep_rank_l, dep_seq_l, dep_chg_l = [], [], []
@@ -1520,9 +1614,9 @@ class ResidentRowsDocSet(ResidentDocSet):
             docs_r = np.fromiter((self.doc_index[d] for d in rc.doc_ids),
                                  np.int64, n_k)[sel]
             js_r = ch_off[:-1][sel]
-            perm = np.fromiter((rank_of.get(a, -1) for a in cols.actors),
-                               np.int64, len(cols.actors))
-            arank_r = perm[np.asarray(cols.change_actor, np.int64)[js_r]]
+            gids = self._frame_gids(cols)
+            arank_r = self._ranks_in(
+                docs_r, gids[np.asarray(cols.change_actor, np.int64)[js_r]])
             seq_r = np.asarray(cols.change_seq, np.int64)[js_r]
             doc_l.append(docs_r)
             j_l.append(js_r)
@@ -1539,8 +1633,10 @@ class ResidentRowsDocSet(ResidentDocSet):
                 dep_pos = pos_of_j[dep_chg_frame]
                 if (dep_pos < 0).any():
                     return None  # dep rows of unadmitted changes: fallback
-                dep_rank_l.append(perm[np.asarray(cols.deps_actor,
-                                                  np.int64)])
+                # a dep's rank in the document of the change that names it
+                dep_rank_l.append(self._ranks_in(
+                    docs_r[dep_pos - off],
+                    gids[np.asarray(cols.deps_actor, np.int64)]))
                 dep_seq_l.append(np.asarray(cols.deps_seq, np.int64))
                 dep_chg_l.append(dep_pos)
             off += len(js_r)
@@ -1618,13 +1714,12 @@ class ResidentRowsDocSet(ResidentDocSet):
         cidx = np.empty(n, np.int64)
         tables = self.tables
         change_log = self.change_log
-        actor_names = self.actors
         cols_of = [rc.cols for rc in rcs]
         for pos, (i, j, r, ar, s_) in enumerate(zip(
                 d.tolist(), j_ord.tolist(), rnd_ord.tolist(),
                 a.tolist(), s.tolist())):
             t = tables[i]
-            t.state_clocks[(actor_names[ar], s_)] = (cmat, pos)
+            t.state_clocks[(t.actors[ar], s_)] = (cmat, pos)
             change_log[i].append(AdmittedRef(cols_of[r], j))
             cidx[pos] = t.n_changes
             t.n_changes += 1
@@ -1666,7 +1761,6 @@ class ResidentRowsDocSet(ResidentDocSet):
             return None
         self._refresh_admission_cache()
         actors = cols.actors
-        rank_of = self.actor_rank
 
         n_k = len(rc.doc_ids)
         doc_of_k = np.fromiter((self.doc_index[d] for d in rc.doc_ids),
@@ -1675,13 +1769,14 @@ class ResidentRowsDocSet(ResidentDocSet):
         ch_per_k = np.diff(ch_off)
         chg_doc = np.repeat(doc_of_k, ch_per_k)
         chg_k = np.repeat(np.arange(n_k), ch_per_k)
+        # A rank is the actor's position in the change's OWN document.
         # The frame's actor table may intern actors that only appear in
-        # deps and have no registered rank yet (their changes haven't
-        # arrived). -1 marks them; any dep on an unknown actor is
+        # deps and have not written the document yet (their changes
+        # haven't arrived). -1 marks them; any dep on such an actor is
         # unsatisfied, which routes the change to the slow path to queue.
-        perm = np.fromiter((rank_of.get(a, -1) for a in actors),
-                           np.int64, len(actors))
-        arank = perm[np.asarray(cols.change_actor, np.int64)]
+        gids = self._frame_gids(cols)
+        arank = self._ranks_in(
+            chg_doc, gids[np.asarray(cols.change_actor, np.int64)])
         seq = np.asarray(cols.change_seq, np.int64)
 
         cc, fs_, hr_, hs_ = (self._clock_cache, self._fsize,
@@ -1695,7 +1790,8 @@ class ResidentRowsDocSet(ResidentDocSet):
         if dep_cnt.any():
             dep_chg = np.repeat(np.arange(n_ch), dep_cnt)
             dep_doc = chg_doc[dep_chg]
-            dep_rank = perm[np.asarray(cols.deps_actor, np.int64)]
+            dep_rank = self._ranks_in(
+                dep_doc, gids[np.asarray(cols.deps_actor, np.int64)])
             dep_seq = np.asarray(cols.deps_seq, np.int64)
             safe_rank = np.maximum(dep_rank, 0)
             bad = np.zeros(n_ch, np.int64)
@@ -1799,7 +1895,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                     adm_frame.append(frame_of[id(pc)])
                     adm_idx.append(pj)
                     adm_doc.append(i)
-                    aranks.append(rank_of[p.actor])
+                    aranks.append(t.actor_rank[p.actor])
                     seqs.append(p.seq)
                     cidxs.append(t.n_changes)
                     t.n_changes += 1
@@ -1854,6 +1950,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             "clock_mat": m_clock,
             "adm_doc": m_doc,
             "adm_cidx": m_cidx,
+            "n_general": len(adm_doc),
         }
 
     def _merged_trips(self, trip_list, least: int = 8):
@@ -1930,12 +2027,14 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._dirty = True
         self._hash_handle = None
         self._h_prev = None
+        self._lane_trips.clear()
 
     def _prime(self) -> None:
         """Upload the host mirror as the device copy."""
         self.rows_dev = self._to_dev(self.rows_host)
         self._dirty = False
         self._h_prev = None
+        self._lane_trips.clear()
 
     def _apply_final_route(self, trip_list, route, interpret):
         """`blocks`, or `whole` for a round that never plans: scatter and
@@ -1989,6 +2088,10 @@ class ResidentRowsDocSet(ResidentDocSet):
         for is dirty."""
         n = len(self.doc_ids)
         self._ensure_hash_mirror()
+        if self._lane_trips:
+            # a registration outside a round (seed_clock) rewrote lanes
+            # and no scatter has carried them: the mirror is the truth
+            self._drop_copy()
         if self._hash_handle is not None and not self._dev_current:
             # the handle predates a re-layout/invalidation (add_docs pad
             # growth, _grow, remap): it can never be consumed — drop it,

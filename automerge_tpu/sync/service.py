@@ -551,12 +551,7 @@ class EngineDocSet:
             suffix = [c for c in rset.change_log[i]
                       if c.seq > img.clock.get(c.actor, 0)]
             for c in suffix:
-                row = t.state_clocks.get((c.actor, c.seq))
-                if row is not None and not isinstance(row, dict):
-                    arr, ridx = row
-                    row = {rset.actors[r]: int(v)
-                           for r, v in enumerate(arr[ridx]) if v}
-                    t.state_clocks[(c.actor, c.seq)] = row
+                row = rset._memo_dict(t, (c.actor, c.seq))
                 if not self._suffix_covers(row, (c.actor, c.seq - 1),
                                            img.clock):
                     metrics.bump("sync_bootstrap_fallbacks")

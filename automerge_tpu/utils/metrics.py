@@ -161,6 +161,20 @@ COUNTERS: dict[str, str] = {
     # rows — docs-minor streaming engine
     "rows_rounds_batched": "round frames through the vectorized admission",
     "rows_rounds_fallback": "round frames through the per-round fallback",
+    "rows_changes_admitted":
+        "changes a round-frame apply admitted (one bump a round, "
+        "resident_rows._apply_round_frames)",
+    "rows_changes_admitted_general":
+        "those of them that went through the general admission (_admit, "
+        "_clock_row: concurrent changes, merges, gaps), not a vectorized "
+        "path",
+    "rows_actor_joins":
+        "(document, actor) pairs registered after the document's first "
+        "change: a device that joins a document "
+        "(resident_rows._adopt_doc_actors)",
+    "rows_actor_remap_lanes":
+        "lanes whose rank-bearing rows a registration rewrote; a join "
+        "rewrites its document's lane alone",
     "rows_dispatch_failed": "device dispatches that failed (host recovered)",
     "rows_log_rebuilt": "engine rebuilds replayed from the admitted log",
     "rows_engine_poisoned": "engines poisoned by an unrecoverable failure",
@@ -566,6 +580,10 @@ GAUGES: dict[str, str] = {
 
 HISTOGRAMS: dict[str, str] = {
     "sync_round_seconds": "latency of coalesced service round flushes",
+    "rows_actor_register_seconds":
+        "a round's actor registration (resident_rows._register_round_"
+        "actors over the round's frames), observed once a round, inside "
+        "phase encode",
     "sync_shard_fanout_seconds":
         "one fan-out of the sharded service: the end of a batch()'s body "
         "(or the entry of flush()) to the last shard's return",

@@ -9,23 +9,28 @@ shapes the 10,000-document chip_smoke.py produces (its CPU rehearsal printed
 them: resident dims (512, 4, 64) over 10,112 lanes, storm buckets (8, 4, 0)
 over 1,024 and 2,048 lanes, span and move tables [8, ., 128]).
 
-The two cells of the benchmark are here at their own shapes too (resident
-dims (512, 4, 32): `reconcile_rows_hash` over [6308, 1280]; `_apply_final`
-on the [6308, 10112] fleet, reconciling the one 128-lane block a request
-dirtied, and every block as the first request after an upload does), and
+The cells of the benchmark are here at their own shapes too. The actor axis
+is a document's own, so `fleet10k`, no document of which has more than two
+writers, loads at resident dims (512, 2, 32): `reconcile_rows_hash` over
+[5282, 1280]; `_apply_final` on the [5282, 10112] fleet, reconciling the one
+128-lane block a request dirtied, and every block as the first request
+after an upload does. `fleet10k-devices`, whose heavy documents have eight
+writers, loads at (512, 8, 32), a working set of 22,248 of the budget's
+22,528 rows: `reconcile_rows_hash` over [8360, 1280], `_apply_final` on the
+[8360, 10112] fleet, and the round's scatter and gather at that height. And
 each compiled program must hold an instruction that the cell's roofline
 metric finds by the patterns of its own file under benchmarks/metrics/: a
 renamed kernel then fails here, and not as `output_malformed` on the chip.
 So are the shapes of a shard of the four-chip cell, `fleet10k-4shard.storm`
 (a quarter of the fleet a chip, the same caps): `reconcile_rows_hash` over
-the [6308, 384] a quarter of a storm round pads to, and over 256 and 512
+the [5282, 384] a quarter of a storm round pads to, and over 256 and 512
 lanes, the neighbours a seed may reach; `_apply_final` on a shard's
-resident [6308, 2560] at one block.
+resident [5282, 2560] at one block.
 
 Since a round keeps the rows on the chip, its two programs are here as
 well: `_scatter_trips` at the power-of-two triplet pads a round of either
-cell reaches, and `gather_lanes` out of the fleet's [6308, 10112] (a
-shard's [6308, 2560]) into the 1152, 1280 and 1408 (256, 384, 512) lanes
+cell reaches, and `gather_lanes` out of the fleet's [5282, 10112] (a
+shard's [5282, 2560]) into the 1152, 1280 and 1408 (256, 384, 512) lanes
 a round's dirty documents pad to. The gather may need no temporary worth
 the name: `rows[:, sel]` as XLA lowers it copies the whole buffer into
 another layout first, 255 MB a request. The scatter is XLA's and does
@@ -58,7 +63,8 @@ HBM_BYTES = 16 * 1024 ** 3     # one v5e chip
 
 FLEET_LANES = 10_112           # pad_to_lanes(10,044 documents)
 CAPS = (512, 4, 64)            # the smoke's resident (I, A, LE)
-BENCH_CAPS = (512, 4, 32)      # the benchmark fleet's (its load stage line)
+BENCH_CAPS = (512, 2, 32)      # fleet10k's (its load stage line)
+DEVICES_CAPS = (512, 8, 32)    # fleet10k-devices': eight writers a heavy doc
 BENCH_STORM_LANES = 1_280      # a storm request's dirty documents, padded
 SHARD_LANES = 2_560            # pad_to_lanes(a shard's 2,510 or 2,512 documents)
 SHARD_STORM_LANES = (256, 384, 512)   # a quarter of a round: 262-354 documents
@@ -66,6 +72,9 @@ STORM_LANES = (1_152, 1_280, 1_408)   # a round: 1,170-1,300 documents
 # a round's merged triplets (9-11 a change), padded to a power of two
 STORM_TRIP_PADS = (8_192, 16_384)
 SHARD_TRIP_PADS = (2_048, 4_096)
+# fleet10k-devices: a change names its deps' clock in more bands, and a
+# join's lane rewrite rides the round's scatter
+DEVICES_TRIP_PADS = (16_384, 32_768)
 
 
 def _dims(i, a, le):
@@ -142,20 +151,20 @@ def _apply_final(caps, trips, blocks=None, lanes=FLEET_LANES):
     return build
 
 
-def _scatter_trips(lanes, trips):
+def _scatter_trips(lanes, trips, caps=BENCH_CAPS):
     def build(chip):
         from automerge_tpu.engine.resident_rows import _scatter_trips
         return _scatter_trips.lower(
-            chip.one((rows_count(*BENCH_CAPS), lanes)), chip.one((trips, 3)))
+            chip.one((rows_count(*caps), lanes)), chip.one((trips, 3)))
     return build
 
 
-def _gather_lanes(lanes, k_pad):
+def _gather_lanes(lanes, k_pad, caps=BENCH_CAPS):
     def build(chip):
         from automerge_tpu.engine.pallas_kernels import gather_lanes
         steps = lanes // 128 + k_pad // 128
         return gather_lanes.lower(
-            chip.one((rows_count(*BENCH_CAPS), lanes)),
+            chip.one((rows_count(*caps), lanes)),
             chip.one((k_pad + 2 * steps,)), k_pad, False)
     return build
 
@@ -235,6 +244,11 @@ CASES = {
         _apply_final(BENCH_CAPS, 16, blocks=1), True, 1 << 20),
     "apply_final-bench-four-blocks": (
         _apply_final(BENCH_CAPS, 16, blocks=4), True, 1 << 20),
+    # eight writers a document: 22,248 of the budget's 22,528 rows
+    "megakernel-devices-one-block": (_megakernel(*DEVICES_CAPS, 128), True),
+    "apply_final-devices-one-block": (
+        _apply_final(DEVICES_CAPS, 16, blocks=1), True, 1 << 20),
+    "apply_final-devices-whole": (_apply_final(DEVICES_CAPS, 1024), True),
     "scan_rounds-fleet": (_scan_rounds_fleet, True),
     "merge_spans": (_merge_spans, False),
     "resolve_moves": (_resolve_moves, False),
@@ -247,17 +261,21 @@ CASES = {
 }
 CASES.update({
     f"scatter_trips-{name}-{trips}-triplets": (
-        _scatter_trips(lanes, trips), False)
-    for name, lanes, pads in (("fleet", FLEET_LANES, STORM_TRIP_PADS),
-                              ("shard", SHARD_LANES, SHARD_TRIP_PADS))
+        _scatter_trips(lanes, trips, caps), False)
+    for name, lanes, pads, caps in (
+        ("fleet", FLEET_LANES, STORM_TRIP_PADS, BENCH_CAPS),
+        ("shard", SHARD_LANES, SHARD_TRIP_PADS, BENCH_CAPS),
+        ("devices", FLEET_LANES, DEVICES_TRIP_PADS, DEVICES_CAPS))
     for trips in pads})
 # a gather that copies the buffer it reads (XLA's `rows[:, sel]` does)
 # fails here: its only large buffer is its output
 CASES.update({
     f"gather_lanes-{name}-{k_pad}-lanes": (
-        _gather_lanes(lanes, k_pad), True, 1 << 20)
-    for name, lanes, pads in (("fleet", FLEET_LANES, STORM_LANES),
-                              ("shard", SHARD_LANES, SHARD_STORM_LANES))
+        _gather_lanes(lanes, k_pad, caps), True, 1 << 20)
+    for name, lanes, pads, caps in (
+        ("fleet", FLEET_LANES, STORM_LANES, BENCH_CAPS),
+        ("shard", SHARD_LANES, SHARD_STORM_LANES, BENCH_CAPS),
+        ("devices", FLEET_LANES, STORM_LANES, DEVICES_CAPS))
     for k_pad in pads})
 
 
@@ -291,7 +309,7 @@ CELL_KERNELS = {
         "apply_final_roofline", _apply_final(BENCH_CAPS, 16),
         (rows_count(*BENCH_CAPS), FLEET_LANES)),
     # a single edit on a shard of the four-chip cell: one block of its
-    # resident [6308, 2560]
+    # resident [5282, 2560]
     "apply_final_roofline-shard-one-block": (
         "apply_final_roofline",
         _apply_final(BENCH_CAPS, 16, blocks=1, lanes=SHARD_LANES),
@@ -310,6 +328,12 @@ CELL_KERNELS.update({
         "megakernel_roofline", _megakernel(*BENCH_CAPS, lanes),
         (rows_count(*BENCH_CAPS), lanes))
     for lanes in STORM_LANES if lanes != BENCH_STORM_LANES})
+# fleet10k-devices.storm: the same round at eight writers a document
+CELL_KERNELS.update({
+    f"megakernel_roofline-devices-{lanes}-lanes": (
+        "megakernel_roofline", _megakernel(*DEVICES_CAPS, lanes),
+        (rows_count(*DEVICES_CAPS), lanes))
+    for lanes in STORM_LANES})
 
 
 def _event_names(compiled) -> list:
