@@ -238,6 +238,28 @@ def _read_route(rset, lanes) -> Route:
     return (minority and _fused_route(rset, lanes)) or share_route(rset, lanes)
 
 
+def _plans(lanes, round_docs: int) -> bool:
+    """Whether an eager engine's round plans (the table of
+    reconcile_route: two documents or more, a lane touched, megabatch
+    on)."""
+    return bool(megabatch_enabled() and round_docs >= MEGABATCH_MIN_DOCS
+                and lanes)
+
+
+def scatters_first(rset, lanes, round_docs: int) -> bool:
+    """The first half of a round's decision, which needs no plan: every
+    route a planning round can get (fused, lanes, read-back whole, a
+    read's) first scatters the round's triplets into a current device
+    copy, so the engine sends that scatter out BEFORE it asks
+    reconcile_route, and the chip works while the router reads the host
+    mirror. False for a round that never plans (its scatter and reconcile
+    are one program), a lazy engine, and a copy that is not current (the
+    column "scatters before the plan" of reconcile_route's table).
+    Reads state, changes none."""
+    return bool(not rset.lazy_dispatch and _plans(lanes, round_docs)
+                and rset._dev_current)
+
+
 @perfscope.phased("route")
 def reconcile_route(rset, lanes, round_docs: int | None = None) -> Route:
     """How the dirty `lanes` (doc indices, ascending) of a resident rows
@@ -249,28 +271,31 @@ def reconcile_route(rset, lanes, round_docs: int | None = None) -> Route:
     handle is pending). Reads state, changes none. "Plans" below is one
     plan_round call: off under AMTPU_MEGABATCH=0 and for fewer than
     MEGABATCH_MIN_DOCS lanes, else the link cost model's verdict on the
-    bucketed dispatches. n = len(rset.doc_ids).
+    bucketed dispatches. n = len(rset.doc_ids). The last column is
+    scatters_first, the half of the decision a round takes before it
+    asks here: where it says "copy current", the round's triplets are
+    already on their way into the device copy when the plan is made.
 
     A round:
 
-    | engine | the round                        | observed                           | route    |
-    |--------|----------------------------------|------------------------------------|----------|
-    | lazy   | any                              |                                    | deferred |
-    | eager  | one document, or no lane touched,| _h_prev valid, the dirty 128-lane  | blocks   |
-    |        | or AMTPU_MEGABATCH=0: never plans| blocks (padded to a power of two)  |          |
-    |        |                                  | at most half of n_pad / 128        |          |
-    | eager  | the same                         | no valid _h_prev (the copy was     | whole,   |
-    |        |                                  | uploaded or re-laid since), or the | readback |
-    |        |                                  | blocks are no minority             | False    |
-    | eager  | two documents or more: plans over| the plan fuses                     | fused    |
-    |        | its lanes whatever their share   |                                    |          |
-    | eager  | the same                         | declined; no other lane is dirty;  | lanes    |
-    |        |                                  | 2 * lanes < n                      |          |
-    | eager  | the same                         | declined; no other lane is dirty;  | whole    |
-    |        |                                  | 2 * lanes >= n                     |          |
-    | eager  | the same                         | declined; lanes from outside the   | a read's,|
-    |        |                                  | round are dirty too (a failed      | over all |
-    |        |                                  | dispatch, a deferred read)         | of them  |
+    | engine | the round                        | observed                           | route    | scatters before the plan |
+    |--------|----------------------------------|------------------------------------|----------|--------------------------|
+    | lazy   | any                              |                                    | deferred | never                    |
+    | eager  | one document, or no lane touched,| _h_prev valid, the dirty 128-lane  | blocks   | never: one program       |
+    |        | or AMTPU_MEGABATCH=0: never plans| blocks (padded to a power of two)  |          |                          |
+    |        |                                  | at most half of n_pad / 128        |          |                          |
+    | eager  | the same                         | no valid _h_prev (the copy was     | whole,   | never: one program       |
+    |        |                                  | uploaded or re-laid since), or the | readback |                          |
+    |        |                                  | blocks are no minority             | False    |                          |
+    | eager  | two documents or more: plans over| the plan fuses                     | fused    | copy current             |
+    |        | its lanes whatever their share   |                                    |          |                          |
+    | eager  | the same                         | declined; no other lane is dirty;  | lanes    | copy current             |
+    |        |                                  | 2 * lanes < n                      |          |                          |
+    | eager  | the same                         | declined; no other lane is dirty;  | whole    | copy current             |
+    |        |                                  | 2 * lanes >= n                     |          |                          |
+    | eager  | the same                         | declined; lanes from outside the   | a read's,| copy current             |
+    |        |                                  | round are dirty too (a failed      | over all |                          |
+    |        |                                  | dispatch, a deferred read)         | of them  |                          |
 
     A read:
 
@@ -291,8 +316,11 @@ def reconcile_route(rset, lanes, round_docs: int | None = None) -> Route:
     over the copy (uploaded where not current), read back, kept as
     _h_prev. handle: one readback of the pending vector. A round's
     fused, lanes and read-back whole first scatter its triplets into the
-    copy where it is current (one that is not is dropped) and drop _h_prev
-    and the handle.
+    copy where it is current (scatters_first: before this router runs;
+    one that is not current is dropped after it) and drop _h_prev and the
+    handle. A round's lanes and read-back whole are dispatched and not
+    read back: the vector stays with the engine as its one unsettled
+    round until the collect half (ResidentRowsDocSet.collect_round).
 
     Where the round and the read differ: a round plans over its lanes
     even when they are a majority of the fleet, a read only for a
@@ -305,8 +333,7 @@ def reconcile_route(rset, lanes, round_docs: int | None = None) -> Route:
         return _read_route(rset, lanes)
     if rset.lazy_dispatch:
         return Route("deferred")
-    if not (megabatch_enabled() and round_docs >= MEGABATCH_MIN_DOCS
-            and lanes):
+    if not _plans(lanes, round_docs):
         blocks = sorted({i // LANE for i in lanes})
         nb = _pad_to(len(blocks), 1)
         if blocks and rset._h_prev is not None and rset._dev_current \

@@ -196,6 +196,13 @@ class ResidentRowsDocSet(ResidentDocSet):
         # Valid exactly while rows_dev is: _apply_final patches the dirty
         # 128-lane blocks' hashes into it instead of rehashing the fleet.
         self._h_prev = None
+        # The one unsettled round: (lanes, h) of a round whose reconcile
+        # was dispatched and whose hashes are not in the mirror yet
+        # (lanes None: every lane). Its lanes stay in _doc_dirty until
+        # _settle reads h back, so dropping the record is always safe;
+        # whatever dirties a lane or loses the copy drops it
+        # (_mark_hash_dirty, _drop_copy), every hash read settles it.
+        self._unsettled = None
         # dense admission cache (vectorized round-frame fast path): per-doc
         # clock rows + single-head frontier summary. Rebuilt lazily from the
         # authoritative DocTables dicts for docs in _cache_dirty.
@@ -1359,25 +1366,79 @@ class ResidentRowsDocSet(ResidentDocSet):
     # round-frame ingress: the streaming sync service's hot path
 
     def apply_round_frames(self, frames, interpret: bool | None = None):
+        """Apply a micro-batch of sync rounds shipped as ROUND FRAMES:
+        the dispatch half and the collect half, one after the other.
+        Returns the device array of the post-batch per-doc hashes, padded
+        to n_pad (slice [:len(doc_ids)] after np.asarray): the handle the
+        one-program routes leave on the device (`blocks`, `whole` with
+        readback False: not read back, the next hashes() consumes it), a
+        copy of the host hash mirror after a route that reads back; None
+        under `deferred`."""
         with metrics.trace("rows_round_apply"):
-            return self._apply_round_frames(frames, interpret)
+            h, read_back = self._dispatch_round_frames(frames, interpret)
+            if not read_back:
+                return h
+            if self._unsettled is not None:
+                self._collect_round(interpret)
+            n = len(self.doc_ids)
+            out = np.zeros(self.n_pad, np.uint32)
+            out[:n] = self._hash_mirror[:n]
+            return self._to_dev(out)
 
-    def _apply_round_frames(self, frames, interpret: bool | None = None):
-        """Apply a micro-batch of sync rounds shipped as ROUND FRAMES
-        (sync/frames.py AMR1: one columnar frame per round covering every
-        document touched that round) in ONE asynchronous device dispatch.
+    def dispatch_round_frames(self, frames,
+                              interpret: bool | None = None) -> None:
+        """The dispatch half alone, for a caller with host work of its
+        own that needs no hash (the sync service's tail): everything up to
+        and including the dispatch of the round's reconcile. The caller
+        owes one collect_round() before it lets anyone read the round as
+        flushed; the engine stays sound if it never comes (the round's
+        lanes stay dirty, and every entry that reads a hash or dirties a
+        lane settles or drops the unsettled round first)."""
+        with metrics.trace("rows_round_apply"):
+            self._dispatch_round_frames(frames, interpret)
 
-        Unlike apply_rounds, this does NOT read hashes back: it returns the
-        device array handle of the post-batch per-doc hashes (padded to
-        n_pad; slice [:len(doc_ids)] after np.asarray). A streaming service
-        advertises clocks from host state and only needs hashes when a
-        convergence check runs — reading them is the caller's explicit
-        barrier. Consecutive calls chain device-side (the rows buffer is
-        donated; the hash array is not, a caller may keep it), and each
-        reconciles only the 128-lane blocks its documents sit in
-        (_dispatch_final), so ingress pipelines: host encode of batch k+1
-        overlaps device work of batch k, and the fixed per-transfer
-        latency leaves the critical path entirely.
+    def collect_round(self, interpret: bool | None = None) -> None:
+        """The collect half of dispatch_round_frames: the round's hashes
+        read into the host mirror (`readback`, the one wait for the
+        device), its lanes clean, the device copy primed after a host
+        gather, and lanes left dirty from outside the round routed as a
+        read. A no-op where the round left nothing unsettled (the fused
+        route reads its buckets back in the dispatch; `blocks`, `whole`
+        with readback False and `deferred` read nothing back). A device
+        failure that surfaces here is the dispatch guard's: the copy
+        dropped, the lanes dirty, DeviceDispatchError with
+        admission_complete=True."""
+        if self._unsettled is not None:
+            metrics.bump("rows_rounds_overlapped")
+            self._collect_round(interpret)
+
+    def _collect_round(self, interpret) -> None:
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        with self._dispatch_guard():
+            # settles, then routes what is dirty from outside the round
+            # (a failed dispatch, a deferred read) as a read; the steady
+            # round leaves none
+            self._refresh_hash_mirror(None, interpret)
+
+    def _dispatch_round_frames(self, frames, interpret):
+        """The dispatch half of a round (sync/frames.py AMR1: one columnar
+        frame per round covering every document touched that round):
+        decode, registration, precheck, admission and the native delta
+        encode (`encode`); the triplets committed to the host mirror and
+        their lanes marked dirty (`commit`); where dispatch.scatters_first
+        says so, the round's scatter sent to the device; then the router,
+        once (`route`), and its route executed up to and including the
+        dispatch of the reconcile (_dispatch_final). Hashes are read back
+        here only by the fused route (a bucket at a time); `lanes` and
+        read-back `whole` leave their vector as the unsettled round for
+        the collect half, the one-program routes leave theirs on the
+        device as the pending handle.
+
+        Returns (handle, read_back): the device hash array of every lane
+        where the route leaves one on the device (else None), and whether
+        the route is one whose hashes reach the host mirror with the
+        collect (False: `deferred` and the one-program routes).
 
         frames: list of round-frame bytes (or decoded RoundColumns).
         Documents must already exist in this set.
@@ -1391,7 +1452,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             # Python-encoder fallback: same semantics, per-doc Change path.
             h = self.apply_rounds([rc.to_dict() for rc in rounds], interpret)
             return self._to_dev(h[-1] if len(h) else
-                                self.hashes(interpret=interpret))
+                                self.hashes(interpret=interpret)), False
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
         # Nothing on this path creates reference cycles, but its allocation
@@ -1437,11 +1498,19 @@ class ResidentRowsDocSet(ResidentDocSet):
                     round_docs = len({d for rc in rounds
                                       for d in rc.doc_ids})
                 with self._dispatch_guard():
+                    if round_dispatch.scatters_first(
+                            self, touched, round_docs):
+                        # the chip scatters while the router reads the
+                        # host mirror
+                        self._scatter_round(trip_list, len(touched))
+                        trip_list = None
                     # routed AFTER the trips commit: the fused route's
                     # bucket shapes see this round's ops
                     route = round_dispatch.reconcile_route(
                         self, touched, round_docs)
-                    return self._dispatch_final(trip_list, route, interpret)
+                    return (self._dispatch_final(trip_list, route,
+                                                 interpret),
+                            route.readback and route.kind != "deferred")
 
     def _frame_gids(self, cols) -> np.ndarray:
         """The instance's id of each name in a frame's actor table; a
@@ -1977,12 +2046,34 @@ class ResidentRowsDocSet(ResidentDocSet):
         padded[n:, 0] = self._bases()["rows"]
         return padded, n
 
+    def _scatter_round(self, trip_list, n_lanes: int) -> None:
+        """The round's triplets into the current device copy, as one
+        scatter; what was computed from the copy before is dropped."""
+        with perfscope.phase("commit"):
+            # a round's count moves with its documents: small
+            # rounds share one shape, large ones a power of two
+            padded, n_trips = self._merged_trips(trip_list, 1024)
+        padded_dev = self._to_dev(padded)
+        with dispatchledger.call_scope(
+                "rows_scatter", backend="device", docs=n_lanes,
+                axes={"trips": (max(n_trips, 1), len(padded))}):
+            self.rows_dev = metrics.dispatch_jit(
+                "scatter_trips", _scatter_trips, self.rows_dev,
+                padded_dev)
+        self._hash_handle = self._h_prev = None
+
     def _dispatch_final(self, trip_list, route, interpret):
         """Execute the route dispatch.reconcile_route gave this round (its
-        docstring holds the table). The rounds' triplets go to the device
-        as one scatter (_merged_trips). Returns the device hash array of
-        every lane, padded to n_pad, not read back where the route leaves
-        it on the device; None under `deferred`."""
+        docstring holds the table), up to the dispatch of its reconcile.
+        `trip_list`: the round's triplets, or None where the round has
+        scattered them into the copy already (dispatch.scatters_first: a
+        round that planned and found the copy current). This function is
+        told, it does not look at the copy again. `fused` reads its
+        buckets back here; `lanes` and read-back `whole` leave their
+        vector as the unsettled round (_settle reads it back). Returns the
+        device hash array of every lane, padded to n_pad, where the route
+        leaves it on the device (`blocks`, `whole` with readback False);
+        else None."""
         if route.kind == "deferred":
             # _cols_triplets already committed the round to the host
             # mirror; the next hash read uploads it and, with the dirty
@@ -1992,33 +2083,20 @@ class ResidentRowsDocSet(ResidentDocSet):
             return None
         if not route.readback:
             return self._apply_final_route(trip_list, route, interpret)
-        if self._dev_current:
-            with perfscope.phase("commit"):
-                # a round's count moves with its documents: small
-                # rounds share one shape, large ones a power of two
-                padded, n_trips = self._merged_trips(trip_list, 1024)
-            padded_dev = self._to_dev(padded)
-            with dispatchledger.call_scope(
-                    "rows_scatter", backend="device", docs=len(route.lanes),
-                    axes={"trips": (max(n_trips, 1), len(padded))}):
-                self.rows_dev = metrics.dispatch_jit(
-                    "scatter_trips", _scatter_trips, self.rows_dev,
-                    padded_dev)
-            self._hash_handle = self._h_prev = None
-        else:
+        if trip_list is not None:
+            # planned without a current copy: nothing to scatter into
             self._drop_copy()
         if route.kind == "fused":
             round_dispatch.apply_round_adaptive(self, route.plan, interpret)
         else:
-            self._reconcile(route, interpret)
-        # lanes a fused round did not take, and lanes left dirty from
-        # outside the round (a failed dispatch, a deferred read), are
-        # routed as a read; the steady round leaves none
-        self._refresh_hash_mirror(None, interpret)
-        n = len(self.doc_ids)
-        out = np.zeros(self.n_pad, np.uint32)
-        out[:n] = self._hash_mirror[:n]
-        return self._to_dev(out)
+            self._reconcile(route, interpret, settle=False)
+        if self._unsettled is None:
+            # read back already (a fused round, a read's fused route):
+            # lanes it did not take, and lanes left dirty from outside
+            # the round, are routed as a read now; the collect half does
+            # the same behind an unsettled round
+            self._refresh_hash_mirror(None, interpret)
+        return None
 
     def _drop_copy(self) -> None:
         """Forget the device copy and what was computed from it; the next
@@ -2027,7 +2105,18 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._dirty = True
         self._hash_handle = None
         self._h_prev = None
+        self._unsettled = None
         self._lane_trips.clear()
+
+    def _mark_hash_dirty(self, idxs) -> None:
+        # behind an unsettled round its vector may predate the write:
+        # dropped, the round's lanes stay dirty with these
+        self._unsettled = None
+        super()._mark_hash_dirty(idxs)
+
+    def _mark_all_hash_dirty(self) -> None:
+        self._unsettled = None
+        super()._mark_all_hash_dirty()
 
     def _prime(self) -> None:
         """Upload the host mirror as the device copy."""
@@ -2088,6 +2177,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         for is dirty."""
         n = len(self.doc_ids)
         self._ensure_hash_mirror()
+        self._settle()
         if self._lane_trips:
             # a registration outside a round (seed_clock) rewrote lanes
             # and no scatter has carried them: the mirror is the truth
@@ -2104,8 +2194,10 @@ class ResidentRowsDocSet(ResidentDocSet):
             self._reconcile(
                 round_dispatch.reconcile_route(self, dirty), interpret)
 
-    def _reconcile(self, route, interpret) -> None:
-        """Execute a read's route (for a round: its `lanes` or `whole`)."""
+    def _reconcile(self, route, interpret, settle: bool = True) -> None:
+        """Execute a read's route (for a round: its `lanes` or `whole`,
+        with `settle` False: dispatched, and left as the unsettled round
+        for the collect half)."""
         if route.kind == "handle":
             return self._read_back_all(self._hash_handle, cached=True)
         if route.kind == "fused":
@@ -2115,13 +2207,17 @@ class ResidentRowsDocSet(ResidentDocSet):
             # None: nothing was fused and the lanes are still dirty
             route = round_dispatch.share_route(self, route.lanes)
         if route.kind == "lanes":
-            return self._reconcile_lanes(route.lanes, interpret)
-        self._reconcile_whole(len(route.lanes), interpret)
+            self._reconcile_lanes(route.lanes, interpret)
+        else:
+            self._reconcile_whole(len(route.lanes), interpret)
+        if settle:
+            self._settle()
 
     def _reconcile_whole(self, n_dirty: int, interpret) -> None:
-        """Reconcile the whole buffer (one kernel shape for the steady
-        fleet), uploading the mirror first where the copy is not current,
-        and read every lane's hash back; the vector stays as _h_prev."""
+        """Dispatch the reconcile of the whole buffer (one kernel shape
+        for the steady fleet), uploading the mirror first where the copy
+        is not current; the vector stays as _h_prev, and unsettled until
+        _settle reads every lane's hash back."""
         if not self._dev_current:
             self._prime()
         with dispatchledger.call_scope(
@@ -2131,7 +2227,29 @@ class ResidentRowsDocSet(ResidentDocSet):
                 "reconcile_rows_hash", reconcile_rows_hash,
                 self.rows_dev, self.dims(), interpret)
         self._h_prev = h   # every lane, from the buffer just primed
-        self._read_back_all(h, cached=False)
+        self._unsettled = (None, h)
+
+    def _settle(self) -> None:
+        """Read the unsettled round's hashes into the host mirror: the
+        one wait for the device. Its lanes go clean; after a host gather
+        an eager engine then uploads the mirror once, so that the next
+        round or read finds the copy (a lazy engine drops it at every
+        round: nothing to prime)."""
+        if self._unsettled is None:
+            return
+        # forgotten BEFORE the barrier: a device failure surfaces there,
+        # and the lanes stay dirty for the retry
+        (idxs, h), self._unsettled = self._unsettled, None
+        if idxs is None:
+            return self._read_back_all(h, cached=False)
+        flightrec.record("rows_hash_readback", docs=len(idxs), cached=False)
+        with perfscope.phase("readback"):
+            vals = self._to_host(h)
+        self._ensure_hash_mirror()[np.asarray(idxs, np.int64)] = \
+            vals[:len(idxs)]
+        self._doc_dirty.difference_update(idxs)
+        if not self._dev_current and not self.lazy_dispatch:
+            self._prime()
 
     def _read_back_all(self, h, cached: bool) -> None:
         """One readback of an all-lane hash vector into the mirror."""
@@ -2183,9 +2301,8 @@ class ResidentRowsDocSet(ResidentDocSet):
         it, on the device (gather_lanes), and only the lane indices cross
         the link. Where it is not (after add_docs pad growth, _grow, a
         remap, compact, a failed dispatch) they are gathered out of the
-        host mirror and uploaded, and an eager engine then uploads the
-        mirror once, so that the next round or read finds the copy (a lazy
-        engine drops it at every round: nothing to prime)."""
+        host mirror and uploaded. Dispatched, not read back: the vector
+        is the unsettled round until _settle."""
         k = len(idxs)
         k_pad = pad_to_lanes(k)
         on_device = self._dev_current
@@ -2212,13 +2329,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             metrics.bump("rows_lane_gathers_device")
         else:
             metrics.bump("rows_lane_gathers_host")
-        flightrec.record("rows_hash_readback", docs=k, cached=False)
-        with perfscope.phase("readback"):
-            vals = self._to_host(h)
-        self._ensure_hash_mirror()[np.asarray(idxs, np.int64)] = vals[:k]
-        self._doc_dirty.difference_update(idxs)
-        if not on_device and not self.lazy_dispatch:
-            self._prime()
+        self._unsettled = (idxs, h)
 
     def hashes(self, interpret: bool | None = None) -> np.ndarray:
         """Current per-doc state hashes from resident state, O(dirty) not

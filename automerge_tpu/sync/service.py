@@ -1361,6 +1361,8 @@ class EngineDocSet:
             # batch's Change objects become columns here, in one pass.
             with perfscope.phase("encode"):
                 round_ = round_from_parts(pending)
+            # the engine's dispatch half: the round admitted and committed
+            # on the host, its device work under way, no hash read back
             self._apply_with_compaction(rset, pending, round_)
         except DeviceDispatchError as e:
             # The admitted part of the flush is durable on the host
@@ -1438,6 +1440,19 @@ class EngineDocSet:
                         floor = self._compaction_floor_locked(d)
                         if floor:
                             rset.archive_log_prefix(d, floor)
+        # Everything above reads log lengths the admission settled and
+        # releases nobody, so it ran while the device reconciled the
+        # round. What follows releases callers, and an acknowledgement
+        # promises the round's hashes in the host mirror: the engine's
+        # collect half first. A device failure that surfaces at its
+        # readback is the pure dispatch failure of the handler above,
+        # seen later (admission_complete=True always here: the copy is
+        # dropped, the lanes stay dirty, the next hash read recovers).
+        try:
+            rset.collect_round()
+        except DeviceDispatchError:
+            pass
+        with perfscope.phase("publish"):
             # Host admission (and any archival) is durable and the snapshot
             # read plane re-keyed: the round's riding tickets can resolve
             # NOW, overlapping the remaining flush tail (span/metric
@@ -1469,7 +1484,8 @@ class EngineDocSet:
 
     def _apply_with_compaction(self, rset, pending: dict, round_) -> None:
         """Apply one coalesced round (`round_`, the frame of the parts in
-        `pending`); on VMEM-budget pressure, compact
+        `pending`) through the engine's dispatch half (the caller collects
+        behind its tail); on VMEM-budget pressure, compact
         every doc to its known-peer clock floor (engine/compaction.py) and
         retry once. RowsBudgetError is raised BEFORE admission, so the
         retry re-submits the identical round against the reclaimed state —
@@ -1488,7 +1504,7 @@ class EngineDocSet:
             self._lazy_resolved = True
 
         try:
-            rset.apply_round_frames([round_])
+            rset.dispatch_round_frames([round_])
         except RowsBudgetError:
             floors = {d: self._compaction_floor_locked(d)
                       for d in rset.doc_ids}
@@ -1497,7 +1513,7 @@ class EngineDocSet:
                        or s["elems_after"] < s["elems_before"]
                        for s in stats.values()):
                 raise   # nothing reclaimable: the batch genuinely oversized
-            rset.apply_round_frames([round_])
+            rset.dispatch_round_frames([round_])
 
     @staticmethod
     def _pending_anchor_pins(pending: dict) -> dict[str, set]:

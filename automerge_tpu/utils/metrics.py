@@ -163,7 +163,7 @@ COUNTERS: dict[str, str] = {
     "rows_rounds_fallback": "round frames through the per-round fallback",
     "rows_changes_admitted":
         "changes a round-frame apply admitted (one bump a round, "
-        "resident_rows._apply_round_frames)",
+        "resident_rows._dispatch_round_frames)",
     "rows_changes_admitted_general":
         "those of them that went through the general admission (_admit, "
         "_clock_row: concurrent changes, merges, gaps), not a vectorized "
@@ -190,6 +190,10 @@ COUNTERS: dict[str, str] = {
     "rows_lane_gathers_host":
         "lane reconciles whose columns were gathered out of the host "
         "mirror and uploaded (the device copy was not current)",
+    "rows_rounds_overlapped":
+        "rounds whose hashes were collected by a call of their own "
+        "(resident_rows.collect_round), after the caller's own work "
+        "behind the dispatch, not inside it",
     # sync — services, wire protocol, transports, log archive
     "sync_frames_sent": "columnar change frames sent",
     "sync_frames_received": "columnar change frames received",

@@ -26,7 +26,8 @@ be checkable run over run:
   stands on the device trace's clock under the same name. On the served
   path the phases are a partition of the blocking thread's time, layer
   boundary by layer boundary (admit → commit_wait | encode → commit →
-  route → pack → upload → dispatch → readback[device_wait] → publish);
+  upload → dispatch → route → pack → upload → dispatch → publish →
+  readback[device_wait] → publish);
   the `metrics.trace` spans `sync_request`, `sync_round_flush` and
   `rows_round_apply` are their parents and carry the request's id. Phase
   names are lint-enforced (the graftlint registry pass) the same way
@@ -84,7 +85,8 @@ PHASES: dict[str, str] = {
     "commit_wait": "a caller parked on its epoch ticket until the flush "
                    "that carried its entry resolves it (sync/epochs.py)",
     "encode": "round-frame decode, actor registration, budget precheck and "
-              "the native delta encode (resident_rows._apply_round_frames)",
+              "the native delta encode "
+              "(resident_rows._dispatch_round_frames)",
     "commit": "the encoded round committed to the host row mirror: growth, "
               "scatter triplets, dirty marks, dedup and padding "
               "(resident_rows)",
@@ -100,9 +102,11 @@ PHASES: dict[str, str] = {
     "device_wait": "explicit host barriers on in-flight device work "
                    "(block_until_ready), inside `readback` on the rows path",
     "readback": "device->host readbacks (hash reads, the trusted barrier)",
-    "publish": "the service's tail after the engine returns: admission "
-               "scans, ledgers, read versions, notify queue, archive "
-               "trigger, ticket resolve, handler gossip (sync/service.py)",
+    "publish": "the service's tail: behind the engine's dispatch half "
+               "admission scans, ledgers, read versions, notify queue, "
+               "archive trigger; behind its collect half (`readback`) the "
+               "round's counters, ticket resolve, handler gossip "
+               "(sync/service.py)",
     "host_materialize": "interpretive apply + snapshot materialization "
                         "(frontend/materialize.py)",
     "sync_wire": "wire encode/decode of sync frames (sync/frames.py)",

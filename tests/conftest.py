@@ -34,6 +34,14 @@ def cpu_link():
     dispatch.calibrate(**saved)
 
 
+@pytest.fixture
+def no_fused_route(monkeypatch):
+    """Rounds of two and more documents take the classic route too, as
+    under AMTPU_MEGABATCH=0."""
+    from automerge_tpu.engine import dispatch
+    monkeypatch.setattr(dispatch, "_megabatch", False)
+
+
 @pytest.fixture(autouse=True)
 def _reset_uuid_factory():
     yield

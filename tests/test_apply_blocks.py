@@ -168,13 +168,6 @@ def _round_route(monkeypatch, fuses=False):
                             lambda rset, plan, interpret=False: None)
 
 
-@pytest.fixture
-def no_fused_route(monkeypatch):
-    """Rounds of two and more documents take the classic route too, as
-    under AMTPU_MEGABATCH=0."""
-    monkeypatch.setattr(round_dispatch, "_megabatch", False)
-
-
 def _first_block(f, monkeypatch):
     f.routed([5], 1, 1)
     f.routed([127], 1, 1)

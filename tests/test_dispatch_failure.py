@@ -176,7 +176,7 @@ def test_partial_admission_restores_whole_round_and_dedups():
     e.add_doc("b")
     chs_a, chs_b = make_doc(1), make_doc(2)
 
-    real = rset.apply_round_frames
+    real = rset.dispatch_round_frames
 
     def partial(frames, interpret=None):
         # really admit doc a (log + clocks + mirror), then fail before b
@@ -184,11 +184,11 @@ def test_partial_admission_restores_whole_round_and_dedups():
         raise DeviceDispatchError("failed after admitting a, before b",
                                   admission_complete=False)
 
-    rset.apply_round_frames = partial
+    rset.dispatch_round_frames = partial
     with e.batch():
         e.apply_changes("a", chs_a)
         e.apply_changes("b", chs_b)
-    rset.apply_round_frames = real
+    rset.dispatch_round_frames = real
 
     # the whole round returns to pending: b's changes were lost mid-round,
     # a's replay is a safe duplicate-drop
@@ -211,17 +211,17 @@ def test_pure_dispatch_failure_retries_nothing():
     rset = e._resident
     if rset._native is None:
         pytest.skip("python-encoder fallback has no dispatch stage")
-    real = rset.apply_round_frames
+    real = rset.dispatch_round_frames
 
     def dispatch_fail(frames, interpret=None):
         real(frames)   # full admission + mirror succeed
         raise DeviceDispatchError("device lost at dispatch",
                                   admission_complete=True)
 
-    rset.apply_round_frames = dispatch_fail
+    rset.dispatch_round_frames = dispatch_fail
     chs = make_doc(4)
     e.apply_changes("d4", chs)
-    rset.apply_round_frames = real
+    rset.dispatch_round_frames = real
 
     assert e._pending == {}
     assert len(rset.change_log[rset.doc_index["d4"]]) == len(chs)
@@ -258,15 +258,15 @@ def test_preadmission_failure_restores_unadmitted_docs():
         pytest.skip("python-encoder fallback exercises a different path")
 
     chs = make_doc(3)
-    real = rset.apply_round_frames
+    real = rset.dispatch_round_frames
 
     def precheck_boom(frames, interpret=None):
         raise RuntimeError("batch would blow the VMEM budget")
 
-    rset.apply_round_frames = precheck_boom
+    rset.dispatch_round_frames = precheck_boom
     with pytest.raises(RuntimeError, match="VMEM"):
         e.apply_changes("d3", chs)
-    rset.apply_round_frames = real
+    rset.dispatch_round_frames = real
 
     # nothing admitted -> the ingress was restored for retry
     assert "d3" in e._pending
@@ -274,3 +274,140 @@ def test_preadmission_failure_restores_unadmitted_docs():
     e.flush()
     assert e._pending == {}
     assert np.uint32(e.hashes()["d3"]) == oracle_hash(chs)
+
+
+# -- a round whose device work is split in a dispatch and a collect half ------
+
+
+def _eager_service_with_a_current_copy(monkeypatch, n=40):
+    """A rows service on the chip's road (reconcile at the flush) whose
+    rounds plan and are declined, as in the benchmark's cells; `n`
+    documents loaded, the device copy current."""
+    from automerge_tpu.engine import dispatch
+
+    monkeypatch.setattr(dispatch, "_megabatch", True)
+    monkeypatch.setattr(
+        dispatch, "plan_round",
+        lambda rset, idxs: dispatch.RoundPlan("per_doc", list(idxs)))
+    e = EngineDocSet(backend="rows")
+    rset = e._resident
+    if rset._native is None:
+        pytest.skip("python-encoder fallback has no dispatch stage")
+    e._lazy_resolved = True
+    rset.lazy_dispatch = False
+    docs = {f"d{i}": make_doc(i) for i in range(n)}
+    with e.batch():
+        for d, chs in docs.items():
+            e.apply_changes(d, chs)
+    assert rset._dev_current and e._pending == {}
+    return e, rset, docs
+
+
+def _second_change(chs):
+    """The next change of the writer of `chs` (make_doc's one change)."""
+    from automerge_tpu.core.change import Change, Op
+    from automerge_tpu.core.ids import ROOT_ID
+    return [Change(actor="W", seq=len(chs) + 1, deps={},
+                   ops=[Op("set", ROOT_ID, key="n", value=-1)])]
+
+
+def test_a_failure_at_the_collect_is_swallowed_and_the_next_read_recovers(
+        monkeypatch):
+    """The device fails after the round's reconcile was dispatched and
+    surfaces where the hashes are read back, behind the service's tail: the
+    same recovery as at any readback (copy dropped, lanes dirty), the
+    round admitted and counted once, its callers released."""
+    from automerge_tpu.utils import metrics
+
+    e, rset, docs = _eager_service_with_a_current_copy(monkeypatch)
+
+    class BoomHandle:
+        def block_until_ready(self):
+            raise RuntimeError("device lost during readback")
+
+    real = rset.collect_round
+    admits = []
+    real_admit = e.doc_ledger.note_admit_round
+
+    def collect(*a, **k):
+        lanes, _h = rset._unsettled
+        rset._unsettled = (lanes, BoomHandle())
+        return real(*a, **k)
+
+    rset.collect_round = collect
+    e.doc_ledger.note_admit_round = lambda counts: (
+        admits.append(dict(counts)), real_admit(counts))[1]
+    m0 = metrics.snapshot()
+    touched = [f"d{i}" for i in range(8)]
+    with e.batch():         # returns: the service swallows the failure
+        for d in touched:
+            e.apply_changes(d, _second_change(docs[d]))
+    rset.collect_round = real
+    e.doc_ledger.note_admit_round = real_admit
+    m1 = metrics.snapshot()
+
+    def delta(k):
+        return m1.get(k, 0) - m0.get(k, 0)
+
+    assert e._pending == {}
+    assert delta("rows_dispatch_failed") == 1
+    assert delta("sync_rounds_flushed") == 1
+    assert delta("sync_ops_ingested") == 8
+    assert admits == [dict.fromkeys(touched, 1)]
+    for d in touched:
+        assert len(rset.change_log[rset.doc_index[d]]) == len(docs[d]) + 1
+    # the copy dropped, nothing unsettled, the round's lanes still dirty
+    assert rset.rows_dev is None and rset._dirty and rset._unsettled is None
+    assert {rset.doc_index[d] for d in touched} <= rset._doc_dirty
+    h = e.hashes()
+    for d in docs:
+        want = docs[d] + _second_change(docs[d]) * (d in touched)
+        assert np.uint32(h[d]) == oracle_hash(want), d
+    # replaying the round is a duplicate-drop
+    e.apply_changes("d0", _second_change(docs["d0"]))
+    assert len(rset.change_log[rset.doc_index["d0"]]) == len(docs["d0"]) + 1
+    e.close()
+
+
+def test_a_failure_at_the_early_scatter_retries_nothing(monkeypatch):
+    """The scatter that goes out before the router runs fails: a pure
+    dispatch failure like any other (admission_complete=True). Nothing is
+    re-queued, the router is never asked, the next read recovers."""
+    from automerge_tpu.engine import dispatch
+    from automerge_tpu.utils import metrics
+
+    e, rset, docs = _eager_service_with_a_current_copy(monkeypatch)
+    asked = []
+    real_route = dispatch.reconcile_route
+    monkeypatch.setattr(
+        dispatch, "reconcile_route",
+        lambda *a, **k: (asked.append(a), real_route(*a, **k))[1])
+    real = rset._scatter_round
+    calls = []
+
+    def scatter_fails(trip_list, n_lanes):
+        calls.append(n_lanes)
+        raise RuntimeError("device lost at the scatter")
+
+    rset._scatter_round = scatter_fails
+    m0 = metrics.snapshot()
+    touched = [f"d{i}" for i in range(8)]
+    with e.batch():
+        for d in touched:
+            e.apply_changes(d, _second_change(docs[d]))
+    rset._scatter_round = real
+    m1 = metrics.snapshot()
+    assert calls == [8] and asked == []
+    assert e._pending == {}
+    assert m1.get("rows_dispatch_failed", 0) \
+        - m0.get("rows_dispatch_failed", 0) == 1
+    assert m1.get("sync_ops_ingested", 0) \
+        - m0.get("sync_ops_ingested", 0) == 8
+    assert rset.rows_dev is None and rset._dirty and rset._unsettled is None
+    for d in touched:
+        assert len(rset.change_log[rset.doc_index[d]]) == len(docs[d]) + 1
+    h = e.hashes()
+    for d in docs:
+        want = docs[d] + _second_change(docs[d]) * (d in touched)
+        assert np.uint32(h[d]) == oracle_hash(want), d
+    e.close()

@@ -279,12 +279,12 @@ def test_flush_failure_reaches_writer_and_retry_succeeds():
     if rset._native is None:
         pytest.skip("python-encoder fallback exercises a different path")
     cs = chs("A", 2)
-    real = rset.apply_round_frames
-    rset.apply_round_frames = lambda *a, **k: (_ for _ in ()).throw(
+    real = rset.dispatch_round_frames
+    rset.dispatch_round_frames = lambda *a, **k: (_ for _ in ()).throw(
         RuntimeError("budget precheck failed"))
     with pytest.raises(RuntimeError, match="precheck"):
         e.apply_changes("d", cs)
-    rset.apply_round_frames = real
+    rset.dispatch_round_frames = real
     assert "d" in e._pending          # restored for retry
     e.flush()
     assert e._pending == {}
@@ -299,7 +299,7 @@ def test_reads_mid_flush_equal_quiesced_reread():
     e = EngineDocSet(backend="rows")
     e.apply_changes("d", chs("A", 1))
     rset = e._resident
-    real = rset.apply_round_frames
+    real = rset.dispatch_round_frames
     entered = threading.Event()
 
     def slow(*a, **k):
@@ -307,7 +307,7 @@ def test_reads_mid_flush_equal_quiesced_reread():
         time.sleep(0.15)
         return real(*a, **k)
 
-    rset.apply_round_frames = slow
+    rset.dispatch_round_frames = slow
     t = threading.Thread(
         target=lambda: e.apply_columns("d", wire_change("A", 2, value=2)),
         daemon=True, name="t-slow-writer")
@@ -318,7 +318,7 @@ def test_reads_mid_flush_equal_quiesced_reread():
     clk = e.clock_of("d")
     assert clk.get("A") in (1, 2)
     t.join(timeout=10)
-    rset.apply_round_frames = real
+    rset.dispatch_round_frames = real
     assert e.clock_of("d") == {"A": 2}
     assert len(e.missing_changes("d", {})) == 2
     assert np.uint32(e.hashes()["d"]) == oracle_hash(chs("A", 2))
@@ -489,15 +489,15 @@ def test_apply_columns_async_pipeline():
     # error propagation: a failing flush reaches the awaiting caller
     rset = e._resident
     if rset._native is not None:
-        real = rset.apply_round_frames
-        rset.apply_round_frames = lambda *a, **k: (_ for _ in ()).throw(
+        real = rset.dispatch_round_frames
+        rset.dispatch_round_frames = lambda *a, **k: (_ for _ in ()).throw(
             RuntimeError("boom async"))
         p = e.apply_columns_async("d", wire_change("A", 6, value=6))
         with pytest.raises(RuntimeError, match="boom async"):
             p.wait()
         with pytest.raises(RuntimeError, match="boom async"):
             p.wait()                    # repeat wait re-raises, no hang
-        rset.apply_round_frames = real
+        rset.dispatch_round_frames = real
         e.flush()                       # retry drains the restored round
         assert e.clock_of("d") == {"A": 6}
     e.close()

@@ -35,15 +35,18 @@ def standin(lazy=False, current=True, h_prev=True, handle=False, dirty=()):
 
 
 def case(name, want, lanes, round_docs=None, verdicts=(), planned=None,
-         over=None, readback=True, blocks=(), megabatch=True, **engine):
+         over=None, readback=True, blocks=(), megabatch=True,
+         scatters=False, **engine):
     """`verdicts`: what plan_round answers, call by call; `planned`: the
     lane lists it must have been asked about (default: none); `over`: the
-    lanes the route reconciles (default: `lanes`)."""
+    lanes the route reconciles (default: `lanes`); `scatters`: the
+    table's last column, what scatters_first answers before the plan."""
     lanes = list(lanes)
     return pytest.param(
         SimpleNamespace(
             engine=engine, lanes=lanes, round_docs=round_docs,
             verdicts=list(verdicts), want=want, readback=readback,
+            scatters=scatters,
             planned=[list(p) for p in (planned or [])],
             over=lanes if over is None else list(over),
             blocks=tuple(blocks), megabatch=megabatch), id=name)
@@ -68,35 +71,36 @@ TABLE = [
     case("round-of-two-touching-no-lane", "whole", [], round_docs=2,
          readback=False),
     case("round-fuses-copy-current", "fused", [10, 600], round_docs=2,
-         verdicts=[FUSES], planned=[[10, 600]]),
+         verdicts=[FUSES], planned=[[10, 600]], scatters=True),
     case("round-fuses-copy-not-current", "fused", [10, 600], round_docs=2,
          verdicts=[FUSES], planned=[[10, 600]], current=False,
          h_prev=False),
     case("round-declined-copy-current", "lanes", [10, 600], round_docs=2,
-         verdicts=[DECLINES], planned=[[10, 600]]),
+         verdicts=[DECLINES], planned=[[10, 600]], scatters=True),
     case("round-declined-copy-not-current", "lanes", [10, 600],
          round_docs=2, verdicts=[DECLINES], planned=[[10, 600]],
          current=False, h_prev=False),
     case("round-one-lane-of-two-documents", "lanes", [10], round_docs=2,
-         verdicts=[DECLINES], planned=[[10]]),
+         verdicts=[DECLINES], planned=[[10]], scatters=True),
     case("round-majority-declined", "whole", range(600), round_docs=600,
-         verdicts=[DECLINES], planned=[range(600)]),
+         verdicts=[DECLINES], planned=[range(600)], scatters=True),
     case("round-majority-still-plans-and-fuses", "fused", range(600),
-         round_docs=600, verdicts=[FUSES], planned=[range(600)]),
+         round_docs=600, verdicts=[FUSES], planned=[range(600)],
+         scatters=True),
     case("round-declined-lanes-from-outside", "lanes", [10, 600],
          round_docs=2, dirty=[300, 301], verdicts=[DECLINES, DECLINES],
          planned=[[10, 600], [10, 300, 301, 600]],
-         over=[10, 300, 301, 600]),
+         over=[10, 300, 301, 600], scatters=True),
     case("round-declined-lanes-from-outside-fuse", "fused", [10, 600],
          round_docs=2, dirty=[300, 301], verdicts=[DECLINES, FUSES],
          planned=[[10, 600], [10, 300, 301, 600]],
-         over=[10, 300, 301, 600]),
+         over=[10, 300, 301, 600], scatters=True),
     case("round-declined-outside-makes-a-majority", "whole", [10, 600],
          round_docs=2, dirty=range(700), verdicts=[DECLINES],
-         planned=[[10, 600]], over=range(700)),
+         planned=[[10, 600]], over=range(700), scatters=True),
     case("round-fuses-whatever-else-is-dirty", "fused", [10, 600],
          round_docs=2, dirty=range(700), verdicts=[FUSES],
-         planned=[[10, 600]]),
+         planned=[[10, 600]], scatters=True),
     # -- a read -----------------------------------------------------------
     case("read-pending-handle", "handle", [], handle=True, over=[]),
     case("read-pending-handle-wins-over-dirty-lanes", "handle", [5, 6],
@@ -131,7 +135,14 @@ def test_the_routers_table(c, monkeypatch):
     if c.round_docs is not None:
         rset._doc_dirty.update(c.lanes)     # a round marks, then asks
     before = (set(rset._doc_dirty), rset._hash_handle, rset._h_prev)
+    if c.round_docs is not None:
+        # the half a round takes first, with no plan; a read never asks
+        assert dispatch.scatters_first(
+            rset, c.lanes, c.round_docs) is c.scatters
+        assert asked == []
     route = dispatch.reconcile_route(rset, c.lanes, c.round_docs)
+    # only a route that reads back (a round that planned) scatters first
+    assert not c.scatters or (route.readback and route.kind != "deferred")
     assert (route.kind, route.readback) == (c.want, c.readback)
     assert list(route.lanes) == c.over
     assert route.blocks == c.blocks
@@ -152,6 +163,14 @@ def test_every_route_of_the_docstring_has_a_case():
     one_program = {c.values[0].want for c in TABLE
                    if not c.values[0].readback}
     assert one_program == {"blocks", "whole"}
+    # the last column: every kind a planning round can get, on both sides
+    assert "scatters before the plan" in doc
+    rounds = [c.values[0] for c in TABLE
+              if c.values[0].round_docs is not None]
+    assert {c.want for c in rounds if c.scatters} \
+        == {"fused", "lanes", "whole"}
+    assert {c.want for c in rounds if not c.scatters} \
+        == {"deferred", "blocks", "whole", "fused", "lanes"}
 
 
 # -- the engine ---------------------------------------------------------------

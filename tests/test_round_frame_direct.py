@@ -116,13 +116,13 @@ def capture_rounds(svc) -> list:
     """Every RoundColumns the service hands its engine from now on."""
     seen: list = []
     rset = svc._resident
-    real = rset.apply_round_frames
+    real = rset.dispatch_round_frames
 
     def spy(frames, interpret=None):
         seen.extend(frames)
         return real(frames, interpret)
 
-    rset.apply_round_frames = spy
+    rset.dispatch_round_frames = spy
     return seen
 
 
@@ -434,15 +434,15 @@ def test_a_preadmission_failure_restores_the_round_as_it_was():
     rset = svc._resident
     if rset._native is None:
         pytest.skip("python-encoder fallback exercises a different path")
-    real = rset.apply_round_frames
+    real = rset.dispatch_round_frames
 
     def boom(frames, interpret=None):
         raise RuntimeError("batch would blow the VMEM budget")
 
-    rset.apply_round_frames = boom
+    rset.dispatch_round_frames = boom
     with pytest.raises(RuntimeError, match="VMEM"):
         send(svc, RETRY_CALLS)
-    rset.apply_round_frames = real
+    rset.dispatch_round_frames = real
     # nothing admitted: every document is back, its parts unconverted and
     # in admission order
     assert list(svc._pending) == list(all_changes(RETRY_CALLS))
@@ -463,7 +463,7 @@ def test_a_midadmission_failure_restores_and_the_retry_admits_the_rest():
     rset = svc._resident
     if rset._native is None:
         pytest.skip("python-encoder fallback exercises a different path")
-    real = rset.apply_round_frames
+    real = rset.dispatch_round_frames
     first = [c for c in RETRY_CALLS if c[0] == "d0"]
 
     def partial(frames, interpret=None):
@@ -472,10 +472,10 @@ def test_a_midadmission_failure_restores_and_the_retry_admits_the_rest():
         raise DeviceDispatchError("failed after d0",
                                   admission_complete=False)
 
-    rset.apply_round_frames = partial
+    rset.dispatch_round_frames = partial
     ops0 = metrics.snapshot().get("sync_ops_ingested", 0)
     send(svc, RETRY_CALLS)          # swallowed: the round is restored
-    rset.apply_round_frames = real
+    rset.dispatch_round_frames = real
     assert list(svc._pending) == list(all_changes(RETRY_CALLS))
     assert all(type(p) is ChangesPart
                for parts in svc._pending.values() for p in parts)
@@ -530,7 +530,7 @@ def test_anchor_pins_of_unconverted_parts_under_a_budget_error():
     assert want["doc"]              # the inserts anchor at real elements
 
     rset = svc._resident
-    real_apply, real_compact = rset.apply_round_frames, rset.compact
+    real_apply, real_compact = rset.dispatch_round_frames, rset.compact
     state = {"raised": 0, "pins": None}
 
     def budget_once(frames, interpret=None):
@@ -543,7 +543,7 @@ def test_anchor_pins_of_unconverted_parts_under_a_budget_error():
         state["pins"] = pins
         return real_compact(floors, pins)
 
-    rset.apply_round_frames, rset.compact = budget_once, compact
+    rset.dispatch_round_frames, rset.compact = budget_once, compact
     with svc.batch():
         for c in new:
             svc.apply_changes("doc", [c])

@@ -139,12 +139,12 @@ def test_a_flush_that_fails_before_admission_writes_neither_ledger(calls):
     svc = EngineDocSet(backend="rows")
     try:
         rset = svc._resident
-        real = rset.apply_round_frames
+        real = rset.dispatch_round_frames
 
         def boom(frames, interpret=None):
             raise RuntimeError("batch would blow the VMEM budget")
 
-        rset.apply_round_frames = boom
+        rset.dispatch_round_frames = boom
         with pytest.raises(RuntimeError, match="VMEM"):
             with svc.batch():
                 for i in range(N_DOCS):
@@ -154,7 +154,7 @@ def test_a_flush_that_fails_before_admission_writes_neither_ledger(calls):
         assert svc.doc_ledger.section() is None
         assert tenantledger.ledger()._admitted_total == 0
         # the retry admits the restored round and writes both, once
-        rset.apply_round_frames = real
+        rset.dispatch_round_frames = real
         svc.flush()
         assert [len(c) for c in calls["doc"]] == [N_DOCS]
         assert [len(c) for c in calls["tenant"]] == [N_DOCS]
