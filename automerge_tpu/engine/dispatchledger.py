@@ -139,17 +139,18 @@ class _Round:
     __slots__ = ("label", "dirty_docs", "calls", "dropped", "ambient",
                  "self_s", "tenants", "mega")
 
-    def __init__(self, dirty_docs, label, tenants=None):
+    def __init__(self, dirty_docs, label):
         self.label = label
         self.dirty_docs = int(dirty_docs)
         self.calls: list[_Call] = []
         self.dropped = 0        # calls past CALL_CAP (counted, undetailed)
         self.ambient = 0        # jit dispatches with no call scope open
         self.self_s = 0.0
-        # per-tenant dirty-doc counts (sync/tenantledger.round_tenants);
+        # per-tenant document counts (note_round_tenants, from the
+        # flush's one fold by tenant);
         # None when the tenant plane is disabled — the folded round then
         # stays byte-identical with pre-tenancy exports
-        self.tenants = tenants
+        self.tenants = None
         # megabatch occupancy summary (note_megabatch) — the ACHIEVED
         # numbers next to the projection `perf dispatch` renders; None
         # keeps pre-r20 folds byte-identical
@@ -458,8 +459,7 @@ class _RoundScope:
 
     __slots__ = ("_rd", "_nested")
 
-    def __init__(self, dirty_docs: int, label: str | None = None,
-                 tenants: dict | None = None):
+    def __init__(self, dirty_docs: int, label: str | None = None):
         self._rd = None
         self._nested = False
         if not enabled():
@@ -468,7 +468,7 @@ class _RoundScope:
         if _tls.round is not None:
             self._nested = True
             return
-        self._rd = _tls.round = _Round(dirty_docs, label, tenants)
+        self._rd = _tls.round = _Round(dirty_docs, label)
         self._rd.self_s += time.perf_counter() - t0
 
     def __enter__(self):
@@ -520,9 +520,18 @@ class _RoundScope:
         return False
 
 
-def round_scope(dirty_docs: int, label: str | None = None,
-                tenants: dict | None = None) -> _RoundScope:
-    return _RoundScope(dirty_docs, label, tenants=tenants)
+def round_scope(dirty_docs: int, label: str | None = None) -> _RoundScope:
+    return _RoundScope(dirty_docs, label)
+
+
+def note_round_tenants(tenants: dict | None) -> None:
+    """The open round's documents by tenant, from the flush's one fold by
+    tenant (sync/tenantledger.note_ingress_round): the split of the
+    round's cost at its fold. Nothing without an open round or with
+    tenancy off."""
+    rd = _tls.round
+    if rd is not None and tenants:
+        rd.tenants = tenants
 
 
 def note_megabatch(summary: dict) -> None:

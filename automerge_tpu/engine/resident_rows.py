@@ -775,7 +775,8 @@ class ResidentRowsDocSet(ResidentDocSet):
         drops the already-admitted prefix idempotently, so the retry
         admits exactly the lost remainder. If nothing was admitted, the
         original error propagates and the caller may safely retry."""
-        log_lens = [len(log) for log in self.change_log]
+        with perfscope.phase("encode"):     # one length a document
+            log_lens = list(map(len, self.change_log))
         try:
             yield
         except DeviceDispatchError:
@@ -1409,7 +1410,6 @@ class ResidentRowsDocSet(ResidentDocSet):
         dropped, the lanes dirty, DeviceDispatchError with
         admission_complete=True."""
         if self._unsettled is not None:
-            metrics.bump("rows_rounds_overlapped")
             self._collect_round(interpret)
 
     def _collect_round(self, interpret) -> None:
@@ -1480,9 +1480,6 @@ class ResidentRowsDocSet(ResidentDocSet):
                         metrics.bump("rows_rounds_batched", len(rounds))
                         encoded = [enc_all]
                     else:
-                        if any(rc.cols.n_changes for rc in rounds):
-                            metrics.bump("rows_rounds_fallback",
-                                         len(rounds))
                         encoded = [self._encode_round_frame(rc)
                                    for rc in rounds]
                     admitted = [e for e in encoded if e is not None]
@@ -1653,9 +1650,9 @@ class ResidentRowsDocSet(ResidentDocSet):
         whose frontier is one head. ONE change concurrent with another
         device's last write, or one document with two heads, returns
         None for the whole round: the benchmark's `fleet10k-devices.storm`
-        takes the per-round fallback every round (`rows_rounds_fallback`;
-        `rows_changes_admitted_general` counts what its general path
-        took), the other cells never. One
+        takes the per-round fallback every round (`rows_rounds_batched`
+        does not move; `rows_changes_admitted_general` counts what its
+        general path took), the other cells never. One
         classification over the concatenated frame columns, one batched
         clock-row construction, ONE native encode call for all rounds;
         per-change Python shrinks to the state-clock memo + change-log
@@ -2142,7 +2139,6 @@ class ResidentRowsDocSet(ResidentDocSet):
             blocks_dev = self._to_dev(np.asarray(route.blocks, np.int32))
             h_prev = self._h_prev
             metrics.bump("rows_apply_block_calls")
-            metrics.bump("rows_apply_blocks", nb)
         else:
             docs_axis = (len(self.doc_ids), self.n_pad)
             blocks_dev = h_prev = None
@@ -2243,11 +2239,11 @@ class ResidentRowsDocSet(ResidentDocSet):
         if idxs is None:
             return self._read_back_all(h, cached=False)
         flightrec.record("rows_hash_readback", docs=len(idxs), cached=False)
-        with perfscope.phase("readback"):
+        with perfscope.phase("readback"):   # the copy and its write
             vals = self._to_host(h)
-        self._ensure_hash_mirror()[np.asarray(idxs, np.int64)] = \
-            vals[:len(idxs)]
-        self._doc_dirty.difference_update(idxs)
+            self._ensure_hash_mirror()[np.asarray(idxs, np.int64)] = \
+                vals[:len(idxs)]
+            self._doc_dirty.difference_update(idxs)
         if not self._dev_current and not self.lazy_dispatch:
             self._prime()
 
@@ -2260,7 +2256,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                          cached=cached)
         with perfscope.phase("readback"):
             vals = self._to_host(h)
-        self._adopt_full_hashes(vals)
+            self._adopt_full_hashes(vals)
         self._hash_handle = None   # consumed into the mirror
 
     def _mega_doc_sizes(self, idxs):

@@ -186,8 +186,6 @@ class EpochIngestBuffer:
         with s.lock:
             s.entries.append(entry)
             s.doc_counts[doc_id] = s.doc_counts.get(doc_id, 0) + 1
-        # (sync_ops_buffered is bumped in bulk at seal time — a per-
-        # append metrics-lock crossing would dominate the append itself)
         return ticket
 
     # -- read-side visibility ------------------------------------------------
@@ -248,8 +246,6 @@ class EpochIngestBuffer:
         finally:
             for s in reversed(self._stripes):
                 s.lock.release()
-        if out:
-            metrics.bump("sync_epochs_sealed")
         return out
 
     @staticmethod

@@ -52,13 +52,16 @@ def request_span(tags: dict | None, **labels):
     and `ops`; a batch knows them only at its exit and sets them on the
     span this yields. Where the thread is already inside a request (a
     shard's batch under the sharded service's) it yields None and opens
-    nothing."""
+    nothing. The root is an outermost served span (perfscope.served):
+    what its phases leave is counted as `unnamed`, its thread's CPU time
+    beside its wall time."""
     if getattr(_request_local, "open", False):
         yield None
         return
     _request_local.open = True
     try:
-        with metrics.trace("sync_request", tags=tags, **labels) as span:
+        with perfscope.served(), \
+                metrics.trace("sync_request", tags=tags, **labels) as span:
             yield span
     finally:
         _request_local.open = False
@@ -934,16 +937,17 @@ class EngineDocSet:
         """`part()` gives the ingress as a pending part, called inside the
         `admit` phase. Inside this thread's batch it may be a ChangesPart
         (native/wire.py: apply_changes' Change objects, checked and kept
-        unconverted), and admission is the bookkeeping alone, under one
-        phase entry a call: the flush converts the round once, inside
-        `encode`. Outside a batch it gives wire columns: converting one
+        unconverted), and admission is the bookkeeping alone, inside the
+        batch's one `admit` entry: the flush converts the round once,
+        inside `encode`. Outside a batch it gives wire columns: converting one
         ingress of Change objects there is admission time, and the epoch
         buffer's contract is stated in columns."""
         if self._batch_owner == threading.get_ident():
-            # inside this thread's batch(): the batch is the request, and
-            # its exit the flush and the drain (which defers while the
-            # batch is open), so the whole call is admission
-            with perfscope.phase("admit"), self._lock:
+            # inside this thread's batch(): the batch is the request and
+            # its body the admission, timed by the batch's one `admit`
+            # entry; its exit is the flush and the drain (which defers
+            # while the batch is open)
+            with self._lock:
                 self._pend_locked(doc_id, part())
                 return self.get_doc(doc_id)
         with perfscope.phase("admit"):
@@ -1095,10 +1099,6 @@ class EngineDocSet:
         # trace plane: stamp-only under self._lock (recording defers to
         # _drain_lag_records, exactly like the oplag tokens above)
         tracer.sealed(sealed_docs)
-        if n_ops:
-            # bulk-counted here (one metrics-lock crossing per seal, and
-            # in OPS — the registered unit — not buffered entries)
-            metrics.bump("sync_ops_buffered", int(n_ops))
         flightrec.record("epoch_seal", shard=self._shard,
                          entries=len(tickets), ops=int(n_ops))
         return tickets
@@ -1234,28 +1234,36 @@ class EngineDocSet:
         if not self._pending:
             return
         labels = self._metric_labels()
-        _n_docs, n_ops = size or self._pending_size()
-        self._round_seq += 1
-        round_no = self._round_seq
-        flightrec.record("round_flush", shard=self._shard, round=round_no,
-                         docs=len(self._pending), ops=int(n_ops))
-        # sampled op-lifecycle tokens riding this round (utils/oplag.py):
-        # taken out NOW so a failing flush drops rather than re-times them
-        toks, self._lag_pending = self._lag_pending, []
-        round_docs = (frozenset(self._pending)
-                      if oplag.enabled() or tracer.enabled() else None)
-        phases0 = perfscope.phase_totals() if toks else None
+        with perfscope.phase("publish"):
+            _n_docs, n_ops = size or self._pending_size()
+            self._round_seq += 1
+            round_no = self._round_seq
+            flightrec.record("round_flush", shard=self._shard,
+                             round=round_no, docs=len(self._pending),
+                             ops=int(n_ops))
+            # sampled op-lifecycle tokens riding this round
+            # (utils/oplag.py): taken out NOW so a failing flush drops
+            # rather than re-times them
+            toks, self._lag_pending = self._lag_pending, []
+            round_docs = (frozenset(self._pending)
+                          if oplag.enabled() or tracer.enabled() else None)
+            phases0 = perfscope.phase_totals() if toks else None
         t0 = _time.perf_counter()
         tags = {"round": round_no}
         if riders is not None:
             tags["riders"] = riders
-        with metrics.trace("sync_round_flush", tags=tags, **labels), \
-                dispatchledger.round_scope(
-                    len(self._pending),
-                    label=(f"shard{self._shard}"
-                           if self._shard is not None else None),
-                    tenants=tenantledger.round_tenants(self._pending)):
+        # the dispatch ledger's split by tenant comes from the tail's one
+        # fold (_flush_pending_inner_locked)
+        with perfscope.served(), \
+                metrics.trace("sync_round_flush", tags=tags, **labels), \
+                contextlib.ExitStack() as scope:
+            scope.enter_context(dispatchledger.round_scope(
+                len(self._pending),
+                label=(f"shard{self._shard}"
+                       if self._shard is not None else None)))
             self._flush_pending_locked(n_ops)
+            with perfscope.phase("publish"):
+                scope.close()       # the dispatch ledger's round fold
         if round_docs is not None:
             deltas = None
             if toks:
@@ -1299,8 +1307,10 @@ class EngineDocSet:
         # reports changed, costing at most spurious idempotent gossip.
         # The rebuild path that needs exact restores does not use
         # _changed: it restores the whole round via admission_complete.
-        pre_gen = getattr(rset, "_rebuild_gen", 0)
-        pre = {d: len(rset.change_log[rset.doc_index[d]]) for d in pending}
+        with perfscope.phase("publish"):
+            pre_gen = getattr(rset, "_rebuild_gen", 0)
+            pre = {d: len(rset.change_log[rset.doc_index[d]])
+                   for d in pending}
 
         def _changed(d):
             if getattr(rset, "_rebuild_gen", 0) != pre_gen:
@@ -1421,7 +1431,12 @@ class EngineDocSet:
             admitted = list(counts)
             if self.doc_ledger is not None:
                 self.doc_ledger.note_admit_round(counts)
-            tenantledger.note_ingress_round(counts)
+            # ONE fold by tenant a round: the tenant ledger's ingress, and
+            # its documents by tenant are the dispatch ledger's split of
+            # the round's cost (a document that admitted nothing dirtied
+            # no lane)
+            dispatchledger.note_round_tenants(
+                tenantledger.note_ingress_round(counts))
             if self.handlers:
                 # no registered handlers -> no notifications to queue: the
                 # post-flush drain then needs no service-lock reacquisition
@@ -1582,7 +1597,9 @@ class EngineDocSet:
         keeps its Change objects as they came (checked at the call, where
         what cannot be encoded still raises); the exit turns the round's
         changes into its frame in one pass, so they are read THERE and
-        must not be mutated before the block returns. Generational GC
+        must not be mutated before the block returns. The outermost
+        batch on a thread is the request (`sync_request`) and its body
+        one `admit` phase. Generational GC
         pauses for the whole block INCLUDING the exit flush
         (utils.gcpause — refcounted, so concurrent nodes cannot re-enable
         each other mid-burst): a burst of small ingress allocations would
@@ -1594,7 +1611,13 @@ class EngineDocSet:
         def _cm():
             with request_span(None, **self._metric_labels()) as span:
                 try:
-                    with self._lock, gc_paused():
+                    with self._lock, gc_paused(), \
+                            contextlib.ExitStack() as admit:
+                        if span is not None:
+                            # the batch is the request and its body the
+                            # admission: ONE `admit` entry for the body and
+                            # the tally of its round, not one a call
+                            admit.enter_context(perfscope.phase("admit"))
                         prev_owner = self._batch_owner
                         self._batch_owner = threading.get_ident()
                         self._batch_depth += 1
@@ -1605,6 +1628,7 @@ class EngineDocSet:
                             self._batch_owner = prev_owner
                             if not self._batch_depth:
                                 size = self._pending_size()
+                                admit.close()
                                 if span is not None:
                                     span.tags = dict(zip(("docs", "ops"),
                                                          size))

@@ -178,7 +178,6 @@ class ShardedEngineDocSet:
         metrics.observe("sync_shard_fanout_seconds",
                         _time.perf_counter() - t0)
         metrics.bump("sync_shard_fanout_rounds")
-        metrics.bump("sync_shard_fanout_shards", sum(1 for d in docs if d))
         metrics.bump("sync_shard_round_docs", sum(docs))
         metrics.bump("sync_shard_round_docs_fullest", max(docs))
 
@@ -200,16 +199,22 @@ class ShardedEngineDocSet:
             # (and so flushed) the shards.
             with request_span(None) as span, \
                     contextlib.ExitStack() as fanout, \
-                    contextlib.ExitStack() as stack:
+                    contextlib.ExitStack() as stack, \
+                    contextlib.ExitStack() as admit:
                 for s in self.shards:
                     stack.enter_context(s.batch())
                 outermost = self.shards[0]._batch_depth == 1
+                if span is not None:
+                    # the body is the request's admission, one `admit`
+                    # (the shards' batches open none inside the request)
+                    admit.enter_context(perfscope.phase("admit"))
                 try:
                     yield self
                 finally:
                     if span is not None or outermost:
                         sizes = [s._pending_size() for s in self.shards]
                         docs = [d for d, _ in sizes]
+                    admit.close()
                     if span is not None:
                         span.tags = {"docs": sum(docs),
                                      "ops": sum(o for _, o in sizes),

@@ -24,11 +24,10 @@ routing). Hooks feed it:
   doc ledger's `note_admit_round` in `_flush_pending_inner_locked`: the
   round's documents folded by tenant, one write a tenant under one
   lock), the classic backend a document (`note_ingress`, a round of
-  one) — and hands each coalesced flush round's per-tenant dirty-doc
-  counts to the
-  dispatch ledger (`round_tenants`), whose round fold forwards the
-  round's **dispatch/padding shares** here (`note_round`, attributed
-  proportionally by dirty-doc count);
+  one) — and hands that one fold's documents by tenant (its return
+  value) to the dispatch ledger (`note_round_tenants`), whose round fold
+  forwards the round's **dispatch/padding shares** here (`note_round`,
+  attributed proportionally by document count);
 - `sync/docledger.py` forwards its wire lanes (`note_wire` — bytes,
   useful-vs-duplicate deliveries, drops) and converge-lag restamps
   (`note_lag`), so the per-doc plane's lanes carry a tenant label;
@@ -233,15 +232,19 @@ class TenantLedger:
     def note_ingress(self, doc_id: str, n_changes: int) -> None:
         self.note_ingress_round({doc_id: n_changes})
 
-    def note_ingress_round(self, counts: dict) -> None:
+    def note_ingress_round(self, counts: dict) -> dict | None:
         """A flush admitted `counts[doc]` changes for each of a round's
         docs: folded by tenant, then one write a tenant under one lock
         and one stamp, leaving what one call a doc would (`admit_events`
         counts the docs; a doc with no change is passed over). The gauges
         are refreshed once, after the writes, when the round's mutations
-        cross a multiple of GAUGE_REFRESH."""
+        cross a multiple of GAUGE_REFRESH. Returns the fold's documents
+        by tenant, the round's one fold by tenant: the service hands it
+        to the dispatch ledger's split of the round's cost
+        (dispatchledger.note_round_tenants). None when the plane is off
+        or nothing was admitted."""
         if not enabled():
-            return
+            return None
         t0 = time.perf_counter()
         fold: dict[str, list] = {}
         for d, n in counts.items():
@@ -255,7 +258,7 @@ class TenantLedger:
                 acc[0] += int(n)
                 acc[1] += 1
         if not fold:
-            return
+            return None
         now = time.time()
         with self._lock:
             events = 0
@@ -268,6 +271,7 @@ class TenantLedger:
                 events += docs
             self._mutated_locked(events)
             self._self_s += time.perf_counter() - t0
+        return {tid: docs for tid, (_n, docs) in fold.items()}
 
     def note_wire(self, doc_id: str, sent: int = 0, bytes_sent: int = 0,
                   useful: int = 0, dup: int = 0, bytes_recv: int = 0,
@@ -380,12 +384,6 @@ class TenantLedger:
             self._wall_total_s += wall
             self._self_s += time.perf_counter() - t0
 
-    def add_self(self, seconds: float) -> None:
-        """Fold externally measured bookkeeping (round_tenants) into the
-        self-time account the duty-cycle gate bounds."""
-        with self._lock:
-            self._self_s += seconds
-
     # -- export --------------------------------------------------------------
 
     def self_seconds(self) -> float:
@@ -481,8 +479,8 @@ def note_ingress(doc_id: str, n_changes: int) -> None:
     _ledger.note_ingress(doc_id, n_changes)
 
 
-def note_ingress_round(counts: dict) -> None:
-    _ledger.note_ingress_round(counts)
+def note_ingress_round(counts: dict) -> dict | None:
+    return _ledger.note_ingress_round(counts)
 
 
 def note_wire(doc_id: str, **kw) -> None:
@@ -500,22 +498,6 @@ def note_shed(doc_id: str, delayed: bool, delay_s: float = 0.0) -> None:
 def note_round(tenant_docs: dict, folded: dict,
                label: str | None = None) -> None:
     _ledger.note_round(tenant_docs, folded, label=label)
-
-
-def round_tenants(doc_ids) -> dict | None:
-    """Per-tenant dirty-doc counts for one flush round's pending set —
-    what sync/service.py hands to dispatchledger.round_scope(tenants=).
-    None when the plane is disabled, so the dispatch ledger's folded
-    rounds stay byte-identical with tenancy off."""
-    if not enabled():
-        return None
-    t0 = time.perf_counter()
-    out: dict[str, int] = {}
-    for d in doc_ids:
-        tid = tenant_of(d)
-        out[tid] = out.get(tid, 0) + 1
-    _ledger.add_self(time.perf_counter() - t0)
-    return out
 
 
 # ---------------------------------------------------------------------------

@@ -160,7 +160,6 @@ COUNTERS: dict[str, str] = {
         "would have exceeded the classic gather)",
     # rows — docs-minor streaming engine
     "rows_rounds_batched": "round frames through the vectorized admission",
-    "rows_rounds_fallback": "round frames through the per-round fallback",
     "rows_changes_admitted":
         "changes a round-frame apply admitted (one bump a round, "
         "resident_rows._dispatch_round_frames)",
@@ -183,17 +182,12 @@ COUNTERS: dict[str, str] = {
     "rows_apply_block_calls":
         "classic-route applies that reconciled only the dirty 128-lane "
         "blocks of the resident rows (resident_rows._apply_final)",
-    "rows_apply_blocks": "128-lane blocks those applies reconciled",
     "rows_lane_gathers_device":
         "lane reconciles whose columns were gathered on the device, out "
         "of the resident rows (resident_rows._reconcile_lanes)",
     "rows_lane_gathers_host":
         "lane reconciles whose columns were gathered out of the host "
         "mirror and uploaded (the device copy was not current)",
-    "rows_rounds_overlapped":
-        "rounds whose hashes were collected by a call of their own "
-        "(resident_rows.collect_round), after the caller's own work "
-        "behind the dispatch, not inside it",
     # sync — services, wire protocol, transports, log archive
     "sync_frames_sent": "columnar change frames sent",
     "sync_frames_received": "columnar change frames received",
@@ -204,6 +198,18 @@ COUNTERS: dict[str, str] = {
     "sync_wire_bytes_sent": "framed bytes written to a TCP transport",
     "sync_wire_bytes_received": "framed bytes read from a TCP transport",
     "sync_ops_ingested": "ops admitted through service round flushes",
+    # the serving thread, counted at the exit of each outermost served
+    # span (perfscope.served: `sync_request` on a caller's thread,
+    # `sync_round_flush` on the flusher's)
+    "sync_serve_cpu_us":
+        "CPU microseconds (user + system) of the serving thread inside "
+        "its outermost served spans (getrusage RUSAGE_THREAD)",
+    "sync_serve_busy_us":
+        "wall microseconds of the outermost served spans less their "
+        "declared waits (phases device_wait and commit_wait)",
+    "sync_serve_preempted":
+        "involuntary context switches of the serving thread inside its "
+        "outermost served spans",
     "sync_rounds_flushed": "coalesced service round flushes",
     "sync_rounds_direct_frame":
         "flushed rounds whose frame came from one changes_to_columns "
@@ -212,19 +218,12 @@ COUNTERS: dict[str, str] = {
     # the sharded service's fan-out (sync/sharded_service.py): one round
     # = the exit of an outermost batch(), or a flush()
     "sync_shard_fanout_rounds": "fan-outs of the sharded service",
-    "sync_shard_fanout_shards":
-        "shards that had pending work at a fan-out, summed over fan-outs",
     "sync_shard_round_docs":
         "documents pending over all shards at a fan-out, summed",
     "sync_shard_round_docs_fullest":
         "documents pending on the fullest shard at a fan-out, summed",
     # epoch-batched ingestion (sync/epochs.py): the lock-free admission
     # path and its snapshot read plane (sync/service.py)
-    "sync_ops_buffered":
-        "ingress ops appended to the epoch ingestion buffer "
-        "(sync/epochs.py; no service lock on this path)",
-    "sync_epochs_sealed":
-        "ingestion epochs sealed into coalesced rounds (sync/epochs.py)",
     "sync_reads_cached":
         "clock_of/missing_changes served lock-free from the per-doc "
         "snapshot read cache (sync/service.py)",
@@ -385,6 +384,10 @@ COUNTERS: dict[str, str] = {
         "aggregate bucket (sync/docledger.py; bounded-memory policy)",
     # obs — the observability subsystem's own signals
     "obs_watchdog_fired": "watchdog budget overruns {name=...}",
+    "obs_gc_collections":
+        "cyclic-collector runs since reset {generation=0|1|2}, over every "
+        "thread (perfscope's gc.callbacks hook; its seconds are the "
+        "perf section's phase `gc`)",
     "obs_budget_exceeded": "trace(budget_s=...) post-hoc overruns {name=...}",
     "obs_flightrec_dumps": "flight-recorder post-mortem dumps {reason=...}",
     # fleet health plane (perf/fleet.py, perf/slo.py, utils/chaos.py)
@@ -1001,16 +1004,29 @@ def snapshot() -> dict:
     """Flat metrics view plus — when the performance plane has recorded
     anything since the last reset — a nested `"perf"` section
     (utils/perfscope.py: per-kernel compile telemetry, phase rollup,
-    memory footprint). The perf attach happens OUTSIDE the metrics lock:
-    perfscope has its own lock and the two must never nest."""
+    memory footprint) and the collector's runs by generation
+    (`obs_gc_collections`). The perf attach happens OUTSIDE the metrics
+    lock: perfscope has its own lock and the two must never nest. The
+    read holds a profiler annotation, `metrics_snapshot`, so an exporter
+    (the benchmark's driver reads one after every request) stands on the
+    device trace's clock as itself."""
+    from . import perfscope
+    with perfscope.annotate("metrics_snapshot"):
+        return _snapshot(perfscope)
+
+
+def _snapshot(perfscope) -> dict:
     out = _global.snapshot()
     try:
-        from . import perfscope
         perf = perfscope.perf_snapshot()
+        gens = perfscope.gc_collections()
     except Exception:
-        perf = None
+        perf, gens = None, ()
     if perf:
         out["perf"] = perf
+    for g, n in enumerate(gens):
+        if n:
+            out[f"obs_gc_collections{{generation={g}}}"] = n
     try:    # the op-lifecycle lag percentiles (same nested-section rule)
         from . import oplag
         lag = oplag.lag_snapshot()
@@ -1424,23 +1440,23 @@ def dispatch_jit(kernel: str, fn, *args, **kwargs):
     ring, so a post-mortem dump shows the last kernels every thread
     pushed at the device before the hang."""
     from . import perfscope
-    marker = perfscope.dispatch_begin(kernel, fn, args, kwargs)
-    try:
-        with perfscope.phase("dispatch"):
+    with perfscope.phase("dispatch"):   # the bookkeeping is the dispatch's
+        marker = perfscope.dispatch_begin(kernel, fn, args, kwargs)
+        try:
             return fn(*args, **kwargs)
-    finally:
-        retraced = perfscope.dispatch_end(marker)
-        bump("engine_kernels_dispatched", kernel=kernel)
-        if retraced:
-            bump("engine_kernels_retraced", kernel=kernel)
-        try:
-            from . import flightrec
-            flightrec.record("dispatch", kernel=kernel,
-                             **({"retraced": True} if retraced else {}))
-        except Exception:
-            pass
-        try:
-            from ..engine import dispatchledger
-            dispatchledger.note_jit(kernel, retraced)
-        except Exception:
-            pass
+        finally:
+            retraced = perfscope.dispatch_end(marker)
+            bump("engine_kernels_dispatched", kernel=kernel)
+            if retraced:
+                bump("engine_kernels_retraced", kernel=kernel)
+            try:
+                from . import flightrec
+                flightrec.record("dispatch", kernel=kernel,
+                                 **({"retraced": True} if retraced else {}))
+            except Exception:
+                pass
+            try:
+                from ..engine import dispatchledger
+                dispatchledger.note_jit(kernel, retraced)
+            except Exception:
+                pass

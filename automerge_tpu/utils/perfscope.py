@@ -31,7 +31,12 @@ be checkable run over run:
   the `metrics.trace` spans `sync_request`, `sync_round_flush` and
   `rows_round_apply` are their parents and carry the request's id. Phase
   names are lint-enforced (the graftlint registry pass) the same way
-  metric names are.
+  metric names are. Beside the phases, two accumulators under the same
+  `phase.<name>` key: `gc`, every collection of the cyclic collector on
+  the thread that runs it (a `gc.callbacks` hook, with its own
+  annotation, nested in whatever phase it interrupts), and `unnamed`,
+  what the phases leave of an outermost served span (`served`), where
+  the serving thread's CPU time is read beside its wall time.
 - **memory gauges** — a throttled `jax.live_arrays()` sample maintains
   the live-array footprint and its high-water mark
   (`obs_live_arrays_bytes` / `obs_live_arrays_peak_bytes`); the engines
@@ -56,18 +61,22 @@ stays on in every mode.
 Locking discipline: the store lock guards only dict arithmetic. Metric
 emission, jax calls, and the AOT analysis all run outside it, so this
 module adds no lock-order edge against the metrics store (the
-lock-discipline pass scans utils/). A phase exit takes no lock at all:
-each thread accumulates into a dict of its own, and the readers
-(`phase_totals()`, `perf_snapshot()`, `reset()`) merge them.
+lock-discipline pass scans utils/). A phase exit and the gc hook take no
+lock at all: each thread accumulates into a slot of its own, registered
+by an atomic append, and the readers (`phase_totals()`,
+`perf_snapshot()`, `gc_collections()`, `reset()`) merge them.
 """
 
 from __future__ import annotations
 
 import functools
+import gc
 import logging
 import os
+import resource
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 
 import jax.profiler
@@ -81,11 +90,14 @@ log = logging.getLogger("automerge_tpu.perfscope")
 PHASES: dict[str, str] = {
     "admit": "one ingress of the service up to its place in the pending "
              "round or the epoch buffer: wire columns, ghost check, append "
-             "(sync/service.py); not the flush, not the park",
+             "(sync/service.py); inside a batch() the whole body and the "
+             "tally of its round, one entry a batch; not the flush, not "
+             "the park",
     "commit_wait": "a caller parked on its epoch ticket until the flush "
                    "that carried its entry resolves it (sync/epochs.py)",
-    "encode": "round-frame decode, actor registration, budget precheck and "
-              "the native delta encode "
+    "encode": "round-frame decode, actor registration, budget precheck, "
+              "the admission guard's snapshot of log lengths and the "
+              "native delta encode "
               "(resident_rows._dispatch_round_frames)",
     "commit": "the encoded round committed to the host row mirror: growth, "
               "scatter triplets, dirty marks, dedup and padding "
@@ -98,15 +110,20 @@ PHASES: dict[str, str] = {
             "the gather of dirty lanes from the host row mirror, or the "
             "plan of their gather on the device (_reconcile_lanes)",
     "upload": "host->device transfers of the resident engines (_to_dev)",
-    "dispatch": "jitted kernel dispatch calls (metrics.dispatch_jit)",
+    "dispatch": "jitted kernel dispatch calls with their bookkeeping: "
+                "signature check, compile attribution, counters, flight "
+                "record (metrics.dispatch_jit)",
     "device_wait": "explicit host barriers on in-flight device work "
                    "(block_until_ready), inside `readback` on the rows path",
     "readback": "device->host readbacks (hash reads, the trusted barrier)",
-    "publish": "the service's tail: behind the engine's dispatch half "
-               "admission scans, ledgers, read versions, notify queue, "
-               "archive trigger; behind its collect half (`readback`) the "
-               "round's counters, ticket resolve, handler gossip "
-               "(sync/service.py)",
+    "publish": "the service's bookkeeping around the engine: before it "
+               "the round's flight record and the log lengths the "
+               "admission scan reads back; behind the engine's dispatch "
+               "half admission scans, ONE fold by tenant, ledgers, read "
+               "versions, notify queue, archive trigger; behind its "
+               "collect half (`readback`) the round's counters, ticket "
+               "resolve, the dispatch ledger's round fold; after the "
+               "lock, handler gossip (sync/service.py)",
     "host_materialize": "interpretive apply + snapshot materialization "
                         "(frontend/materialize.py)",
     "sync_wire": "wire encode/decode of sync frames (sync/frames.py)",
@@ -119,7 +136,21 @@ PHASES: dict[str, str] = {
                     "(sync/sharded_service.py)",
     "span_merge": "span-granularity text-merge placement: run placement "
                   "walks + ElemList splices (core/textspans.py)",
+    # not phase() sites: accumulators kept beside the phases, under the
+    # same `phase.<name>` key of the perf section
+    "gc": "the cyclic collector, every collection on the thread that "
+          "runs it (a gc.callbacks hook, installed at import): nested in "
+          "whatever phase it interrupts, as device_wait in readback",
+    "unnamed": "what the phases leave of an outermost served span "
+               "(`sync_request` on a caller's thread, `sync_round_flush` "
+               "on the flusher's): its duration minus the phases on its "
+               "thread inside it, minus the collector outside any phase",
 }
+
+#: phases that hold others on the served path (the shards' flushes, the
+#: shards' hash reads): their time is a parent's, like a span's, and the
+#: phases inside them are the partition
+CONTAINERS = frozenset({"shard_fanout", "fleet_hashes"})
 
 #: seconds between jax.live_arrays() footprint samples (the walk is
 #: O(live arrays); dispatch sites sample opportunistically)
@@ -165,11 +196,11 @@ class _Store:
     def __init__(self):
         self.lock = threading.Lock()
         self.kernels: dict[str, _KernelStats] = {}
-        # phase accumulators, name -> [seconds, count]: one dict a live
-        # thread (written by its owner alone, without this lock) and the
-        # folded totals of the threads that have exited
-        self.thread_phases: list[tuple[threading.Thread, dict]] = []
+        # one _Slot a live thread (written by its owner alone, without
+        # this lock) and the folded totals of the threads that have exited
+        self.slots: list = []
         self.retired_phases: dict[str, list] = {}
+        self.retired_gc_gens = [0, 0, 0]
         self.live_bytes = 0
         self.live_peak = 0
         self._last_live = 0.0
@@ -454,6 +485,44 @@ def _analyze(kernel: str, fn, args: tuple, kwargs: dict, marker) -> None:
 # phase attribution
 
 
+class _Slot:
+    """One thread's accumulators, written by that thread alone and with
+    no lock (the gc hook runs inside any allocation, so it may take
+    none); the readers merge them under the store lock. `acc` is the
+    phases' {name: [seconds, count]}; `depth` counts the open phases
+    that are not containers, and `top_s` the seconds of the outermost
+    ones: what the phases cover of this thread's time. The collector's
+    slots are apart from `acc`, which a phase exit may be updating when
+    a collection starts."""
+
+    __slots__ = ("thread", "acc", "depth", "top_s", "serving", "gc_s",
+                 "gc_out_s", "gc_gens", "gc_t0", "gc_note")
+
+    def __init__(self, thread):
+        self.thread = thread
+        self.acc: dict = {}
+        self.depth = 0
+        self.top_s = 0.0
+        self.serving = 0
+        self.gc_s = 0.0
+        self.gc_out_s = 0.0        # the collector outside any phase
+        self.gc_gens = [0, 0, 0]   # collections by generation
+        self.gc_t0 = 0.0
+        self.gc_note = None
+
+
+# slots of threads not yet seen by a reader: appended without a lock (a
+# deque's append is atomic), moved into the store by the next reader
+_fresh: deque = deque()
+
+
+def _thread_slot() -> _Slot:
+    slot = _Slot(threading.current_thread())
+    _tls.slot = slot
+    _fresh.append(slot)
+    return slot
+
+
 def _fold(into: dict, acc: dict) -> None:
     # list(): the owner may insert a name while this runs
     for name, (s, c) in list(acc.items()):
@@ -465,76 +534,205 @@ def _fold(into: dict, acc: dict) -> None:
             e[1] += c
 
 
-def _thread_phases() -> dict:
-    """Register and return the calling thread's accumulator (its first
-    phase exit). Accumulators of threads that have exited are folded
-    into the retired totals here, so respawning flusher threads do not
-    grow the registry."""
-    acc: dict = {}
-    with _store.lock:
-        live = []
-        for t, a in _store.thread_phases:
-            if t.is_alive():
-                live.append((t, a))
-            else:
-                _fold(_store.retired_phases, a)
-        live.append((threading.current_thread(), acc))
-        _store.thread_phases = live
-    _tls.phases = acc
-    return acc
+def _live_slots() -> list:
+    """The slots of live threads; those of exited threads are folded
+    into the retired totals, so respawning flusher threads do not grow
+    the registry. Call with the store lock held."""
+    while _fresh:
+        _store.slots.append(_fresh.popleft())
+    live = []
+    for slot in _store.slots:
+        if slot.thread.is_alive():
+            live.append(slot)
+        else:
+            _fold(_store.retired_phases, slot.acc)
+            _fold(_store.retired_phases, _gc_row(slot))
+            for g, n in enumerate(slot.gc_gens):
+                _store.retired_gc_gens[g] += n
+    _store.slots = live
+    return live
+
+
+def _gc_row(slot: _Slot) -> dict:
+    n = sum(slot.gc_gens)
+    return {"gc": [slot.gc_s, n]} if n else {}
 
 
 def _merged_phases() -> dict[str, list]:
-    """name -> [seconds, count] over every thread. Call with the store
-    lock held."""
+    """name -> [seconds, count] over every thread, the collector's
+    accumulator under `gc`. Call with the store lock held."""
+    live = _live_slots()
     out = {n: list(e) for n, e in _store.retired_phases.items()}
-    for _t, acc in _store.thread_phases:
-        _fold(out, acc)
+    for slot in live:
+        _fold(out, slot.acc)
+        _fold(out, _gc_row(slot))
     return out
 
 
-# bound once: a phase entry/exit is a per-admission cost, and each
-# attribute lookup it saves is some 3 % of it
+def gc_collections() -> list[int]:
+    """Collections since the last reset, by generation, over every
+    thread."""
+    with _store.lock:
+        live = _live_slots()
+        gens = list(_store.retired_gc_gens)
+        for slot in live:
+            for g, n in enumerate(slot.gc_gens):
+                gens[g] += n
+    return gens
+
+
+# bound once: a phase entry/exit is a per-call cost, and each attribute
+# lookup it saves is some 3 % of it
 _Annotation = jax.profiler.TraceAnnotation
 _annotation_init = _Annotation.__init__
 _annotation_enter = _Annotation.__enter__
 _annotation_exit = _Annotation.__exit__
 _now = time.perf_counter
 
+#: a profiler annotation alone, no accumulator: a read that should stand
+#: on the device trace's clock under its own name (metrics.snapshot)
+annotate = _Annotation
+
 
 class phase(_Annotation):
     """`with phase(name):` accumulates wall time under one of the
     registered PHASES and holds a `jax.profiler.TraceAnnotation(name)`
     for the same interval, so a phase has the same name in the counters
-    and on the profiler's timeline. Cheap enough for a per-admission
-    site: two perf_counter reads, the annotation (a no-op without a
-    profiler session), one thread-local dict update, no lock. On the
-    served path phases are placed as a partition; elsewhere a nested
-    phase counts in both."""
+    and on the profiler's timeline. Cheap enough for a per-call site:
+    two perf_counter reads, the annotation (a no-op without a profiler
+    session), the thread's slot, no lock. On the served path phases are
+    placed as a partition: a phase opened inside another (`device_wait`
+    in `readback`) counts in both, and only the outermost in the time
+    the phases cover (`top_s`); a CONTAINERS phase counts in neither
+    depth nor cover, so the phases inside it are the partition."""
 
-    __slots__ = ("_name", "_t0")
+    __slots__ = ("_name", "_t0", "_slot", "_nest")
 
     def __init__(self, name: str):
         _annotation_init(self, name)
         self._name = name
+        self._nest = name not in CONTAINERS
 
     def __enter__(self):
         _annotation_enter(self)
+        try:
+            slot = _tls.slot
+        except AttributeError:
+            slot = _thread_slot()
+        self._slot = slot
+        slot.depth += self._nest
         self._t0 = _now()
 
     def __exit__(self, exc_type, exc, tb):
         dt = _now() - self._t0
         _annotation_exit(self, exc_type, exc, tb)
-        try:
-            acc = _tls.phases
-        except AttributeError:
-            acc = _thread_phases()
+        slot = self._slot
+        if self._nest:
+            slot.depth -= 1
+            if not slot.depth:
+                slot.top_s += dt
+        acc = slot.acc
         e = acc.get(self._name)
         if e is None:
             acc[self._name] = [dt, 1]
         else:
             e[0] += dt
             e[1] += 1
+
+
+def _on_gc(stage: str, info: dict) -> None:
+    """gc.callbacks hook: every collection timed on the thread that runs
+    it, under `gc`, and held as a `TraceAnnotation("gc")` for the same
+    interval. Takes no lock and calls nothing that could: it runs inside
+    whatever allocation set it off."""
+    try:
+        slot = _tls.slot
+    except AttributeError:
+        slot = _thread_slot()
+    if stage == "start":
+        note = slot.gc_note = _Annotation("gc")
+        _annotation_enter(note)
+        slot.gc_t0 = _now()
+        return
+    dt = _now() - slot.gc_t0
+    _annotation_exit(slot.gc_note, None, None, None)
+    slot.gc_note = None
+    slot.gc_s += dt
+    slot.gc_gens[info["generation"]] += 1
+    if not slot.depth:
+        slot.gc_out_s += dt
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
+
+
+_getrusage = resource.getrusage
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)  # Linux only
+_WAITS = ("device_wait", "commit_wait")
+
+
+def _waits(acc: dict) -> float:
+    return sum(acc[w][0] for w in _WAITS if w in acc)
+
+
+class served:
+    """`with served():` around an outermost served span (`sync_request`
+    on a caller's thread, `sync_round_flush` on the flusher's; opened
+    inside another served span or inside a phase, it does nothing). At
+    its exit the span's duration minus the phases that ran on its thread
+    inside it, minus the collector outside any phase, goes to `unnamed`;
+    and from one `getrusage(RUSAGE_THREAD)` at each end, the counters
+    `sync_serve_cpu_us` (the thread's CPU time), `sync_serve_busy_us`
+    (the wall time less its declared waits, `device_wait` and
+    `commit_wait`) and `sync_serve_preempted` (involuntary context
+    switches): on the CPU, or off it."""
+
+    __slots__ = ("_slot", "_t0", "_top0", "_gc0", "_wait0", "_ru0")
+
+    def __enter__(self):
+        try:
+            slot = _tls.slot
+        except AttributeError:
+            slot = _thread_slot()
+        self._slot = slot
+        slot.serving += 1
+        if slot.serving > 1 or slot.depth:
+            self._t0 = None
+            return self
+        self._top0 = slot.top_s
+        self._gc0 = slot.gc_out_s
+        self._wait0 = _waits(slot.acc)
+        self._ru0 = (_getrusage(_RUSAGE_THREAD)
+                     if _RUSAGE_THREAD is not None else None)
+        self._t0 = _now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        slot = self._slot
+        slot.serving -= 1
+        if self._t0 is None:
+            return False
+        dt = _now() - self._t0
+        ru1 = _getrusage(_RUSAGE_THREAD) if self._ru0 is not None else None
+        unnamed = dt - (slot.top_s - self._top0) - (slot.gc_out_s - self._gc0)
+        acc = slot.acc
+        e = acc.get("unnamed")
+        if e is None:
+            acc["unnamed"] = [unnamed, 1]
+        else:
+            e[0] += unnamed
+            e[1] += 1
+        from . import metrics
+        metrics.bump("sync_serve_busy_us",
+                     round((dt - _waits(acc) + self._wait0) * 1e6))
+        if ru1 is not None:
+            ru0 = self._ru0
+            metrics.bump("sync_serve_cpu_us", round(
+                (ru1.ru_utime + ru1.ru_stime - ru0.ru_utime - ru0.ru_stime)
+                * 1e6))
+            metrics.bump("sync_serve_preempted", ru1.ru_nivcsw - ru0.ru_nivcsw)
+        return False
 
 
 def phase_totals() -> dict[str, float]:
@@ -652,9 +850,16 @@ def reset() -> None:
             st.lower_s = 0.0
         _store.kernels = {k: st for k, st in _store.kernels.items()
                           if st.signatures}
+        live = _live_slots()    # folds the exited threads first
         _store.retired_phases.clear()
-        for _t, acc in _store.thread_phases:
-            acc.clear()     # in place: the owner keeps writing into it
+        _store.retired_gc_gens = [0, 0, 0]
+        for slot in live:
+            # in place: the owner keeps writing into them. `top_s`,
+            # `gc_out_s` and `depth` stay: a served span open across the
+            # reset reads them as differences
+            slot.acc.clear()
+            slot.gc_s = 0.0
+            slot.gc_gens[:] = [0, 0, 0]
         _store.live_bytes = 0
         _store.live_peak = 0
         _store._last_live = 0.0

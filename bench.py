@@ -711,8 +711,11 @@ def run_fleet_config(n_docs=100_000, n_shards=8, n_rounds=6,
         round_ts_q.append(dt_q)
     gc.unfreeze()
     m1 = metrics.snapshot()
-    flushes = {k: m1.get(k, 0) - m0.get(k, 0)
-               for k in ("rows_rounds_batched", "rows_rounds_fallback")}
+    batched = m1.get("rows_rounds_batched", 0) - m0.get("rows_rounds_batched", 0)
+    flushed = sum(m1.get(k, 0) - m0.get(k, 0) for k in m1
+                  if k.startswith("sync_rounds_flushed"))
+    flushes = {"rows_rounds_batched": batched,
+               "rows_rounds_fallback": flushed - batched}
 
     round_s = statistics.median(round_ts)
     round_s_small = statistics.median(round_ts_q)
@@ -932,7 +935,7 @@ def run_multiwriter_config(writer_counts=(1, 2, 4, 8), ops_per_writer=400,
             return (m1.get(key, 0) or 0) - (m0.get(key, 0) or 0)
 
         n_ops = n_writers * ops_per_writer
-        rounds = delta("rows_rounds_batched") + delta("rows_rounds_fallback")
+        rounds = delta("sync_rounds_flushed")
         out = {
             "mode": ingest_mode,
             "depth": depth,

@@ -140,10 +140,27 @@ class Fleet:
         return handle
 
 
+# the blocks each block-route `apply_final` dispatch was handed
+_BLOCKS: list = []
+
+
+@pytest.fixture(autouse=True)
+def _block_counts(monkeypatch):
+    real = metrics.dispatch_jit
+
+    def dispatch_jit(kernel, fn, *args, **kwargs):
+        if kernel == "apply_final" and args[2] is not None:
+            _BLOCKS.append(len(args[2]))
+        return real(kernel, fn, *args, **kwargs)
+
+    monkeypatch.setattr(metrics, "dispatch_jit", dispatch_jit)
+    yield
+    _BLOCKS.clear()
+
+
 def _counters():
     snap = metrics.snapshot()
-    return (snap.get("rows_apply_block_calls", 0),
-            snap.get("rows_apply_blocks", 0))
+    return (snap.get("rows_apply_block_calls", 0), sum(_BLOCKS))
 
 
 def _gathers():

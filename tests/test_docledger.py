@@ -169,15 +169,18 @@ def test_section_is_pure_and_json_clean_and_resets():
         for s in (1, 2):
             a.apply_changes("d", [_chg("x", s)])
             drain()
-        s1 = metrics.snapshot()
-        s2 = metrics.snapshot()
+        from automerge_tpu.utils.gcpause import gc_paused
+        with gc_paused():   # a collection in between would be counted
+            s1 = metrics.snapshot()
+            s2 = metrics.snapshot()
         assert s1 == s2, "snapshot export must be pure (no wall reads)"
         assert json.loads(json.dumps(s1)) == s1
         nodes = s1["docledger"]["nodes"]
         assert set(nodes) == {"A", "B"}
         assert nodes["B"]["docs"]["d"]["peers"]["A"]["recv_useful"] == 2
-        metrics.reset()
-        assert metrics.snapshot() == {}
+        with gc_paused():
+            metrics.reset()
+            assert metrics.snapshot() == {}
         # a still-live service re-registers on its next mutation
         a.apply_changes("d", [_chg("x", 3)])
         drain()

@@ -89,10 +89,8 @@ def test_concurrent_writers_group_commit_and_converge():
               - m0.get("sync_rounds_flushed", 0))
     total = n_writers * n_ops
     assert 0 < rounds < total, (rounds, total)   # coalescing happened
-    assert (m1.get("sync_epochs_sealed", 0)
-            - m0.get("sync_epochs_sealed", 0)) >= 1
-    assert (m1.get("sync_ops_buffered", 0)
-            - m0.get("sync_ops_buffered", 0)) == total
+    assert (m1.get("sync_ops_ingested", 0)
+            - m0.get("sync_ops_ingested", 0)) == total
     wait_key = "sync_lock_wait_s{lock=service}_sum"
     assert (m1.get(wait_key, 0.0) - m0.get(wait_key, 0.0)) < 0.5
     for w, d in docs.items():
@@ -417,8 +415,7 @@ def test_batch_still_one_round_in_epoch_mode():
         for i in range(5):
             e.apply_changes(f"d{i}", chs(f"W{i}", 1))
     snap = am.metrics.snapshot()
-    assert (snap.get("rows_rounds_batched", 0)
-            + snap.get("rows_rounds_fallback", 0)) == 1, snap
+    assert snap.get("sync_rounds_flushed", 0) == 1, snap
     for i in range(5):
         assert np.uint32(e.hashes()[f"d{i}"]) == oracle_hash(chs(f"W{i}", 1))
     e.close()

@@ -43,10 +43,12 @@ def test_trace_context_manager():
 
 
 def test_reset():
+    from automerge_tpu.utils.gcpause import gc_paused
     metrics.reset()
     am.change(am.init(), lambda d: d.__setitem__("a", 1))
-    metrics.reset()
-    assert metrics.snapshot() == {}
+    with gc_paused():       # a collection in between would be counted
+        metrics.reset()
+        assert metrics.snapshot() == {}
 
 
 # -- structured tracer ------------------------------------------------------

@@ -102,8 +102,10 @@ def test_perf_section_resets_with_metrics():
     k = _fresh_kernel(11)
     metrics.dispatch_jit("pf_reset", k, jnp.arange(4))
     assert "perf" in metrics.snapshot()
-    metrics.reset()
-    assert metrics.snapshot() == {}
+    from automerge_tpu.utils.gcpause import gc_paused
+    with gc_paused():       # a collection in between would be counted
+        metrics.reset()
+        assert metrics.snapshot() == {}
     # a post-reset dispatch still gets its cached analysis rows (the jit
     # cache survives reset; re-lowering+compiling per bench config would
     # double compile cost for nothing)
@@ -218,13 +220,30 @@ def test_phase_totals_merge_threads_and_survive_their_exit():
     assert perfscope.phase_totals()["pack"] > 0
     # exited threads are folded away as later ones register, not kept
     # one dict each: at most the last generation is still listed
-    assert sum(not t.is_alive()
-               for t, _acc in perfscope._store.thread_phases) <= 4
+    assert sum(not s.thread.is_alive()
+               for s in perfscope._store.slots) <= 4
     metrics.reset()
     assert "pack" not in perfscope.phase_totals()
     with perfscope.phase("pack"):       # this thread's dict was cleared in
         pass                            # place and still counts
     assert metrics.snapshot()["perf"]["phases"]["pack"]["count"] == 1
+
+
+def test_a_reset_forgets_threads_that_exited_before_any_read():
+    """An exited thread's slot is folded into the retired totals by the
+    next reader; a reset that is that reader folds it before it clears."""
+    import threading
+
+    def work():
+        with perfscope.phase("pack"):
+            pass
+
+    metrics.reset()
+    t = threading.Thread(target=work)
+    t.start()
+    t.join()
+    metrics.reset()
+    assert "pack" not in perfscope.phase_totals()
 
 
 def test_a_phase_is_a_profiler_annotation_of_its_own_name():

@@ -412,7 +412,6 @@ class TestRoundFrames:
             [encode_round_frame(r) for r in rounds[1:]]))[:len(ids)]
         snap = am.metrics.snapshot()
         assert snap.get("rows_rounds_batched", 0) == 4, snap
-        assert snap.get("rows_rounds_fallback", 0) == 0, snap
         hs = b.apply_rounds(rounds)
         np.testing.assert_array_equal(h, hs[-1])
         a.sync_tables()

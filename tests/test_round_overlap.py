@@ -12,7 +12,7 @@ before it releases anybody. Held here:
     `apply_round_frames` and the plain reference;
 (c) every entry of the engine that reads a hash or can dirty or re-lay a
     lane, called between the halves;
-(d) the counter `rows_rounds_overlapped`.
+(d) the share of flushed rounds whose collect half found them unsettled.
 """
 
 import threading
@@ -59,6 +59,20 @@ def routes(monkeypatch):
 
 def _count(name) -> int:
     return metrics.snapshot().get(name, 0)
+
+
+def _collects(rset, monkeypatch) -> list:
+    """The collects that found a round unsettled, recorded: calls of the
+    engine's `_collect_round` (collect_round makes one exactly then)."""
+    seen: list = []
+    real = rset._collect_round
+
+    def collect(interpret):
+        seen.append(interpret)
+        return real(interpret)
+
+    monkeypatch.setattr(rset, "_collect_round", collect)
+    return seen
 
 
 # -- (a) the order of one round through the service ---------------------------
@@ -286,7 +300,7 @@ def test_dispatch_then_collect_equals_apply_round_frames(
     assert setup(halves) == docs
     del routes[:]
     got = whole.rset.apply_round_frames([whole.frame(docs)])
-    overlapped0 = _count("rows_rounds_overlapped")
+    collects = _collects(halves.rset, monkeypatch)
     halves.rset.dispatch_round_frames([halves.frame(docs)])
     assert [r for r in routes if not r.startswith("read:")] == [kind, kind]
     assert (halves.rset._unsettled is not None) == unsettled
@@ -296,7 +310,7 @@ def test_dispatch_then_collect_equals_apply_round_frames(
             <= halves.rset._doc_dirty
     halves.rset.collect_round()
     assert halves.rset._unsettled is None
-    assert _count("rows_rounds_overlapped") - overlapped0 == int(unsettled)
+    assert len(collects) == int(unsettled)
     want = _oracle(whole.logs)
     if kind == "deferred":
         assert got is None and halves.rset._doc_dirty
@@ -492,7 +506,7 @@ OVERLAP = {
 
 
 @pytest.mark.parametrize("name", sorted(OVERLAP))
-def test_rows_rounds_overlapped_over_rounds_flushed(name, request,
+def test_rounds_collected_apart_over_rounds_flushed(name, request,
                                                     monkeypatch):
     fixtures, drive, flushes, share = OVERLAP[name]
     monkeypatch.setattr(dispatch, "_megabatch", True)
@@ -505,15 +519,14 @@ def test_rows_rounds_overlapped_over_rounds_flushed(name, request,
         for _ in range(40):
             s.svc.apply_changes(s.ids[299], [_edit(s.logs[s.ids[299]])])
         s.svc.hashes()
-    over0, flushed0 = (_count("rows_rounds_overlapped"),
-                       _count("sync_rounds_flushed"))
+    collects = _collects(s.rset, monkeypatch)
+    flushed0 = _count("sync_rounds_flushed")
     fused0 = _count("engine_megabatch_rounds")
     drive(s)
     flushed = _count("sync_rounds_flushed") - flushed0
     assert flushed == flushes
-    assert (_count("rows_rounds_overlapped") - over0) / flushed == share
+    assert len(collects) / flushed == share
     assert (_count("engine_megabatch_rounds") - fused0 > 0) \
         == (name == "fused-rounds")
-    assert "rows_rounds_overlapped" in metrics.COUNTERS
     s.check()
     s.svc.close()

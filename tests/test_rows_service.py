@@ -74,8 +74,7 @@ def test_rows_batch_coalesces_to_one_round():
     snap = am.metrics.snapshot()
     # six ingresses, ONE round applied (batched or per-round is shape-
     # dependent; the coalescing itself is what this asserts)
-    assert (snap.get("rows_rounds_batched", 0)
-            + snap.get("rows_rounds_fallback", 0)) == 1, snap
+    assert snap.get("sync_rounds_flushed", 0) == 1, snap
     for did, doc in docs.items():
         want = oracle_hash(doc._doc.opset.get_missing_changes({}))
         assert np.uint32(e.hashes()[did]) == want

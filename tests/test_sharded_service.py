@@ -39,8 +39,8 @@ def test_burst_coalesces_to_one_dispatch_per_shard():
             e.apply_changes(f"d{i}", chs)
             hashes_want[f"d{i}"] = oracle_hash(chs)
     snap = am.metrics.snapshot()
-    rounds = (snap.get("rows_rounds_batched", 0)
-              + snap.get("rows_rounds_fallback", 0))
+    rounds = sum(v for k, v in snap.items()
+                 if k.startswith("sync_rounds_flushed"))
     # at least one round dispatched AT batch exit (not deferred to the
     # hashes() read below), at most one per shard
     assert 1 <= rounds <= e.n_shards, snap
@@ -150,8 +150,8 @@ def test_mixed_tenant_batch_coalesces_and_attributes_per_shard():
             e.apply_changes(did, chs)
             hashes_want[did] = oracle_hash(chs)
     snap = am.metrics.snapshot()
-    rounds = (snap.get("rows_rounds_batched", 0)
-              + snap.get("rows_rounds_fallback", 0))
+    rounds = sum(v for k, v in snap.items()
+                 if k.startswith("sync_rounds_flushed"))
     assert 1 <= rounds <= e.n_shards, snap
     h = e.hashes()
     for did, want in hashes_want.items():

@@ -70,7 +70,7 @@ def test_disabled_hooks_record_nothing(monkeypatch):
     tenantledger.note_lag("tenant/a/d", 0.5)
     tenantledger.note_shed("tenant/a/d", delayed=False)
     tenantledger.note_round({"a": 1}, {"dispatches": 4})
-    assert tenantledger.round_tenants(["tenant/a/d"]) is None
+    assert tenantledger.note_ingress_round({"tenant/a/d": 2}) is None
     assert tenantledger.ledger().section() is None
     assert tenantledger.snapshot_section() is None
     snap = metrics.snapshot()
@@ -253,10 +253,15 @@ def test_overflow_folds_with_disclosure():
         tenantledger.MAX_TENANTS + 5
 
 
-def test_round_tenants_groups_pending_docs():
-    got = tenantledger.round_tenants(
-        ["tenant/a/1", "tenant/a/2", "tenant/b/1", "plain"])
+def test_the_round_fold_gives_the_dispatch_split_its_documents():
+    """One fold by tenant a round: note_ingress_round returns the
+    admitted documents by tenant, the dispatch ledger's split; a
+    document that admitted nothing dirtied no lane and is not in it."""
+    got = tenantledger.note_ingress_round(
+        {"tenant/a/1": 2, "tenant/a/2": 1, "tenant/b/1": 1, "plain": 3,
+         "tenant/c/1": 0})
     assert got == {"a": 2, "b": 1, "_default": 1}
+    assert tenantledger.note_ingress_round({"tenant/c/1": 0}) is None
 
 
 def test_snapshot_section_rides_metrics_snapshot_and_reset():
