@@ -388,6 +388,14 @@ COUNTERS: dict[str, str] = {
         "cyclic-collector runs since reset {generation=0|1|2}, over every "
         "thread (perfscope's gc.callbacks hook; its seconds are the "
         "perf section's phase `gc`)",
+    "obs_gc_freezes":
+        "pause sections whose survivors were frozen at their last exit: a "
+        "net gen-0 count above the collector's first threshold "
+        "(utils/gcpause.py; gc.collect(1), then gc.freeze())",
+    "obs_gc_full_passes":
+        "full collections over the unfrozen heap (unfreeze, collect, "
+        "freeze), run when the objects frozen since the last one exceed "
+        "what it left (utils/gcpause.py)",
     "obs_budget_exceeded": "trace(budget_s=...) post-hoc overruns {name=...}",
     "obs_flightrec_dumps": "flight-recorder post-mortem dumps {reason=...}",
     # fleet health plane (perf/fleet.py, perf/slo.py, utils/chaos.py)
@@ -461,6 +469,10 @@ GAUGES: dict[str, str] = {
         "shards served from the hash cache on the last fleet hash read",
     "sync_hashes_dirty_shards":
         "shards re-read (dirty since epoch) on the last fleet hash read",
+    "obs_gc_frozen_since_pass":
+        "objects counted into the frozen heap since the last full pass: "
+        "the survivors at each freeze, an overcount (what dies by refcount "
+        "after its freeze stays in it) (utils/gcpause.py)",
     "obs_live_arrays_bytes": "sampled live jax-array footprint (bytes)",
     "obs_live_arrays_peak_bytes":
         "high-water mark of the live jax-array footprint since reset",
