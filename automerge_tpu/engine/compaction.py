@@ -4,8 +4,8 @@ The reference never reclaims history: its OpSet appends forever
 (/root/reference/src/op_set.js:250) and its only compaction analog is a
 save/load round trip (/root/reference/src/automerge.js:223-226) that still
 replays every change. A heap program degrades gradually under that growth;
-the rows engine instead has a hard admission wall — `pack.rows_dims_eligible`
-bounds the megakernel's VMEM working set, so a single long-lived document
+the rows engine instead has a hard admission wall — `pack.rows_dims_fit`
+bounds the reconcile kernels' VMEM working set, so a single long-lived document
 (a year of keystrokes) marches monotonically into a typed budget error.
 Compaction is the TPU-first answer: reclaim row slots whose ops can no
 longer influence ANY future state, so the device working set tracks the
@@ -286,8 +286,9 @@ def compact_doc(rset, i: int, floor: dict[str, int],
                     band[ns] = old[s]
         # fresh RGA positions for every compacted list (ghosts included in
         # the linearization, rank-compressed over the slotted entries)
-        for lrow in rset.ins_log[i]:
-            prow, pval = rset._linearized_pos_rows(i, lrow)
+        if rset.ins_log[i]:
+            _, prow, pval = rset._linearized_pos_rows(
+                (i, lrow) for lrow in rset.ins_log[i])
             col[prow] = pval
         t.max_elems = max(
             (sum(1 for (s, _, _, _) in e if s >= 0)

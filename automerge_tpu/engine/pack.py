@@ -153,14 +153,35 @@ def rows_dims_eligible(i: int, a: int, le: int) -> bool:
             and working <= ROWS_VMEM_BUDGET)
 
 
+def rows_kernel(i: int, a: int, le: int) -> str | None:
+    """The reconcile kernel that takes per-doc dims (ops, actors,
+    list-element slots): "standard" where rows_dims_eligible holds, else
+    "xl" where the doubly blocked variant that `reconcile_rows_hash` falls
+    back to does (pallas_kernels.rows_dims_eligible_xl), else None."""
+    if rows_dims_eligible(i, a, le):
+        return "standard"
+    from .pallas_kernels import rows_dims_eligible_xl
+    return "xl" if rows_dims_eligible_xl(i, a, le) else None
+
+
+def rows_dims_fit(i: int, a: int, le: int) -> bool:
+    """The resident engines' admission budget over per-doc dims: a kernel
+    takes them (rows_kernel), and the XL variant only where the standard
+    kernel would take the op axis alone. So the XL variant stretches the
+    element axis only; history past the standard kernel is compaction's to
+    reclaim (engine/compaction.py), since a longer op band costs every
+    lane."""
+    kernel = rows_kernel(i, a, le)
+    return kernel == "standard" or (
+        kernel == "xl" and le <= ROWS_MAX_ELEMS
+        and rows_dims_eligible(i, a, 0))
+
+
 def rows_eligible(batch: dict, max_fids: int) -> bool:
     d, i = batch["op_mask"].shape
     a = batch["clock"].shape[2]
     l, e = batch["ins_mask"].shape[1:]
-    if rows_dims_eligible(i, a, l * e):
-        return True
-    from .pallas_kernels import rows_dims_eligible_xl
-    return rows_dims_eligible_xl(i, a, l * e)
+    return rows_kernel(i, a, l * e) is not None
 
 
 @perfscope.phased("pack")

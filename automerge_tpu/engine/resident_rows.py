@@ -24,6 +24,7 @@ from-scratch batch path.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import time
 from functools import partial
 
@@ -180,6 +181,8 @@ class ResidentRowsDocSet(ResidentDocSet):
         # [k, 3] (row, lane, value) parts the next scatter carries
         self._lane_trips: list[np.ndarray] = []
         self._gid_memo: dict = {}     # id(frame columns) -> (them, ids)
+        # (lane width, n_pad, dims) the lane route has run at (_warm_lanes)
+        self._lanes_warm: set = set()
         self._rows_ready = True
         self._alloc_rows()
         self.rows_dev = None
@@ -643,39 +646,47 @@ class ResidentRowsDocSet(ResidentDocSet):
 
     def _check_rows_budget(self, cap_ops: int | None = None,
                            le: int | None = None) -> None:
-        from .pack import rows_dims_eligible
+        from .pack import rows_dims_fit
         cap_ops = self.cap_ops if cap_ops is None else cap_ops
         le = self.cap_lists * self.cap_elems if le is None else le
-        if not rows_dims_eligible(cap_ops, self.cap_actors, le):
+        if not rows_dims_fit(cap_ops, self.cap_actors, le):
             raise _budget_error(cap_ops, self.cap_actors, le)
 
-    def _linearized_pos_rows(self, doc_idx: int, lrow: int):
-        """Fresh RGA positions for one touched list from its ins log:
-        (ip-band row indices, positions), both int64 arrays. Ghost entries
-        (compacted-away tombstones, slot == -1) participate in the
-        linearization — they are the ordering basis for their retained
-        descendants — but ship no row; positions are rank-compressed over
-        the slotted entries so they stay dense in [0, cap_elems) (the
-        XLA visible_ranks path scatters by position)."""
-        from ..native.linearize import linearize_host
-        entries = self.ins_log[doc_idx][lrow]
-        n = len(entries)
-        elem = np.fromiter((e for (_, e, _, _) in entries), np.int32, n)
-        arank = np.fromiter((a for (_, _, a, _) in entries), np.int32, n)
-        parent = np.fromiter((p for (_, _, _, p) in entries), np.int32, n)
-        slots = np.fromiter((s for (s, _, _, _) in entries), np.int64, n)
-        pos = np.asarray(
-            linearize_host(np.ones(n, dtype=bool), elem, arank, parent),
-            np.int64)
+    def _linearized_pos_rows(self, lists):
+        """Fresh RGA positions for touched lists, `lists` an iterable of
+        (doc index, list row), from their ins logs in one native call
+        (native.linearize.linearize_lists): (docs, ip-band row indices,
+        positions), int64 arrays. Ghost entries (compacted-away tombstones,
+        slot == -1) participate in the linearization — they are the
+        ordering basis for their retained descendants — but ship no row;
+        positions are rank-compressed over the slotted entries so they stay
+        dense in [0, cap_elems) (the XLA visible_ranks path scatters by
+        position)."""
+        from ..native.linearize import linearize_lists
+        lists = list(lists)
+        logs = [self.ins_log[d][lrow] for d, lrow in lists]
+        lens = np.fromiter(map(len, logs), np.int64, len(logs))
+        starts = np.zeros(len(logs) + 1, np.int64)
+        np.cumsum(lens, out=starts[1:])
+        ent = np.array(list(itertools.chain.from_iterable(logs)),
+                       np.int64).reshape(-1, 4)
+        slots = ent[:, 0]
+        pos = linearize_lists(ent[:, 1], ent[:, 2], ent[:, 3], starts)
+        key = np.array(lists, np.int64).reshape(-1, 2)
+        docs = np.repeat(key[:, 0], lens)
+        lrows = np.repeat(key[:, 1], lens)
         slotted = slots >= 0
         if not slotted.all():
-            k = int(slotted.sum())
-            order = np.argsort(pos[slotted], kind="stable")
-            dense = np.empty(k, np.int64)
-            dense[order] = np.arange(k)
-            pos, slots = dense, slots[slotted]
-        rows = self._bases()["ip"] + lrow * self.cap_elems + slots
-        return rows, pos
+            keep = np.flatnonzero(slotted)
+            owner = np.repeat(np.arange(len(logs)), lens)[keep]
+            order = np.lexsort((pos[keep], owner))
+            dense = np.empty(len(keep), np.int64)
+            dense[order] = (np.arange(len(keep))
+                            - np.searchsorted(owner[order], owner[order]))
+            pos, slots = dense, slots[keep]
+            docs, lrows = docs[keep], lrows[keep]
+        rows = self._bases()["ip"] + lrows * self.cap_elems + slots
+        return docs, rows, pos
 
     def _round_triplets(self, changes_by_doc) -> np.ndarray:
         """Encode one round into (P, 3) int32 scatter triplets
@@ -726,8 +737,9 @@ class ResidentRowsDocSet(ResidentDocSet):
                 put(b["io"] + le, i, self.list_hash[i][lrow])
                 touched_lists.add(lrow)
             # re-linearize touched lists; ship fresh position rows
-            for lrow in touched_lists:
-                prow, pval = self._linearized_pos_rows(i, lrow)
+            if touched_lists:
+                _, prow, pval = self._linearized_pos_rows(
+                    (i, lrow) for lrow in touched_lists)
                 for r, v in zip(prow.tolist(), pval.tolist()):
                     put(r, i, v)
             self.op_count[i] += len(delta.ops)
@@ -1221,13 +1233,18 @@ class ResidentRowsDocSet(ResidentDocSet):
 
         cap_ops = max(self.cap_ops,
                       _pad_to(int(need_ops.max(initial=1))))
-        cap_elems = max(self.cap_elems, _pad_to(
-            self._elems_hi + max(n_elems.values(), default=0)))
-        cap_lists = max(self.cap_lists, _pad_to(
-            self._lists_hi + max(n_lists.values(), default=0), 1))
-        from .pack import rows_dims_eligible
-        if not rows_dims_eligible(cap_ops, self.cap_actors,
-                                  cap_lists * cap_elems):
+        # a document's lists after the round: its own largest and count
+        # now, and at most what its ops add
+        tables = self.tables
+        cap_elems = max(self.cap_elems, _pad_to(max(
+            (tables[i].max_elems + k for i, k in n_elems.items()),
+            default=0)))
+        cap_lists = max(self.cap_lists, _pad_to(max(
+            (tables[i].n_lists + k for i, k in n_lists.items()),
+            default=0), 1))
+        from .pack import rows_dims_fit
+        if not rows_dims_fit(cap_ops, self.cap_actors,
+                             cap_lists * cap_elems):
             raise _budget_error(cap_ops, self.cap_actors,
                                 cap_lists * cap_elems)
 
@@ -1331,29 +1348,34 @@ class ResidentRowsDocSet(ResidentDocSet):
 
         ins = bd.ins_rows
         if len(ins):
-            touched = set()
-            ir, idd, iv = [], [], []
-            for (d, lrow, slot_, elem, arank, parent_slot, fid) in ins:
-                d, lrow, slot_ = int(d), int(lrow), int(slot_)
-                entries = self.ins_log[d].setdefault(lrow, [])
-                s2i = self.ins_idx[d].setdefault(lrow, {})
-                parent = (s2i.get(int(parent_slot), int(parent_slot))
+            t_elem = time.perf_counter()
+            touched = {}
+            io = []
+            ins_log, ins_idx, list_hash = \
+                self.ins_log, self.ins_idx, self.list_hash
+            for (d, lrow, slot_, elem, arank, parent_slot, _fid) \
+                    in ins.tolist():
+                entries = ins_log[d].setdefault(lrow, [])
+                s2i = ins_idx[d].setdefault(lrow, {})
+                parent = (s2i.get(parent_slot, parent_slot)
                           if parent_slot >= 0 else -1)
                 s2i[slot_] = len(entries)
-                entries.append((slot_, int(elem), int(arank), parent))
-                le = lrow * E + slot_
-                ir += [b["im"] + le, b["if"] + le, b["io"] + le]
-                idd += [d, d, d]
-                iv += [1, int(fid), self.list_hash[d][lrow]]
-                touched.add((d, lrow))
-            parts_r.append(np.asarray(ir, np.int64))
-            parts_d.append(np.asarray(idd, np.int64))
-            parts_v.append(np.asarray(iv, np.int64))
-            for (d, lrow) in touched:
-                prow, pval = self._linearized_pos_rows(d, lrow)
-                parts_r.append(prow)
-                parts_d.append(np.full(len(prow), d, np.int64))
-                parts_v.append(pval)
+                entries.append((slot_, elem, arank, parent))
+                io.append(list_hash[d][lrow])
+                touched[(d, lrow)] = None
+            ins = ins.astype(np.int64)
+            le = ins[:, 1] * E + ins[:, 2]
+            for g, v in (("im", np.ones(len(ins), np.int64)),
+                         ("if", ins[:, 6]), ("io", np.asarray(io, np.int64))):
+                parts_r.append(b[g] + le)
+                parts_d.append(ins[:, 0])
+                parts_v.append(v)
+            pdoc, prow, pval = self._linearized_pos_rows(touched)
+            parts_r.append(prow)
+            parts_d.append(pdoc)
+            parts_v.append(pval)
+            metrics.observe("rows_elem_admit_seconds",
+                            time.perf_counter() - t_elem)
 
         if not parts_r:
             return np.zeros((0, 3), np.int32)
@@ -1592,14 +1614,18 @@ class ResidentRowsDocSet(ResidentDocSet):
                 np.add.at(n_lists, op_doc, (acts == l1) | (acts == l2))
 
         cap_ops = max(self.cap_ops, _pad_to(int(need_ops.max(initial=1))))
-        cap_elems = max(self.cap_elems,
-                        _pad_to(self._elems_hi + int(n_elems.max(initial=0))))
-        cap_lists = max(self.cap_lists,
-                        _pad_to(self._lists_hi + int(n_lists.max(initial=0)),
-                                1))
-        from .pack import rows_dims_eligible
-        if not rows_dims_eligible(cap_ops, self.cap_actors,
-                                  cap_lists * cap_elems):
+        # a document's lists after the round: its own largest and count
+        # now, and at most what its ops add
+        tables = self.tables
+        cap_elems = max(self.cap_elems, _pad_to(max(
+            (tables[i].max_elems + int(n_elems[i])
+             for i in np.flatnonzero(n_elems)), default=0)))
+        cap_lists = max(self.cap_lists, _pad_to(max(
+            (tables[i].n_lists + int(n_lists[i])
+             for i in np.flatnonzero(n_lists)), default=0), 1))
+        from .pack import rows_dims_fit
+        if not rows_dims_fit(cap_ops, self.cap_actors,
+                             cap_lists * cap_elems):
             raise _budget_error(cap_ops, self.cap_actors,
                                 cap_lists * cap_elems)
 
@@ -2084,7 +2110,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             # planned without a current copy: nothing to scatter into
             self._drop_copy()
         if route.kind == "fused":
-            round_dispatch.apply_round_adaptive(self, route.plan, interpret)
+            self._fuse(route.plan, interpret)
         else:
             self._reconcile(route, interpret, settle=False)
         if self._unsettled is None:
@@ -2094,6 +2120,43 @@ class ResidentRowsDocSet(ResidentDocSet):
             # the same behind an unsettled round
             self._refresh_hash_mirror(None, interpret)
         return None
+
+    def _fuse(self, plan, interpret):
+        """The fused route: the plan's bucketed dispatches out of the host
+        mirror (dispatch.apply_round_adaptive). An eager engine then keeps
+        the lane route ready for the round a plan declines: where the copy
+        is not current it uploads the mirror, as _settle does after a host
+        gather; where it is (a load's re-layouts have passed), it runs the
+        lane route's two programs once at each lane width it fuses
+        (_warm_lanes). Fused buckets follow the documents' sizes; the lane
+        route has one shape a width, so a fleet whose documents cross a
+        bucket's size, and whose plans then decline, compiles nothing
+        there. Returns the round's summary, or None where nothing was
+        fused."""
+        summary = round_dispatch.apply_round_adaptive(self, plan, interpret)
+        if summary is not None and not self.lazy_dispatch:
+            if self._dev_current:
+                self._warm_lanes(plan.docs, interpret)
+            else:
+                self._prime()
+        return summary
+
+    def _warm_lanes(self, idxs: list[int], interpret) -> None:
+        """Gather `idxs` out of the current copy and reconcile them, as
+        _reconcile_lanes does, the first time the copy's layout meets
+        their lane width; the hashes are not read."""
+        k_pad = pad_to_lanes(len(idxs))
+        key = (k_pad, self.n_pad, self.dims())
+        if key in self._lanes_warm:
+            return
+        self._lanes_warm.add(key)
+        sel = np.asarray(idxs + [idxs[-1]] * (k_pad - len(idxs)), np.int64)
+        plan_dev = self._to_dev(lane_gather_plan(sel, self.n_pad))
+        sub_dev = metrics.dispatch_jit("gather_lanes", gather_lanes,
+                                       self.rows_dev, plan_dev, k_pad,
+                                       interpret)
+        metrics.dispatch_jit("reconcile_rows_hash", reconcile_rows_hash,
+                             sub_dev, self.dims(), interpret)
 
     def _drop_copy(self) -> None:
         """Forget the device copy and what was computed from it; the next
@@ -2197,8 +2260,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         if route.kind == "handle":
             return self._read_back_all(self._hash_handle, cached=True)
         if route.kind == "fused":
-            if round_dispatch.apply_round_adaptive(
-                    self, route.plan, interpret) is not None:
+            if self._fuse(route.plan, interpret) is not None:
                 return
             # None: nothing was fused and the lanes are still dirty
             route = round_dispatch.share_route(self, route.lanes)

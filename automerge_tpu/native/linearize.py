@@ -57,3 +57,26 @@ def linearize_host(ins_mask: np.ndarray, ins_elem: np.ndarray,
         pos += 1
         v = nxt[v]
     return out
+
+
+def linearize_lists(elem: np.ndarray, actor: np.ndarray, parent: np.ndarray,
+                    starts: np.ndarray) -> np.ndarray:
+    """Positions of many lists' elements, each in its own list's RGA order,
+    in one linearize_host call. List k holds entries starts[k]:starts[k+1];
+    an entry's parent indexes its own list's entries (-1: the list's head).
+    Each list gets a head node of its own, keyed below every element and
+    chained behind the previous list's head, so list k's elements are placed
+    between its head and the next head and the lists never interleave."""
+    starts = np.asarray(starts, np.int64)
+    k = len(starts) - 1
+    lens = np.diff(starts)
+    owner = np.repeat(np.arange(k, dtype=np.int64), lens)
+    parent = np.asarray(parent, np.int64)
+    node_parent = np.where(parent >= 0, parent + starts[owner] + k, owner)
+    pos = linearize_host(
+        np.ones(k + len(owner), dtype=bool),
+        np.concatenate([np.iinfo(np.int32).min + np.arange(k),
+                        np.asarray(elem, np.int64)]),
+        np.concatenate([np.zeros(k, np.int64), np.asarray(actor, np.int64)]),
+        np.concatenate([np.arange(-1, k - 1), node_parent]))
+    return pos[k:].astype(np.int64) - pos[owner] - 1

@@ -603,6 +603,11 @@ HISTOGRAMS: dict[str, str] = {
         "a round's actor registration (resident_rows._register_round_"
         "actors over the round's frames), observed once a round, inside "
         "phase encode",
+    "rows_elem_admit_seconds":
+        "a round's element bookkeeping: the ins log, the element bands' "
+        "triplets and the fresh positions of every list it inserted into "
+        "(resident_rows._cols_triplets), observed once a round that "
+        "inserts, inside phase commit",
     "sync_shard_fanout_seconds":
         "one fan-out of the sharded service: the end of a batch()'s body "
         "(or the entry of flush()) to the last shard's return",

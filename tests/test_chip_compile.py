@@ -17,7 +17,10 @@ writers, loads at resident dims (512, 2, 32): `reconcile_rows_hash` over
 after an upload does. `fleet10k-devices`, whose heavy documents have eight
 writers, loads at (512, 8, 32), a working set of 22,248 of the budget's
 22,528 rows: `reconcile_rows_hash` over [8360, 1280], `_apply_final` on the
-[8360, 10112] fleet, and the round's scatter and gather at that height. And
+[8360, 10112] fleet, and the round's scatter and gather at that height.
+`boards10k`, eight devices a board and three lists of 128 element slots,
+loads at (512, 8, 512), past the standard kernel's budget: the XL variant
+over [10760, 1280] and the round's scatter into [10760, 10112]. And
 each compiled program must hold an instruction that the cell's roofline
 metric finds by the patterns of its own file under benchmarks/metrics/: a
 renamed kernel then fails here, and not as `output_malformed` on the chip.
@@ -65,6 +68,9 @@ FLEET_LANES = 10_112           # pad_to_lanes(10,044 documents)
 CAPS = (512, 4, 64)            # the smoke's resident (I, A, LE)
 BENCH_CAPS = (512, 2, 32)      # fleet10k's (its load stage line)
 DEVICES_CAPS = (512, 8, 32)    # fleet10k-devices': eight writers a heavy doc
+# boards10k's: eight devices a board, three lists of 128 element slots (the
+# list axis pads to four): past the standard kernel's budget, the XL variant
+BOARDS_CAPS = (512, 8, 512)
 BENCH_STORM_LANES = 1_280      # a storm request's dirty documents, padded
 SHARD_LANES = 2_560            # pad_to_lanes(a shard's 2,510 or 2,512 documents)
 SHARD_STORM_LANES = (256, 384, 512)   # a quarter of a round: 262-354 documents
@@ -75,6 +81,10 @@ SHARD_TRIP_PADS = (2_048, 4_096)
 # fleet10k-devices: a change names its deps' clock in more bands, and a
 # join's lane rewrite rides the round's scatter
 DEVICES_TRIP_PADS = (16_384, 32_768)
+# boards10k: a change is 3.6 ops in the mean and an insert rewrites the
+# positions of its whole list: a round's scatter sorts s32[131072] on the
+# chip, and a smaller round pads to 65,536
+BOARDS_TRIP_PADS = (65_536, 131_072)
 
 
 def _dims(i, a, le):
@@ -249,6 +259,9 @@ CASES = {
     "apply_final-devices-one-block": (
         _apply_final(DEVICES_CAPS, 16, blocks=1), True, 1 << 20),
     "apply_final-devices-whole": (_apply_final(DEVICES_CAPS, 1024), True),
+    # boards10k.storm: a round's gathered lanes through the XL variant
+    "megakernel-boards-xl-storm": (
+        _megakernel(*BOARDS_CAPS, BENCH_STORM_LANES), True),
     "scan_rounds-fleet": (_scan_rounds_fleet, True),
     "merge_spans": (_merge_spans, False),
     "resolve_moves": (_resolve_moves, False),
@@ -265,7 +278,8 @@ CASES.update({
     for name, lanes, pads, caps in (
         ("fleet", FLEET_LANES, STORM_TRIP_PADS, BENCH_CAPS),
         ("shard", SHARD_LANES, SHARD_TRIP_PADS, BENCH_CAPS),
-        ("devices", FLEET_LANES, DEVICES_TRIP_PADS, DEVICES_CAPS))
+        ("devices", FLEET_LANES, DEVICES_TRIP_PADS, DEVICES_CAPS),
+        ("boards", FLEET_LANES, BOARDS_TRIP_PADS, BOARDS_CAPS))
     for trips in pads})
 # a gather that copies the buffer it reads (XLA's `rows[:, sel]` does)
 # fails here: its only large buffer is its output
@@ -275,7 +289,8 @@ CASES.update({
     for name, lanes, pads, caps in (
         ("fleet", FLEET_LANES, STORM_LANES, BENCH_CAPS),
         ("shard", SHARD_LANES, SHARD_STORM_LANES, BENCH_CAPS),
-        ("devices", FLEET_LANES, STORM_LANES, DEVICES_CAPS))
+        ("devices", FLEET_LANES, STORM_LANES, DEVICES_CAPS),
+        ("boards", FLEET_LANES, STORM_LANES, BOARDS_CAPS))
     for k_pad in pads})
 
 
@@ -333,6 +348,12 @@ CELL_KERNELS.update({
     f"megakernel_roofline-devices-{lanes}-lanes": (
         "megakernel_roofline", _megakernel(*DEVICES_CAPS, lanes),
         (rows_count(*DEVICES_CAPS), lanes))
+    for lanes in STORM_LANES})
+# boards10k.storm: the XL variant under the same instruction name
+CELL_KERNELS.update({
+    f"megakernel_roofline-boards-{lanes}-lanes": (
+        "megakernel_roofline", _megakernel(*BOARDS_CAPS, lanes),
+        (rows_count(*BOARDS_CAPS), lanes))
     for lanes in STORM_LANES})
 
 

@@ -70,6 +70,31 @@ def test_long_chain_fast():
     assert dt < 1.0, f"host linearize too slow: {dt:.3f}s"
 
 
+@pytest.mark.parametrize("fallback", [False, True])
+@pytest.mark.parametrize("seed", range(4))
+def test_many_lists_in_one_call_match_one_call_each(seed, fallback,
+                                                    monkeypatch):
+    """linearize_lists over lists of 0-40 elements, whose (elem, actor)
+    keys repeat from list to list, gives each list's own order."""
+    import automerge_tpu.native.linearize as lin
+    if fallback:
+        monkeypatch.setattr(lin, "get_lib", lambda: None)
+    rng = random.Random(seed)
+    lists = []
+    for _ in range(rng.randint(1, 12)):
+        n = rng.randint(0, 40)
+        mask, elem, actor, parent = random_tree(rng, n) if n > 1 else (
+            np.ones(n, bool), np.ones(n, np.int32), np.zeros(n, np.int32),
+            np.full(n, -1, np.int32))
+        lists.append((elem[mask], actor[mask], parent[mask]))
+    starts = np.cumsum([0] + [len(e) for e, _, _ in lists])
+    pos = lin.linearize_lists(*(np.concatenate([x[j] for x in lists])
+                                for j in range(3)), starts)
+    for k, (elem, actor, parent) in enumerate(lists):
+        own = linearize_host(np.ones(len(elem), bool), elem, actor, parent)
+        np.testing.assert_array_equal(pos[starts[k]:starts[k + 1]], own)
+
+
 def test_empty():
     out = linearize_host(np.zeros(4, bool), np.zeros(4, np.int32),
                          np.zeros(4, np.int32), np.full(4, -1, np.int32))

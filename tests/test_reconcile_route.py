@@ -296,9 +296,34 @@ def test_a_fused_verdict_under_cpu_link(plans, cpu_link):
     f.check(f.rset.apply_round_frames([f.frame([3, 4, 200])]))
     assert _count("engine_megabatch_rounds") - fused0 == 1
     assert plans[-1] == ([3, 4, 200], "megabatch")
-    # the buckets come out of the host mirror: a fleet that has only ever
-    # fused holds no device copy
-    assert f.rset.rows_dev is None and f.rset._h_prev is None
+    # the buckets come out of the host mirror; then the round uploads the
+    # mirror (a fused round reads its hashes back: no _h_prev)
+    assert f.rset._dev_current and f.rset._h_prev is None
+    # the next fuses over the current copy and runs the lane route once at
+    # its width
+    f.check(f.rset.apply_round_frames([f.frame([3, 4, 200])]))
+    assert plans[-1] == ([3, 4, 200], "megabatch")
+    assert (128, f.rset.n_pad, f.rset.dims()) in f.rset._lanes_warm
+
+
+def test_a_round_declined_after_fused_ones_compiles_nothing(
+        plans, cpu_link, monkeypatch):
+    """Fused rounds, then one the plan declines (its documents crossed a
+    bucket's size): it gathers its lanes out of the copy the fused rounds
+    left current, with the programs they ran at its width."""
+    f = Fleet(history=lambda i: 40 if i == 0 else 1)
+    f.check(f.rset.apply_round_frames([f.frame([3, 4, 200])]))
+    assert plans[-1] == ([3, 4, 200], "megabatch")
+    monkeypatch.setattr(dispatch, "plan_round",
+                        lambda rset, idxs: dispatch.RoundPlan("per_doc",
+                                                              list(idxs)))
+    retraced0 = {k: _count(f"engine_kernels_retraced{{kernel={k}}}")
+                 for k in ("gather_lanes", "reconcile_rows_hash")}
+    gathers0 = _count("rows_lane_gathers_device")
+    f.check(f.rset.apply_round_frames([f.frame([5, 6, 201])]))
+    assert _count("rows_lane_gathers_device") - gathers0 == 1
+    assert {k: _count(f"engine_kernels_retraced{{kernel={k}}}")
+            for k in retraced0} == retraced0
 
 
 def test_a_single_edit_after_a_relayout_copies_no_mirror(plans):
