@@ -27,6 +27,7 @@ about today's bench numbers.
 from __future__ import annotations
 
 import functools
+import math
 
 import numpy as np
 
@@ -82,6 +83,20 @@ def doc_block_spec(shape):
 # per-fid one-hots are gone entirely (fid equality is joined directly), so
 # the field count F is unbounded too.
 #
+# The dims are the WIDEST document's, so a block of 128 small documents
+# would spend most of its join on padding. Each grid step therefore reads
+# its block's live extent (block_extents, computed on the device from the
+# same rows) and runs its op-axis loops only to n_ops, its last row with
+# op_mask set in any lane rounded up to the loop's block height, and its
+# actor-band loops only to n_act, its highest actor rank of a live op + 1.
+# Exact: a row at or past n_ops has op_mask 0 in every lane, so it neither
+# dominates nor is dominated, is never a candidate and adds nothing to the
+# hash; a band at or past n_act is selected by `actor == a`, which no live
+# op meets; scratch rows the loops no longer write are read only under a
+# candidate mask that is false there. Shapes stay static: the extents are
+# runtime data, so no new program is compiled for them. The element loops
+# stay whole (a list's slots are a prefix of its own band, not of LE).
+#
 # The hash must stay bit-identical to kernels.state_hash, so the murmur
 # finalizer is reproduced in int32 arithmetic (wraparound add/mul and
 # logical shifts give the same bits as the uint32 original).
@@ -114,6 +129,12 @@ def _mix4_i32(a, b, c, d):
 _BLK = 8
 
 
+def _extents_at(ext_ref):
+    """This grid step's (n_ops, n_act) out of block_extents' SMEM vector."""
+    blk = pl.program_id(0)
+    return ext_ref[2 * blk], ext_ref[2 * blk + 1]
+
+
 def _make_reconcile_kernel(I, A, LE, a_set, a_del):
     """Build the fused kernel body for static per-doc dims.
 
@@ -130,11 +151,12 @@ def _make_reconcile_kernel(I, A, LE, a_set, a_del):
     r_ipos, r_iobj, r_ilist = b["ip"], b["io"], b["il"]
     r_ah = b["ah"]
 
-    def kernel(x_ref, o_ref, *scratch):
+    def kernel(x_ref, ext_ref, o_ref, *scratch):
         # Mosaic lowers dynamic block addressing only through refs, so every
         # blocked join reads its j/elem block from x_ref via pl.ds and
         # accumulates full-axis results either in a fori carry (pure
         # accumulation) or a VMEM scratch ref (block stores).
+        n_ops, n_act = _extents_at(ext_ref)
         om = x_ref[r_om:r_om + I, :]
         action = x_ref[r_ac:r_ac + I, :]
         fid = x_ref[r_fid:r_fid + I, :]
@@ -170,12 +192,12 @@ def _make_reconcile_kernel(I, A, LE, a_set, a_del):
                 return acc | hit.astype(jnp.int32)
 
             cp = jax.lax.fori_loop(
-                0, A, cp_a, jnp.zeros((_BLK, I, d), jnp.int32))
+                0, n_act, cp_a, jnp.zeros((_BLK, I, d), jnp.int32))
             return dominated | jnp.any(base & (cp > 0),
                                        axis=0).astype(jnp.int32)
 
         dominated = jax.lax.fori_loop(
-            0, I // _BLK, dom_block, jnp.zeros((I, d), jnp.int32))
+            0, n_ops // _BLK, dom_block, jnp.zeros((I, d), jnp.int32))
         survivor = (amask > 0) & (dominated == 0)
         candidate = survivor & (action != a_del)
         cand_i = candidate.astype(jnp.int32)
@@ -234,7 +256,7 @@ def _make_reconcile_kernel(I, A, LE, a_set, a_del):
                     jnp.max(jnp.where(m, vis_rank[None], -1), axis=1)
                 return carry
 
-            jax.lax.fori_loop(0, I // _BLK, opmap_block, 0)
+            jax.lax.fori_loop(0, n_ops // _BLK, opmap_block, 0)
             op_is_list = isl_ref[:]
             key1 = jnp.where(op_is_list > 0, oh_ref[:], jnp.int32(-7))
             key2 = jnp.where(op_is_list > 0, rk_ref[:], fh)
@@ -248,7 +270,7 @@ def _make_reconcile_kernel(I, A, LE, a_set, a_del):
             row = x_ref[pl.ds(r_ah + a, 1), :]
             return acc + jnp.where(actor == a, row, 0)
 
-        ah_op = jax.lax.fori_loop(0, A, ah_fold,
+        ah_op = jax.lax.fori_loop(0, n_act, ah_fold,
                                   jnp.zeros_like(actor))
         contrib = _mix4_i32(key1, key2, ah_op, vh)
         o_ref[:] = jnp.sum(jnp.where(candidate, contrib, 0), axis=0,
@@ -276,8 +298,9 @@ def _make_reconcile_kernel_xl(I, A, LE, a_set, a_del, BI=32, BJ=32, BE=8):
     r_ipos, r_iobj, r_ilist = b["ip"], b["io"], b["il"]
     r_ah = b["ah"]
 
-    def kernel(x_ref, o_ref, dom_ref, *scratch):
+    def kernel(x_ref, ext_ref, o_ref, dom_ref, *scratch):
         d = x_ref.shape[1]
+        n_ops, n_act = _extents_at(ext_ref)   # n_ops: a multiple of BI, BJ
 
         def amask_at(j0, n):
             om_j = x_ref[pl.ds(r_om + j0, n), :]
@@ -309,16 +332,16 @@ def _make_reconcile_kernel_xl(I, A, LE, a_set, a_del, BI=32, BJ=32, BE=8):
                     return cp | hit.astype(jnp.int32)
 
                 cp = jax.lax.fori_loop(
-                    0, A, cp_a, jnp.zeros((BJ, BI, d), jnp.int32))
+                    0, n_act, cp_a, jnp.zeros((BJ, BI, d), jnp.int32))
                 return acc | jnp.any(base & (cp > 0),
                                      axis=0).astype(jnp.int32)
 
             dom_i = jax.lax.fori_loop(
-                0, I // BJ, dom_jblock, jnp.zeros((BI, d), jnp.int32))
+                0, n_ops // BJ, dom_jblock, jnp.zeros((BI, d), jnp.int32))
             dom_ref[pl.ds(i0, BI), :] = dom_i
             return carry
 
-        jax.lax.fori_loop(0, I // BI, dom_iblock, 0)
+        jax.lax.fori_loop(0, n_ops // BI, dom_iblock, 0)
 
         def cand_at(j0, n):
             """Surviving value-carrying ops of a block (recomputed from the
@@ -343,7 +366,7 @@ def _make_reconcile_kernel_xl(I, A, LE, a_set, a_del, BI=32, BJ=32, BE=8):
                     return acc | hit.astype(jnp.int32)
 
                 hit = jax.lax.fori_loop(
-                    0, I // BJ, vis_jblock,
+                    0, n_ops // BJ, vis_jblock,
                     jnp.zeros((BE, d), jnp.int32))
                 im_b = x_ref[pl.ds(r_imask + e0, BE), :]
                 valid = (im_b > 0) & (ifid_b >= 0)
@@ -411,7 +434,7 @@ def _make_reconcile_kernel_xl(I, A, LE, a_set, a_del, BI=32, BJ=32, BE=8):
                 rk_ref[pl.ds(i0, BI), :] = rk
                 return carry
 
-            jax.lax.fori_loop(0, I // BI, opmap_iblock, 0)
+            jax.lax.fori_loop(0, n_ops // BI, opmap_iblock, 0)
 
         # ---- hash contribution, blocked accumulation ---------------------
         def hash_iblock(ib, acc):
@@ -434,14 +457,14 @@ def _make_reconcile_kernel_xl(I, A, LE, a_set, a_del, BI=32, BJ=32, BE=8):
                 row = x_ref[pl.ds(r_ah + a, 1), :]
                 return ah_acc + jnp.where(act_b == a, row, 0)
 
-            ah_b = jax.lax.fori_loop(0, A, ah_fold,
+            ah_b = jax.lax.fori_loop(0, n_act, ah_fold,
                                      jnp.zeros_like(act_b))
             contrib = _mix4_i32(key1, key2, ah_b, vh_b)
             return acc + jnp.sum(jnp.where(cnd, contrib, 0), axis=0,
                                  keepdims=True)
 
         o_ref[:] = jax.lax.fori_loop(
-            0, I // BI, hash_iblock, jnp.zeros((1, d), jnp.int32))
+            0, n_ops // BI, hash_iblock, jnp.zeros((1, d), jnp.int32))
 
     return kernel
 
@@ -472,17 +495,10 @@ def rows_dims_eligible_xl(i: int, a: int, le: int) -> bool:
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("dims", "interpret", "force_xl"))
-def reconcile_rows_hash(rows, dims: tuple, interpret: bool = False,
-                        force_xl: bool = False):
-    """Fused reconcile + state hash over a docs-minor row buffer.
-
-    rows: [ROWS, D_pad] int32 (see pack.pack_rows); dims is the static
-    (I, A, LE, a_set, a_del) tuple. Returns [D_pad] uint32 per-doc
-    state hashes, bit-identical to kernels.apply_doc(...)["hash"].
-    """
-    I, A, LE, a_set, a_del = dims
+def _takes_xl(dims: tuple, force_xl: bool) -> bool:
+    """Whether reconcile_rows_hash runs the XL variant for static `dims`;
+    raises on dims that the blocked joins cannot step through."""
+    I, A, LE = dims[:3]
     if I % _BLK or LE % _BLK:
         # The blocked joins step in _BLK-row tiles with no tail handling; an
         # unpadded dim would silently drop ops/elements from the joins and
@@ -490,19 +506,85 @@ def reconcile_rows_hash(rows, dims: tuple, interpret: bool = False,
         raise ValueError(
             f"megakernel dims must be multiples of {_BLK}: I={I}, LE={LE} "
             f"(pad ops/elements before packing)")
-    rows_n, d_pad = rows.shape
     from .pack import rows_dims_eligible
     if rows_dims_eligible(I, A, LE) and not force_xl:
-        kernel = _make_reconcile_kernel(I, A, LE, a_set, a_del)
-        scratch = []
+        return False
+    # base working set would blow VMEM (live [8, I, d] intermediates):
+    # the doubly-blocked XL kernel, dominated mask in scratch
+    if I % _XL_BI:
+        raise ValueError(f"XL kernel needs I % {_XL_BI} == 0, I={I}")
+    return True
+
+
+def _extent_step(xl: bool) -> int:
+    """The block height n_ops is rounded up to: every op-axis loop of the
+    variant steps by a divisor of it."""
+    return math.lcm(_XL_BI, _XL_BJ) if xl else _BLK
+
+
+def block_extents(rows, dims: tuple, force_xl: bool = False):
+    """The live extent of each 128-lane block of `rows`, as the kernel
+    reads it: int32 [2 * blocks] of (n_ops, n_act) pairs. n_ops is the
+    block's last row with op_mask set in any lane, plus one, rounded up to
+    the variant's block height; n_act is the highest actor rank of a live
+    op in the block, plus one. One small reduction over two bands."""
+    from .pack import row_bases
+    I, A = dims[:2]
+    b = row_bases(*dims[:3])
+    step = _extent_step(_takes_xl(dims, force_xl))
+    nb = rows.shape[1] // 128
+    live = (rows[b["om"]:b["om"] + I] > 0).reshape(I, nb, 128)
+    row = jnp.arange(1, I + 1, dtype=jnp.int32)[:, None, None]
+    last = jnp.max(jnp.where(live, row, 0), axis=(0, 2))
+    act = rows[b["act"]:b["act"] + I].reshape(I, nb, 128)
+    n_act = jnp.max(jnp.where(live, act + 1, 0), axis=(0, 2))
+    return jnp.stack([_round_up(last, step), jnp.clip(n_act, 0, A)],
+                     axis=1).reshape(-1)
+
+
+def host_block_extents(ops, actors, dims: tuple,
+                       force_xl: bool = False) -> np.ndarray:
+    """block_extents from the host's own account of the lanes, with no
+    readback: `ops` and `actors` give each lane's op rows in use and its
+    document's actor count. Equal to block_extents where every op row in
+    use is live and every actor holds an op."""
+    I, A = dims[:2]
+    step = _extent_step(_takes_xl(dims, force_xl))
+    ops = np.minimum(np.asarray(ops, np.int64), I).reshape(-1, 128)
+    act = np.where(ops > 0, np.asarray(actors, np.int64).reshape(-1, 128), 0)
+    return np.stack([_round_up(ops.max(axis=1), step),
+                     np.minimum(act.max(axis=1), A)],
+                    axis=1).reshape(-1).astype(np.int32)
+
+
+def join_steps(ext, dims: tuple, force_xl: bool = False) -> tuple[int, int]:
+    """(run, full): the actor-band trips of the domination join that the
+    kernel runs over the blocks of `ext` (block_extents' layout), and
+    those the static dims would run. A trip compares one block of ops
+    against the block's i side in one band."""
+    I, A = dims[:2]
+    ext = np.asarray(ext, np.int64)
+    n_ops, n_act = ext[0::2], ext[1::2]
+    if _takes_xl(dims, force_xl):
+        run = (n_ops // _XL_BI) * (n_ops // _XL_BJ) * n_act
+        full = (I // _XL_BI) * (I // _XL_BJ) * A
     else:
-        # base working set would blow VMEM (live [8, I, d] intermediates):
-        # the doubly-blocked XL kernel, dominated mask in scratch
-        if I % _XL_BI:
-            raise ValueError(f"XL kernel needs I % {_XL_BI} == 0, I={I}")
+        run, full = (n_ops // _BLK) * n_act, (I // _BLK) * A
+    return int(run.sum()), int(full * len(n_ops))
+
+
+def _rows_hash_call(rows, ext, dims: tuple, interpret: bool, xl: bool):
+    """The kernel's pallas_call over `rows` with the block extents `ext`
+    (block_extents' layout) in SMEM; `rows` stays the first operand."""
+    I, A, LE, a_set, a_del = dims
+    rows_n, d_pad = rows.shape
+    if xl:
         kernel = _make_reconcile_kernel_xl(I, A, LE, a_set, a_del,
                                            _XL_BI, _XL_BJ)
         scratch = [pltpu.VMEM((I, 128), jnp.int32)]    # dominated
+    else:
+        kernel = _make_reconcile_kernel(I, A, LE, a_set, a_del)
+        scratch = []
     if LE > 0:
         scratch += [pltpu.VMEM((LE, 128), jnp.int32),  # elem visibility
                     pltpu.VMEM((LE, 128), jnp.int32),  # elem rank
@@ -513,7 +595,8 @@ def reconcile_rows_hash(rows, dims: tuple, interpret: bool = False,
         kernel,
         grid=(d_pad // 128,),
         in_specs=[pl.BlockSpec((rows_n, 128), lambda d: (0, d),
-                               memory_space=pltpu.VMEM)],
+                               memory_space=pltpu.VMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((1, 128), lambda d: (0, d),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.int32),
@@ -521,8 +604,32 @@ def reconcile_rows_hash(rows, dims: tuple, interpret: bool = False,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(rows)
+    )(rows, ext)
     return jax.lax.bitcast_convert_type(out[0], jnp.uint32)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("dims", "interpret", "force_xl"))
+def reconcile_rows_hash(rows, dims: tuple, interpret: bool = False,
+                        force_xl: bool = False):
+    """Fused reconcile + state hash over a docs-minor row buffer.
+
+    rows: [ROWS, D_pad] int32 (see pack.pack_rows); dims is the static
+    (I, A, LE, a_set, a_del) tuple. Returns [D_pad] uint32 per-doc
+    state hashes, bit-identical to kernels.apply_doc(...)["hash"].
+
+    The live extent of each 128-lane block (block_extents) is computed
+    here, on the device, from the rows themselves, so every caller gets
+    it and nothing from the host can disagree with the rows. Each block's
+    domination join then runs only to its fullest lane's last live op and
+    its highest actor rank, not to the dims the widest document set: exact,
+    since the rows and bands past them are masked out of every join (the
+    comment above _make_reconcile_kernel says why). The extents are data,
+    not static arguments: a buffer compiles once a shape, as before.
+    """
+    xl = _takes_xl(dims, force_xl)
+    return _rows_hash_call(rows, block_extents(rows, dims, force_xl), dims,
+                           interpret, xl)
 
 
 # ---------------------------------------------------------------------------

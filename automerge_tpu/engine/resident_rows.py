@@ -38,8 +38,8 @@ from .encode import _pad_to
 from .resident import ResidentDocSet
 from . import dispatch as round_dispatch
 from .pack import LANE, pad_to_lanes
-from .pallas_kernels import (gather_lanes, lane_gather_plan,
-                             reconcile_rows_hash)
+from .pallas_kernels import (gather_lanes, host_block_extents, join_steps,
+                             lane_gather_plan, reconcile_rows_hash)
 from ..utils import flightrec, metrics, perfscope
 
 
@@ -2372,6 +2372,11 @@ class ResidentRowsDocSet(ResidentDocSet):
             # what crosses the link: the gather's plan, or the lanes
             staged = lane_gather_plan(sel, self.n_pad) if on_device \
                 else np.ascontiguousarray(self.rows_host[:, sel])
+            # the share of the static domination join the kernel still
+            # runs, from the host's account of the lanes' extents
+            run, full = join_steps(host_block_extents(
+                self.op_count[sel], (self._doc_gids[sel] >= 0).sum(axis=1),
+                self.dims()), self.dims())
         sub_dev = self._to_dev(staged)
         with dispatchledger.call_scope(
                 "rows_hash", backend="device", docs=k,
@@ -2387,6 +2392,8 @@ class ResidentRowsDocSet(ResidentDocSet):
             metrics.bump("rows_lane_gathers_device")
         else:
             metrics.bump("rows_lane_gathers_host")
+        metrics.bump("rows_join_steps_run", run)
+        metrics.bump("rows_join_steps_full", full)
         self._unsettled = (idxs, h)
 
     def hashes(self, interpret: bool | None = None) -> np.ndarray:

@@ -188,6 +188,14 @@ COUNTERS: dict[str, str] = {
     "rows_lane_gathers_host":
         "lane reconciles whose columns were gathered out of the host "
         "mirror and uploaded (the device copy was not current)",
+    "rows_join_steps_run":
+        "actor-band trips of the reconcile kernel's domination join that "
+        "lane reconciles ran, each 128-lane block to its live extent "
+        "(host account: the lanes' op counts and actor counts; "
+        "resident_rows._reconcile_lanes)",
+    "rows_join_steps_full":
+        "the trips the same lane reconciles would run at the static dims; "
+        "run / full is the share of the static join still executed",
     # sync — services, wire protocol, transports, log archive
     "sync_frames_sent": "columnar change frames sent",
     "sync_frames_received": "columnar change frames received",

@@ -256,6 +256,10 @@ CASES = {
         _apply_final(BENCH_CAPS, 16, blocks=4), True, 1 << 20),
     # eight writers a document: 22,248 of the budget's 22,528 rows
     "megakernel-devices-one-block": (_megakernel(*DEVICES_CAPS, 128), True),
+    # a round of fleet10k-devices.storm: ten blocks, each bounded by the
+    # live extents the wrapper reduces out of the rows (an SMEM operand)
+    "megakernel-devices-storm": (
+        _megakernel(*DEVICES_CAPS, BENCH_STORM_LANES), True),
     "apply_final-devices-one-block": (
         _apply_final(DEVICES_CAPS, 16, blocks=1), True, 1 << 20),
     "apply_final-devices-whole": (_apply_final(DEVICES_CAPS, 1024), True),

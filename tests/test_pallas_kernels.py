@@ -257,3 +257,159 @@ def test_xl_kernel_parity_concurrent_text():
         docs.append(m._doc.opset.get_missing_changes({}))
     dims = _xl_parity(docs)
     assert dims[0] >= 32 and dims[2] >= 32  # ops and elems both blocked
+
+
+# ---------------------------------------------------------------------------
+# Live extents: each 128-lane block's joins run to its own fullest lane
+
+
+def _dims(i, a, le):
+    from automerge_tpu.engine.encode import A_DEL, A_SET
+    return (i, a, le, int(A_SET), int(A_DEL))
+
+
+def _extent_rows(dims, lane_ops, lane_actors, seed=0):
+    """A docs-minor buffer whose lane j holds `lane_ops[j]` op rows, the
+    last one live and about one in seven below it dead (op_mask 0), written
+    by actor ranks 0 .. lane_actors[j] - 1 (the highest among them). Every
+    other cell, past a lane's ops included, holds arbitrary values: the
+    joins must mask them out, not find them zero."""
+    from automerge_tpu.engine.encode import A_DEL, A_SET
+    from automerge_tpu.engine.pack import row_bases, rows_count
+    I, A, LE = dims[:3]
+    rng = np.random.default_rng(seed)
+    b = row_bases(I, A, LE)
+    rows = rng.integers(-3, 9, size=(rows_count(I, A, LE), len(lane_ops)),
+                        dtype=np.int32)
+    rows[b["om"]:b["om"] + I] = 0
+    big = rng.integers(-2**31, 2**31 - 1, size=rows.shape, dtype=np.int32)
+    for g in ("fh", "vh"):
+        rows[b[g]:b[g] + I] = big[b[g]:b[g] + I]
+    rows[b["ah"]:b["ah"] + A] = big[b["ah"]:b["ah"] + A]
+    for j, (n, k) in enumerate(zip(lane_ops, lane_actors)):
+        if not n:
+            continue
+        live = rng.random(n) < 0.85
+        live[n - 1] = True
+        act = rng.integers(0, k, n)
+        act[n - 1] = k - 1
+        for g, vals in (("om", live), ("act", act),
+                        ("ac", rng.choice([A_SET, A_SET, A_DEL, 0], n)),
+                        ("fid", rng.integers(0, 6, n)),
+                        ("seq", rng.integers(1, 6, n)),
+                        ("chg", rng.integers(0, 8, n))):
+            rows[b[g]:b[g] + n, j] = vals
+        for a in range(A):
+            rows[b["co"] + a * I:b["co"] + a * I + n, j] = \
+                rng.integers(0, 6, n)
+    if LE:
+        rows[b["if"]:b["if"] + LE] = rng.integers(-1, 6, size=(LE, 1))
+        rows[b["il"]:b["il"] + LE] = (np.arange(LE) // 8)[:, None]
+    return rows
+
+
+def _extent_blocks(I, A, seed=0):
+    """Four 128-lane blocks: short lanes of one or two writers; lanes of
+    every length with one at exactly I ops and every actor count from 1 to
+    A; empty lanes; lanes up to I/2 ops, actor counts 1 to A."""
+    rng = np.random.default_rng(seed)
+    ops = np.concatenate([rng.integers(0, 40, 128), rng.integers(1, I, 128),
+                          np.zeros(128, int), rng.integers(0, I // 2, 128)])
+    ops[128 + 77] = I
+    actors = np.concatenate([rng.integers(1, 3, 128), np.arange(128) % A + 1,
+                             np.zeros(128, int), np.arange(128) % A + 1])
+    return ops, np.where(ops > 0, actors, 0)
+
+
+@pytest.mark.parametrize("dims,force_xl", [
+    (_dims(512, 8, 32), False),     # fleet10k-devices: the standard kernel
+    (_dims(512, 8, 32), True),      # the same rows through the XL variant
+    (_dims(512, 8, 512), False),    # boards10k: XL by its dims
+], ids=["standard-devices", "xl-forced-devices", "xl-boards"])
+def test_bounded_join_matches_the_full_extent(dims, force_xl):
+    """The kernel bounded by each block's live extent hashes bit-identically
+    to the same kernel run at the static dims, and the extents are the
+    blocks' own: short, full, empty and holed."""
+    import jax.numpy as jnp
+
+    from automerge_tpu.engine import pallas_kernels as pk
+
+    I, A = dims[:2]
+    ops, actors = _extent_blocks(I, A)
+    rows = jnp.asarray(_extent_rows(dims, ops, actors))
+    interp = jax.default_backend() != "tpu"
+    ext = np.asarray(jax.jit(pk.block_extents, static_argnums=(1, 2))(
+        rows, dims, force_xl))
+    step = 32 if pk._takes_xl(dims, force_xl) else 8
+    short = -(-int(ops[:128].max()) // step) * step
+    assert ext.tolist() == [short, int(actors[:128].max()), I, A,
+                            0, 0, -(-int(ops[384:].max()) // step) * step, A]
+    got = np.asarray(pk.reconcile_rows_hash(rows, dims, interp, force_xl))
+    full = jnp.asarray(np.tile(np.int32([I, A]), 4))
+    want = np.asarray(jax.jit(pk._rows_hash_call, static_argnums=(2, 3, 4))(
+        rows, full, dims, interp, pk._takes_xl(dims, force_xl)))
+    assert (want[ops > 0] != 0).any()
+    np.testing.assert_array_equal(got, want)
+    # the host's account of the same lanes gives the same extents
+    np.testing.assert_array_equal(
+        pk.host_block_extents(ops, actors, dims, force_xl), ext)
+    run, full_steps = pk.join_steps(ext, dims, force_xl)
+    assert 0 < run < full_steps
+
+
+def test_lane_reconcile_counts_the_join_the_kernel_runs():
+    """A lane reconcile bumps rows_join_steps_run / _full from the engine's
+    op and actor counts, with no readback; they equal the extents the
+    kernel's wrapper computes from the gathered rows, and its hashes are
+    the oracle's."""
+    import jax.numpy as jnp
+
+    from automerge_tpu.core.change import Change, Op
+    from automerge_tpu.core.ids import ROOT_ID
+    from automerge_tpu.engine.batchdoc import apply_batch
+    from automerge_tpu.engine.pallas_kernels import (block_extents,
+                                                     join_steps)
+    from automerge_tpu.engine.resident_rows import ResidentRowsDocSet
+    from automerge_tpu.native.wire import changes_to_columns
+    from automerge_tpu.sync.frames import round_from_parts
+    from automerge_tpu.utils import metrics
+
+    ids = [f"d{i:03d}" for i in range(300)]
+    logs = {}
+    for i, d in enumerate(ids[:256]):
+        # one writer and 1-3 ops, then 1-5 writers and one long history;
+        # from 256 on empty documents
+        writers = 1 if i < 128 else 1 + i % 5
+        n = 1 + i % 3 if i < 128 else (60 if i == 200 else 2 + i % 9)
+        chs, clock = [], {}
+        for s in range(n):
+            w = f"{d}-w{s % writers}"
+            clock[w] = clock.get(w, 0) + 1
+            chs.append(Change(w, clock[w], {a: q for a, q in clock.items()
+                                            if a != w},
+                              [Op("set", ROOT_ID, key=f"k{s % 4}",
+                                  value=s)]))
+        logs[d] = chs
+    rset = ResidentRowsDocSet(ids)
+    if rset._native is None:
+        pytest.skip("round frames need the native encoder")
+    rset.apply_round_frames([round_from_parts(
+        {d: [changes_to_columns(c)] for d, c in logs.items()})])
+    idxs = list(range(0, 300, 2))        # two blocks: one of them empty
+    sel = np.asarray(idxs + [idxs[-1]] * (256 - len(idxs)))
+    dims = rset.dims()
+    ext = np.asarray(block_extents(jnp.asarray(rset.rows_host[:, sel]),
+                                   dims))
+    assert ext.tolist() == [64, 5, 0, 0]
+    before = metrics.snapshot()
+    rset._reconcile_lanes(idxs, interpret=jax.default_backend() != "tpu")
+    after = metrics.snapshot()
+    run, full = join_steps(ext, dims)
+    assert [after.get(f"rows_join_steps_{k}", 0)
+            - before.get(f"rows_join_steps_{k}", 0)
+            for k in ("run", "full")] == [run, full]
+    rset._settle()
+    _, _, out = apply_batch([logs.get(ids[i], []) for i in idxs])
+    np.testing.assert_array_equal(
+        rset._hash_mirror[np.asarray(idxs)],
+        np.asarray(out["hash"]).astype(np.uint32))
