@@ -106,229 +106,264 @@ def _floor_ranks(rset, i: int, floor: dict[str, int]) -> np.ndarray:
     return out
 
 
-def _op_keep_mask(om, ac, fid, act, seq, chg, co, floor_r) -> np.ndarray:
-    """Keep mask over op slots: candidates, plus above-floor DEL survivors.
+_OP_BANDS = (("om", 0), ("ac", -1), ("fid", -1), ("act", 0), ("seq", 0),
+             ("chg", 0), ("fh", 0), ("vh", 0))
+_ELEM_BANDS = (("im", 0), ("if", -1), ("ip", 0), ("io", -1))
+# lanes compacted together: one gather of their columns, one keep mask
+CHUNK = 256
+
+
+def _op_keep_mask(b, cols, I: int, A: int, floor_r) -> tuple:
+    """Keep mask [I, k] over the op slots of k lanes (`cols`: their dense
+    columns, [rows, k]; `floor_r` [A, k]: each lane's floor by actor rank):
+    candidates, plus above-floor DEL survivors. Returns it with the mask of
+    the assigns whose field keeps an element's slot: the candidates, and
+    every assign above the floor.
 
     Mirrors kernels.field_states' domination join on the host: op j
     dominates op i iff both assigns on the same field, j's change-clock at
-    i's actor >= i's seq, and they come from different changes.
+    i's actor >= i's seq, and they come from different changes. Every
+    lane's assigns are sorted by (lane, field), so the pairs of one field
+    lie at distances below its count: one vectorized pass a distance, as
+    many passes as the busiest field has assigns (a handful), not one a
+    field nor one a lane.
     """
-    amask = om.astype(bool) & (ac >= A_SET)
-    dominated = np.zeros(len(om), bool)
-    idx = np.nonzero(amask)[0]
-    if len(idx):
-        f = fid[idx]
-        order = np.argsort(f, kind="stable")
-        sidx = idx[order]
-        fs = fid[sidx]
-        starts = np.r_[0, np.nonzero(fs[1:] != fs[:-1])[0] + 1, len(fs)]
-        for g0, g1 in zip(starts[:-1], starts[1:]):
-            grp = sidx[g0:g1]
-            if len(grp) < 2:
-                continue
-            # clock of op j's change evaluated at op i's actor: [j, i]
-            cj_at_i = co[np.ix_(act[grp], grp)].T
-            dom = (cj_at_i >= seq[grp][None, :]) \
-                & (chg[grp][:, None] != chg[grp][None, :])
-            dominated[grp] = dom.any(axis=0)
-    survivor = amask & ~dominated
-    below = seq <= floor_r[np.clip(act, 0, len(floor_r) - 1)]
-    return survivor & ~((ac == A_DEL) & below)
+    om, ac, fid, act, seq, chg = (cols[b[g]:b[g] + I] for g in (
+        "om", "ac", "fid", "act", "seq", "chg"))
+    co = cols[b["co"]:b["co"] + A * I].reshape(A, I, -1)
+    amask = (om != 0) & (ac >= A_SET)
+    lane, slot = np.nonzero(amask.T)             # lane-major
+    key = (lane.astype(np.int64) << 32) | fid[slot, lane].astype(np.int64)
+    order = np.argsort(key, kind="stable")
+    lane, slot, key = lane[order], slot[order], key[order]
+    dominated = np.zeros(amask.shape, bool)
+    for d in range(1, len(key)):
+        same = np.flatnonzero(key[d:] == key[:-d])
+        if not len(same):
+            break      # every field has at most d assigns
+        ln, si, sj = lane[same], slot[same], slot[same + d]
+        other = chg[si, ln] != chg[sj, ln]
+        hit = other & (co[act[si, ln], sj, ln] >= seq[si, ln])
+        dominated[si[hit], ln[hit]] = True
+        hit = other & (co[act[sj, ln], si, ln] >= seq[sj, ln])
+        dominated[sj[hit], ln[hit]] = True
+    lanes = np.arange(amask.shape[1])[None, :]
+    above = seq > floor_r[np.clip(act, 0, A - 1), lanes]
+    keep = amask & ~dominated & ~((ac == A_DEL) & ~above)
+    return keep, (keep & (ac != A_DEL)) | (amask & above)
 
 
-def compact_doc(rset, i: int, floor: dict[str, int],
-                pins: set | None = None) -> dict:
-    """Compact one document's row state in place. Returns reclaim stats.
+def _compact_lanes(rset, idxs: list, floors: list, pins: list) -> list:
+    """Compact the documents at lanes `idxs` in place, each to its floor
+    (`floors`, aligned), keeping the slots of its `pins` (element ids that
+    must keep their slots regardless of the floor: anchors referenced by
+    known-but-not-yet-admitted changes, a coalesced pending round, the
+    un-replayed tail of a rebuild; the floor argument covers only changes
+    *generated after* their sender saw the tombstone, not ones already in
+    flight). One gather of the lanes' columns, one keep mask and one
+    packing of the op bands for them all, each document's element reclaim
+    on its own column, one linearization of every list they hold, and the
+    columns that changed written back. Returns each lane's reclaim stats,
+    `changed` among them.
 
-    `pins` is a set of element ids that must keep their slots regardless of
-    the floor: anchors referenced by known-but-not-yet-admitted changes (a
-    coalesced pending round, the un-replayed tail of a rebuild) — the floor
-    argument covers only changes *generated after* their sender saw the
-    tombstone, not ones already in flight.
-
-    The caller owns invalidation (`_dirty`, hash handle) and native-encoder
-    sync; use ResidentRowsDocSet.compact() rather than calling this
-    directly.
+    The caller owns invalidation (hash marks, the device copy) and
+    native-encoder sync; use ResidentRowsDocSet.compact() rather than
+    calling this directly.
     """
     b = rset._bases()
-    I, A, E = rset.cap_ops, rset.cap_actors, rset.cap_elems
-    col = rset.rows_host[:, i]
-    om = col[b["om"]:b["om"] + I].copy()
-    ac = col[b["ac"]:b["ac"] + I].copy()
-    fid = col[b["fid"]:b["fid"] + I].copy()
-    act = col[b["act"]:b["act"] + I].copy()
-    seq = col[b["seq"]:b["seq"] + I].copy()
-    chg = col[b["chg"]:b["chg"] + I].copy()
-    fh = col[b["fh"]:b["fh"] + I].copy()
-    vh = col[b["vh"]:b["vh"] + I].copy()
-    co = col[b["co"]:b["co"] + A * I].reshape(A, I).copy()
-    floor_r = _floor_ranks(rset, i, floor)
-
-    keep = _op_keep_mask(om, ac, fid, act, seq, chg, co, floor_r)
-    n_ops0 = int(rset.op_count[i])
-    kidx = np.nonzero(keep)[0]
-    n_keep = len(kidx)
+    I, A = rset.cap_ops, rset.cap_actors
+    sel = np.asarray(idxs, np.int64)
+    was = rset.rows_host[:, sel]                 # a gather: a dense copy
+    cols = was.copy()
+    floor_r = np.stack([_floor_ranks(rset, i, f)
+                        for i, f in zip(idxs, floors)], axis=1)
+    keep, slot_fids = _op_keep_mask(b, cols, I, A, floor_r)
+    fid = cols[b["fid"]:b["fid"] + I]
+    keep_fids = [set(fid[slot_fids[:, t], t].tolist())
+                 for t in range(len(idxs))]
 
     # ---- rewrite the op bands: survivors packed to the front ----
-    def pack_band(base, src, fill):
-        col[base:base + I] = fill
-        col[base:base + n_keep] = src[kidx]
+    n_keep = keep.sum(axis=0)
+    order = np.argsort(~keep, axis=0, kind="stable")
+    live = np.arange(I)[:, None] < n_keep[None, :]
+    for g, fill in _OP_BANDS:
+        band = cols[b[g]:b[g] + I]
+        band[:] = np.where(live, np.take_along_axis(band, order, 0), fill)
+    co = cols[b["co"]:b["co"] + A * I].reshape(A, I, len(idxs))
+    co[:] = np.where(live[None], np.take_along_axis(co, order[None], 1), 0)
 
-    pack_band(b["om"], om, 0)
-    pack_band(b["ac"], ac, -1)
-    pack_band(b["fid"], fid, -1)
-    pack_band(b["act"], act, 0)
-    pack_band(b["seq"], seq, 0)
-    pack_band(b["chg"], chg, 0)
-    pack_band(b["fh"], fh, 0)
-    pack_band(b["vh"], vh, 0)
-    co_new = np.zeros_like(co)
-    co_new[:, :n_keep] = co[:, kidx]
-    col[b["co"]:b["co"] + A * I] = co_new.reshape(-1)
-    rset.op_count[i] = n_keep
+    stats, lists = [], []
+    for t, i in enumerate(idxs):
+        n_ops0 = int(rset.op_count[i])
+        rset.op_count[i] = rset.tables[i].n_ops = int(n_keep[t])
+        n_elems = _reclaim_elems(rset, i, cols[:, t], b, keep_fids[t],
+                                 pins[t])
+        if n_elems is None:
+            n_elems = (_slotted(rset, i),) * 2
+        else:
+            lists += [(i, lrow) for lrow in rset.ins_log[i]]
+        stats.append({"ops_before": n_ops0, "ops_after": int(n_keep[t]),
+                      "elems_before": n_elems[0], "elems_after": n_elems[1]})
+    if lists:
+        # fresh RGA positions for every list of a compacted document
+        # (ghosts included in the linearization, rank-compressed over the
+        # slotted entries)
+        docs, prow, pval = rset._linearized_pos_rows(lists)
+        col_of = {i: t for t, i in enumerate(idxs)}
+        cols[prow, [col_of[d] for d in docs.tolist()]] = pval
+    changed = (cols != was).any(axis=0)
+    if changed.any():
+        rset.rows_host[:, sel[changed]] = cols[:, changed]
+    for s, c in zip(stats, changed.tolist()):
+        s["changed"] = c
+    return stats
 
-    # ---- element reclaim ----
-    # Host truth for elements is ins_log (slot, elem-counter, actor-rank,
-    # parent-slot per list row) plus the rows bands themselves; the eid is
-    # reconstructible as "actor:counter" (core/ids.make_elem_id — the same
-    # format both encoders intern) and the element's field id is read from
-    # the `if` band, so this pass works identically over the native and
-    # pure-Python encoders.
+
+def _slotted(rset, i: int) -> int:
+    return sum(1 for e in rset.ins_log[i].values()
+               for (s, _, _, _) in e if s >= 0)
+
+
+def _reclaim_elems(rset, i: int, col, b, keep_fids: set, pins):
+    """Element reclaim of document i on its dense column `col` (its op
+    bands already packed): (slotted elements before, after), or None where
+    queued changes may anchor anywhere and nothing is reclaimed.
+
+    Host truth for elements is ins_log (slot, elem-counter, actor-rank,
+    parent-slot per list row) plus the rows bands themselves; the eid is
+    reconstructible as "actor:counter" (core/ids.make_elem_id — the same
+    format both encoders intern) and the element's field id is read from
+    the `if` band, so this pass works identically over the native and
+    pure-Python encoders. `keep_fids`: the fields whose element keeps its
+    slot (a candidate, or an assign above the floor), from the ORIGINAL
+    ops."""
     t = rset.tables[i]
-    n_elems0 = sum(1 for e in rset.ins_log[i].values()
-                   for (s, _, _, _) in e if s >= 0)
-    n_elems1 = n_elems0
-    # fid sets that gate element visibility / reclaim, from the ORIGINAL ops
-    amask = om.astype(bool) & (ac >= A_SET)
-    cand_fids = set(fid[kidx[(ac[kidx] != A_DEL)]].tolist())
-    above = amask & (seq > floor_r[np.clip(act, 0, len(floor_r) - 1)])
-    fids_above = set(fid[above].tolist())
+    if t.queue:
+        return None
+    from ..core.ids import make_elem_id
 
-    if not t.queue:  # queued changes may anchor anywhere: skip elem GC
-        from ..core.ids import make_elem_id
-        from ..native.linearize import linearize_host
-
-        n_elems0 = n_elems1 = 0
-        for lrow, entries in list(rset.ins_log[i].items()):
-            base = lrow * E
-            fid_band = col[b["if"] + base:b["if"] + base + E]
-            n = len(entries)
-            n_slotted = sum(1 for (s, _, _, _) in entries if s >= 0)
-            n_elems0 += n_slotted
-            # keep_slot: the element keeps its device band slot — visible,
-            # or some op on its field is still above the floor. A slotted
-            # entry losing this becomes a GHOST: it keeps its RGA ordering
-            # key in this host tree (its retained descendants and future
-            # siblings of its parent still compare against that key) but
-            # frees the band slot. Ghost entries with no tree-retained
-            # child drop from the host tree entirely.
-            keep_slot = np.zeros(n, bool)
-            keep_tree = np.zeros(n, bool)
-            has_kept_child: set[int] = set()
-            for k in range(n - 1, -1, -1):
-                slot, elem_c, arank_c, parent = entries[k]
-                if slot >= 0:
-                    efid = int(fid_band[slot])
-                    keep_slot[k] = (efid in cand_fids
-                                    or efid in fids_above
-                                    or (bool(pins) and make_elem_id(
-                                        t.actors[arank_c], elem_c)
-                                        in pins))
-                if keep_slot[k] or k in has_kept_child:
-                    keep_tree[k] = True
-                    if parent >= 0:
-                        has_kept_child.add(parent)
-            n_keep_slots = int(keep_slot.sum())
-            n_elems1 += n_keep_slots
-            if n_keep_slots == n_slotted and keep_tree.all():
+    E = rset.cap_elems
+    n_elems0 = n_elems1 = 0
+    for lrow, entries in list(rset.ins_log[i].items()):
+        base = lrow * E
+        fid_band = col[b["if"] + base:b["if"] + base + E].tolist()
+        n = len(entries)
+        n_slotted = sum(1 for (s, _, _, _) in entries if s >= 0)
+        n_elems0 += n_slotted
+        # keep_slot: the element keeps its device band slot — visible,
+        # or some op on its field is still above the floor. A slotted
+        # entry losing this becomes a GHOST: it keeps its RGA ordering
+        # key in this host tree (its retained descendants and future
+        # siblings of its parent still compare against that key) but
+        # frees the band slot. Ghost entries with no tree-retained
+        # child drop from the host tree entirely.
+        keep_slot = [False] * n
+        keep_tree = [False] * n
+        has_kept_child: set[int] = set()
+        for k in range(n - 1, -1, -1):
+            slot, elem_c, arank_c, parent = entries[k]
+            if slot >= 0:
+                keep_slot[k] = (fid_band[slot] in keep_fids
+                                or (bool(pins) and make_elem_id(
+                                    t.actors[arank_c], elem_c) in pins))
+            if keep_slot[k] or k in has_kept_child:
+                keep_tree[k] = True
+                if parent >= 0:
+                    has_kept_child.add(parent)
+        n_keep_slots = sum(keep_slot)
+        n_elems1 += n_keep_slots
+        if n_keep_slots == n_slotted and all(keep_tree):
+            continue
+        # rebuild the entry list: tree-retained entries in arrival
+        # order; slots renumber densely over the slot-keeping ones so
+        # the encoders' next-slot rule (len(elem_slots[obj])) keeps
+        # assigning fresh slots past the compacted set
+        idx_map: dict[int, int] = {}
+        slot_remap: dict[int, int] = {}
+        new_entries: list[tuple] = []
+        for k in range(n):
+            if not keep_tree[k]:
                 continue
-            # rebuild the entry list: tree-retained entries in arrival
-            # order; slots renumber densely over the slot-keeping ones so
-            # the encoders' next-slot rule (len(elem_slots[obj])) keeps
-            # assigning fresh slots past the compacted set
-            idx_map: dict[int, int] = {}
-            slot_remap: dict[int, int] = {}
-            new_entries: list[tuple] = []
-            for k in np.nonzero(keep_tree)[0]:
-                slot, elem, arank, parent = entries[k]
-                ns = -1
-                if keep_slot[k]:
-                    ns = len(slot_remap)
-                    slot_remap[slot] = ns
-                idx_map[k] = len(new_entries)
-                new_entries.append(
-                    (ns, elem, arank,
-                     idx_map[parent] if parent >= 0 else -1))
-            # every slotted entry that lost its slot (ghosted or fully
-            # dropped) is a forbidden future anchor
-            for k in np.nonzero(~keep_slot)[0]:
-                slot, elem, arank, _parent = entries[k]
-                if slot >= 0:
-                    rset.ghost_eids[i].add(
-                        make_elem_id(rset.tables[i].actors[arank], elem))
-            rset.ins_log[i][lrow] = new_entries
-            rset.ins_idx[i][lrow] = {
-                s: k for k, (s, _, _, _) in enumerate(new_entries)
-                if s >= 0}
-            oi = rset.list_obj[i].get(lrow)
-            if oi is not None and t.elem_slots.get(oi):
-                # pure-Python encoder path: its eid->slot map lives here
-                eid_by_slot = {s: eid
-                               for eid, s in t.elem_slots[oi].items()}
-                t.elem_slots[oi] = {eid_by_slot[s]: ns
-                                    for s, ns in slot_remap.items()}
-            # rewrite this list's element bands
-            for g, fill in (("im", 0), ("if", -1), ("ip", 0), ("io", -1)):
-                band = col[b[g] + base:b[g] + base + E]
-                old = band.copy()
-                band[:] = fill
-                for s, ns in slot_remap.items():
-                    band[ns] = old[s]
-        # fresh RGA positions for every compacted list (ghosts included in
-        # the linearization, rank-compressed over the slotted entries)
-        if rset.ins_log[i]:
-            _, prow, pval = rset._linearized_pos_rows(
-                (i, lrow) for lrow in rset.ins_log[i])
-            col[prow] = pval
-        t.max_elems = max(
-            (sum(1 for (s, _, _, _) in e if s >= 0)
-             for e in rset.ins_log[i].values()), default=0)
-
-    t.n_ops = n_keep
-    return {"ops_before": n_ops0, "ops_after": n_keep,
-            "elems_before": n_elems0, "elems_after": n_elems1}
+            slot, elem, arank, parent = entries[k]
+            ns = -1
+            if keep_slot[k]:
+                ns = len(slot_remap)
+                slot_remap[slot] = ns
+            idx_map[k] = len(new_entries)
+            new_entries.append(
+                (ns, elem, arank, idx_map[parent] if parent >= 0 else -1))
+        # every slotted entry that lost its slot (ghosted or fully
+        # dropped) is a forbidden future anchor
+        ghosts = rset.ghost_eids[i]
+        for k in range(n):
+            slot, elem, arank, _parent = entries[k]
+            if slot >= 0 and not keep_slot[k]:
+                ghosts.add(make_elem_id(t.actors[arank], elem))
+        rset.ins_log[i][lrow] = new_entries
+        rset.ins_idx[i][lrow] = {
+            s: k for k, (s, _, _, _) in enumerate(new_entries) if s >= 0}
+        oi = rset.list_obj[i].get(lrow)
+        if oi is not None and t.elem_slots.get(oi):
+            # pure-Python encoder path: its eid->slot map lives here
+            eid_by_slot = {s: eid for eid, s in t.elem_slots[oi].items()}
+            t.elem_slots[oi] = {eid_by_slot[s]: ns
+                                for s, ns in slot_remap.items()}
+        # rewrite this list's element bands: the kept slots, densely
+        src = np.fromiter(slot_remap, np.int64, len(slot_remap))
+        for g, fill in _ELEM_BANDS:
+            band = col[b[g] + base:b[g] + base + E]
+            kept = band[src]
+            band[:] = fill
+            band[:len(src)] = kept
+    t.max_elems = max((sum(1 for (s, _, _, _) in e if s >= 0)
+                       for e in rset.ins_log[i].values()), default=0)
+    return n_elems0, n_elems1
 
 
 def compact(rset, floors: dict[str, dict[str, int]],
             pins: dict[str, set] | None = None) -> dict[str, dict]:
     """Compact every doc in `floors` (doc_id -> clock floor) in place.
     `pins` maps doc_id -> element ids that must keep their slots (anchors
-    of known-but-unadmitted changes; see compact_doc).
+    of known-but-unadmitted changes; see _compact_lanes).
 
-    Engine-level invalidation and native-encoder slot sync happen here;
-    the device buffer re-uploads lazily from the compacted host mirror.
+    Engine-level invalidation and native-encoder slot sync happen here, in
+    O(the documents compacted), CHUNK lanes at a time: where the device
+    copy is current, the lanes a compaction rewrote are written into it
+    from the mirror (`_put_lanes`), so the copy stays current; `_elems_hi`
+    is a high-water mark and stays (a compaction only frees slots, and the
+    caps never shrink). Counts the documents whose slots moved
+    (`rows_docs_compacted`).
     """
     rset._check_poisoned()
-    rset.sync_tables()
-    stats: dict[str, dict] = {}
-    touched = False
+    todo = []
     for doc_id, floor in floors.items():
         rset.compaction_floors[doc_id] = dict(floor)
         i = rset.doc_index.get(doc_id)
-        if i is None:
-            continue
-        s = compact_doc(rset, i, floor,
-                        (pins or {}).get(doc_id))
-        stats[doc_id] = s
-        if s["ops_after"] < s["ops_before"] \
-                or s["elems_after"] < s["elems_before"]:
-            touched = True
-            if rset._native is not None:
+        if i is not None:
+            rset._sync_stale_table(rset.tables[i])
+            todo.append((doc_id, i, floor, (pins or {}).get(doc_id)))
+    stats: dict[str, dict] = {}
+    rewritten = []
+    for lo in range(0, len(todo), CHUNK):
+        part = todo[lo:lo + CHUNK]
+        got = _compact_lanes(rset, [p[1] for p in part],
+                             [p[2] for p in part], [p[3] for p in part])
+        for (doc_id, i, _floor, _pins), s in zip(part, got):
+            if s.pop("changed"):
+                rewritten.append(i)
+            stats[doc_id] = s
+            if rset._native is not None and (
+                    s["ops_after"] < s["ops_before"]
+                    or s["elems_after"] < s["elems_before"]):
                 _sync_native_elem_slots(rset, i)
-    if touched:
-        rset._drop_copy()
-        rset._elems_hi = max((t.max_elems for t in rset.tables), default=0)
-        metrics.bump("rows_docs_compacted")
+    if rewritten and rset._dev_current:
+        rset._put_lanes(rewritten)
+        metrics.bump("rows_lanes_put", len(rewritten))
+    moved = sum(1 for s in stats.values()
+                if s["ops_after"] < s["ops_before"]
+                or s["elems_after"] < s["elems_before"])
+    if moved:
+        metrics.bump("rows_docs_compacted", moved)
     return stats
 
 

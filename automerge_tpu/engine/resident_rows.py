@@ -42,7 +42,8 @@ from .pallas_kernels import (gather_lanes, host_block_extents, join_steps,
                              lane_gather_plan, reconcile_rows_hash)
 from ..utils import flightrec, metrics, perfscope
 
-
+# lanes one _put_lanes call carries: one program shape for every count
+LANE_PUT = 32
 
 
 class DeviceDispatchError(RuntimeError):
@@ -70,17 +71,39 @@ class RowsBudgetError(RuntimeError):
     VMEM budget. Recoverable: the instance is untouched — compact the
     long-lived docs (ResidentRowsDocSet.compact, engine/compaction.py) to
     reclaim dominated/tombstoned slots and retry, or shard the DocSet. The
-    sync service does the compact-and-retry automatically."""
+    sync service compacts the documents a round takes past the caps
+    before it grows them (dispatch_round_frames' `compactor`), so it
+    raises this only where compaction could not make room: a floor an
+    idle peer holds down, or a document whose live state is that large.
+    `doc_ids` names the documents the round would take past the current
+    caps, where the round's precheck knows them: the round without them
+    fits, and the service refuses them alone."""
+
+    def __init__(self, msg: str, *, doc_ids=()):
+        super().__init__(msg)
+        self.doc_ids = tuple(doc_ids)
 
 
-def _budget_error(cap_ops: int, actors: int,
-                  elem_slots: int) -> RowsBudgetError:
+def _budget_error(cap_ops: int, actors: int, elem_slots: int,
+                  doc_ids=()) -> RowsBudgetError:
     return RowsBudgetError(
         f"this batch could grow the resident rows state past the "
         f"megakernel VMEM budget (ops<={cap_ops}, actors={actors}, "
         f"elem slots<={elem_slots}); compact the long-lived docs "
         f"(ResidentRowsDocSet.compact) or shard this DocSet across "
-        f"more rows instances")
+        f"more rows instances", doc_ids=doc_ids)
+
+
+def ins_anchors(i: int, cols, op_lo: int, op_hi: int):
+    """(i, anchor key) of each insert among ops [op_lo, op_hi) of wire
+    columns `cols` (a document's part): the pairs
+    ResidentRowsDocSet.check_ghost_anchors reads."""
+    from ..storage import _ACTION_IDX
+    acts = np.asarray(cols.op_action[op_lo:op_hi])
+    for j in np.flatnonzero(acts == _ACTION_IDX["ins"]).tolist():
+        k = int(cols.op_key[op_lo + j])
+        if k >= 0:
+            yield i, cols.keys[k]
 
 
 class CompactionAnchorError(RuntimeError):
@@ -183,6 +206,8 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._gid_memo: dict = {}     # id(frame columns) -> (them, ids)
         # (lane width, n_pad, dims) the lane route has run at (_warm_lanes)
         self._lanes_warm: set = set()
+        # mirror shapes whose lanes' put has run (_warm_put)
+        self._puts_warm: set = set()
         self._rows_ready = True
         self._alloc_rows()
         self.rows_dev = None
@@ -371,6 +396,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._dirty = True
         self._h_prev = None
         self._lane_trips.clear()
+        metrics.bump("rows_caps_grown")
         # re-layout preserves hashes but rewrites every lane: conservative
         self._mark_all_hash_dirty()
 
@@ -572,17 +598,20 @@ class ResidentRowsDocSet(ResidentDocSet):
     # ------------------------------------------------------------------
     # delta encoding to scatter triplets
 
-    def _reserve_for(self, rounds) -> None:
+    def _reserve_for(self, rounds, compactor=None) -> None:
         """Upper-bound capacity growth so row offsets stay fixed across the
         whole micro-batch. Counts submitted changes PLUS every change still
         buffered in the per-doc causal queues — a delta in this batch can
         release queued changes from earlier calls, so admitted counts are
-        bounded by (queued + submitted), not by this batch alone."""
+        bounded by (queued + submitted), not by this batch alone. A
+        `compactor` compacts the documents past the caps first, as
+        _precheck_round_frames does."""
         need_ops = self.op_count.copy()
         need_ch = self.change_count.copy()
-        n_elems = {}
+        n_elems = np.zeros(self.cap_docs, np.int64)
+        n_lists = np.zeros(self.cap_docs, np.int64)
         new_fids = {}
-        n_lists = {}
+        anchors = []
 
         def count(i, c):
             need_ch[i] += 1
@@ -592,16 +621,10 @@ class ResidentRowsDocSet(ResidentDocSet):
             new_fids[i] = new_fids.get(i, 0) + len(c.ops)
             for op in c.ops:
                 if op.action == "ins":
-                    n_elems[i] = n_elems.get(i, 0) + 1
-                    if op.key in self.ghost_eids[i]:
-                        raise CompactionAnchorError(
-                            f"insert anchored at compacted element "
-                            f"{op.key!r} in doc {self.doc_ids[i]!r}; the "
-                            f"sender is below the compaction horizon — "
-                            f"full resync required",
-                            doc_id=self.doc_ids[i])
+                    n_elems[i] += 1
+                    anchors.append((i, op.key))
                 elif op.action in ("makeList", "makeText"):
-                    n_lists[i] = n_lists.get(i, 0) + 1
+                    n_lists[i] += 1
 
         for i, t in enumerate(self.tables):
             for p in t.queue:  # _Pending records; rows path payloads are Changes
@@ -611,23 +634,19 @@ class ResidentRowsDocSet(ResidentDocSet):
                 i = self.doc_index[doc_id]
                 for c in changes:
                     count(i, c)
-        grow = {}
-        if need_ops.max(initial=0) > self.cap_ops:
-            grow["cap_ops"] = _pad_to(int(need_ops.max()))
+        self.check_ghost_anchors(anchors)
+        if compactor is not None:
+            # the round-frame precheck's rule (_precheck_round_frames):
+            # the documents past the caps compact first
+            over = self._over_caps(need_ops, n_elems, n_lists)
+            if len(over):
+                self._compact_over(over, compactor)
+                return self._reserve_for(rounds)
         if need_ch.max(initial=0) > self.cap_changes:
             # change ids live in the rows themselves (clock_op replaced the
             # per-change clock bands), so growing the change cap never
             # re-layouts the buffer.
             self.cap_changes = _pad_to(int(need_ch.max()))
-        cur_elems = max((len(s) for t in self.tables
-                         for s in t.elem_slots.values()), default=0)
-        add_elems = max(n_elems.values(), default=0)
-        if cur_elems + add_elems > self.cap_elems:
-            grow["cap_elems"] = _pad_to(cur_elems + add_elems)
-        cur_lists = max((len(t.list_rows) for t in self.tables), default=0)
-        add_lists = max(n_lists.values(), default=0)
-        if cur_lists + add_lists > self.cap_lists:
-            grow["cap_lists"] = _pad_to(cur_lists + add_lists, 1)
         need_fids = max((len(self.tables[i].fields) + n
                          for i, n in new_fids.items()), default=0)
         if need_fids > self.cap_fids:
@@ -637,10 +656,8 @@ class ResidentRowsDocSet(ResidentDocSet):
             self.cap_fids = _pad_to(need_fids)
         # budget-check the PROSPECTIVE caps before _grow re-lays the buffer:
         # a rejected batch must leave the instance fully usable
-        self._check_rows_budget(
-            grow.get("cap_ops", self.cap_ops),
-            grow.get("cap_lists", self.cap_lists)
-            * grow.get("cap_elems", self.cap_elems))
+        grow = {k: v for k, v in self._fit_or_raise(
+            need_ops, n_elems, n_lists).items() if v > getattr(self, k)}
         if grow:
             self._grow(**grow)
 
@@ -668,8 +685,11 @@ class ResidentRowsDocSet(ResidentDocSet):
         lens = np.fromiter(map(len, logs), np.int64, len(logs))
         starts = np.zeros(len(logs) + 1, np.int64)
         np.cumsum(lens, out=starts[1:])
-        ent = np.array(list(itertools.chain.from_iterable(logs)),
-                       np.int64).reshape(-1, 4)
+        # the entries' four ints in one flat pass: half the time of
+        # np.array over the list of tuples
+        ent = np.fromiter(itertools.chain.from_iterable(
+            itertools.chain.from_iterable(logs)), np.int64,
+            4 * int(starts[-1])).reshape(-1, 4)
         slots = ent[:, 0]
         pos = linearize_lists(ent[:, 1], ent[:, 2], ent[:, 3], starts)
         key = np.array(lists, np.int64).reshape(-1, 2)
@@ -1051,12 +1071,14 @@ class ResidentRowsDocSet(ResidentDocSet):
     # ------------------------------------------------------------------
     # device path
 
-    def apply_rounds(self, rounds, interpret: bool | None = None):
+    def apply_rounds(self, rounds, interpret: bool | None = None,
+                     compactor=None):
         """Apply a micro-batch of sync rounds in ONE device dispatch.
 
         rounds: list of {doc_id: [Change]} — applied in order, reconciling
         after each. Returns np.ndarray [len(rounds), n_docs] uint32 state
-        hashes (one row per round).
+        hashes (one row per round). `compactor`: as dispatch_round_frames'
+        (the Python encoder's served path).
 
         Actor ranks are the sorted-string ranks of the WHOLE micro-batch's
         actor universe (all rounds are registered before any is encoded, so
@@ -1078,7 +1100,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             interpret = jax.default_backend() != "tpu"
         for r in rounds:
             self._register_actors(r)
-        self._reserve_for(rounds)
+        self._reserve_for(rounds, compactor)
         with self._admission_guard():
             pre_rows = self.rows_host.copy() \
                 if not self._dev_current else None
@@ -1181,23 +1203,19 @@ class ResidentRowsDocSet(ResidentDocSet):
     # ------------------------------------------------------------------
     # native columnar ingress
 
-    def _check_ghost_anchors_cols(self, i: int, cols, op_lo: int,
-                                  op_hi: int) -> None:
-        """Reject ins ops anchored at compacted-away elements BEFORE
-        admission (see CompactionAnchorError)."""
-        ghosts = self.ghost_eids[i]
-        if not ghosts:
-            return
-        from ..storage import _ACTION_IDX
-        acts = np.asarray(cols.op_action[op_lo:op_hi])
-        for j in np.nonzero(acts == _ACTION_IDX["ins"])[0].tolist():
-            k = int(cols.op_key[op_lo + j])
-            if k >= 0 and cols.keys[k] in ghosts:
+    def check_ghost_anchors(self, anchors) -> None:
+        """Reject, BEFORE admission, an insert anchored at an element
+        compaction reclaimed (see CompactionAnchorError). `anchors`: the
+        (doc index, anchor key) pairs of the ingress's inserts, as Change
+        ops give them or as ins_anchors reads them out of wire columns."""
+        ghosts = self.ghost_eids
+        for i, key in anchors:
+            if key in ghosts[i]:
                 raise CompactionAnchorError(
-                    f"insert anchored at compacted element "
-                    f"{cols.keys[k]!r} in doc {self.doc_ids[i]!r}; the "
-                    f"sender is below the compaction horizon — full "
-                    f"resync required", doc_id=self.doc_ids[i])
+                    f"insert anchored at compacted element {key!r} in doc "
+                    f"{self.doc_ids[i]!r}; the sender is below the "
+                    f"compaction horizon — full resync required",
+                    doc_id=self.doc_ids[i])
 
     def _precheck_rows_budget_cols(self, rounds) -> None:
         """Upper-bound VMEM-budget check from the submitted columns plus the
@@ -1210,17 +1228,17 @@ class ResidentRowsDocSet(ResidentDocSet):
         list_idxs = (_ACTION_IDX["makeList"], _ACTION_IDX["makeText"])
 
         need_ops = self.op_count.copy()
-        n_elems: dict[int, int] = {}
-        n_lists: dict[int, int] = {}
+        n_elems = np.zeros(self.cap_docs, np.int64)
+        n_lists = np.zeros(self.cap_docs, np.int64)
 
         def count(i, cols, j):
             o0, o1 = int(cols.op_off[j]), int(cols.op_off[j + 1])
             need_ops[i] += o1 - o0
             acts = np.asarray(cols.op_action[o0:o1])
-            n_elems[i] = n_elems.get(i, 0) + int((acts == ins_idx).sum())
-            n_lists[i] = n_lists.get(i, 0) + int(
-                np.isin(acts, list_idxs).sum())
-            self._check_ghost_anchors_cols(i, cols, o0, o1)
+            n_elems[i] += int((acts == ins_idx).sum())
+            n_lists[i] += int(np.isin(acts, list_idxs).sum())
+            if self.ghost_eids[i]:
+                self.check_ghost_anchors(ins_anchors(i, cols, o0, o1))
 
         for i, t in enumerate(self.tables):
             for p in t.queue:  # native instances queue (cols, j) payloads
@@ -1230,23 +1248,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                 i = self.doc_index[doc_id]
                 for j in range(cols.n_changes):
                     count(i, cols, j)
-
-        cap_ops = max(self.cap_ops,
-                      _pad_to(int(need_ops.max(initial=1))))
-        # a document's lists after the round: its own largest and count
-        # now, and at most what its ops add
-        tables = self.tables
-        cap_elems = max(self.cap_elems, _pad_to(max(
-            (tables[i].max_elems + k for i, k in n_elems.items()),
-            default=0)))
-        cap_lists = max(self.cap_lists, _pad_to(max(
-            (tables[i].n_lists + k for i, k in n_lists.items()),
-            default=0), 1))
-        from .pack import rows_dims_fit
-        if not rows_dims_fit(cap_ops, self.cap_actors,
-                             cap_lists * cap_elems):
-            raise _budget_error(cap_ops, self.cap_actors,
-                                cap_lists * cap_elems)
+        self._fit_or_raise(need_ops, n_elems, n_lists)
 
     def _native_encode_round(self, cols_by_doc):
         """Causal admission (Python, per change) + ONE native batch encode
@@ -1408,17 +1410,20 @@ class ResidentRowsDocSet(ResidentDocSet):
             out[:n] = self._hash_mirror[:n]
             return self._to_dev(out)
 
-    def dispatch_round_frames(self, frames,
-                              interpret: bool | None = None) -> None:
+    def dispatch_round_frames(self, frames, interpret: bool | None = None,
+                              compactor=None) -> None:
         """The dispatch half alone, for a caller with host work of its
         own that needs no hash (the sync service's tail): everything up to
         and including the dispatch of the round's reconcile. The caller
         owes one collect_round() before it lets anyone read the round as
         flushed; the engine stays sound if it never comes (the round's
         lanes stay dirty, and every entry that reads a hash or dirties a
-        lane settles or drops the unsettled round first)."""
+        lane settles or drops the unsettled round first). `compactor`
+        (doc ids -> (floors, pins)) lets the precheck compact the
+        documents the round takes past the caps (_precheck_round_frames);
+        without one the round grows the caps or raises RowsBudgetError."""
         with metrics.trace("rows_round_apply"):
-            self._dispatch_round_frames(frames, interpret)
+            self._dispatch_round_frames(frames, interpret, compactor)
 
     def collect_round(self, interpret: bool | None = None) -> None:
         """The collect half of dispatch_round_frames: the round's hashes
@@ -1443,7 +1448,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             # round leaves none
             self._refresh_hash_mirror(None, interpret)
 
-    def _dispatch_round_frames(self, frames, interpret):
+    def _dispatch_round_frames(self, frames, interpret, compactor=None):
         """The dispatch half of a round (sync/frames.py AMR1: one columnar
         frame per round covering every document touched that round):
         decode, registration, precheck, admission and the native delta
@@ -1472,7 +1477,8 @@ class ResidentRowsDocSet(ResidentDocSet):
                   for f in frames]
         if self._native is None:
             # Python-encoder fallback: same semantics, per-doc Change path.
-            h = self.apply_rounds([rc.to_dict() for rc in rounds], interpret)
+            h = self.apply_rounds([rc.to_dict() for rc in rounds], interpret,
+                                  compactor)
             return self._to_dev(h[-1] if len(h) else
                                 self.hashes(interpret=interpret)), False
         if interpret is None:
@@ -1490,7 +1496,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                     self._register_round_actors(rc)
                 metrics.observe("rows_actor_register_seconds",
                                 time.perf_counter() - t0)
-                self._precheck_round_frames(rounds)
+            self._precheck_round_frames(rounds, compactor)
             # steady-state fast path: ONE vectorized admission + native
             # encode for the whole micro-batch; falls back to per-round
             # encode (full protocol handling) when any change breaks the
@@ -1527,9 +1533,10 @@ class ResidentRowsDocSet(ResidentDocSet):
                     # bucket shapes see this round's ops
                     route = round_dispatch.reconcile_route(
                         self, touched, round_docs)
-                    return (self._dispatch_final(trip_list, route,
-                                                 interpret),
-                            route.readback and route.kind != "deferred")
+                    h = self._dispatch_final(trip_list, route, interpret)
+                    if self._dev_current and not self.lazy_dispatch:
+                        self._warm_put()
+                    return h, route.readback and route.kind != "deferred"
 
     def _frame_gids(self, cols) -> np.ndarray:
         """The instance's id of each name in a frame's actor table; a
@@ -1569,19 +1576,28 @@ class ResidentRowsDocSet(ResidentDocSet):
                 names.setdefault(i, set()).add(cols.actors[k])
             self._register_doc_actors(names)
 
-    def _precheck_round_frames(self, rounds) -> None:
+    def _precheck_round_frames(self, rounds, compactor=None) -> None:
         """Vectorized VMEM-budget precheck for round frames (the analog of
         _precheck_rows_budget_cols, one numpy pass per round instead of
         per-change slicing), plus the ghost-anchor reject for compacted
-        docs."""
-        for rc in rounds:
-            if any(self.ghost_eids[self.doc_index[d]] for d in rc.doc_ids):
-                off = np.asarray(rc.change_off, np.int64)
-                op_off = np.asarray(rc.cols.op_off, np.int64)
-                for k, d in enumerate(rc.doc_ids):
-                    self._check_ghost_anchors_cols(
-                        self.doc_index[d], rc.cols,
-                        int(op_off[off[k]]), int(op_off[off[k + 1]]))
+        docs. With a `compactor` the documents the round would take past
+        the current caps are compacted first, each to its own floor
+        (_compact_over); only what still passes the caps grows them, and
+        only where a kernel takes the grown dims: else RowsBudgetError."""
+        with perfscope.phase("encode"):
+            need = self._round_needs(rounds)
+            over = self._over_caps(*need) if compactor is not None else ()
+        if len(over):
+            self._compact_over(over, compactor)
+            with perfscope.phase("encode"):
+                need = self._round_needs(rounds)
+        self._fit_or_raise(*need)
+
+    def _round_needs(self, rounds):
+        """Each document's op rows, inserts and new lists once the round
+        and the causal queues are admitted, as [cap_docs] arrays (an upper
+        bound: duplicates count as applied). An insert of the round
+        anchored at a compacted element raises CompactionAnchorError."""
         from ..storage import _ACTION_IDX
         ins_idx = _ACTION_IDX["ins"]
         l1, l2 = _ACTION_IDX["makeList"], _ACTION_IDX["makeText"]
@@ -1598,6 +1614,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                 acts = np.asarray(cols.op_action[o0:o1])
                 n_elems[i] += int((acts == ins_idx).sum())
                 n_lists[i] += int(((acts == l1) | (acts == l2)).sum())
+        ghosts = self.ghost_eids
         for rc in rounds:
             cols = rc.cols
             doc_idx = np.fromiter((self.doc_index[d] for d in rc.doc_ids),
@@ -1607,12 +1624,64 @@ class ResidentRowsDocSet(ResidentDocSet):
             ops_per_doc = op_off[off[1:]] - op_off[off[:-1]]
             np.add.at(need_ops, doc_idx, ops_per_doc)
             acts = np.asarray(cols.op_action)
-            if (acts == ins_idx).any() or (acts == l1).any() \
-                    or (acts == l2).any():
+            is_ins = acts == ins_idx
+            is_list = (acts == l1) | (acts == l2)
+            if is_ins.any() or is_list.any():
                 op_doc = np.repeat(doc_idx, ops_per_doc)
-                np.add.at(n_elems, op_doc, acts == ins_idx)
-                np.add.at(n_lists, op_doc, (acts == l1) | (acts == l2))
+                np.add.at(n_elems, op_doc, is_ins)
+                np.add.at(n_lists, op_doc, is_list)
+            if is_ins.any():
+                for k, i in enumerate(doc_idx.tolist()):
+                    if ghosts[i]:
+                        self.check_ghost_anchors(ins_anchors(
+                            i, cols, int(op_off[off[k]]),
+                            int(op_off[off[k + 1]])))
+        return need_ops, n_elems, n_lists
 
+    def _over_caps(self, need_ops, n_elems, n_lists,
+                   fresh: bool = False) -> np.ndarray:
+        """Documents the round would take past the current op or element
+        caps: with history alone, the ones compaction can make room in (a
+        fresh document has nothing to reclaim); with `fresh` every one,
+        and those past the list cap too."""
+        over = need_ops > self.cap_ops
+        el = np.flatnonzero(n_elems)
+        if len(el):
+            max_elems = np.fromiter((self.tables[i].max_elems for i in el),
+                                    np.int64, len(el))
+            over[el[max_elems + n_elems[el] > self.cap_elems]] = True
+        if fresh:
+            ls = np.flatnonzero(n_lists)
+            n_now = np.fromiter((self.tables[i].n_lists for i in ls),
+                                np.int64, len(ls))
+            over[ls[n_now + n_lists[ls] > self.cap_lists]] = True
+        over = np.flatnonzero(over[:len(self.doc_ids)])
+        return over if fresh else over[self.op_count[over] > 0]
+
+    def _compact_over(self, over, compactor) -> None:
+        """Compact the documents `over` (indices), each to the floor the
+        `compactor` gives it: compactor(doc ids) -> (floors, pins), the
+        sync service's clock floors and the anchors of its pending round.
+        Where the device copy is current the rewritten lanes are written
+        into it (compaction.compact, _put_lanes)."""
+        docs = [self.doc_ids[i] for i in over.tolist()]
+        t0 = time.perf_counter()
+        with perfscope.phase("compact"):
+            floors, pins = compactor(docs)
+            stats = self.compact(floors, pins)
+        metrics.observe("rows_compact_seconds", time.perf_counter() - t0)
+        metrics.bump("rows_compact_docs", len(docs))
+        ops0 = sum(s["ops_before"] for s in stats.values())
+        metrics.bump("rows_compact_ops_before", ops0)
+        metrics.bump("rows_compact_ops_reclaimed",
+                     ops0 - sum(s["ops_after"] for s in stats.values()))
+        metrics.bump("rows_compact_slots_reclaimed", sum(
+            s["elems_before"] - s["elems_after"] for s in stats.values()))
+
+    def _fit_or_raise(self, need_ops, n_elems, n_lists) -> dict:
+        """The caps the round needs, {"cap_ops", "cap_elems", "cap_lists"};
+        RowsBudgetError where they are dims no kernel takes, naming the
+        documents past the current caps (the round without them fits)."""
         cap_ops = max(self.cap_ops, _pad_to(int(need_ops.max(initial=1))))
         # a document's lists after the round: its own largest and count
         # now, and at most what its ops add
@@ -1626,8 +1695,12 @@ class ResidentRowsDocSet(ResidentDocSet):
         from .pack import rows_dims_fit
         if not rows_dims_fit(cap_ops, self.cap_actors,
                              cap_lists * cap_elems):
+            over = self._over_caps(need_ops, n_elems, n_lists, fresh=True)
             raise _budget_error(cap_ops, self.cap_actors,
-                                cap_lists * cap_elems)
+                                cap_lists * cap_elems,
+                                [self.doc_ids[i] for i in over.tolist()])
+        return {"cap_ops": cap_ops, "cap_elems": cap_elems,
+                "cap_lists": cap_lists}
 
     def _refresh_admission_cache(self) -> None:
         """Rebuild the dense clock/frontier cache rows for stale docs. The
@@ -2085,6 +2158,44 @@ class ResidentRowsDocSet(ResidentDocSet):
                 padded_dev)
         self._hash_handle = self._h_prev = None
 
+    def _put_lanes(self, idxs) -> None:
+        """The mirror's columns of lanes `idxs` into the current device
+        copy, where a lane was rewritten in place (compaction): uploaded
+        LANE_PUT at a time, the last lane repeated into the padding, and
+        written on the device column by column (_put_cols), one program
+        whatever the count. Hashes are the caller's: a compaction moves
+        none, and marks its lanes dirty for the kernel's check. Cells of
+        these lanes pended for the next scatter (a registration's) predate
+        the rewrite: the columns carry their truth, so they are dropped.
+
+        A put that fails leaves the copy behind the mirror (or donated
+        away): the copy is dropped, as after any failed dispatch, and the
+        error is DeviceDispatchError. A compaction runs in the precheck,
+        before anything of the round is admitted, so the caller replays
+        the round (admission_complete=False); the next route uploads the
+        mirror."""
+        if self._lane_trips:
+            self._lane_trips = [t[~np.isin(t[:, 1], idxs)]
+                                for t in self._lane_trips]
+        try:
+            for lo in range(0, len(idxs), LANE_PUT):
+                part = list(idxs[lo:lo + LANE_PUT])
+                sel = np.asarray(part + [part[-1]] * (LANE_PUT - len(part)),
+                                 np.int32)
+                cols = self._to_dev(
+                    np.ascontiguousarray(self.rows_host[:, sel]))
+                sel_dev = self._to_dev(sel)
+                with dispatchledger.call_scope(
+                        "rows_put_lanes", backend="device", docs=len(part),
+                        axes={"docs": (len(part), LANE_PUT)}):
+                    self.rows_dev = metrics.dispatch_jit(
+                        "put_lanes", _put_cols, self.rows_dev, cols, sel_dev)
+        except Exception as e:
+            self._drop_copy()
+            metrics.bump("rows_dispatch_failed")
+            raise DeviceDispatchError(
+                f"lanes' put failed: {e}", admission_complete=False) from e
+
     def _dispatch_final(self, trip_list, route, interpret):
         """Execute the route dispatch.reconcile_route gave this round (its
         docstring holds the table), up to the dispatch of its reconcile.
@@ -2184,6 +2295,18 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._dirty = False
         self._h_prev = None
         self._lane_trips.clear()
+
+    def _warm_put(self) -> None:
+        """Write lane 0's own column into the current copy (_put_lanes)
+        behind the first round that leaves the copy current in its
+        layout, so that the program a compaction runs is compiled by the
+        first round after a load or a re-layout, and not inside the first
+        round that compacts, which comes later and at no round one can
+        name."""
+        key = self.rows_host.shape
+        if key not in self._puts_warm:
+            self._puts_warm.add(key)
+            self._put_lanes([0])
 
     def _apply_final_route(self, trip_list, route, interpret):
         """`blocks`, or `whole` for a round that never plans: scatter and
@@ -2358,8 +2481,8 @@ class ResidentRowsDocSet(ResidentDocSet):
         Where the device copy is current the columns are gathered out of
         it, on the device (gather_lanes), and only the lane indices cross
         the link. Where it is not (after add_docs pad growth, _grow, a
-        remap, compact, a failed dispatch) they are gathered out of the
-        host mirror and uploaded. Dispatched, not read back: the vector
+        failed dispatch) they are gathered out of the host mirror and
+        uploaded. Dispatched, not read back: the vector
         is the unsettled round until _settle."""
         k = len(idxs)
         k_pad = pad_to_lanes(k)
@@ -2558,6 +2681,18 @@ def _scatter_trips(rows, trips):
     `indices_are_sorted`, true as both are of merged triplets: with them
     the chip's scatter lost a cell of 12,300 every few rounds."""
     return rows.at[trips[:, 0], trips[:, 1]].set(trips[:, 2], mode="drop")
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _put_cols(rows, cols, lanes):
+    """Columns `cols` [rows, k] into the resident rows, donated, at lanes
+    `lanes` [k]: one in-place dynamic_update_slice a column, where a
+    scatter over the lane axis would have XLA re-lay the whole buffer.
+    A lane named twice carries the same column both times."""
+    def put(k, rows):
+        col = jax.lax.dynamic_slice_in_dim(cols, k, 1, axis=1)
+        return jax.lax.dynamic_update_slice(rows, col, (0, lanes[k]))
+    return jax.lax.fori_loop(0, lanes.shape[0], put, rows)
 
 
 @partial(jax.jit, static_argnames=("dims", "interpret"),

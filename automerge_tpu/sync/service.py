@@ -33,8 +33,10 @@ import os
 from ..core.change import Change
 from ..engine import dispatchledger
 from ..engine.resident import ResidentDocSet
-from ..engine.resident_rows import CompactionAnchorError, DeviceDispatchError
-from ..native.wire import changes_part, changes_to_columns
+from ..engine.resident_rows import (CompactionAnchorError,
+                                    DeviceDispatchError, RowsBudgetError,
+                                    ins_anchors)
+from ..native.wire import ChangesPart, changes_part, changes_to_columns
 from ..utils import (chaos, flightrec, lockprof, metrics, oplag, perfscope,
                      tracer)
 from . import docledger, epochs, tenantledger
@@ -979,10 +981,13 @@ class EngineDocSet:
         if rset.ghost_eids[i]:
             # reject a ghost-anchored ingress HERE, before it coalesces:
             # only the offending sender's call errors, never a round
-            # shared with innocent peers. The check reads columns, so a
-            # compacted document's ingress is converted at its call.
-            part = part.columns()
-            rset._check_ghost_anchors_cols(i, part, 0, part.n_ops)
+            # shared with innocent peers. A ChangesPart is read as the
+            # caller's ops and stays unconverted, for the round's one pass
+            rset.check_ghost_anchors(
+                ((i, op.key) for c in part.changes for op in c.ops
+                 if op.action == "ins")
+                if isinstance(part, ChangesPart)
+                else ins_anchors(i, part, 0, part.n_ops))
         self._pending.setdefault(doc_id, []).append(part)
         tok = oplag.admit(doc_id)
         tracer.admit(doc_id)
@@ -1084,8 +1089,8 @@ class EngineDocSet:
                 rset = self._resident
                 i = rset.doc_index[e.doc_id]
                 if rset.ghost_eids[i]:
-                    rset._check_ghost_anchors_cols(
-                        i, e.cols, 0, len(e.cols.op_action))
+                    rset.check_ghost_anchors(
+                        ins_anchors(i, e.cols, 0, len(e.cols.op_action)))
             except BaseException as exc:
                 e.ticket.resolve(exc)
                 continue
@@ -1364,6 +1369,7 @@ class EngineDocSet:
                                     n_ops: int) -> None:
         from .frames import round_from_parts
 
+        refused = None
         try:
             # one frame for the whole coalesced round: the unit the engine
             # routes (engine/dispatch.py reconcile_route); converged hashes
@@ -1373,7 +1379,32 @@ class EngineDocSet:
                 round_ = round_from_parts(pending)
             # the engine's dispatch half: the round admitted and committed
             # on the host, its device work under way, no hash read back
-            self._apply_with_compaction(rset, pending, round_)
+            try:
+                self._dispatch_round(rset, pending, round_)
+            except RowsBudgetError as e:
+                if not e.doc_ids:
+                    raise
+                # The documents no kernel can take even compacted (a floor
+                # an idle peer holds down) are refused alone, before
+                # admission, as a ghost-anchored round is: dropped
+                # unacknowledged, their riders told so, the error raised
+                # once this flush is done. The clock this node advertises
+                # lacks their changes, so their senders offer them again.
+                # The rest of the round fits the current caps and admits
+                # now: one such list never stops the node.
+                refused = e
+                for d in e.doc_ids:
+                    n_ops -= sum(p.n_ops for p in pending.pop(d, ()))
+                riders = self._inflight_tickets
+                self._inflight_tickets = [
+                    t for t in riders if t.doc_id not in e.doc_ids]
+                epochs.EpochIngestBuffer.resolve(
+                    [t for t in riders if t.doc_id in e.doc_ids], e)
+                if not pending:
+                    raise
+                with perfscope.phase("encode"):
+                    round_ = round_from_parts(pending)
+                self._dispatch_round(rset, pending, round_)
         except DeviceDispatchError as e:
             # The admitted part of the flush is durable on the host
             # (change_log, clocks, queue and the row mirror are consistent).
@@ -1496,18 +1527,21 @@ class EngineDocSet:
             # tail's phase and after the riders are released, not at the
             # caller's frame exit where no span would see the time
             pending.clear()
+        if refused is not None:
+            raise refused
 
-    def _apply_with_compaction(self, rset, pending: dict, round_) -> None:
+    def _dispatch_round(self, rset, pending: dict, round_) -> None:
         """Apply one coalesced round (`round_`, the frame of the parts in
         `pending`) through the engine's dispatch half (the caller collects
-        behind its tail); on VMEM-budget pressure, compact
-        every doc to its known-peer clock floor (engine/compaction.py) and
-        retry once. RowsBudgetError is raised BEFORE admission, so the
-        retry re-submits the identical round against the reclaimed state —
-        this is what lets a single long-lived document outlive the
-        pre-compaction budget instead of hitting a hard admission wall."""
-        from ..engine.resident_rows import RowsBudgetError
-
+        behind its tail), once. The documents the round would take past
+        the resident caps are compacted first, each to its known-peer
+        clock floor with the round's insert anchors pinned (the engine's
+        precheck asks `compactor` for those documents alone); only what
+        compaction cannot make room for grows the caps, and where no
+        kernel takes the grown dims the round raises RowsBudgetError,
+        before admission. This is what lets a long-lived document outlive
+        the pre-compaction budget instead of hitting a hard admission
+        wall."""
         if not getattr(self, "_lazy_resolved", False):
             # CPU-backend services defer the reconcile to hash reads
             # (admission is O(changes); a per-flush reconcile is O(state));
@@ -1518,17 +1552,16 @@ class EngineDocSet:
             rset.lazy_dispatch = jax.default_backend() == "cpu"
             self._lazy_resolved = True
 
-        try:
-            rset.dispatch_round_frames([round_])
-        except RowsBudgetError:
-            floors = {d: self._compaction_floor_locked(d)
-                      for d in rset.doc_ids}
-            stats = rset.compact(floors, self._pending_anchor_pins(pending))
-            if not any(s["ops_after"] < s["ops_before"]
-                       or s["elems_after"] < s["elems_before"]
-                       for s in stats.values()):
-                raise   # nothing reclaimable: the batch genuinely oversized
-            rset.dispatch_round_frames([round_])
+        def compactor(docs: list) -> tuple:
+            # the flush holds the lock already: taken again (re-entrant),
+            # so that the floors' reads of the peer registry are seen
+            # under it wherever the engine calls this
+            with self._lock:
+                return ({d: self._compaction_floor_locked(d) for d in docs},
+                        self._pending_anchor_pins(
+                            {d: pending[d] for d in docs if d in pending}))
+
+        rset.dispatch_round_frames([round_], compactor=compactor)
 
     @staticmethod
     def _pending_anchor_pins(pending: dict) -> dict[str, set]:

@@ -178,7 +178,22 @@ COUNTERS: dict[str, str] = {
     "rows_log_rebuilt": "engine rebuilds replayed from the admitted log",
     "rows_engine_poisoned": "engines poisoned by an unrecoverable failure",
     "rows_horizon_truncated": "log prefixes truncated below the horizon",
-    "rows_docs_compacted": "documents compacted in place",
+    "rows_docs_compacted":
+        "documents whose op or element slots a compaction reclaimed, by "
+        "any caller (engine/compaction.compact: the served round, "
+        "bootstrap, the chunked replay)",
+    "rows_compact_docs":
+        "documents the served round compacted before admission: those it "
+        "would take past the resident caps (resident_rows._compact_over)",
+    "rows_compact_ops_before":
+        "op rows those documents held before their compaction",
+    "rows_compact_ops_reclaimed":
+        "op rows their compaction reclaimed",
+    "rows_compact_slots_reclaimed":
+        "element slots their compaction reclaimed",
+    "rows_caps_grown":
+        "re-layouts of the resident rows for grown caps (resident_rows."
+        "_grow): each re-shapes the whole buffer",
     "rows_apply_block_calls":
         "classic-route applies that reconciled only the dirty 128-lane "
         "blocks of the resident rows (resident_rows._apply_final)",
@@ -188,6 +203,9 @@ COUNTERS: dict[str, str] = {
     "rows_lane_gathers_host":
         "lane reconciles whose columns were gathered out of the host "
         "mirror and uploaded (the device copy was not current)",
+    "rows_lanes_put":
+        "lanes a compaction rewrote that were written into the current "
+        "device copy from the mirror (resident_rows._put_lanes)",
     "rows_join_steps_run":
         "actor-band trips of the reconcile kernel's domination join that "
         "lane reconciles ran, each 128-lane block to its live extent "
@@ -616,6 +634,10 @@ HISTOGRAMS: dict[str, str] = {
         "triplets and the fresh positions of every list it inserted into "
         "(resident_rows._cols_triplets), observed once a round that "
         "inserts, inside phase commit",
+    "rows_compact_seconds":
+        "a round's per-document compaction (resident_rows._compact_over: "
+        "floors, pins and compaction of the documents past the caps), "
+        "observed once a round that compacts, phase compact",
     "sync_shard_fanout_seconds":
         "one fan-out of the sharded service: the end of a batch()'s body "
         "(or the entry of flush()) to the last shard's return",

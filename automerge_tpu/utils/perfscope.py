@@ -25,7 +25,8 @@ be checkable run over run:
   section is read) and, while a profiler session runs, the same interval
   stands on the device trace's clock under the same name. On the served
   path the phases are a partition of the blocking thread's time, layer
-  boundary by layer boundary (admit → commit_wait | encode → commit →
+  boundary by layer boundary (admit → commit_wait | encode → [compact →
+  encode →] commit →
   upload → dispatch → route → pack → upload → dispatch → publish →
   readback[device_wait] → publish);
   the `metrics.trace` spans `sync_request`, `sync_round_flush` and
@@ -99,6 +100,11 @@ PHASES: dict[str, str] = {
               "the admission guard's snapshot of log lengths and the "
               "native delta encode "
               "(resident_rows._dispatch_round_frames)",
+    "compact": "the round's per-document compaction on the served path: "
+               "the floors and pins of the documents the round would take "
+               "past the resident caps, and their compaction "
+               "(resident_rows._compact_over, inside the precheck, out of "
+               "`encode`)",
     "commit": "the encoded round committed to the host row mirror: growth, "
               "scatter triplets, dirty marks, dedup and padding "
               "(resident_rows)",

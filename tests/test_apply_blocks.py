@@ -271,8 +271,10 @@ def _round_after_compact(f, monkeypatch):
     _round_route(monkeypatch)
     stats = f.rset.compact({f.ids[42]: {"W": len(f.logs[42])}})
     assert stats[f.ids[42]]["ops_after"] < stats[f.ids[42]]["ops_before"]
-    assert f.rset.rows_dev is None
-    f.round([42, 43], "host")
+    # the copy stays current: the rewritten lane was written into it
+    assert f.rset._dev_current and np.array_equal(
+        np.asarray(f.rset.rows_dev)[:, 42], f.rset.rows_host[:, 42])
+    f.round([42, 43], "device")
     f.round([42, 44], "device")
 
 
@@ -321,8 +323,12 @@ def _after_compact(f, monkeypatch):
     f.routed([42], 1, 1)       # a second write of "n": the first is dominated
     stats = f.rset.compact({f.ids[42]: {"W": len(f.logs[42])}})
     assert stats[f.ids[42]]["ops_after"] < stats[f.ids[42]]["ops_before"]
-    assert f.rset.rows_dev is None and f.rset._h_prev is None
-    f.routed([42], 0)
+    # the copy and the vector stay: the rewritten lane was written into
+    # the copy, and its hash did not move
+    assert f.rset._dev_current and f.rset._h_prev is not None
+    assert np.array_equal(np.asarray(f.rset.rows_dev)[:, 42],
+                          f.rset.rows_host[:, 42])
+    f.routed([42], 1, 1)
     f.routed([43], 1, 1)
 
 

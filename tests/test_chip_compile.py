@@ -20,7 +20,10 @@ writers, loads at (512, 8, 32), a working set of 22,248 of the budget's
 [8360, 10112] fleet, and the round's scatter and gather at that height.
 `boards10k`, eight devices a board and three lists of 128 element slots,
 loads at (512, 8, 512), past the standard kernel's budget: the XL variant
-over [10760, 1280] and the round's scatter into [10760, 10112]. And
+over [10760, 1280] and the round's scatter into [10760, 10112]. `lists10k`,
+four devices and two lists of 256 slots a document, loads at (512, 4, 512):
+the XL variant over [8708, 1280], the round's scatter and gather, and the
+in-place put of the lanes a round compacted. And
 each compiled program must hold an instruction that the cell's roofline
 metric finds by the patterns of its own file under benchmarks/metrics/: a
 renamed kernel then fails here, and not as `output_malformed` on the chip.
@@ -71,6 +74,9 @@ DEVICES_CAPS = (512, 8, 32)    # fleet10k-devices': eight writers a heavy doc
 # boards10k's: eight devices a board, three lists of 128 element slots (the
 # list axis pads to four): past the standard kernel's budget, the XL variant
 BOARDS_CAPS = (512, 8, 512)
+# lists10k's: four devices a list, two lists of 256 element slots a
+# document: the XL variant over [8708, .]
+LISTS_CAPS = (512, 4, 512)
 BENCH_STORM_LANES = 1_280      # a storm request's dirty documents, padded
 SHARD_LANES = 2_560            # pad_to_lanes(a shard's 2,510 or 2,512 documents)
 SHARD_STORM_LANES = (256, 384, 512)   # a quarter of a round: 262-354 documents
@@ -85,6 +91,9 @@ DEVICES_TRIP_PADS = (16_384, 32_768)
 # positions of its whole list: a round's scatter sorts s32[131072] on the
 # chip, and a smaller round pads to 65,536
 BOARDS_TRIP_PADS = (65_536, 131_072)
+# lists10k: 3.6 ops a change, and the positions of some 900 lists of
+# 100-180 slots a round
+LISTS_TRIP_PADS = (131_072, 262_144)
 
 
 def _dims(i, a, le):
@@ -179,6 +188,17 @@ def _gather_lanes(lanes, k_pad, caps=BENCH_CAPS):
     return build
 
 
+def _put_cols(lanes, caps):
+    """A compaction's put of 32 rewritten lanes into the resident rows."""
+    def build(chip):
+        from automerge_tpu.engine.resident_rows import LANE_PUT, _put_cols
+        rows = rows_count(*caps)
+        return _put_cols.lower(chip.one((rows, lanes)),
+                               chip.one((rows, LANE_PUT)),
+                               chip.one((LANE_PUT,)))
+    return build
+
+
 def _scan_rounds_fleet(chip):
     from automerge_tpu.engine.resident_rows import _scan_rounds
     return _scan_rounds.lower(
@@ -266,6 +286,12 @@ CASES = {
     # boards10k.storm: a round's gathered lanes through the XL variant
     "megakernel-boards-xl-storm": (
         _megakernel(*BOARDS_CAPS, BENCH_STORM_LANES), True),
+    # lists10k.storm: a round's gathered lanes through the XL variant, and
+    # the put of a round's compacted lanes, written in place (a put that
+    # copies the buffer fails on its temporaries)
+    "megakernel-lists-xl-storm": (
+        _megakernel(*LISTS_CAPS, BENCH_STORM_LANES), True),
+    "put_lanes-lists": (_put_cols(FLEET_LANES, LISTS_CAPS), False, 1 << 20),
     "scan_rounds-fleet": (_scan_rounds_fleet, True),
     "merge_spans": (_merge_spans, False),
     "resolve_moves": (_resolve_moves, False),
@@ -283,7 +309,8 @@ CASES.update({
         ("fleet", FLEET_LANES, STORM_TRIP_PADS, BENCH_CAPS),
         ("shard", SHARD_LANES, SHARD_TRIP_PADS, BENCH_CAPS),
         ("devices", FLEET_LANES, DEVICES_TRIP_PADS, DEVICES_CAPS),
-        ("boards", FLEET_LANES, BOARDS_TRIP_PADS, BOARDS_CAPS))
+        ("boards", FLEET_LANES, BOARDS_TRIP_PADS, BOARDS_CAPS),
+        ("lists", FLEET_LANES, LISTS_TRIP_PADS, LISTS_CAPS))
     for trips in pads})
 # a gather that copies the buffer it reads (XLA's `rows[:, sel]` does)
 # fails here: its only large buffer is its output
@@ -294,7 +321,8 @@ CASES.update({
         ("fleet", FLEET_LANES, STORM_LANES, BENCH_CAPS),
         ("shard", SHARD_LANES, SHARD_STORM_LANES, BENCH_CAPS),
         ("devices", FLEET_LANES, STORM_LANES, DEVICES_CAPS),
-        ("boards", FLEET_LANES, STORM_LANES, BOARDS_CAPS))
+        ("boards", FLEET_LANES, STORM_LANES, BOARDS_CAPS),
+        ("lists", FLEET_LANES, STORM_LANES, LISTS_CAPS))
     for k_pad in pads})
 
 

@@ -178,7 +178,7 @@ def test_partial_admission_restores_whole_round_and_dedups():
 
     real = rset.dispatch_round_frames
 
-    def partial(frames, interpret=None):
+    def partial(frames, interpret=None, compactor=None):
         # really admit doc a (log + clocks + mirror), then fail before b
         real([round_from_parts({"a": [changes_to_columns(chs_a)]})])
         raise DeviceDispatchError("failed after admitting a, before b",
@@ -213,7 +213,7 @@ def test_pure_dispatch_failure_retries_nothing():
         pytest.skip("python-encoder fallback has no dispatch stage")
     real = rset.dispatch_round_frames
 
-    def dispatch_fail(frames, interpret=None):
+    def dispatch_fail(frames, interpret=None, compactor=None):
         real(frames)   # full admission + mirror succeed
         raise DeviceDispatchError("device lost at dispatch",
                                   admission_complete=True)
@@ -260,7 +260,7 @@ def test_preadmission_failure_restores_unadmitted_docs():
     chs = make_doc(3)
     real = rset.dispatch_round_frames
 
-    def precheck_boom(frames, interpret=None):
+    def precheck_boom(frames, interpret=None, compactor=None):
         raise RuntimeError("batch would blow the VMEM budget")
 
     rset.dispatch_round_frames = precheck_boom
@@ -366,6 +366,62 @@ def test_a_failure_at_the_collect_is_swallowed_and_the_next_read_recovers(
     # replaying the round is a duplicate-drop
     e.apply_changes("d0", _second_change(docs["d0"]))
     assert len(rset.change_log[rset.doc_index["d0"]]) == len(docs["d0"]) + 1
+    e.close()
+
+
+def test_a_failed_put_of_compacted_lanes_drops_the_copy_and_replays(
+        monkeypatch):
+    """A round takes a document past the op rows; the precheck compacts it
+    on the host and writes its lane into the current copy, and that put
+    fails. The copy is dropped (it still holds the lane's old columns), the
+    round, of which nothing was admitted, returns to pending, and its
+    replay, which has nothing left to compact, converges to the oracle."""
+    from automerge_tpu.core.change import Change, Op
+    from automerge_tpu.core.ids import ROOT_ID
+    from automerge_tpu.engine import resident_rows
+    from automerge_tpu.utils import metrics
+
+    e, rset, docs = _eager_service_with_a_current_copy(monkeypatch)
+    log = list(docs["d0"])
+
+    def overwrite():
+        return [Change(actor="W", seq=len(log) + 1, deps={},
+                       ops=[Op("set", ROOT_ID, key="n", value=len(log))])]
+    i = rset.doc_index["d0"]
+    while rset.op_count[i] < rset.cap_ops:    # dominated writes pile up
+        ch = overwrite()
+        e.apply_changes("d0", ch)
+        log += ch
+    assert rset._dev_current and e._pending == {}
+    dims = rset.dims()
+
+    def put_fails(*a):
+        raise RuntimeError("device lost at the lanes' put")
+    monkeypatch.setattr(resident_rows, "_put_cols", put_fails)
+    m0 = metrics.snapshot()
+    ch = overwrite()
+    e.apply_changes("d0", ch)
+    log += ch
+    m1 = metrics.snapshot()
+    monkeypatch.undo()
+
+    def delta(k):
+        return m1.get(k, 0) - m0.get(k, 0)
+
+    # the mirror was compacted, nothing of the round admitted
+    assert rset.op_count[i] < dims[0]
+    assert delta("engine_kernels_dispatched{kernel=put_lanes}") == 1
+    assert delta("rows_dispatch_failed") == 1
+    assert delta("sync_ops_ingested") == 0
+    assert rset.rows_dev is None and rset._dirty and not rset._dev_current
+    assert "d0" in e._pending
+    assert len(rset.change_log[i]) == len(log) - 1
+    e.flush()
+    assert e._pending == {} and rset.dims() == dims
+    assert len(rset.change_log[i]) == len(log)
+    h = e.hashes()
+    for d in docs:
+        assert np.uint32(h[d]) == oracle_hash(log if d == "d0" else docs[d])
     e.close()
 
 

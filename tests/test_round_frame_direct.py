@@ -118,9 +118,9 @@ def capture_rounds(svc) -> list:
     rset = svc._resident
     real = rset.dispatch_round_frames
 
-    def spy(frames, interpret=None):
+    def spy(frames, interpret=None, compactor=None):
         seen.extend(frames)
-        return real(frames, interpret)
+        return real(frames, interpret, compactor)
 
     rset.dispatch_round_frames = spy
     return seen
@@ -405,10 +405,10 @@ def test_a_ghost_anchored_ingress_is_rejected_at_its_call_in_a_batch():
             svc.apply_changes("doc", [bad])
         except CompactionAnchorError:
             raised = True
-        # a compacted document's sound ingress is converted at its call
-        # (the check reads columns) and admits with the round
+        # a compacted document's sound ingress is checked as the caller's
+        # ops, stays unconverted for the round's one pass, and admits
         svc.apply_changes("doc", [changes_of(d2)[-1]])
-        assert isinstance(svc._pending["doc"][0], WireColumns)
+        assert isinstance(svc._pending["doc"][0], ChangesPart)
         assert isinstance(svc._pending["other"][0], ChangesPart)
     assert raised
     assert len(rset.change_log[i]) == log_before + 1
@@ -436,7 +436,7 @@ def test_a_preadmission_failure_restores_the_round_as_it_was():
         pytest.skip("python-encoder fallback exercises a different path")
     real = rset.dispatch_round_frames
 
-    def boom(frames, interpret=None):
+    def boom(frames, interpret=None, compactor=None):
         raise RuntimeError("batch would blow the VMEM budget")
 
     rset.dispatch_round_frames = boom
@@ -466,7 +466,7 @@ def test_a_midadmission_failure_restores_and_the_retry_admits_the_rest():
     real = rset.dispatch_round_frames
     first = [c for c in RETRY_CALLS if c[0] == "d0"]
 
-    def partial(frames, interpret=None):
+    def partial(frames, interpret=None, compactor=None):
         # really admit d0, then fail before the rest of the round
         real([joined(first, False)])
         raise DeviceDispatchError("failed after d0",
@@ -515,9 +515,10 @@ def test_pending_size_and_the_ledgers_count_unconverted_parts():
 
 
 def test_anchor_pins_of_unconverted_parts_under_a_budget_error():
-    """A RowsBudgetError at the flush compacts with the pending round's
-    insert anchors pinned: the pins of ChangesParts are those of the same
-    ingress as columns, and the round then admits."""
+    """A document the round takes past the resident caps compacts before
+    admission with the pending round's insert anchors pinned: the pins of
+    ChangesParts are those of the same ingress as columns, and the round
+    then admits, dispatched once."""
     d = build_history()
     svc = EngineDocSet(backend="rows")
     svc.apply_changes("doc", changes_of(d))
@@ -530,26 +531,33 @@ def test_anchor_pins_of_unconverted_parts_under_a_budget_error():
     assert want["doc"]              # the inserts anchor at real elements
 
     rset = svc._resident
-    real_apply, real_compact = rset.dispatch_round_frames, rset.compact
-    state = {"raised": 0, "pins": None}
+    real_over, real_compact = rset._over_caps, rset.compact
+    real_apply = rset.dispatch_round_frames
+    state = {"over": 0, "pins": None, "dispatched": 0}
 
-    def budget_once(frames, interpret=None):
-        if not state["raised"]:
-            state["raised"] = 1
-            raise RowsBudgetError("forced")
-        return real_apply(frames, interpret)
+    def over_once(*need):
+        if not state["over"]:
+            state["over"] = 1
+            return np.array([rset.doc_index["doc"]])
+        return real_over(*need)
 
     def compact(floors, pins=None):
         state["pins"] = pins
         return real_compact(floors, pins)
 
-    rset.dispatch_round_frames, rset.compact = budget_once, compact
+    def dispatched(frames, interpret=None, compactor=None):
+        state["dispatched"] += 1
+        return real_apply(frames, interpret, compactor)
+
+    rset._over_caps, rset.compact = over_once, compact
+    rset.dispatch_round_frames = dispatched
     with svc.batch():
         for c in new:
             svc.apply_changes("doc", [c])
         svc.apply_changes("other", one_op(1))
         assert type(svc._pending["doc"][0]) is ChangesPart
-    assert state["raised"] == 1 and state["pins"] == want
+    assert state["over"] == 1 and state["pins"] == want
+    assert state["dispatched"] == 1
     assert not (want["doc"] & rset.ghost_eids[rset.doc_index["doc"]])
     assert np.uint32(svc.hashes()["doc"]) == oracle_hash(changes_of(d))
 

@@ -141,7 +141,7 @@ def test_a_flush_that_fails_before_admission_writes_neither_ledger(calls):
         rset = svc._resident
         real = rset.dispatch_round_frames
 
-        def boom(frames, interpret=None):
+        def boom(frames, interpret=None, compactor=None):
             raise RuntimeError("batch would blow the VMEM budget")
 
         rset.dispatch_round_frames = boom
