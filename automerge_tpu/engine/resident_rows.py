@@ -44,6 +44,11 @@ from ..utils import flightrec, metrics, perfscope
 
 # lanes one _put_lanes call carries: one program shape for every count
 LANE_PUT = 32
+# inserts a list may take in one round and still be placed against the
+# mirror's positions (_placed_pos_rows: a pass over the list's cells an
+# insert); a list that takes more (a load, a paste) is re-linearized, which
+# costs its entries once
+PLACE_MAX = 64
 
 
 class DeviceDispatchError(RuntimeError):
@@ -150,8 +155,15 @@ class ResidentRowsDocSet(ResidentDocSet):
         # RGA ordering key in this host tree while freeing their device
         # band slot, so entry indices are the only stable parent reference.
         # ins_idx maps slot -> entry index per list for appends.
+        # The mirror's `ip` band is the authority for the CURRENT positions
+        # of a list's slotted entries: a round's inserts that are each the
+        # list's newest element are placed against it (_placed_pos_rows),
+        # and only the other lists are re-linearized from this log.
         self.ins_idx: list[dict[int, dict[int, int]]] = [
             {} for _ in self.doc_ids]
+        # per-doc: list_row -> an upper bound of the list's largest element
+        # counter (raised on every ins_log append, kept through compaction)
+        self.elem_hi: list[dict[int, int]] = [{} for _ in self.doc_ids]
         # eids whose element was compacted away (ghost or fully dropped):
         # a conforming peer can never anchor an insert at one (the clock
         # floor guarantees every peer saw the tombstone), so an ingress
@@ -208,6 +220,9 @@ class ResidentRowsDocSet(ResidentDocSet):
         self._lanes_warm: set = set()
         # mirror shapes whose lanes' put has run (_warm_put)
         self._puts_warm: set = set()
+        # (mirror shape, triplet pad) the round's scatter has run at
+        # (_scatter_round, _warm_scatter)
+        self._scatters_warm: set = set()
         self._rows_ready = True
         self._alloc_rows()
         self.rows_dev = None
@@ -309,6 +324,7 @@ class ResidentRowsDocSet(ResidentDocSet):
             self.list_hash.append({})
             self.list_obj.append({})
             self.ins_idx.append({})
+            self.elem_hi.append({})
             self.ghost_eids.append(set())
             self.change_log.append([])
             self.log_horizon.append({})
@@ -708,6 +724,31 @@ class ResidentRowsDocSet(ResidentDocSet):
         rows = self._bases()["ip"] + lrows * self.cap_elems + slots
         return docs, rows, pos
 
+    def _placed_pos_rows(self, docs, lrows, n_old, lid, parent):
+        """Positions of lists whose round inserts are each the list's
+        newest element (counter above every counter it holds), anchored at
+        the head or at a slotted entry. RGA orders siblings by descending
+        (counter, actor), so such an insert is its anchor's first child and
+        lands right after it, ghosts included: on the dense ranks over the
+        slotted entries it takes pos(anchor) + 1 (0 at the head), and every
+        slotted entry at or past that rank moves up by one. The ranks are
+        read out of the mirror's `ip` band and placed in one native call
+        (native.linearize.place_lists).
+
+        docs, lrows, n_old: per list, its slotted entries before the round
+        (slots 0..n_old-1; the round's take n_old, n_old + 1, ...); lid,
+        parent: per insert in admission order, its list's index and its
+        anchor's slot (-1: the head). Returns (docs, ip-band row indices,
+        positions) of the cells whose position changed and of the new
+        slots, int64 arrays."""
+        from ..native.linearize import place_lists
+        ins_off = np.zeros(len(docs) + 1, np.int64)
+        np.cumsum(np.bincount(lid, minlength=len(docs)), out=ins_off[1:])
+        return place_lists(
+            self.rows_host, docs,
+            self._bases()["ip"] + lrows * self.cap_elems, n_old, ins_off,
+            parent[np.argsort(lid, kind="stable")])
+
     def _round_triplets(self, changes_by_doc) -> np.ndarray:
         """Encode one round into (P, 3) int32 scatter triplets
         (row, doc, value) and apply them to the host mirror."""
@@ -751,6 +792,8 @@ class ResidentRowsDocSet(ResidentDocSet):
                           if parent_slot >= 0 else -1)
                 s2i[slot] = len(entries)
                 entries.append((slot, elem, arank, parent))
+                hi = self.elem_hi[i]
+                hi[lrow] = max(hi.get(lrow, 0), elem)
                 le = lrow * E + slot
                 put(b["im"] + le, i, 1)
                 put(b["if"] + le, i, fid)
@@ -1351,20 +1394,37 @@ class ResidentRowsDocSet(ResidentDocSet):
         ins = bd.ins_rows
         if len(ins):
             t_elem = time.perf_counter()
+            # (doc, list row) -> [index, slotted entries before the round,
+            # inserts, placeable]: placeable while each insert is the
+            # list's newest element, in the next slot, anchored at the head
+            # or at a slotted entry (_placed_pos_rows)
             touched = {}
+            lid = []
             io = []
-            ins_log, ins_idx, list_hash = \
-                self.ins_log, self.ins_idx, self.list_hash
+            ins_log, ins_idx, list_hash, elem_hi = \
+                self.ins_log, self.ins_idx, self.list_hash, self.elem_hi
             for (d, lrow, slot_, elem, arank, parent_slot, _fid) \
                     in ins.tolist():
                 entries = ins_log[d].setdefault(lrow, [])
                 s2i = ins_idx[d].setdefault(lrow, {})
+                hi = elem_hi[d]
+                bound = hi.get(lrow, 0)
+                st = touched.get((d, lrow))
+                if st is None:
+                    st = touched[(d, lrow)] = [len(touched), slot_, 0, True]
+                st[2] += 1
+                if st[3] and not (elem > bound and slot_ == len(s2i)
+                                  and (parent_slot < 0 or parent_slot in s2i)
+                                  and st[2] <= PLACE_MAX):
+                    st[3] = False
+                hi[lrow] = max(bound, elem)
                 parent = (s2i.get(parent_slot, parent_slot)
                           if parent_slot >= 0 else -1)
                 s2i[slot_] = len(entries)
                 entries.append((slot_, elem, arank, parent))
                 io.append(list_hash[d][lrow])
-                touched[(d, lrow)] = None
+                lid.append(st[0])
+            lid = np.asarray(lid, np.int64)
             ins = ins.astype(np.int64)
             le = ins[:, 1] * E + ins[:, 2]
             for g, v in (("im", np.ones(len(ins), np.int64)),
@@ -1372,10 +1432,32 @@ class ResidentRowsDocSet(ResidentDocSet):
                 parts_r.append(b[g] + le)
                 parts_d.append(ins[:, 0])
                 parts_v.append(v)
-            pdoc, prow, pval = self._linearized_pos_rows(touched)
-            parts_r.append(prow)
-            parts_d.append(pdoc)
-            parts_v.append(pval)
+            lists = np.array([(d, lrow, n, ok) for (d, lrow), (_, n, _, ok)
+                              in touched.items()], np.int64)
+            ok = lists[:, 3].astype(bool)
+            n_pos = 0
+            if ok.any():
+                row_ok = ok[lid]
+                # the placed lists' indices among themselves
+                sub = np.cumsum(ok) - 1
+                pdoc, prow, pval = self._placed_pos_rows(
+                    lists[ok, 0], lists[ok, 1], lists[ok, 2],
+                    sub[lid[row_ok]], ins[row_ok, 5])
+                parts_r.append(prow)
+                parts_d.append(pdoc)
+                parts_v.append(pval)
+                n_pos += len(prow)
+            if not ok.all():
+                pdoc, prow, pval = self._linearized_pos_rows(
+                    lists[~ok, :2].tolist())
+                parts_r.append(prow)
+                parts_d.append(pdoc)
+                parts_v.append(pval)
+                n_pos += len(prow)
+            n_placed = int(ok.sum())
+            metrics.bump("rows_elem_lists_placed", n_placed)
+            metrics.bump("rows_elem_lists_relinearized", len(ok) - n_placed)
+            metrics.bump("rows_elem_pos_rows_shipped", n_pos)
             metrics.observe("rows_elem_admit_seconds",
                             time.perf_counter() - t_elem)
 
@@ -2121,7 +2203,7 @@ class ResidentRowsDocSet(ResidentDocSet):
     def _merged_trips(self, trip_list, least: int = 8):
         """The rounds' scatter triplets as one scatter: merged in round
         order with last-wins dedup (rounds only overwrite each other on
-        re-linearized position rows), padded to a power of two (`least`
+        position rows), padded to a power of two (`least`
         or more) with a row past the buffer, which the scatter drops.
         Returns (the padded [p, 3] int32 array, the count before
         padding)."""
@@ -2157,6 +2239,27 @@ class ResidentRowsDocSet(ResidentDocSet):
                 "scatter_trips", _scatter_trips, self.rows_dev,
                 padded_dev)
         self._hash_handle = self._h_prev = None
+        key = (self.rows_host.shape, len(padded))
+        if key not in self._scatters_warm:
+            # the first round at this pad compiled its scatter: the next
+            # pad up is compiled beside it, so that a later round whose
+            # count crosses this pad's edge (in a window too) finds it
+            self._scatters_warm.add(key)
+            self._warm_scatter(2 * len(padded))
+
+    def _warm_scatter(self, p: int) -> None:
+        """Run the round's scatter at pad `p`, once a mirror shape, with
+        every triplet past the buffer: the scatter drops them all. Counted
+        as a kernel of its own (`scatter_trips_warm`): it is no round's."""
+        key = (self.rows_host.shape, p)
+        if key in self._scatters_warm:
+            return
+        self._scatters_warm.add(key)
+        padded = np.zeros((p, 3), np.int32)
+        padded[:, 0] = self._bases()["rows"]
+        self.rows_dev = metrics.dispatch_jit(
+            "scatter_trips_warm", _scatter_trips, self.rows_dev,
+            self._to_dev(padded))
 
     def _put_lanes(self, idxs) -> None:
         """The mirror's columns of lanes `idxs` into the current device

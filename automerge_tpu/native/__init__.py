@@ -108,6 +108,11 @@ def get_lib():
         if hasattr(lib, "amtpu_linearize"):
             lib.amtpu_linearize.argtypes = [ctypes.c_int64] + \
                 [ctypes.c_void_p] * 5
+        if hasattr(lib, "amtpu_place_lists"):
+            lib.amtpu_place_lists.argtypes = [
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64] + \
+                [ctypes.c_void_p] * 5 + [ctypes.c_int64, ctypes.c_void_p]
+            lib.amtpu_place_lists.restype = ctypes.c_int64
         _lib = lib
         return _lib
 

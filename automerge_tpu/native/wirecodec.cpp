@@ -547,3 +547,61 @@ extern "C" void amtpu_linearize(int64_t n, const int32_t* elem,
   int32_t pos = 0;
   for (int32_t v = next[0]; v != -1; v = next[v]) out_pos[v - 1] = pos++;
 }
+
+// ---------------------------------------------------------------------------
+// Placement of a round's list inserts against the positions the mirror holds.
+//
+// List k is the column doc[k] of the row-major int32 mirror (`stride`
+// columns), its cells the rows base[k] + slot: the first n_old[k] hold the
+// dense positions of its slotted entries before the round, the next ones
+// are the round's new slots. Its inserts ins_off[k] .. ins_off[k+1], in
+// admission order, are each the list's newest element, so each is its
+// anchor's first child and lands right after it: it takes the anchor's
+// position + 1 (0 at the head: parent -1) and every placed cell at or past
+// that position moves up by one. Writes (doc, row, position) of every cell
+// whose position changed and of every new slot into out[0..2][...] (each
+// row of `out` holds `cap` entries); returns their count. The mirror is only
+// read, a row at a time across the lists (its columns lie a row apart);
+// O(cells x inserts) a list.
+
+extern "C" int64_t amtpu_place_lists(int64_t n_lists, const int32_t* mirror,
+                                     int64_t stride, const int64_t* doc,
+                                     const int64_t* base, const int64_t* n_old,
+                                     const int64_t* ins_off,
+                                     const int32_t* parent, int64_t cap,
+                                     int64_t* out) {
+  std::vector<int64_t> off(n_lists + 1, 0);
+  int64_t most = 0;
+  for (int64_t k = 0; k < n_lists; ++k) {
+    off[k + 1] = off[k] + n_old[k] + ins_off[k + 1] - ins_off[k];
+    most = std::max(most, n_old[k]);
+  }
+  std::vector<int32_t> cell(off[n_lists]);
+  for (int64_t c = 0; c < most; ++c)
+    for (int64_t k = 0; k < n_lists; ++k)
+      if (c < n_old[k]) cell[off[k] + c] = mirror[(base[k] + c) * stride + doc[k]];
+  const std::vector<int32_t> was(cell);
+  int64_t* out_doc = out;
+  int64_t* out_row = out + cap;
+  int64_t* out_pos = out + 2 * cap;
+  int64_t n_out = 0;
+  for (int64_t k = 0; k < n_lists; ++k) {
+    int32_t* cl = cell.data() + off[k];
+    const int32_t* old = was.data() + off[k];
+    const int64_t held = n_old[k];
+    int64_t n = held;
+    for (int64_t j = ins_off[k]; j < ins_off[k + 1]; ++j, ++n) {
+      const int32_t p = parent[j] >= 0 ? cl[parent[j]] + 1 : 0;
+      for (int64_t c = 0; c < n; ++c) cl[c] += cl[c] >= p;
+      cl[n] = p;
+    }
+    for (int64_t c = 0; c < n; ++c) {
+      if (c < held && cl[c] == old[c]) continue;
+      out_doc[n_out] = doc[k];
+      out_row[n_out] = base[k] + c;
+      out_pos[n_out] = cl[c];
+      ++n_out;
+    }
+  }
+  return n_out;
+}

@@ -214,6 +214,18 @@ COUNTERS: dict[str, str] = {
     "rows_join_steps_full":
         "the trips the same lane reconciles would run at the static dims; "
         "run / full is the share of the static join still executed",
+    "rows_elem_lists_placed":
+        "lists a round inserted into whose positions were placed against "
+        "the mirror's: each insert the list's newest element, anchored at "
+        "the head or a slotted entry (resident_rows._placed_pos_rows)",
+    "rows_elem_lists_relinearized":
+        "lists a round inserted into that were re-linearized from their "
+        "ins log (a concurrent or older insert, more than PLACE_MAX "
+        "inserts); placed / (placed + relinearized) is the hit share",
+    "rows_elem_pos_rows_shipped":
+        "position cells (`ip` band) the round's triplets carried: the "
+        "cells a placement moved and the new slots, every slotted cell of "
+        "a re-linearized list",
     # sync — services, wire protocol, transports, log archive
     "sync_frames_sent": "columnar change frames sent",
     "sync_frames_received": "columnar change frames received",

@@ -34,6 +34,8 @@ import run  # noqa: E402
 import traffic  # noqa: E402
 from test_benchmark import DEVICE_METRICS, _rewrite, eager  # noqa: E402
 
+from automerge_tpu.utils import metrics  # noqa: E402
+
 CELL = "boards10k.storm"
 SEED = 2**31 + 42
 NEW_METRICS = ("elem_admit_mean_ms",)
@@ -109,6 +111,26 @@ def test_the_traced_run_reads_the_new_metrics(small, monkeypatch):
     assert {"flush_mean_ms", "encode_share", "resident_gather_share",
             "compiles_in_window", "megakernel_roofline"} <= want
     assert want - set(DEVICE_METRICS) <= set(got)
+
+
+def test_a_traced_run_places_most_lists_and_relinearizes_the_rest(
+        small, monkeypatch):
+    """Add-card and reorder insert into the boards' lists: where each
+    insert is its list's newest element it is placed against the mirror's
+    positions, and a list a concurrent insert reaches is re-linearized.
+    Both engage in a traced run, which stays correct with every compared
+    number 0."""
+    monkeypatch.setattr(run, "TRACE_DIR", os.path.join(small, ".bench_trace"))
+    monkeypatch.setattr(run, "TRACE_START_SHARE", 0.0)
+    names = ("rows_elem_lists_placed", "rows_elem_lists_relinearized")
+    before = {n: int(metrics.snapshot().get(n, 0)) for n in names}
+    res = run_small(small, trace=1, max_requests=8)
+    assert res["correct"] is True and res["failed"] == 0
+    assert all(row["value"] == row["limit"] == 0
+               for row in res["compared"].values())
+    placed, relin = (int(metrics.snapshot().get(n, 0)) - before[n]
+                     for n in names)
+    assert placed > relin > 0
 
 
 def test_the_check_declares_six_controls():

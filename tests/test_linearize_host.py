@@ -99,3 +99,83 @@ def test_empty():
     out = linearize_host(np.zeros(4, bool), np.zeros(4, np.int32),
                          np.zeros(4, np.int32), np.full(4, -1, np.int32))
     assert (out == -1).all()
+
+
+def _dense(elem, actor, parent, slotted):
+    """Full RGA positions of one list's entries, ranked over the slotted
+    ones: the position column the rows mirror holds (ghosts order, but
+    hold no cell)."""
+    pos = linearize_host(np.ones(len(elem), bool), elem, actor, parent)
+    rank = np.empty(len(elem), np.int64)
+    rank[np.argsort(pos)] = np.arange(len(elem))
+    keep = np.flatnonzero(slotted)
+    out = np.empty(len(keep), np.int64)
+    out[np.argsort(rank[keep])] = np.arange(len(keep))
+    return out
+
+
+@pytest.mark.parametrize("fallback", [False, True])
+@pytest.mark.parametrize("seed", range(6))
+def test_placed_inserts_equal_the_linearization(seed, fallback, monkeypatch):
+    """place_lists over lists with ghosts (entries that order but hold no
+    slot), each given 0-6 inserts that are the list's newest element,
+    chained, at the head or at a slotted entry: the positions it returns
+    and the ones it leaves alone are the linearization of the grown list,
+    and it returns only the cells that moved and the new slots."""
+    import automerge_tpu.native.linearize as lin
+    if fallback:
+        monkeypatch.setattr(lin, "get_lib", lambda: None)
+    rng = random.Random(100 + seed)
+    n_lists, rows = rng.randint(1, 9), 64
+    mirror = np.full((rows * 2, n_lists + 3), -7, np.int32)
+    lists, doc, base, n_old, ins_off, parents = [], [], [], [], [0], []
+    for k in range(n_lists):
+        mask, elem, actor, parent = random_tree(rng, rng.randint(2, 40))
+        elem, actor, parent = elem[mask], actor[mask], parent[mask]
+        n = len(elem)
+        slotted = np.array([rng.random() < 0.6 for _ in range(n)], bool)
+        slot = np.cumsum(slotted) - 1
+        d, b = k + 1, rng.choice([0, rows])
+        held = int(slotted.sum())
+        mirror[b:b + held, d] = _dense(elem, actor, parent, slotted)
+        # the round's inserts: counters past every counter in the list
+        top = int(elem.max(initial=0))
+        entry_of = {int(s): j for j, s in enumerate(slot) if slotted[j]}
+        for t in range(rng.randint(0, 6)):
+            anchor = rng.randint(-1, held + t - 1)
+            top += rng.randint(1, 3)
+            parents.append(anchor)
+            entry_of[held + t] = len(elem)
+            elem = np.append(elem, top)
+            actor = np.append(actor, rng.randint(0, 3))
+            parent = np.append(parent, entry_of[anchor] if anchor >= 0
+                               else -1)
+            slotted = np.append(slotted, True)
+        lists.append((d, b, held, elem, actor, parent, slotted))
+        doc.append(d)
+        base.append(b)
+        n_old.append(held)
+        ins_off.append(len(parents))
+    before = mirror.copy()
+    docs, rows_, pos = lin.place_lists(mirror, np.array(doc), np.array(base),
+                                       np.array(n_old), np.array(ins_off),
+                                       np.array(parents))
+    np.testing.assert_array_equal(mirror, before)      # only read
+    after = mirror.copy()
+    after[rows_, docs] = pos
+    for d, b, held, elem, actor, parent, slotted in lists:
+        want = _dense(elem, actor, parent, slotted)
+        np.testing.assert_array_equal(after[b:b + len(want), d], want)
+        moved = {r for r, dd in zip(rows_.tolist(), docs.tolist())
+                 if dd == d and b <= r < b + len(want)}
+        assert moved == {b + c for c in range(len(want))
+                         if c >= held or want[c] != before[b + c, d]}
+
+
+def test_placed_inserts_refuse_an_anchor_not_yet_placed():
+    import automerge_tpu.native.linearize as lin
+    mirror = np.zeros((8, 2), np.int32)
+    with pytest.raises(ValueError):
+        # the list's first insert anchored at the slot it is about to take
+        lin.place_lists(mirror, np.array([0]), np.array([0]), np.array([2]),
+                        np.array([0, 1]), np.array([2]))

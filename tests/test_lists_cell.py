@@ -126,6 +126,26 @@ def test_the_traced_run_reads_the_new_metrics(small, monkeypatch):
     assert want - set(DEVICE_METRICS) <= set(got)
 
 
+def test_a_traced_run_places_most_lists_and_relinearizes_the_rest(
+        small, monkeypatch):
+    """The round's inserts are placed against the mirror's positions where
+    each is its list's newest element, and the lists a concurrent insert
+    reaches are re-linearized: both engage in a traced run, which stays
+    correct with every compared number 0."""
+    monkeypatch.setattr(run, "TRACE_DIR", os.path.join(small, ".bench_trace"))
+    monkeypatch.setattr(run, "TRACE_START_SHARE", 0.0)
+    names = ("rows_elem_lists_placed", "rows_elem_lists_relinearized",
+             "rows_elem_pos_rows_shipped")
+    before = {n: counter(n) for n in names}
+    res = run_small(small, trace=1, max_requests=10)
+    assert res["correct"] is True and res["failed"] == 0
+    assert all(row["value"] == row["limit"] == 0
+               for row in res["compared"].values())
+    placed, relin, shipped = (counter(n) - before[n] for n in names)
+    assert placed > relin > 0
+    assert shipped > 0
+
+
 def test_the_new_metrics_read_zero_on_a_program_without_the_phase():
     """A program older than the per-document compaction has no `compact`
     phase and no counters of it: its traced run still ends with a result."""

@@ -530,3 +530,39 @@ def test_rounds_collected_apart_over_rounds_flushed(name, request,
         == (name == "fused-rounds")
     s.check()
     s.svc.close()
+
+
+def _kernel(name, what) -> int:
+    kernels = (metrics.snapshot().get("perf") or {}).get("kernels") or {}
+    return (kernels.get(name) or {}).get(what, 0)
+
+
+def test_the_first_round_at_a_pad_compiles_the_next_pad_up(declines,
+                                                           monkeypatch):
+    """A round's scatter pads its triplets to a power of two. The first
+    round at a pad also runs the scatter once at the next pad up, every
+    triplet dropped (`scatter_trips_warm`): a later round whose count
+    crosses the pad's edge finds its program and compiles nothing."""
+    s = Service()
+    rset = s.rset
+    pads = []
+    real = rset._merged_trips
+
+    def merged(trip_list, least=8):
+        out = real(trip_list, least)
+        pads.append(len(out[0]))
+        return out
+
+    monkeypatch.setattr(rset, "_merged_trips", merged)
+    warm = _kernel("scatter_trips_warm", "dispatches")
+    s.batch(s.ids[:100])
+    assert pads == [1024]
+    assert (rset.rows_host.shape, 2048) in rset._scatters_warm
+    assert _kernel("scatter_trips_warm", "dispatches") == warm + 1
+    compiles = _kernel("scatter_trips", "compiles")
+    s.batch(s.ids[:200])
+    assert pads == [1024, 2048]
+    assert _kernel("scatter_trips", "compiles") == compiles
+    assert _kernel("scatter_trips_warm", "dispatches") == warm + 1
+    s.check()
+    s.svc.close()
