@@ -4,9 +4,14 @@ The wire codec parses the JSON change wire straight into columnar integer
 arrays (the engine's native input), skipping per-op Python object
 construction — the measured host-side bottleneck of wire ingestion.
 
-The shared library is built on demand with g++ into this package's _build/
+The round converter (framecodec.cpp, `wire.changes_frame`) turns a batch's
+Change objects into the round's AMW1 frame in one pass over their slots,
+through the CPython API.
+
+The shared libraries are built on demand with g++ into this package's _build/
 directory; if no toolchain is available the callers fall back to the pure-
-Python path transparently (`wire.parse_changes_json` returns None).
+Python path transparently (`wire.parse_changes_json` and
+`wire.changes_frame` return None).
 """
 
 from __future__ import annotations
@@ -15,6 +20,7 @@ import ctypes
 import os
 import subprocess
 import sys
+import sysconfig
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -27,19 +33,28 @@ _lib = None
 _lib_error: str | None = None
 
 
-def _build_shared(src: str, lib_path: str) -> str | None:
-    """Compile one .cpp into a shared library, atomically installed."""
+def _build_shared(src: str, lib_path: str,
+                  python_api: bool = False) -> str | None:
+    """Compile one .cpp into a shared library, atomically installed. A
+    source over the CPython API (`python_api`) compiles against the
+    running interpreter's headers."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # Compile to a process-unique temp path and rename into place: another
     # process may be loading (or also building) the library concurrently, and
     # rename is atomic while g++'s output writing is not.
     tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp]
+    include = sysconfig.get_paths()["include"] if python_api else None
+    if include:
+        cmd[1:1] = ["-I", include]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as exc:
         return f"toolchain unavailable: {exc}"
     if proc.returncode != 0:
+        if python_api and "Python.h" in proc.stderr:
+            return (f"compile failed: Python.h not found in {include} "
+                    f"(the interpreter's C headers are not installed)")
         return f"compile failed: {proc.stderr[:500]}"
     try:
         os.replace(tmp, lib_path)
@@ -48,10 +63,12 @@ def _build_shared(src: str, lib_path: str) -> str | None:
     return None
 
 
-def load_shared(src_name: str, lib_name: str,
-                state: dict) -> "ctypes.CDLL | None":
+def load_shared(src_name: str, lib_name: str, state: dict,
+                python_api: bool = False) -> "ctypes.CDLL | None":
     """Build-if-stale + load a native library; `state` caches the result
-    (keys: lib, error) so each library is attempted once per process."""
+    (keys: lib, error) so each library is attempted once per process. A
+    library over the CPython API (`python_api`) is loaded as a PyDLL: its
+    calls keep the GIL."""
     if state.get("lib") is not None or state.get("error") is not None:
         return state.get("lib")
     src = os.path.join(_HERE, src_name)
@@ -59,12 +76,12 @@ def load_shared(src_name: str, lib_name: str,
     if not os.path.exists(lib_path) or (
             os.path.exists(src)
             and os.path.getmtime(src) > os.path.getmtime(lib_path)):
-        err = _build_shared(src, lib_path)
+        err = _build_shared(src, lib_path, python_api)
         if err is not None:
             state["error"] = err
             return None
     try:
-        state["lib"] = ctypes.CDLL(lib_path)
+        state["lib"] = (ctypes.PyDLL if python_api else ctypes.CDLL)(lib_path)
     except OSError as exc:
         state["error"] = str(exc)
         return None

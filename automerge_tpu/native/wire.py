@@ -3,12 +3,13 @@
 from __future__ import annotations
 
 import ctypes
+import threading
 from dataclasses import dataclass
 
 import numpy as np
 
-from ..storage import _ACTION_IDX
-from . import get_lib
+from ..storage import _ACTION_IDX, _ACTIONS
+from . import get_lib, load_shared
 
 V_NONE, V_NULL, V_FALSE, V_TRUE, V_INT, V_DOUBLE, V_STR, V_BIGINT = range(8)
 
@@ -267,6 +268,46 @@ def changes_to_columns(changes) -> WireColumns:
         op_vstr=np.asarray(op_vstr, np.int32),
         actors=actors.items, objects=objects.items, keys=keys.items,
         messages=messages.items, strings=strings.items)
+
+
+_frame_state: dict = {}
+_frame_lock = threading.Lock()
+
+
+def _frame_fn():
+    """The native round converter (framecodec.cpp), bound to Change and Op;
+    None where it did not build or bind (`_frame_state["error"]` says why)."""
+    fn = _frame_state.get("fn")
+    if fn is not None or "error" in _frame_state:
+        return fn
+    with _frame_lock:
+        lib = load_shared("framecodec.cpp", "libamtpuframe.so", _frame_state,
+                          python_api=True)
+        if lib is None or "fn" in _frame_state:
+            return _frame_state.get("fn")
+        from ..core.change import Change, Op
+        lib.amtpu_frame_init.restype = ctypes.c_int
+        lib.amtpu_frame_init.argtypes = [ctypes.py_object] * 3
+        code = lib.amtpu_frame_init(Change, Op, _ACTIONS)
+        if code != 0:
+            _frame_state["error"] = f"amtpu_frame_init failed: {code}"
+            return None
+        fn = lib.amtpu_changes_frame
+        fn.restype = ctypes.py_object
+        fn.argtypes = [ctypes.py_object]
+        _frame_state["fn"] = fn
+        return fn
+
+
+def changes_frame(changes: list) -> tuple[bytes, tuple] | None:
+    """The AMW1 frame of a list of Change objects made natively, with its
+    five string tables (actors, objects, keys, messages, strings) as lists
+    of the changes' own str objects: the same bytes and tables as
+    `columns_to_bytes(changes_to_columns(changes))`. None where the library
+    is not there, or where a field is not of the exact plain type and range
+    (a lone surrogate among them): changes_to_columns decides those."""
+    fn = _frame_fn()
+    return None if fn is None else fn(changes)
 
 
 class ChangesPart:

@@ -49,7 +49,8 @@ import numpy as np
 
 from ..core.change import Change
 from ..utils import perfscope
-from ..native.wire import WireColumns, changes_to_columns  # noqa: F401
+from ..native.wire import (  # noqa: F401
+    WireColumns, changes_frame, changes_to_columns)
 # changes_to_columns is re-exported: it lives beside WireColumns so the
 # engine can use it without importing the sync package.
 
@@ -196,9 +197,11 @@ def columns_to_bytes(cols: WireColumns) -> bytes:
     return b"".join(parts)
 
 
-def bytes_to_columns(data: bytes) -> WireColumns:
+def bytes_to_columns(data: bytes, tables: tuple | None = None) -> WireColumns:
     """Deserialize a frame: `np.frombuffer` views over the payload (copy-free
-    for the integer columns) plus the five string tables."""
+    for the integer columns) plus the five string tables, decoded from the
+    frame or, where the caller already holds them (`tables`: actors,
+    objects, keys, messages, strings), taken as they are."""
     if data[:4] != FRAME_MAGIC:
         raise ValueError("not a columnar wire frame (bad magic)")
     (n_changes, n_ops, n_deps, n_actors, n_objects, n_keys, n_messages,
@@ -212,12 +215,14 @@ def bytes_to_columns(data: bytes) -> WireColumns:
         pos += nbytes
         return out
 
-    def table(n):
+    def table(n, t):
         nonlocal pos
         offsets = arr(n + 1, np.int32)
         blob_len = int(offsets[-1]) if n else 0
-        blob = data[pos:pos + blob_len]
         pos += blob_len
+        if tables is not None:
+            return tables[t]
+        blob = data[pos - blob_len:pos]
         return [blob[offsets[i]:offsets[i + 1]].decode("utf-8", "surrogatepass")
                 for i in range(n)]
 
@@ -237,8 +242,9 @@ def bytes_to_columns(data: bytes) -> WireColumns:
         op_vint=arr(n_ops, np.int64),
         op_vdbl=arr(n_ops, np.float64),
         op_vstr=arr(n_ops, np.int32),
-        actors=table(n_actors), objects=table(n_objects), keys=table(n_keys),
-        messages=table(n_messages), strings=table(n_strings))
+        actors=table(n_actors, 0), objects=table(n_objects, 1),
+        keys=table(n_keys, 2), messages=table(n_messages, 3),
+        strings=table(n_strings, 4))
     if pos != len(data):
         raise ValueError(f"frame has {len(data) - pos} trailing bytes")
     # retain the raw frame: it is the native delta encoder's direct input
@@ -267,18 +273,21 @@ class RoundColumns:
     round, plus the doc table mapping contiguous change ranges to doc ids.
     `cols.frame_bytes` is the embedded AMW1 frame — the native delta
     encoder's direct input, shared by all documents of the round.
-    `direct` says the columns came from ONE changes_to_columns pass over
-    the round's Change objects, with no join of column parts
-    (round_from_parts; the service counts such rounds)."""
+    `direct` says the columns came from ONE pass over the round's Change
+    objects, with no join of column parts, and `native` that every such
+    pass was the native converter's (round_from_parts; the service counts
+    both kinds of round)."""
 
-    __slots__ = ("doc_ids", "change_off", "cols", "direct")
+    __slots__ = ("doc_ids", "change_off", "cols", "direct", "native")
 
     def __init__(self, doc_ids: list[str], change_off: np.ndarray,
-                 cols: WireColumns, direct: bool = False):
+                 cols: WireColumns, direct: bool = False,
+                 native: bool = False):
         self.doc_ids = doc_ids
         self.change_off = change_off
         self.cols = cols
         self.direct = direct
+        self.native = native
 
     def to_dict(self) -> dict[str, list[Change]]:
         chs = self.cols.to_changes()  # bulk materialization, one pass
@@ -322,21 +331,33 @@ def round_from_parts(doc_parts: dict[str, list]) -> RoundColumns:
     SEVERAL parts a document, each a WireColumns or a ChangesPart (an
     ingress a batch kept as Change objects, native/wire.py). Documents in
     the dict's order, a document's parts in admission order. Every run of
-    ChangesParts, across documents, is converted in ONE
-    changes_to_columns pass; a round made of nothing else (a batch of
-    apply_changes calls) is that one pass and joins nothing (`direct`).
-    Column parts between the runs (apply_columns inside the batch, sealed
-    epoch entries) are joined with the runs' columns by ONE
+    ChangesParts, across documents, is converted in ONE pass: the native
+    converter's (`changes_frame`), which makes the frame bytes with the
+    columns, or changes_to_columns where that declines. A round made of
+    nothing else (a batch of apply_changes calls) is that one pass and
+    joins nothing (`direct`; `native` where every run converted
+    natively). Column parts between the runs (apply_columns inside the
+    batch, sealed epoch entries) are joined with the runs' columns by ONE
     concat_columns, never a merge a document. The frame is the same
-    bytes whichever way the parts came: both intern a string where the
-    ops first meet it."""
+    bytes whichever way the parts came: all of them intern a string where
+    the ops first meet it."""
     from ..native.wire import ChangesPart, concat_columns
 
     doc_ids = list(doc_parts)
     flat: list[WireColumns] = []
     run: list[Change] = []
+    native = True
     off = np.zeros(len(doc_ids) + 1, np.int32)
     n_changes = 0
+
+    def convert(run: list) -> WireColumns:
+        nonlocal native
+        made = changes_frame(run)
+        if made is None:
+            native = False
+            return changes_to_columns(run)
+        return bytes_to_columns(*made)
+
     for k, d in enumerate(doc_ids):
         for p in doc_parts[d]:
             n_changes += p.n_changes
@@ -344,19 +365,19 @@ def round_from_parts(doc_parts: dict[str, list]) -> RoundColumns:
                 run.extend(p.changes)
                 continue
             if run:
-                flat.append(changes_to_columns(run))
+                flat.append(convert(run))
                 run = []
             flat.append(p)
         off[k + 1] = n_changes
     direct = not flat and bool(doc_ids)
     if run or not flat:
-        flat.append(changes_to_columns(run))
+        flat.append(convert(run))
     merged = concat_columns(flat)
     # single-part passthrough may already carry its received frame bytes;
     # only serialize when absent (and cache for the native encoder)
     if getattr(merged, "frame_bytes", None) is None:
         merged.frame_bytes = columns_to_bytes(merged)
-    return RoundColumns(doc_ids, off, merged, direct)
+    return RoundColumns(doc_ids, off, merged, direct, direct and native)
 
 
 @perfscope.phased("sync_wire")

@@ -1520,6 +1520,8 @@ class EngineDocSet:
                 metrics.bump("sync_rounds_flushed", **labels)
                 if round_.direct:
                     metrics.bump("sync_rounds_direct_frame", **labels)
+                if round_.native:
+                    metrics.bump("sync_rounds_native_frame", **labels)
                 metrics.bump("sync_ops_ingested", int(n_ops - restored),
                              **labels)
             self._early_resolve_locked()

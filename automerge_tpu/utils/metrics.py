@@ -250,9 +250,13 @@ COUNTERS: dict[str, str] = {
         "outermost served spans",
     "sync_rounds_flushed": "coalesced service round flushes",
     "sync_rounds_direct_frame":
-        "flushed rounds whose frame came from one changes_to_columns "
-        "pass over a batch's Change objects, with no join of column "
-        "parts (sync/frames.py round_from_parts)",
+        "flushed rounds whose frame came from one pass over a batch's "
+        "Change objects, with no join of column parts (sync/frames.py "
+        "round_from_parts)",
+    "sync_rounds_native_frame":
+        "flushed rounds of sync_rounds_direct_frame whose one pass was "
+        "the native converter's (native/framecodec.cpp), not "
+        "changes_to_columns",
     # the sharded service's fan-out (sync/sharded_service.py): one round
     # = the exit of an outermost batch(), or a flush()
     "sync_shard_fanout_rounds": "fan-outs of the sharded service",

@@ -9,6 +9,9 @@ bytes as the join's; an ingress that cannot be encoded, and a ghost-anchored
 one, fail at their own call; a failed flush restores the round whichever
 kind its parts are; everything that reads a pending part reads both kinds;
 and the counter `sync_rounds_direct_frame` says which rounds took the road.
+The native round converter (native/framecodec.cpp) makes the same frame and
+tables as that pass, declines whatever the Python path might treat
+otherwise, and `sync_rounds_native_frame` counts the rounds it made.
 """
 
 import numpy as np
@@ -627,3 +630,251 @@ def test_the_counter_rises_once_for_each_shard_that_flushed():
             one_op(docs.index(only[0])) + one_op(0, seq=2))
     finally:
         svc.close()
+
+
+# -- (g) the native converter -----------------------------------------------
+
+
+def python_frame(run):
+    """The reference: the frame and tables of changes_to_columns."""
+    from automerge_tpu.sync.frames import columns_to_bytes
+    cols = changes_to_columns(run)
+    return columns_to_bytes(cols), (cols.actors, cols.objects, cols.keys,
+                                    cols.messages, cols.strings)
+
+
+def assert_native_equals_python(run):
+    from automerge_tpu.native.wire import changes_frame
+    made = changes_frame(run)
+    assert made is not None, run
+    frame, tables = made
+    want, want_tables = python_frame(run)
+    assert frame == want
+    assert tables == want_tables
+    # the caller's own str objects, the first met of each
+    for got_t, want_t in zip(tables[:4], want_tables[:4]):
+        assert all(a is b for a, b in zip(got_t, want_t))
+
+
+NATIVE_RUNS = {
+    "empty": [],
+    "multi-change-documents": two_writers() + list_and_text()
+    + two_writers()[1:],
+    "deps-and-messages": two_writers(),
+    "scalars-at-the-edges": [Change("V", 1, {"W": 2**31 - 1}, [
+        Op("set", ROOT_ID, key=f"k{j}", value=v)
+        for j, v in enumerate(SCALARS) if v != "é\ud800"] + [
+        Op("link", ROOT_ID, key="l", value="V:1"),
+        Op("del", ROOT_ID, key="k0", value={"ignored": 1}),
+        Op("ins", "V:list", key="_head", elem=-(2**31)),
+        Op("move", "V:list", key="V:2", value="V:3", elem=2**31 - 1)],
+        message="é")],
+    "one-op-a-change": [c for i in range(50) for c in one_op(i)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(NATIVE_RUNS))
+def test_the_native_frame_is_the_python_frame(case):
+    assert_native_equals_python(NATIVE_RUNS[case])
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_native_frame_is_the_python_frame_on_drawn_runs(seed):
+    """Random runs of the plain fields (and of what is not plain): where
+    the native converter answers, its frame and tables are the Python
+    path's; where it declines, the Python path converts or raises."""
+    import random
+
+    from automerge_tpu.native.wire import changes_frame
+    rng = random.Random(1000 + seed)
+    native = declined = 0
+    for _ in range(600):
+        run = drawn_changes(rng)
+        if changes_frame(run) is None:
+            declined += 1
+            continue
+        native += 1
+        assert_native_equals_python(run)
+    assert native >= 80 and declined >= 80, (native, declined)
+
+
+class _Str(str):
+    pass
+
+
+class _Float(float):
+    pass
+
+
+def _change(**field):
+    """One plain change of an ins and a set, with one field replaced."""
+    op_fields = {k: field.pop(k) for k in ("action", "obj", "key", "value",
+                                           "elem") if k in field}
+    ops = [Op("ins", "X:list", key="_head", elem=1),
+           Op(op_fields.get("action", "set"), op_fields.get("obj", "X:list"),
+              key=op_fields.get("key", "X:1"),
+              value=op_fields.get("value", 5), elem=op_fields.get("elem"))]
+    return Change(field.get("actor", "X"), field.get("seq", 1),
+                  field.get("deps", {"Y": 1}), ops,
+                  message=field.get("message"))
+
+
+class _ChangeSub(Change):
+    __slots__ = ()
+
+
+class _OpSub(Op):
+    __slots__ = ()
+
+
+def _unset_op():
+    op = Op.__new__(Op)
+    op.action, op.obj, op.key, op.elem = "set", ROOT_ID, "k", None
+    return Change("X", 1, {}, [op])       # no value slot: Python raises
+
+
+# what the native converter declines: every case the Python path decides
+DECLINED = {
+    "lone-surrogate-value": _change(value="a\ud800"),
+    "lone-surrogate-key": _change(key="\udfff"),
+    "lone-surrogate-obj": _change(obj="o\ud800"),
+    "lone-surrogate-actor": _change(actor="\ud800"),
+    "lone-surrogate-dep-actor": _change(deps={"\ud800": 1}),
+    "lone-surrogate-message": _change(message="m\udc00"),
+    "str-subclass-actor": _change(actor=_Str("X")),
+    "str-subclass-action": _change(action=_Str("set")),
+    "str-subclass-value": _change(value=_Str("v")),
+    "float-subclass-value": _change(value=_Float(1.5)),
+    "numpy-float-value": _change(value=np.float64(2.5)),
+    "int-subclass-value": _change(value=_Seq(3)),
+    "bool-seq": _change(seq=True),
+    "seq-past-int32": _change(seq=2**31),
+    "dep-seq-past-int32": _change(deps={"Y": -(2**31) - 1}),
+    "numpy-elem": _change(action="ins", elem=np.int64(2)),
+    "string-elem": _change(action="ins", elem="2"),
+    "elem-past-int32": _change(action="ins", elem=2**31),
+    "change-subclass": _ChangeSub("X", 1, {}, [Op("set", ROOT_ID, key="k",
+                                                  value=1)]),
+    "op-subclass": Change("X", 1, {}, [_OpSub("set", ROOT_ID, key="k",
+                                              value=1)]),
+    "unknown-action": _change(action="frobnicate"),
+    "unsupported-value": _change(value={"a": 1}),
+    "unset-slot": _unset_op(),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECLINED))
+def test_what_the_native_converter_declines_the_python_path_decides(
+        case, monkeypatch):
+    """A run holding the odd change is declined whole, and the round falls
+    back to changes_to_columns: the same frame as a round made in Python
+    alone, or the same error."""
+    from automerge_tpu.native.wire import ChangesPart, changes_frame
+    from automerge_tpu.sync import frames
+    run = one_op(0) + [DECLINED[case]] + one_op(1, seq=2)
+    assert changes_frame(run) is None
+    parts = {"d0": [ChangesPart(tuple(run[:1]), 1)],
+             "odd": [ChangesPart(tuple(run[1:2]), len(run[1].ops))],
+             "d1": [ChangesPart(tuple(run[2:]), 1)]}
+    try:
+        want = python_frame(run)[0]
+    except Exception as e:          # noqa: BLE001 - the reference decides
+        with pytest.raises(type(e)):
+            round_from_parts(parts)
+        return
+    got = round_from_parts(parts)
+    assert (got.direct, got.native) == (True, False)
+    assert got.cols.frame_bytes == want
+    monkeypatch.setattr(frames, "changes_frame", lambda run: None)
+    assert round_from_parts(parts).cols.frame_bytes == want
+
+
+def test_columns_between_runs_join_the_native_runs_as_the_python_runs(
+        monkeypatch):
+    """Column parts between runs of ChangesParts: each run converted
+    natively, then joined; the frame is the one a round made in Python
+    alone gives, and such a round is neither direct nor native."""
+    from automerge_tpu.sync import frames
+    parts: dict = {}
+    for d, chs in ROUNDS["changes-and-columns"] + [("lt", list_and_text())]:
+        part = (changes_to_columns(chs.changes) if isinstance(chs, Cols)
+                else changes_part(chs))
+        parts.setdefault(d, []).append(part)
+    assert any(type(p) is ChangesPart for ps in parts.values() for p in ps)
+    got = round_from_parts(parts)
+    monkeypatch.setattr(frames, "changes_frame", lambda run: None)
+    want = round_from_parts(parts)
+    assert (got.direct, got.native, want.native) == (False, False, False)
+    assert got.doc_ids == want.doc_ids
+    assert got.change_off.tolist() == want.change_off.tolist()
+    assert got.cols.frame_bytes == want.cols.frame_bytes
+
+
+def test_a_native_round_reads_as_the_python_round():
+    """The native round's columns are views of its own frame: every column
+    and table equal to the Python path's, the frame its bytes."""
+    from automerge_tpu.native.wire import ChangesPart
+    calls = ROUNDS["deps-and-messages"] + ROUNDS["list-and-text"]
+    parts: dict = {}
+    for d, chs in calls:
+        parts.setdefault(d, []).append(ChangesPart(tuple(chs), sum(
+            len(c.ops) for c in chs)))
+    got = round_from_parts(parts)
+    assert (got.direct, got.native) == (True, True)
+    want = changes_to_columns([c for _d, chs in calls for c in chs])
+    for field in ("change_actor", "change_seq", "change_msg", "deps_off",
+                  "deps_actor", "deps_seq", "op_off", "op_action", "op_obj",
+                  "op_key", "op_elem", "op_vtag", "op_vint", "op_vdbl",
+                  "op_vstr", "actors", "objects", "keys", "messages",
+                  "strings"):
+        a, b = getattr(got.cols, field), getattr(want, field)
+        assert np.array_equal(np.asarray(a), np.asarray(b)), field
+    assert got.cols.to_changes() == want.to_changes()
+
+
+def native_rounds() -> int:
+    return rounds("sync_rounds_native_frame")
+
+
+def test_a_batch_round_counts_as_native_and_direct_once(monkeypatch):
+    """A batch() round of Change objects bumps the native and the
+    direct-frame counters once each; the same round with the converter
+    stubbed out bumps the direct one alone, and both services hold the
+    same hashes."""
+    from automerge_tpu.sync import frames
+    calls = ROUNDS["deps-and-messages"] + ROUNDS["list-and-text"] + \
+        ROUNDS["one-op-a-change"]
+    svc = EngineDocSet(backend="rows")
+    n0, d0 = native_rounds(), direct_rounds()
+    send(svc, calls)
+    assert (native_rounds() - n0, direct_rounds() - d0) == (1, 1)
+
+    stubbed = EngineDocSet(backend="rows")
+    monkeypatch.setattr(frames, "changes_frame", lambda run: None)
+    n0, d0 = native_rounds(), direct_rounds()
+    send(stubbed, calls)
+    assert (native_rounds() - n0, direct_rounds() - d0) == (0, 1)
+    assert stubbed.hashes() == svc.hashes()
+    for d, chs in all_changes(calls).items():
+        assert np.uint32(svc.hashes()[d]) == oracle_hash(chs), d
+
+
+def test_the_build_names_a_missing_python_header(monkeypatch, tmp_path):
+    """Where the converter does not build for want of the interpreter's C
+    headers, the build's error says so."""
+    import subprocess
+
+    from automerge_tpu import native
+
+    class Failed:
+        returncode = 1
+        stderr = ("framecodec.cpp:25:10: fatal error: Python.h: No such "
+                  "file or directory")
+
+    monkeypatch.setattr(subprocess, "run", lambda *a, **k: Failed())
+    monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path))
+    err = native._build_shared("framecodec.cpp",
+                               str(tmp_path / "libx.so"), python_api=True)
+    assert err.startswith("compile failed: Python.h not found in ")
+    plain = native._build_shared("wirecodec.cpp", str(tmp_path / "liby.so"))
+    assert "Python.h not found" not in plain
