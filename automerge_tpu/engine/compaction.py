@@ -179,8 +179,7 @@ def _compact_lanes(rset, idxs: list, floors: list, pins: list) -> list:
                         for i, f in zip(idxs, floors)], axis=1)
     keep, slot_fids = _op_keep_mask(b, cols, I, A, floor_r)
     fid = cols[b["fid"]:b["fid"] + I]
-    keep_fids = [set(fid[slot_fids[:, t], t].tolist())
-                 for t in range(len(idxs))]
+    keep_fids = [fid[slot_fids[:, t], t] for t in range(len(idxs))]
 
     # ---- rewrite the op bands: survivors packed to the front ----
     n_keep = keep.sum(axis=0)
@@ -199,16 +198,17 @@ def _compact_lanes(rset, idxs: list, floors: list, pins: list) -> list:
         n_elems = _reclaim_elems(rset, i, cols[:, t], b, keep_fids[t],
                                  pins[t])
         if n_elems is None:
-            n_elems = (_slotted(rset, i),) * 2
+            n_elems = (rset.lists.n_slotted(i),) * 2
         else:
-            lists += [(i, lrow) for lrow in rset.ins_log[i]]
+            lists += [(i, lrow) for lrow in rset.lists.rows(i)]
         stats.append({"ops_before": n_ops0, "ops_after": int(n_keep[t]),
                       "elems_before": n_elems[0], "elems_after": n_elems[1]})
     if lists:
         # fresh RGA positions for every list of a compacted document
         # (ghosts included in the linearization, rank-compressed over the
         # slotted entries)
-        docs, prow, pval = rset._linearized_pos_rows(lists)
+        docs, prow, pval = rset.lists.linearized(
+            lists, b["ip"], rset.cap_elems)
         col_of = {i: t for t, i in enumerate(idxs)}
         cols[prow, [col_of[d] for d in docs.tolist()]] = pval
     changed = (cols != was).any(axis=0)
@@ -219,105 +219,47 @@ def _compact_lanes(rset, idxs: list, floors: list, pins: list) -> list:
     return stats
 
 
-def _slotted(rset, i: int) -> int:
-    return sum(1 for e in rset.ins_log[i].values()
-               for (s, _, _, _) in e if s >= 0)
-
-
-def _reclaim_elems(rset, i: int, col, b, keep_fids: set, pins):
+def _reclaim_elems(rset, i: int, col, b, keep_fids, pins):
     """Element reclaim of document i on its dense column `col` (its op
     bands already packed): (slotted elements before, after), or None where
-    queued changes may anchor anywhere and nothing is reclaimed.
-
-    Host truth for elements is ins_log (slot, elem-counter, actor-rank,
-    parent-slot per list row) plus the rows bands themselves; the eid is
-    reconstructible as "actor:counter" (core/ids.make_elem_id — the same
-    format both encoders intern) and the element's field id is read from
-    the `if` band, so this pass works identically over the native and
-    pure-Python encoders. `keep_fids`: the fields whose element keeps its
-    slot (a candidate, or an assign above the floor), from the ORIGINAL
-    ops."""
+    queued changes may anchor anywhere and nothing is reclaimed. An element
+    keeps its band slot while pinned or while its field (the `if` band) is
+    among `keep_fids` (a candidate, or an assign above the floor, from the
+    ORIGINAL ops); the list store ghosts the rest (ListStore.reclaim) and
+    this pass packs the element bands to the slots kept."""
     t = rset.tables[i]
     if t.queue:
         return None
-    from ..core.ids import make_elem_id
-
+    lists = rset.lists
     E = rset.cap_elems
-    n_elems0 = n_elems1 = 0
-    for lrow, entries in list(rset.ins_log[i].items()):
+    n_elems0 = lists.n_slotted(i)
+    for lrow in lists.rows(i):
         base = lrow * E
-        fid_band = col[b["if"] + base:b["if"] + base + E].tolist()
-        n = len(entries)
-        n_slotted = sum(1 for (s, _, _, _) in entries if s >= 0)
-        n_elems0 += n_slotted
-        # keep_slot: the element keeps its device band slot — visible,
-        # or some op on its field is still above the floor. A slotted
-        # entry losing this becomes a GHOST: it keeps its RGA ordering
-        # key in this host tree (its retained descendants and future
-        # siblings of its parent still compare against that key) but
-        # frees the band slot. Ghost entries with no tree-retained
-        # child drop from the host tree entirely.
-        keep_slot = [False] * n
-        keep_tree = [False] * n
-        has_kept_child: set[int] = set()
-        for k in range(n - 1, -1, -1):
-            slot, elem_c, arank_c, parent = entries[k]
-            if slot >= 0:
-                keep_slot[k] = (fid_band[slot] in keep_fids
-                                or (bool(pins) and make_elem_id(
-                                    t.actors[arank_c], elem_c) in pins))
-            if keep_slot[k] or k in has_kept_child:
-                keep_tree[k] = True
-                if parent >= 0:
-                    has_kept_child.add(parent)
-        n_keep_slots = sum(keep_slot)
-        n_elems1 += n_keep_slots
-        if n_keep_slots == n_slotted and all(keep_tree):
+        slot = lists.log(i, lrow)[:, 0]
+        keep_slot = slot >= 0
+        keep_slot[keep_slot] = np.isin(
+            col[b["if"] + base + slot[keep_slot]], keep_fids)
+        if pins:
+            keep_slot |= (slot >= 0) & np.fromiter(
+                (e in pins for e in lists.elem_ids(i, lrow, t.actors)),
+                bool, len(slot))
+        src = lists.reclaim(i, lrow, keep_slot, t.actors)
+        if src is None:
             continue
-        # rebuild the entry list: tree-retained entries in arrival
-        # order; slots renumber densely over the slot-keeping ones so
-        # the encoders' next-slot rule (len(elem_slots[obj])) keeps
-        # assigning fresh slots past the compacted set
-        idx_map: dict[int, int] = {}
-        slot_remap: dict[int, int] = {}
-        new_entries: list[tuple] = []
-        for k in range(n):
-            if not keep_tree[k]:
-                continue
-            slot, elem, arank, parent = entries[k]
-            ns = -1
-            if keep_slot[k]:
-                ns = len(slot_remap)
-                slot_remap[slot] = ns
-            idx_map[k] = len(new_entries)
-            new_entries.append(
-                (ns, elem, arank, idx_map[parent] if parent >= 0 else -1))
-        # every slotted entry that lost its slot (ghosted or fully
-        # dropped) is a forbidden future anchor
-        ghosts = rset.ghost_eids[i]
-        for k in range(n):
-            slot, elem, arank, _parent = entries[k]
-            if slot >= 0 and not keep_slot[k]:
-                ghosts.add(make_elem_id(t.actors[arank], elem))
-        rset.ins_log[i][lrow] = new_entries
-        rset.ins_idx[i][lrow] = {
-            s: k for k, (s, _, _, _) in enumerate(new_entries) if s >= 0}
-        oi = rset.list_obj[i].get(lrow)
-        if oi is not None and t.elem_slots.get(oi):
+        oi = lists.obj(i, lrow)
+        if t.elem_slots.get(oi):
             # pure-Python encoder path: its eid->slot map lives here
             eid_by_slot = {s: eid for eid, s in t.elem_slots[oi].items()}
-            t.elem_slots[oi] = {eid_by_slot[s]: ns
-                                for s, ns in slot_remap.items()}
+            t.elem_slots[oi] = {
+                eid_by_slot[s]: ns for ns, s in enumerate(src.tolist())}
         # rewrite this list's element bands: the kept slots, densely
-        src = np.fromiter(slot_remap, np.int64, len(slot_remap))
         for g, fill in _ELEM_BANDS:
             band = col[b[g] + base:b[g] + base + E]
             kept = band[src]
             band[:] = fill
             band[:len(src)] = kept
-    t.max_elems = max((sum(1 for (s, _, _, _) in e if s >= 0)
-                       for e in rset.ins_log[i].values()), default=0)
-    return n_elems0, n_elems1
+    t.max_elems = lists.max_slotted(i)
+    return n_elems0, lists.n_slotted(i)
 
 
 def compact(rset, floors: dict[str, dict[str, int]],
@@ -371,19 +313,9 @@ def _sync_native_elem_slots(rset, i: int) -> None:
     """Mirror doc i's renumbered element slots into the native encoder
     (DocState.elem_slots / max_elems in native/deltaenc.cpp): the C++ side
     assigns the next slot as len(elem_slots[obj]) and resolves insert
-    anchors through that map, so it must see exactly the compacted view.
-    The eid is rebuilt from the ins_log entry (core/ids.make_elem_id
-    format, identical to the C++ interning key in deltaenc.cpp A_INS)."""
-    from ..core.ids import make_elem_id
-
-    objs, slots, eids = [], [], []
-    for lrow, entries in rset.ins_log[i].items():
-        oi = rset.list_obj[i][lrow]
-        for (slot, elem, arank, _parent) in entries:
-            if slot < 0:   # ghosts stay out of the encoder's maps
-                continue
-            objs.append(oi)
-            slots.append(slot)
-            eids.append(make_elem_id(rset.tables[i].actors[arank], elem))
+    anchors through that map, so it must see exactly the compacted view
+    (ListStore.encoder_slots: the eid in core/ids.make_elem_id's format,
+    identical to the C++ interning key in deltaenc.cpp A_INS)."""
+    objs, slots, eids = rset.lists.encoder_slots(i, rset.tables[i].actors)
     rset._native.reset_elem_slots(i, objs, slots, eids,
                                   rset.tables[i].max_elems)

@@ -24,7 +24,6 @@ from-scratch batch path.
 from __future__ import annotations
 
 import contextlib
-import itertools
 import time
 from functools import partial
 
@@ -34,7 +33,10 @@ import jax
 import jax.numpy as jnp
 
 from . import dispatchledger
-from .encode import _pad_to
+from .encode import A_MAKE_LIST, A_MAKE_TEXT, _pad_to
+# CompactionAnchorError is re-exported: callers catch it beside the errors
+# below
+from .lists import CompactionAnchorError, ListStore, anchor_pins  # noqa: F401
 from .resident import ResidentDocSet
 from . import dispatch as round_dispatch
 from .pack import LANE, pad_to_lanes
@@ -44,11 +46,6 @@ from ..utils import flightrec, metrics, perfscope
 
 # lanes one _put_lanes call carries: one program shape for every count
 LANE_PUT = 32
-# inserts a list may take in one round and still be placed against the
-# mirror's positions (_placed_pos_rows: a pass over the list's cells an
-# insert); a list that takes more (a load, a paste) is re-linearized, which
-# costs its entries once
-PLACE_MAX = 64
 
 
 class DeviceDispatchError(RuntimeError):
@@ -99,33 +96,6 @@ def _budget_error(cap_ops: int, actors: int, elem_slots: int,
         f"more rows instances", doc_ids=doc_ids)
 
 
-def ins_anchors(i: int, cols, op_lo: int, op_hi: int):
-    """(i, anchor key) of each insert among ops [op_lo, op_hi) of wire
-    columns `cols` (a document's part): the pairs
-    ResidentRowsDocSet.check_ghost_anchors reads."""
-    from ..storage import _ACTION_IDX
-    acts = np.asarray(cols.op_action[op_lo:op_hi])
-    for j in np.flatnonzero(acts == _ACTION_IDX["ins"]).tolist():
-        k = int(cols.op_key[op_lo + j])
-        if k >= 0:
-            yield i, cols.keys[k]
-
-
-class CompactionAnchorError(RuntimeError):
-    """An ingress inserts after an element that compaction reclaimed. The
-    clock floor guarantees every known peer saw that element's tombstone, so
-    a conforming frontend can never emit this anchor (it only anchors at
-    elements visible in its own state); the sender is either below the
-    compaction horizon (needs a full resync) or nonconforming. Raised
-    BEFORE admission — the node is untouched. The rejection is
-    deterministic: the sync service drops the offending doc's round
-    (`doc_id` below) instead of re-queueing it."""
-
-    def __init__(self, msg: str, *, doc_id: str | None = None):
-        super().__init__(msg)
-        self.doc_id = doc_id
-
-
 class ResidentRowsDocSet(ResidentDocSet):
     """Resident DocSet whose device state IS the megakernel row buffer."""
 
@@ -139,36 +109,9 @@ class ResidentRowsDocSet(ResidentDocSet):
         # runs. Mixing encoders on one instance would desync interning state.
         super().__init__(doc_ids, native=native)
         self.n_pad = pad_to_lanes(max(len(self.doc_ids), 1))
-        # per-doc: list_row -> [(slot, elem, arank, parent_slot), ...]
-        self.ins_log: list[dict[int, list[tuple]]] = [
-            {} for _ in self.doc_ids]
-        # per-doc: list_row -> owning-object content hash
-        self.list_hash: list[dict[int, int]] = [{} for _ in self.doc_ids]
-        # per-doc: list_row -> object interning index (compaction uses it
-        # to address the encoder's per-object element-slot maps)
-        self.list_obj: list[dict[int, int]] = [{} for _ in self.doc_ids]
-        # NOTE on ins_log semantics: each entry is (slot, elem_counter,
-        # actor_rank, parent); `parent` is the ENTRY INDEX of the anchor
-        # within the same list's entry list (not its slot). Before any
-        # compaction the two coincide (slots assign densely in arrival
-        # order); after compaction, ghost entries (slot == -1) keep their
-        # RGA ordering key in this host tree while freeing their device
-        # band slot, so entry indices are the only stable parent reference.
-        # ins_idx maps slot -> entry index per list for appends.
-        # The mirror's `ip` band is the authority for the CURRENT positions
-        # of a list's slotted entries: a round's inserts that are each the
-        # list's newest element are placed against it (_placed_pos_rows),
-        # and only the other lists are re-linearized from this log.
-        self.ins_idx: list[dict[int, dict[int, int]]] = [
-            {} for _ in self.doc_ids]
-        # per-doc: list_row -> an upper bound of the list's largest element
-        # counter (raised on every ins_log append, kept through compaction)
-        self.elem_hi: list[dict[int, int]] = [{} for _ in self.doc_ids]
-        # eids whose element was compacted away (ghost or fully dropped):
-        # a conforming peer can never anchor an insert at one (the clock
-        # floor guarantees every peer saw the tombstone), so an ingress
-        # that does is rejected pre-admission (CompactionAnchorError).
-        self.ghost_eids: list[set] = [set() for _ in self.doc_ids]
+        # every list's host state: its insert log, slots and ghosts
+        # (engine/lists.py)
+        self.lists = ListStore(len(self.doc_ids))
         # last compaction floor per doc_id (rebuild-from-log re-compacts
         # with these so a rebuilt long-lived doc fits the budget again)
         self.compaction_floors: dict[str, dict[str, int]] = {}
@@ -316,16 +259,11 @@ class ResidentRowsDocSet(ResidentDocSet):
             return
         old_cap_docs = self.cap_docs
         first_new = len(self.doc_ids)
+        self.lists.add_docs(len(fresh))
         for d in fresh:
             self.doc_index[d] = len(self.doc_ids)
             self.doc_ids.append(d)
             self.tables.append(DocTables())
-            self.ins_log.append({})
-            self.list_hash.append({})
-            self.list_obj.append({})
-            self.ins_idx.append({})
-            self.elem_hi.append({})
-            self.ghost_eids.append(set())
             self.change_log.append([])
             self.log_horizon.append({})
         # fresh lanes need one reconcile for their empty-doc hash;
@@ -540,7 +478,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         """Host-mirror sink of a registration ({doc index: its new sorted
         actor list}): each document rewrites ITS lane and nothing else —
         its act row through the document's permutation, its co bands, its
-        ah column, its ins_log ranks, its row of the admission cache and
+        ah column, its lists' ranks, its row of the admission cache and
         its lazy memos. The lane's hash goes dirty; the other lanes, the
         device copy and _h_prev stay as they are: where the copy is
         current the cells that changed are pended as triplets
@@ -581,10 +519,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                 co = was[A + k:].reshape(len(actors), k)
                 now[A + k:] = 0
                 now[A + k:].reshape(len(actors), k)[perm] = co[:n_old]
-                for lrow, entries in self.ins_log[i].items():
-                    self.ins_log[i][lrow] = [
-                        (s, e, int(perm[a]) if a < n_old else a, p)
-                        for (s, e, a, p) in entries]
+                self.lists.remap_ranks(i, perm, n_old)
                 if self._clock_cache is not None \
                         and self._clock_cache.shape[1] == A:
                     # (another width: _refresh_admission_cache rebuilds)
@@ -627,7 +562,6 @@ class ResidentRowsDocSet(ResidentDocSet):
         n_elems = np.zeros(self.cap_docs, np.int64)
         n_lists = np.zeros(self.cap_docs, np.int64)
         new_fids = {}
-        anchors = []
 
         def count(i, c):
             need_ch[i] += 1
@@ -638,9 +572,9 @@ class ResidentRowsDocSet(ResidentDocSet):
             for op in c.ops:
                 if op.action == "ins":
                     n_elems[i] += 1
-                    anchors.append((i, op.key))
                 elif op.action in ("makeList", "makeText"):
                     n_lists[i] += 1
+            self.check_ingress_anchors(i, [c])
 
         for i, t in enumerate(self.tables):
             for p in t.queue:  # _Pending records; rows path payloads are Changes
@@ -650,7 +584,6 @@ class ResidentRowsDocSet(ResidentDocSet):
                 i = self.doc_index[doc_id]
                 for c in changes:
                     count(i, c)
-        self.check_ghost_anchors(anchors)
         if compactor is not None:
             # the round-frame precheck's rule (_precheck_round_frames):
             # the documents past the caps compact first
@@ -685,76 +618,45 @@ class ResidentRowsDocSet(ResidentDocSet):
         if not rows_dims_fit(cap_ops, self.cap_actors, le):
             raise _budget_error(cap_ops, self.cap_actors, le)
 
-    def _linearized_pos_rows(self, lists):
-        """Fresh RGA positions for touched lists, `lists` an iterable of
-        (doc index, list row), from their ins logs in one native call
-        (native.linearize.linearize_lists): (docs, ip-band row indices,
-        positions), int64 arrays. Ghost entries (compacted-away tombstones,
-        slot == -1) participate in the linearization — they are the
-        ordering basis for their retained descendants — but ship no row;
-        positions are rank-compressed over the slotted entries so they stay
-        dense in [0, cap_elems) (the XLA visible_ranks path scatters by
-        position)."""
-        from ..native.linearize import linearize_lists
-        lists = list(lists)
-        logs = [self.ins_log[d][lrow] for d, lrow in lists]
-        lens = np.fromiter(map(len, logs), np.int64, len(logs))
-        starts = np.zeros(len(logs) + 1, np.int64)
-        np.cumsum(lens, out=starts[1:])
-        # the entries' four ints in one flat pass: half the time of
-        # np.array over the list of tuples
-        ent = np.fromiter(itertools.chain.from_iterable(
-            itertools.chain.from_iterable(logs)), np.int64,
-            4 * int(starts[-1])).reshape(-1, 4)
-        slots = ent[:, 0]
-        pos = linearize_lists(ent[:, 1], ent[:, 2], ent[:, 3], starts)
-        key = np.array(lists, np.int64).reshape(-1, 2)
-        docs = np.repeat(key[:, 0], lens)
-        lrows = np.repeat(key[:, 1], lens)
-        slotted = slots >= 0
-        if not slotted.all():
-            keep = np.flatnonzero(slotted)
-            owner = np.repeat(np.arange(len(logs)), lens)[keep]
-            order = np.lexsort((pos[keep], owner))
-            dense = np.empty(len(keep), np.int64)
-            dense[order] = (np.arange(len(keep))
-                            - np.searchsorted(owner[order], owner[order]))
-            pos, slots = dense, slots[keep]
-            docs, lrows = docs[keep], lrows[keep]
-        rows = self._bases()["ip"] + lrows * self.cap_elems + slots
-        return docs, rows, pos
-
-    def _placed_pos_rows(self, docs, lrows, n_old, lid, parent):
-        """Positions of lists whose round inserts are each the list's
-        newest element (counter above every counter it holds), anchored at
-        the head or at a slotted entry. RGA orders siblings by descending
-        (counter, actor), so such an insert is its anchor's first child and
-        lands right after it, ghosts included: on the dense ranks over the
-        slotted entries it takes pos(anchor) + 1 (0 at the head), and every
-        slotted entry at or past that rank moves up by one. The ranks are
-        read out of the mirror's `ip` band and placed in one native call
-        (native.linearize.place_lists).
-
-        docs, lrows, n_old: per list, its slotted entries before the round
-        (slots 0..n_old-1; the round's take n_old, n_old + 1, ...); lid,
-        parent: per insert in admission order, its list's index and its
-        anchor's slot (-1: the head). Returns (docs, ip-band row indices,
-        positions) of the cells whose position changed and of the new
-        slots, int64 arrays."""
-        from ..native.linearize import place_lists
-        ins_off = np.zeros(len(docs) + 1, np.int64)
-        np.cumsum(np.bincount(lid, minlength=len(docs)), out=ins_off[1:])
-        return place_lists(
-            self.rows_host, docs,
-            self._bases()["ip"] + lrows * self.cap_elems, n_old, ins_off,
-            parent[np.argsort(lid, kind="stable")])
+    def _elem_trips(self, ins) -> list:
+        """A round's inserts `ins` (ListStore.append) appended to their
+        lists, as scatter-triplet parts (rows, docs, values): their
+        im/if/io cells, then the positions of every list they touched,
+        placed where the store says so, else re-linearized."""
+        t_elem = time.perf_counter()
+        b, E = self._bases(), self.cap_elems
+        lid, io, lists, ok = self.lists.append(ins)
+        ins = ins.astype(np.int64)
+        le = ins[:, 1] * E + ins[:, 2]
+        parts = [(b[g] + le, ins[:, 0], v) for g, v in (
+            ("im", np.ones(len(ins), np.int64)), ("if", ins[:, 6]),
+            ("io", io))]
+        pos = []
+        if ok.any():
+            row_ok = ok[lid]
+            # the placed lists' indices among themselves
+            sub = np.cumsum(ok) - 1
+            pos.append(self.lists.placed(
+                self.rows_host, b["ip"], E, lists[ok], sub[lid[row_ok]],
+                ins[row_ok, 5]))
+        if not ok.all():
+            pos.append(self.lists.linearized(lists[~ok, :2], b["ip"], E))
+        n_placed = int(ok.sum())
+        metrics.bump("rows_elem_lists_placed", n_placed)
+        metrics.bump("rows_elem_lists_relinearized", len(ok) - n_placed)
+        metrics.bump("rows_elem_pos_rows_shipped",
+                     sum(len(prow) for _, prow, _ in pos))
+        metrics.observe("rows_elem_admit_seconds",
+                        time.perf_counter() - t_elem)
+        return parts + [(prow, pdoc, pval) for pdoc, prow, pval in pos]
 
     def _round_triplets(self, changes_by_doc) -> np.ndarray:
         """Encode one round into (P, 3) int32 scatter triplets
         (row, doc, value) and apply them to the host mirror."""
         b = self._bases()
-        I, E = self.cap_ops, self.cap_elems
+        I = self.cap_ops
         rows, docs, vals = [], [], []
+        ins = []
 
         def put(r, d, v):
             rows.append(r); docs.append(d); vals.append(int(v))
@@ -781,37 +683,19 @@ class ResidentRowsDocSet(ResidentDocSet):
                 row = delta.clocks[chg - c0]
                 for a in np.nonzero(row)[0]:
                     put(b["co"] + int(a) * I + s, i, row[a])
+            objects = self.tables[i].objects
             for (lrow, oi, objhash) in delta.new_lists:
-                self.list_hash[i][lrow] = objhash
-                self.list_obj[i][lrow] = oi
-            touched_lists = set()
-            for (lrow, slot, elem, arank, parent_slot, fid) in delta.ins:
-                entries = self.ins_log[i].setdefault(lrow, [])
-                s2i = self.ins_idx[i].setdefault(lrow, {})
-                parent = (s2i.get(parent_slot, parent_slot)
-                          if parent_slot >= 0 else -1)
-                s2i[slot] = len(entries)
-                entries.append((slot, elem, arank, parent))
-                hi = self.elem_hi[i]
-                hi[lrow] = max(hi.get(lrow, 0), elem)
-                le = lrow * E + slot
-                put(b["im"] + le, i, 1)
-                put(b["if"] + le, i, fid)
-                put(b["io"] + le, i, self.list_hash[i][lrow])
-                touched_lists.add(lrow)
-            # re-linearize touched lists; ship fresh position rows
-            if touched_lists:
-                _, prow, pval = self._linearized_pos_rows(
-                    (i, lrow) for lrow in touched_lists)
-                for r, v in zip(prow.tolist(), pval.tolist()):
-                    put(r, i, v)
+                self.lists.add(i, lrow, oi, objhash, objects[oi][0])
+            ins += [(i, *r) for r in delta.ins]
             self.op_count[i] += len(delta.ops)
             self.change_count[i] += len(delta.clocks)
 
-        trips = np.stack([np.asarray(rows, np.int32),
-                          np.asarray(docs, np.int32),
-                          np.asarray(vals, np.int32)], axis=1) \
-            if rows else np.zeros((0, 3), np.int32)
+        parts = [(np.asarray(rows, np.int64), np.asarray(docs, np.int64),
+                  np.asarray(vals, np.int64))]
+        if ins:
+            parts += self._elem_trips(np.asarray(ins, np.int64))
+        trips = np.stack([np.concatenate(c) for c in zip(*parts)],
+                         axis=1).astype(np.int32)
         # mirror update
         self.rows_host[trips[:, 0], trips[:, 1]] = trips[:, 2]
         return trips
@@ -1079,8 +963,6 @@ class ResidentRowsDocSet(ResidentDocSet):
         are pinned — the log legitimately inserts after elements whose
         tombstones are below the stored floor (they were ghosted only
         AFTER those inserts admitted in the original instance)."""
-        from ..core.ids import HEAD
-
         pos = {d: 0 for d in round_}
         while True:
             part = {d: chs[pos[d]:pos[d] + chunk]
@@ -1098,15 +980,8 @@ class ResidentRowsDocSet(ResidentDocSet):
                               else dict(
                                   fresh.tables[fresh.doc_index[d]].clock))
                           for d in fresh.doc_ids}
-                pins: dict[str, set] = {}
-                for d, chs in round_.items():
-                    tail = chs[pos[d]:]
-                    p = {op.key for c in tail for op in c.ops
-                         if op.action == "ins" and op.key
-                         and op.key != HEAD}
-                    if p:
-                        pins[d] = p
-                fresh.compact(floors, pins)
+                fresh.compact(floors, anchor_pins(
+                    {d: [chs[pos[d]:]] for d, chs in round_.items()}))
                 fresh.apply_rounds([part])
             for d, chs in part.items():
                 pos[d] += len(chs)
@@ -1246,19 +1121,11 @@ class ResidentRowsDocSet(ResidentDocSet):
     # ------------------------------------------------------------------
     # native columnar ingress
 
-    def check_ghost_anchors(self, anchors) -> None:
-        """Reject, BEFORE admission, an insert anchored at an element
-        compaction reclaimed (see CompactionAnchorError). `anchors`: the
-        (doc index, anchor key) pairs of the ingress's inserts, as Change
-        ops give them or as ins_anchors reads them out of wire columns."""
-        ghosts = self.ghost_eids
-        for i, key in anchors:
-            if key in ghosts[i]:
-                raise CompactionAnchorError(
-                    f"insert anchored at compacted element {key!r} in doc "
-                    f"{self.doc_ids[i]!r}; the sender is below the "
-                    f"compaction horizon — full resync required",
-                    doc_id=self.doc_ids[i])
+    def check_ingress_anchors(self, i: int, part, lo: int = 0,
+                              hi: int | None = None) -> None:
+        """ListStore.check: refuse an insert of document i anchored at a
+        ghost of its list, before admission (CompactionAnchorError)."""
+        self.lists.check(i, self.doc_ids[i], part, lo, hi)
 
     def _precheck_rows_budget_cols(self, rounds) -> None:
         """Upper-bound VMEM-budget check from the submitted columns plus the
@@ -1267,7 +1134,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         changes are counted as if applied; the exact post-encode check in
         _grow_for_rounds still runs."""
         from ..storage import _ACTION_IDX
-        ins_idx = _ACTION_IDX["ins"]
+        ins_code = _ACTION_IDX["ins"]
         list_idxs = (_ACTION_IDX["makeList"], _ACTION_IDX["makeText"])
 
         need_ops = self.op_count.copy()
@@ -1278,10 +1145,9 @@ class ResidentRowsDocSet(ResidentDocSet):
             o0, o1 = int(cols.op_off[j]), int(cols.op_off[j + 1])
             need_ops[i] += o1 - o0
             acts = np.asarray(cols.op_action[o0:o1])
-            n_elems[i] += int((acts == ins_idx).sum())
+            n_elems[i] += int((acts == ins_code).sum())
             n_lists[i] += int(np.isin(acts, list_idxs).sum())
-            if self.ghost_eids[i]:
-                self.check_ghost_anchors(ins_anchors(i, cols, o0, o1))
+            self.check_ingress_anchors(i, cols, o0, o1)
 
         for i, t in enumerate(self.tables):
             for p in t.queue:  # native instances queue (cols, j) payloads
@@ -1353,9 +1219,9 @@ class ResidentRowsDocSet(ResidentDocSet):
         if enc is None:
             return np.zeros((0, 3), np.int32)
         b = self._bases()
-        I, E = self.cap_ops, self.cap_elems
+        I = self.cap_ops
         bd = enc["bd"]
-        parts_r, parts_d, parts_v = [], [], []
+        parts = []          # (rows, docs, values)
 
         op = bd.op_rows.astype(np.int64)
         if len(op):
@@ -1368,9 +1234,7 @@ class ResidentRowsDocSet(ResidentDocSet):
                          ("fid", op[:, 2]), ("act", op[:, 3]),
                          ("seq", op[:, 4]), ("chg", op[:, 5]),
                          ("fh", op[:, 7]), ("vh", op[:, 8])):
-                parts_r.append(b[g] + slot)
-                parts_d.append(doc)
-                parts_v.append(v)
+                parts.append((b[g] + slot, doc, v))
             # per-op change-clock rows into the actor-major clock_op bands;
             # (doc, cidx) keys are ascending in both arrays, so the op ->
             # admitted-change join is one searchsorted
@@ -1379,93 +1243,26 @@ class ResidentRowsDocSet(ResidentDocSet):
             ai = np.searchsorted(key_adm, key_op)
             cmat = enc["clock_mat"][ai]                      # [k, A]
             oi, a = np.nonzero(cmat)
-            parts_r.append(b["co"] + a * I + slot[oi])
-            parts_d.append(doc[oi])
-            parts_v.append(cmat[oi, a])
+            parts.append((b["co"] + a * I + slot[oi], doc[oi], cmat[oi, a]))
             ids, cnts = np.unique(doc, return_counts=True)
             self.op_count[ids] += cnts
         ids, cnts = np.unique(enc["adm_doc"], return_counts=True)
         self.change_count[ids] += cnts
 
-        for (d, lrow, oi, objhash) in bd.newlist_rows:
-            self.list_hash[int(d)][int(lrow)] = int(objhash)
-            self.list_obj[int(d)][int(lrow)] = int(oi)
+        # a new list's object id: the new objects of list kinds, in the
+        # order the encoder made them (and their newlist rows)
+        oids = [name for (_d, name, kind) in bd.new_objects
+                if kind in (A_MAKE_LIST, A_MAKE_TEXT)]
+        for (d, lrow, oi, objhash), oid in zip(bd.newlist_rows.tolist(),
+                                               oids):
+            self.lists.add(d, lrow, oi, objhash, oid)
+        if len(bd.ins_rows):
+            parts += self._elem_trips(bd.ins_rows)
 
-        ins = bd.ins_rows
-        if len(ins):
-            t_elem = time.perf_counter()
-            # (doc, list row) -> [index, slotted entries before the round,
-            # inserts, placeable]: placeable while each insert is the
-            # list's newest element, in the next slot, anchored at the head
-            # or at a slotted entry (_placed_pos_rows)
-            touched = {}
-            lid = []
-            io = []
-            ins_log, ins_idx, list_hash, elem_hi = \
-                self.ins_log, self.ins_idx, self.list_hash, self.elem_hi
-            for (d, lrow, slot_, elem, arank, parent_slot, _fid) \
-                    in ins.tolist():
-                entries = ins_log[d].setdefault(lrow, [])
-                s2i = ins_idx[d].setdefault(lrow, {})
-                hi = elem_hi[d]
-                bound = hi.get(lrow, 0)
-                st = touched.get((d, lrow))
-                if st is None:
-                    st = touched[(d, lrow)] = [len(touched), slot_, 0, True]
-                st[2] += 1
-                if st[3] and not (elem > bound and slot_ == len(s2i)
-                                  and (parent_slot < 0 or parent_slot in s2i)
-                                  and st[2] <= PLACE_MAX):
-                    st[3] = False
-                hi[lrow] = max(bound, elem)
-                parent = (s2i.get(parent_slot, parent_slot)
-                          if parent_slot >= 0 else -1)
-                s2i[slot_] = len(entries)
-                entries.append((slot_, elem, arank, parent))
-                io.append(list_hash[d][lrow])
-                lid.append(st[0])
-            lid = np.asarray(lid, np.int64)
-            ins = ins.astype(np.int64)
-            le = ins[:, 1] * E + ins[:, 2]
-            for g, v in (("im", np.ones(len(ins), np.int64)),
-                         ("if", ins[:, 6]), ("io", np.asarray(io, np.int64))):
-                parts_r.append(b[g] + le)
-                parts_d.append(ins[:, 0])
-                parts_v.append(v)
-            lists = np.array([(d, lrow, n, ok) for (d, lrow), (_, n, _, ok)
-                              in touched.items()], np.int64)
-            ok = lists[:, 3].astype(bool)
-            n_pos = 0
-            if ok.any():
-                row_ok = ok[lid]
-                # the placed lists' indices among themselves
-                sub = np.cumsum(ok) - 1
-                pdoc, prow, pval = self._placed_pos_rows(
-                    lists[ok, 0], lists[ok, 1], lists[ok, 2],
-                    sub[lid[row_ok]], ins[row_ok, 5])
-                parts_r.append(prow)
-                parts_d.append(pdoc)
-                parts_v.append(pval)
-                n_pos += len(prow)
-            if not ok.all():
-                pdoc, prow, pval = self._linearized_pos_rows(
-                    lists[~ok, :2].tolist())
-                parts_r.append(prow)
-                parts_d.append(pdoc)
-                parts_v.append(pval)
-                n_pos += len(prow)
-            n_placed = int(ok.sum())
-            metrics.bump("rows_elem_lists_placed", n_placed)
-            metrics.bump("rows_elem_lists_relinearized", len(ok) - n_placed)
-            metrics.bump("rows_elem_pos_rows_shipped", n_pos)
-            metrics.observe("rows_elem_admit_seconds",
-                            time.perf_counter() - t_elem)
-
-        if not parts_r:
+        if not parts:
             return np.zeros((0, 3), np.int32)
-        trips = np.stack([np.concatenate(parts_r),
-                          np.concatenate(parts_d),
-                          np.concatenate(parts_v)], axis=1).astype(np.int32)
+        trips = np.stack([np.concatenate(c) for c in zip(*parts)],
+                         axis=1).astype(np.int32)
         self.rows_host[trips[:, 0], trips[:, 1]] = trips[:, 2]
         return trips
 
@@ -1681,7 +1478,7 @@ class ResidentRowsDocSet(ResidentDocSet):
         bound: duplicates count as applied). An insert of the round
         anchored at a compacted element raises CompactionAnchorError."""
         from ..storage import _ACTION_IDX
-        ins_idx = _ACTION_IDX["ins"]
+        ins_code = _ACTION_IDX["ins"]
         l1, l2 = _ACTION_IDX["makeList"], _ACTION_IDX["makeText"]
 
         need_ops = self.op_count.copy()
@@ -1694,9 +1491,9 @@ class ResidentRowsDocSet(ResidentDocSet):
                 o0, o1 = int(cols.op_off[j]), int(cols.op_off[j + 1])
                 need_ops[i] += o1 - o0
                 acts = np.asarray(cols.op_action[o0:o1])
-                n_elems[i] += int((acts == ins_idx).sum())
+                n_elems[i] += int((acts == ins_code).sum())
                 n_lists[i] += int(((acts == l1) | (acts == l2)).sum())
-        ghosts = self.ghost_eids
+        ghosted = self.lists.ghosted
         for rc in rounds:
             cols = rc.cols
             doc_idx = np.fromiter((self.doc_index[d] for d in rc.doc_ids),
@@ -1706,18 +1503,18 @@ class ResidentRowsDocSet(ResidentDocSet):
             ops_per_doc = op_off[off[1:]] - op_off[off[:-1]]
             np.add.at(need_ops, doc_idx, ops_per_doc)
             acts = np.asarray(cols.op_action)
-            is_ins = acts == ins_idx
+            is_ins = acts == ins_code
             is_list = (acts == l1) | (acts == l2)
             if is_ins.any() or is_list.any():
                 op_doc = np.repeat(doc_idx, ops_per_doc)
                 np.add.at(n_elems, op_doc, is_ins)
                 np.add.at(n_lists, op_doc, is_list)
-            if is_ins.any():
+            if ghosted and is_ins.any():
                 for k, i in enumerate(doc_idx.tolist()):
-                    if ghosts[i]:
-                        self.check_ghost_anchors(ins_anchors(
+                    if i in ghosted:
+                        self.check_ingress_anchors(
                             i, cols, int(op_off[off[k]]),
-                            int(op_off[off[k + 1]])))
+                            int(op_off[off[k + 1]]))
         return need_ops, n_elems, n_lists
 
     def _over_caps(self, need_ops, n_elems, n_lists,

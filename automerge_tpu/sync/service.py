@@ -33,10 +33,9 @@ import os
 from ..core.change import Change
 from ..engine import dispatchledger
 from ..engine.resident import ResidentDocSet
-from ..engine.resident_rows import (CompactionAnchorError,
-                                    DeviceDispatchError, RowsBudgetError,
-                                    ins_anchors)
-from ..native.wire import ChangesPart, changes_part, changes_to_columns
+from ..engine.lists import CompactionAnchorError, anchor_pins
+from ..engine.resident_rows import DeviceDispatchError, RowsBudgetError
+from ..native.wire import changes_part, changes_to_columns
 from ..utils import (chaos, flightrec, lockprof, metrics, oplag, perfscope,
                      tracer)
 from . import docledger, epochs, tenantledger
@@ -977,17 +976,11 @@ class EngineDocSet:
         rset = self._resident
         if doc_id not in rset.doc_index:
             self.add_doc(doc_id)
-        i = rset.doc_index[doc_id]
-        if rset.ghost_eids[i]:
-            # reject a ghost-anchored ingress HERE, before it coalesces:
-            # only the offending sender's call errors, never a round
-            # shared with innocent peers. A ChangesPart is read as the
-            # caller's ops and stays unconverted, for the round's one pass
-            rset.check_ghost_anchors(
-                ((i, op.key) for c in part.changes for op in c.ops
-                 if op.action == "ins")
-                if isinstance(part, ChangesPart)
-                else ins_anchors(i, part, 0, part.n_ops))
+        # reject a ghost-anchored ingress HERE, before it coalesces: only
+        # the offending sender's call errors, never a round shared with
+        # innocent peers. A ChangesPart is read as the caller's ops and
+        # stays unconverted, for the round's one pass
+        rset.check_ingress_anchors(rset.doc_index[doc_id], part)
         self._pending.setdefault(doc_id, []).append(part)
         tok = oplag.admit(doc_id)
         tracer.admit(doc_id)
@@ -1087,10 +1080,7 @@ class EngineDocSet:
             try:
                 self.add_doc(e.doc_id)
                 rset = self._resident
-                i = rset.doc_index[e.doc_id]
-                if rset.ghost_eids[i]:
-                    rset.check_ghost_anchors(
-                        ins_anchors(i, e.cols, 0, len(e.cols.op_action)))
+                rset.check_ingress_anchors(rset.doc_index[e.doc_id], e.cols)
             except BaseException as exc:
                 e.ticket.resolve(exc)
                 continue
@@ -1560,35 +1550,10 @@ class EngineDocSet:
             # under it wherever the engine calls this
             with self._lock:
                 return ({d: self._compaction_floor_locked(d) for d in docs},
-                        self._pending_anchor_pins(
+                        anchor_pins(
                             {d: pending[d] for d in docs if d in pending}))
 
         rset.dispatch_round_frames([round_], compactor=compactor)
-
-    @staticmethod
-    def _pending_anchor_pins(pending: dict) -> dict[str, set]:
-        """Anchor element ids the coalesced pending round inserts after:
-        compaction must not reclaim these — the round was generated before
-        its sender could have seen any tombstone-covering floor, so the
-        floor argument does not apply to it (it is already in flight)."""
-        import numpy as np
-
-        from ..core.ids import HEAD
-        from ..storage import _ACTION_IDX
-
-        pins: dict[str, set] = {}
-        for d, parts in pending.items():
-            p: set = set()
-            for part in parts:
-                cols = part.columns()
-                acts = np.asarray(cols.op_action)
-                for j in np.nonzero(acts == _ACTION_IDX["ins"])[0].tolist():
-                    k = int(cols.op_key[j])
-                    if k >= 0 and cols.keys[k] != HEAD:
-                        p.add(cols.keys[k])
-            if p:
-                pins[d] = p
-        return pins
 
     def flush(self) -> None:
         """Apply any coalesced ingress now (rows backend; no-op otherwise).

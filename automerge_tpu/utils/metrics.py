@@ -217,7 +217,7 @@ COUNTERS: dict[str, str] = {
     "rows_elem_lists_placed":
         "lists a round inserted into whose positions were placed against "
         "the mirror's: each insert the list's newest element, anchored at "
-        "the head or a slotted entry (resident_rows._placed_pos_rows)",
+        "the head or a slotted entry (lists.ListStore.placed)",
     "rows_elem_lists_relinearized":
         "lists a round inserted into that were re-linearized from their "
         "ins log (a concurrent or older insert, more than PLACE_MAX "
@@ -648,7 +648,7 @@ HISTOGRAMS: dict[str, str] = {
     "rows_elem_admit_seconds":
         "a round's element bookkeeping: the ins log, the element bands' "
         "triplets and the fresh positions of every list it inserted into "
-        "(resident_rows._cols_triplets), observed once a round that "
+        "(resident_rows._elem_trips), observed once a round that "
         "inserts, inside phase commit",
     "rows_compact_seconds":
         "a round's per-document compaction (resident_rows._compact_over: "

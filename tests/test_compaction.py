@@ -82,6 +82,32 @@ def test_admission_and_linearization_after_compaction():
     assert "".join(e.materialize("doc")["data"]["t"]) == "HI woXrld"
 
 
+def test_a_list_compacted_to_nothing_takes_inserts_again():
+    """Every element of a list deleted below the floor: compaction empties
+    its insert log, and the next inserts (at the head) admit into it, with
+    no rebuild from the log, and converge with an uncompacted replica."""
+    from automerge_tpu.utils import metrics
+    d = am.change(am.init("alice"), lambda x: x.__setitem__("t", am.Text()))
+    d = am.change(d, lambda x: x["t"].insert_at(0, *"hello world"))
+    d = am.change(d, lambda x: [x["t"].delete_at(0) for _ in range(11)])
+    e = EngineDocSet(backend="rows")
+    e.apply_changes("doc", changes_of(d))
+    rset = e._resident
+    i = rset.doc_index["doc"]
+    floor = dict(rset.tables[i].clock)
+    assert rset.compact({"doc": floor})["doc"]["elems_after"] == 0
+    assert len(rset.lists.log(i, 0)) == 0
+
+    rebuilt = metrics.snapshot().get("rows_log_rebuilt", 0)
+    d2 = am.change(d, lambda x: x["t"].insert_at(0, *"xy"))
+    e.apply_changes("doc", [c for c in changes_of(d2)
+                            if c.seq > floor.get(c.actor, 0)])
+    assert metrics.snapshot().get("rows_log_rebuilt", 0) == rebuilt
+    assert len(rset.lists.log(i, 0)) == 2
+    assert np.uint32(e.hashes()["doc"]) == oracle_hash(changes_of(d2))
+    assert "".join(e.materialize("doc")["data"]["t"]) == "xy"
+
+
 def test_concurrent_conflicts_survive_compaction():
     """Mutually-concurrent candidates (winner + conflicts) are visible
     state; both must survive and hash identically to a fresh replica."""
@@ -179,7 +205,7 @@ def test_pins_protect_pending_round_anchors():
     # slot (and its anchor chain), without it it would ghost
     pinned = "alice:3"
     stats = rset.compact({"doc": floor}, pins={"doc": {pinned}})["doc"]
-    assert pinned not in rset.ghost_eids[i]
+    assert pinned not in rset.lists.ghosts(i)
     assert stats["elems_after"] > 5   # the pin (and its chain) retained
     assert np.uint32(e.hashes()["doc"]) == oracle_hash(changes_of(d))
 
@@ -191,10 +217,10 @@ def test_anchor_at_compacted_element_rejected_preadmission():
     rset = e._resident
     i = rset.doc_index["doc"]
     rset.compact({"doc": dict(rset.tables[i].clock)})
-    assert rset.ghost_eids[i]
+    assert rset.lists.ghosts(i)
 
     # forge a nonconforming change anchored at a ghosted element
-    ghost = sorted(rset.ghost_eids[i])[0]
+    ghost = sorted(rset.lists.ghosts(i))[0]
     from automerge_tpu.core.change import Change, Op
     # the text object id, from the change that created it
     text_obj = changes_of(d)[1].ops[0].obj

@@ -404,7 +404,7 @@ def test_a_concurrent_insert_at_a_tombstone_above_the_floor_survives():
             floor = svc._compaction_floor_locked("doc")
             stats = rset.compact({"doc": floor})["doc"]
         assert stats["ops_after"] < stats["ops_before"]
-        assert f"{a}:3" not in rset.ghost_eids[rset.doc_index["doc"]]
+        assert f"{a}:3" not in rset.lists.ghosts(rset.doc_index["doc"], obj)
         svc.apply_changes("doc", [straggler])
         log = chs + later + [straggler]
         assert np.uint32(svc.hashes()["doc"]) == rb.state_hash(log)
@@ -429,10 +429,63 @@ def test_a_floor_past_the_deletion_rejects_the_straggler_loudly():
         rset = svc._resident
         with svc._lock:
             rset.compact({"doc": {a: 2, b: 1}})
-        assert f"{a}:3" in rset.ghost_eids[rset.doc_index["doc"]]
+        assert f"{a}:3" in rset.lists.ghosts(rset.doc_index["doc"], obj)
         with pytest.raises(CompactionAnchorError):
             svc.apply_changes("doc", [straggler])
         assert svc.materialize("doc") == rb.state(chs + later)
+    finally:
+        svc.close()
+
+
+def _two_lists():
+    """Lists `la` and `lb` of one document, each holding A's elements
+    `A:1`..`A:5` (a list's element counters are its own), and B's change
+    that saw them. Returns (changes, a, b)."""
+    a, b = "a" * 32, "b" * 32
+    ops = []
+    for obj, key in (("la", "a"), ("lb", "b")):
+        ops += [Op("makeList", obj), Op("link", ROOT, key=key, value=obj)]
+        prev = "_head"
+        for k in range(1, 6):
+            ops += [Op("ins", obj, key=prev, elem=k),
+                    Op("set", obj, key=f"{a}:{k}", value=f"{key}{k}")]
+            prev = f"{a}:{k}"
+    chs = [Change(a, 1, {}, ops),
+           Change(b, 1, {a: 1}, [Op("set", "la", key=f"{a}:1", value="w")])]
+    return chs, a, b
+
+
+def test_a_ghost_of_one_list_does_not_refuse_an_insert_into_another():
+    """A deletes list a's `A:3` and the list is compacted past the
+    deletion: `A:3` is a ghost of list a alone. A's insert anchored at
+    list b's `A:3`, which is visible, admits and lands where the RGA puts
+    it; B's straggler anchored at list a's ghost is still refused before
+    admission."""
+    chs, a, b = _two_lists()
+    later = [Change(a, 2, {b: 1}, [Op("del", "la", key=f"{a}:3")])]
+    into_b = Change(a, 3, {}, [Op("ins", "lb", key=f"{a}:3", elem=6),
+                               Op("set", "lb", key=f"{a}:6", value="s")])
+    straggler = Change(b, 2, {a: 1}, [Op("ins", "la", key=f"{a}:3", elem=6),
+                                      Op("set", "la", key=f"{b}:6",
+                                         value="t")])
+    svc = EngineDocSet(backend="rows")
+    try:
+        svc.apply_changes("doc", chs + later)
+        rset = svc._resident
+        i = rset.doc_index["doc"]
+        with svc._lock:
+            rset.compact({"doc": {a: 2, b: 1}})
+        svc.apply_changes("doc", [into_b])
+        assert f"{a}:3" in rset.lists.ghosts(i, "la")
+        assert f"{a}:3" not in rset.lists.ghosts(i, "lb")
+        log = chs + later + [into_b]
+        assert np.uint32(svc.hashes()["doc"]) == rb.state_hash(log)
+        assert svc.materialize("doc") == rb.state(log)
+        assert rb.state(log)["data"]["b"] == ["b1", "b2", "b3", "s", "b4",
+                                              "b5"]
+        with pytest.raises(CompactionAnchorError):
+            svc.apply_changes("doc", [straggler])
+        assert svc.materialize("doc") == rb.state(log)
     finally:
         svc.close()
 

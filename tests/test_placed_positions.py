@@ -1,10 +1,10 @@
 """List inserts placed against the host mirror's positions
-(ResidentRowsDocSet._placed_pos_rows) against the full re-linearization.
+(ListStore.placed, engine/lists.py) against the full re-linearization.
 
 A round's inserts that are each their list's newest element are placed
 after their anchors in the positions the mirror's `ip` band already holds;
 every other list is re-linearized from its ins log. After every round the
-`ip` band must equal, cell for cell, what `_linearized_pos_rows` derives
+`ip` band must equal, cell for cell, what `ListStore.linearized` derives
 from the ins logs of every list: over seeded multi-writer rounds (chains of
 inserts in one change, head inserts, two lists in one document, concurrent
 inserts at one anchor), across a compaction that leaves ghosts, and across
@@ -32,8 +32,9 @@ def counter(name: str) -> int:
 
 def assert_positions_are_the_linearization(rset):
     lists = [(i, lrow) for i in range(len(rset.doc_ids))
-             for lrow in rset.ins_log[i]]
-    docs, rows, pos = rset._linearized_pos_rows(lists)
+             for lrow in rset.lists.rows(i)]
+    docs, rows, pos = rset.lists.linearized(
+        lists, rset._bases()["ip"], rset.cap_elems)
     np.testing.assert_array_equal(rset.rows_host[rows, docs], pos)
 
 
@@ -126,15 +127,15 @@ def test_the_mirror_positions_are_the_full_linearization(seed):
     list, each round a change or two to each: mostly by a writer that has
     seen everything, now and then by two that have not seen each other's
     last edits (concurrent inserts, equal and lower counters at one
-    anchor). The one-list document is compacted to its whole clock,
-    leaving ghosts, and inserted into after; a writer whose name sorts
-    first joins both and permutes the ranks."""
+    anchor). Both documents are compacted to their whole clocks, leaving
+    ghosts (in both lists of the two-list document, whose writers' element
+    ids repeat from list to list), and inserted into after; a writer whose
+    name sorts first joins both and permutes the ranks."""
     rng = random.Random(1000 + seed)
     docs = {"two": Writers(rng, ["m" * 32, "t" * 32], lists=("a", "b")),
             "one": Writers(rng, ["m" * 32, "t" * 32], lists=("a",))}
     svc = EngineDocSet(backend="rows")
-    # caps the rounds never reach: no compaction but the one below (a
-    # document's ghosts are kept by element id alone, not by list)
+    # caps the rounds never reach: no compaction but the ones below
     svc._resident.reserve(ops_per_doc=512, elems_per_list=256,
                           lists_per_doc=2)
     placed0 = counter("rows_elem_lists_placed")
@@ -145,15 +146,17 @@ def test_the_mirror_positions_are_the_full_linearization(seed):
                 for w in docs.values():
                     w.join("c" * 32)     # sorts before both: ranks move
             if r == 20:
-                docs["one"].sync()
+                for w in docs.values():
+                    w.sync()
                 _round(svc, docs)
                 rset = svc._resident
-                i = rset.doc_index["one"]
-                stats = rset.compact(
-                    {"one": dict(rset.tables[i].clock)})["one"]
-                assert stats["elems_after"] < stats["elems_before"]
-                assert any(s < 0 for e in rset.ins_log[i].values()
-                           for (s, _, _, _) in e)
+                for doc in docs:
+                    i = rset.doc_index[doc]
+                    stats = rset.compact(
+                        {doc: dict(rset.tables[i].clock)})[doc]
+                    assert stats["elems_after"] < stats["elems_before"]
+                    assert any((rset.lists.log(i, lrow)[:, 0] < 0).any()
+                               for lrow in rset.lists.rows(i))
                 assert_positions_are_the_linearization(rset)
             for w in docs.values():
                 _edit(rng, w)

@@ -20,6 +20,7 @@ import pytest
 import automerge_tpu as am
 from automerge_tpu.core.change import Change, Op
 from automerge_tpu.core.ids import ROOT_ID
+from automerge_tpu.engine.lists import anchor_pins
 from automerge_tpu.engine.resident_rows import (
     CompactionAnchorError, DeviceDispatchError, RowsBudgetError)
 from automerge_tpu.native.wire import (
@@ -388,10 +389,10 @@ def compacted_text():
     rset = svc._resident
     i = rset.doc_index["doc"]
     rset.compact({"doc": dict(rset.tables[i].clock)})
-    assert rset.ghost_eids[i]
+    assert rset.lists.ghosts(i)
     text_obj = changes_of(d)[1].ops[0].obj
     bad = Change("alice", len(changes_of(d)) + 1, {}, [
-        Op("ins", text_obj, key=sorted(rset.ghost_eids[i])[0], elem=999)])
+        Op("ins", text_obj, key=sorted(rset.lists.ghosts(i))[0], elem=999)])
     return svc, d, bad
 
 
@@ -529,7 +530,7 @@ def test_anchor_pins_of_unconverted_parts_under_a_budget_error():
     d = am.change(d, lambda x: x["t"].insert_at(2, "X"))
     d = am.change(d, lambda x: x["t"].insert_at(5, "Y", "Z"))
     new = changes_of(d)[base:]
-    want = EngineDocSet._pending_anchor_pins(
+    want = anchor_pins(
         {"doc": [changes_to_columns([c]) for c in new]})
     assert want["doc"]              # the inserts anchor at real elements
 
@@ -561,7 +562,7 @@ def test_anchor_pins_of_unconverted_parts_under_a_budget_error():
         assert type(svc._pending["doc"][0]) is ChangesPart
     assert state["over"] == 1 and state["pins"] == want
     assert state["dispatched"] == 1
-    assert not (want["doc"] & rset.ghost_eids[rset.doc_index["doc"]])
+    assert not (want["doc"] & rset.lists.ghosts(rset.doc_index["doc"]))
     assert np.uint32(svc.hashes()["doc"]) == oracle_hash(changes_of(d))
 
 
